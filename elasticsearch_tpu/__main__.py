@@ -13,6 +13,34 @@ import sys
 import threading
 
 
+def start_node(host: str = "127.0.0.1", port: int = 9200, data=None,
+               name: str = "node-0",
+               cluster_name: str = "elasticsearch-tpu"):
+    """Wire one node to its HTTP frontend and start serving; port 0 binds
+    a free port (read it back from server.port). Returns (node, server) —
+    the caller owns shutdown (server.stop(), node.close())."""
+    from elasticsearch_tpu.common.compile_cache import configure_compile_cache
+    from elasticsearch_tpu.common.settings import Settings
+    from elasticsearch_tpu.node import Node
+    from elasticsearch_tpu.rest import HttpServer, RestController, register_handlers
+
+    configure_compile_cache()
+    node = Node(Settings({"cluster.name": cluster_name}),
+                data_path=data, node_name=name)
+    rc = RestController()
+    register_handlers(node, rc)
+    from elasticsearch_tpu.plugins import load_plugins
+
+    loaded = load_plugins(node, rc)
+    if loaded:
+        print(f"[{name}] plugins loaded: {', '.join(loaded)}", flush=True)
+    server = HttpServer(rc, host=host, port=port,
+                        thread_pool=node.thread_pool)
+    server.start()
+    print(f"[{name}] started, http on {host}:{server.port}", flush=True)
+    return node, server
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="elasticsearch-tpu")
     ap.add_argument("--host", default="127.0.0.1")
@@ -22,23 +50,8 @@ def main(argv=None) -> int:
     ap.add_argument("--cluster-name", default="elasticsearch-tpu")
     args = ap.parse_args(argv)
 
-    from elasticsearch_tpu.common.settings import Settings
-    from elasticsearch_tpu.node import Node
-    from elasticsearch_tpu.rest import HttpServer, RestController, register_handlers
-
-    node = Node(Settings({"cluster.name": args.cluster_name}),
-                data_path=args.data, node_name=args.name)
-    rc = RestController()
-    register_handlers(node, rc)
-    from elasticsearch_tpu.plugins import load_plugins
-
-    loaded = load_plugins(node, rc)
-    if loaded:
-        print(f"[{args.name}] plugins loaded: {', '.join(loaded)}", flush=True)
-    server = HttpServer(rc, host=args.host, port=args.port,
-                        thread_pool=node.thread_pool)
-    server.start()
-    print(f"[{args.name}] started, http on {args.host}:{server.port}", flush=True)
+    node, server = start_node(args.host, args.port, data=args.data,
+                              name=args.name, cluster_name=args.cluster_name)
 
     stop = threading.Event()
     signal.signal(signal.SIGINT, lambda *a: stop.set())

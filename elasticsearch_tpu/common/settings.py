@@ -313,8 +313,8 @@ declare_knob("ES_TPU_TURBO_COLD_DF", "int", None,
              "Doc-frequency threshold below which terms stay cold "
              "(host-rescored); default: parallel/turbo.py COLD_DF")
 declare_knob("ES_TPU_TURBO_MESH", "int", None,
-             "Max devices for the fused multi-partition Turbo mesh "
-             "(default all visible; 0 disables fusion)")
+             "Max devices for the fused multi-partition mesh of the Turbo "
+             "and kNN engines (default all visible; 0 disables fusion)")
 declare_knob("ES_TPU_FORCE_TURBO", "flag", False,
              "'1' forces Turbo eligibility off-TPU (interpret-mode "
              "differential tests)")

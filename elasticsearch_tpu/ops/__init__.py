@@ -7,7 +7,7 @@ from elasticsearch_tpu.ops.scoring import (
     next_bucket,
     pad_block_ids,
 )
-from elasticsearch_tpu.ops.knn import knn_scores, knn_top_k
+from elasticsearch_tpu.ops.knn import bf16_operand, knn_scores, knn_top_k
 
 __all__ = [
     "BLOCK",
@@ -17,6 +17,7 @@ __all__ = [
     "masked_top_k",
     "next_bucket",
     "pad_block_ids",
+    "bf16_operand",
     "knn_scores",
     "knn_top_k",
 ]

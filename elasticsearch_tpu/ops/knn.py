@@ -28,6 +28,19 @@ import jax
 import jax.numpy as jnp
 
 
+def bf16_operand(x: jax.Array) -> jax.Array:
+    """A gemm operand rounded to bf16 for real. `astype` alone is not
+    enough on the TPU: for a single query XLA turns the gemm into an f32
+    multiply-reduce, and that program scored the UNROUNDED query on the
+    chip — up to 1.6e-4 (relative) from the same query in a Q >= 8 batch,
+    which takes the MXU (PERF.md, PR 22). `reduce_precision` is the
+    rounding the compiler may not drop; the cast after it is exact."""
+    if x.dtype == jnp.bfloat16:
+        return x
+    return jax.lax.reduce_precision(
+        x, exponent_bits=8, mantissa_bits=7).astype(jnp.bfloat16)
+
+
 @partial(jax.jit, static_argnames=("similarity",))
 def knn_scores(
     queries: jax.Array,       # [Q, dims] f32
@@ -38,8 +51,8 @@ def knn_scores(
     similarity: str = "cosine",
 ) -> jax.Array:
     """Dense [Q, n_docs] similarity scores; missing docs score -inf."""
-    v = vectors.astype(jnp.bfloat16)
-    q = queries.astype(jnp.bfloat16)
+    v = bf16_operand(vectors)
+    q = bf16_operand(queries)
     dots = jax.lax.dot_general(
         q, v,
         (((1,), (1,)), ((), ())),

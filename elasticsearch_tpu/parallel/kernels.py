@@ -55,8 +55,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from elasticsearch_tpu.parallel.compat import CompilerParams as _CompilerParams
-
 SW = 65536            # docs per superwindow (candidate granularity)
 TILE = 16384          # docs per build tile (outer-product target)
 SW_ROWS = SW // 128   # 512
@@ -194,7 +192,7 @@ def sweep_rowmax(qscale, cols_hi, cols_lo, wq, live, *, QC: int, nsw: int):
             jax.ShapeDtypeStruct((nsw, QC, CAND_PAD), jnp.float32),
             jax.ShapeDtypeStruct((nsw, QC, CAND_PAD), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_interpret(),
     )
@@ -322,7 +320,7 @@ def sweep_rowmax_conj(qscale, nreq, cols_hi, cols_lo, wq, wp, live,
             jax.ShapeDtypeStruct((nsw, QC, CAND_PAD), jnp.float32),
             jax.ShapeDtypeStruct((nsw, QC, CAND_PAD), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_interpret(),
     )
@@ -433,7 +431,7 @@ def intersect_bitset(q_slots, q_neg, bits, *, QC: int, nsw: int):
         _intersect_kernel(),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((QC, wgr, 128), jnp.uint32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_interpret(),
     )
@@ -461,7 +459,7 @@ def _sweep_bitset_kernel(QC: int, Hpt: int):
         sw = pl.program_id(0)
 
         # expand this chunk's 16-bit half of the intersected word row
-        w = mask_blk[...][:, 0, :]                        # [QC, 128] u32
+        w = mask_blk[:, pl.ds(c // 2, 1), :][:, 0, :]     # [QC, 128] u32
         shifts = (jax.lax.broadcasted_iota(
             jnp.int32, (1, CHUNK_ROWS, 1), 1)
             + (c % 2) * CHUNK_ROWS).astype(jnp.uint32)
@@ -561,9 +559,11 @@ def sweep_rowmax_bitset(qscale, cols_hi, cols_lo, wq, mask, live,
                          lambda sw, c: (sw * N_CHUNKS + c, 0, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pltpu.VMEM),        # wq
-            pl.BlockSpec((QC, 1, 128),
-                         lambda sw, c: (0, sw * SW_WORD_ROWS + c // 2, 0),
-                         memory_space=pltpu.VMEM),        # mask word row
+            # the superwindow's 16 word rows (fetched once per sw; the
+            # TPU lowering refuses a 1-sublane block of a middle axis) —
+            # the kernel picks its chunk's row
+            pl.BlockSpec((QC, SW_WORD_ROWS, 128), lambda sw, c: (0, sw, 0),
+                         memory_space=pltpu.VMEM),
             pl.BlockSpec((CHUNK_ROWS, 128),
                          lambda sw, c: (sw * N_CHUNKS + c, 0),
                          memory_space=pltpu.VMEM),        # live chunk
@@ -585,7 +585,7 @@ def sweep_rowmax_bitset(qscale, cols_hi, cols_lo, wq, mask, live,
             jax.ShapeDtypeStruct((nsw, QC, CAND_PAD), jnp.float32),
             jax.ShapeDtypeStruct((nsw, QC, CAND_PAD), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_interpret(),
     )
@@ -661,7 +661,7 @@ def merge_topk(scores, ords, *, k: int):
             jax.ShapeDtypeStruct((QB, KP), jnp.int32),
             jax.ShapeDtypeStruct((QB, KP), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_interpret(),
     )
@@ -913,7 +913,7 @@ def sparse_gather(coff, cw, ct0, ct1, pool, *, n_tiles: int):
                 lambda t, rc, coff, cw, ct0, ct1: (t, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((n_tiles, 128, 128), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_interpret(),
     )(coff, cw, ct0, ct1, pool)
@@ -936,7 +936,7 @@ def sparse_gather(coff, cw, ct0, ct1, pool, *, n_tiles: int):
         ),
         out_shape=jax.ShapeDtypeStruct((n_rc, SPARSE_GRAN // 128, 128),
                                        jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_interpret(),
     )
@@ -1023,7 +1023,7 @@ def _agg_counts(mask, doc, seg, ct0, ct1, n_segments: int):
         ),
         out_shape=jax.ShapeDtypeStruct((Q, n_tiles, 128, 128),
                                        jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_interpret(),
     )(ct0, ct1,
@@ -1100,11 +1100,11 @@ def _knn_pass_kernel(similarity: str, masked: bool):
         dot = jax.lax.dot_general(
             qi8[...], q8_blk[0], dn,
             preferred_element_type=jnp.int32)              # [QC, KNN_W]
-        meta = meta_blk[...]                               # [4, 1, KNN_W]
-        scale = meta[0, 0][None, :]                        # per-row int8 step
-        row_l1 = meta[1, 0][None, :]                       # dequantized L1
-        nrm = meta[2, 0][None, :]                          # stored-row L2
-        okf = meta[3, 0][None, :]                          # exists & live
+        meta = meta_blk[0]                                 # [4, KNN_W]
+        scale = meta[0:1, :]                               # per-row int8 step
+        row_l1 = meta[1:2, :]                              # dequantized L1
+        nrm = meta[2:3, :]                                 # stored-row L2
+        okf = meta[3:4, :]                                 # exists & live
         qm = qmeta[...]                                    # [QC, 8]
         sq = qm[:, 0:1]
         est = dot.astype(jnp.float32) * (scale * sq)
@@ -1122,9 +1122,10 @@ def _knn_pass_kernel(similarity: str, masked: bool):
         else:   # l2_norm: larger dot -> smaller distance -> larger score
             d2 = jnp.maximum(qm[:, 3:4] + nrm * nrm - 2.0 * dot_best, 0.0)
             opt = 1.0 / (1.0 + jnp.sqrt(d2))
-        ok = (okf > 0) & (act_blk[...] > 0)
+        ok = (okf > 0) & (act_blk[0] > 0)                  # act [QC, 1]
         if masked:
-            ok = ok & (fmask_blk[:, 0, :] > 0)
+            # widened first: the v5e VPU has no int8 compare
+            ok = ok & (fmask_blk[0].astype(jnp.int32) > 0)
         opt = jnp.where(ok, opt, -jnp.inf)
         QC = opt.shape[0]
         cols = jax.lax.broadcasted_iota(jnp.int32, (QC, KNN_W), 1)
@@ -1167,12 +1168,16 @@ def knn_int8_window_topc(qi8, qmeta, q8, meta, act, fmask=None, *,
           4 1/max(|q|_2, 1e-20), 5 sq/2; rest zero
     q8    [nw, dimsP, KNN_W] i8 — window-major stored rows (transposed:
           dims on sublanes, docs on lanes — the MXU contraction layout)
-    meta  [4, nw, KNN_W] f32 — rows (scale, row_l1, nrm, okf); dead pad
+    meta  [nw, 4, KNN_W] f32 — rows (scale, row_l1, nrm, okf); dead pad
           docs carry okf 0 and never surface
-    act   [QC, nw] f32 — per-query window activity (IVF probe; all-ones
-          when nprobe = 0)
-    fmask [QC, nw, KNN_W] i8 or None — per-query doc filter in STORED
+    act   [nw, QC, 1] f32 — per-query window activity (IVF probe;
+          all-ones when nprobe = 0)
+    fmask [nw, QC, KNN_W] i8 or None — per-query doc filter in STORED
           row order (serving candidate masks / live deletes)
+
+    The per-window operands are window-major because the TPU lowering
+    wants a block's last two dims (8k, 128m) or the array's own, which a
+    1-wide slice of a middle window axis is not.
 
     Returns (scores [nw, QC, KNN_CANDW] f32, rows [nw, QC, KNN_CANDW]
     i32) — rows are global stored-row ids (w * KNN_W + lane); empty
@@ -1186,14 +1191,14 @@ def knn_int8_window_topc(qi8, qmeta, q8, meta, act, fmask=None, *,
         pl.BlockSpec(memory_space=pltpu.VMEM),             # qmeta
         pl.BlockSpec((1, dimsP, KNN_W), lambda w: (w, 0, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((4, 1, KNN_W), lambda w: (0, w, 0),
+        pl.BlockSpec((1, 4, KNN_W), lambda w: (w, 0, 0),
                      memory_space=pltpu.VMEM),
-        pl.BlockSpec((QC, 1), lambda w: (0, w),
+        pl.BlockSpec((1, QC, 1), lambda w: (w, 0, 0),
                      memory_space=pltpu.VMEM),             # act column
     ]
     args = [qi8, qmeta, q8, meta, act]
     if fmask is not None:
-        in_specs.append(pl.BlockSpec((QC, 1, KNN_W), lambda w: (0, w, 0),
+        in_specs.append(pl.BlockSpec((1, QC, KNN_W), lambda w: (w, 0, 0),
                                      memory_space=pltpu.VMEM))
         args.append(fmask)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1214,7 +1219,7 @@ def knn_int8_window_topc(qi8, qmeta, q8, meta, act, fmask=None, *,
             jax.ShapeDtypeStruct((nw, QC, KNN_CANDW), jnp.float32),
             jax.ShapeDtypeStruct((nw, QC, KNN_CANDW), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_interpret(),
     )

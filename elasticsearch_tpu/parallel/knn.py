@@ -20,10 +20,12 @@ spmd.sharded_knn_topk) into a first-class serving engine able to hold
   * **Exact f32 rescore, bit-identical.** Survivors (C = k *
     ES_TPU_KNN_RESCORE_MULT per query) are gathered ON HOST from the
     partition's stored f32 rows, uploaded, and rescored in ONE 2D bf16
-    gemm — gathering rows commutes with the bf16 cast, and a 2D gemm
-    over gathered rows reproduces the corresponding columns of the full
-    dense matmul bitwise (a batched dot_general does NOT, which is why
-    all queries' candidates flatten into one [Q*C, dims] matrix). The
+    gemm by ops.knn.knn_scores, the dense route's own scorer — gathering
+    rows commutes with the bf16 cast, and a 2D gemm over gathered rows
+    reproduces the corresponding columns of the full dense matmul to the
+    last ulp of the backend's gemm tiling (a batched dot_general does
+    NOT, which is why all queries' candidates flatten into one
+    [Q*C, dims] matrix). The
     exact k-th score is then compared against the exclusion bound
     u_excl = max(optimistic score of the first dropped candidate, the
     per-window truncation tails): strictly above it, the top-k is
@@ -73,6 +75,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from elasticsearch_tpu.common import faults, hbm_ledger, integrity, metrics
@@ -80,7 +83,6 @@ from elasticsearch_tpu.common.faults import DeviceFaultError, FaultRecord
 from elasticsearch_tpu.common.health import EngineHealth
 from elasticsearch_tpu.common.settings import knob
 from elasticsearch_tpu.ops.knn import knn_scores
-from elasticsearch_tpu.parallel.compat import shard_map
 from elasticsearch_tpu.parallel.kernels import (
     KNN_CANDW, KNN_W, knn_int8_window_topc,
 )
@@ -175,7 +177,7 @@ def _part_body(qf, qi8, qmeta, q8, meta, cent, cvalid, overlap, fmask,
     QC = qf.shape[0]
     nw = q8.shape[0]
     if nprobe <= 0:
-        act = jnp.ones((QC, nw), jnp.float32)
+        act = jnp.ones((nw, QC), jnp.float32)
         frac = jnp.ones((QC,), jnp.float32)
     else:
         dims = qf.shape[1]
@@ -194,17 +196,17 @@ def _part_body(qf, qi8, qmeta, q8, meta, cent, cvalid, overlap, fmask,
         thr = jax.lax.top_k(cs, npb)[0][:, -1:]
         probed = ((cs >= thr) & (cvalid[None, :] > 0)).astype(jnp.float32)
         hit = jax.lax.dot_general(
-            probed, overlap, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [QC, nw]
+            overlap, probed, (((0,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [nw, QC]
         act = (hit > 0).astype(jnp.float32)
-        livew = (jnp.max(overlap, axis=0) > 0).astype(jnp.float32)[None, :]
-        frac = (jnp.sum(act * livew, axis=1)
-                / jnp.maximum(jnp.sum(livew, axis=1), 1.0))
+        livew = (jnp.max(overlap, axis=0) > 0).astype(jnp.float32)[:, None]
+        frac = (jnp.sum(act * livew, axis=0)
+                / jnp.maximum(jnp.sum(livew), 1.0))
     # trace-time call: _part_body only ever runs inside the jit-decorated
     # _pass1_solo/_pass1_fused programs, dispatched under device_dispatch
     # ("knn_score") at the call sites below
     out_s, out_r = knn_int8_window_topc(  # tpulint: disable=TPU001
-        qi8, qmeta, q8, meta, act, fmask, similarity=similarity)
+        qi8, qmeta, q8, meta, act[:, :, None], fmask, similarity=similarity)
     fs = jnp.transpose(out_s, (1, 0, 2)).reshape(QC, nw * KNN_CANDW)
     fr = jnp.transpose(out_r, (1, 0, 2)).reshape(QC, nw * KNN_CANDW)
     # 2-key sort = (optimistic desc, stored row asc); -inf empties sink
@@ -266,31 +268,19 @@ def _rescore_program(qf, rows, nrmg, okg, ordg, u_excl, *,
                      similarity: str, C: int, k: int):
     """Exact rescore of the gathered candidate rows + the certificate.
 
-    ONE 2D bf16 gemm over the flattened [Q*C, dims] candidate matrix —
-    per-query batching would change f32 accumulation order and break
-    bit-identity with the dense reference — then each query extracts its
-    own C columns. The similarity transforms repeat ops.knn.knn_scores
-    verbatim on the same f32 inputs, so every surviving score is the
-    reference score bit-for-bit."""
+    The flattened [Q*C, dims] candidate matrix goes through
+    ops.knn.knn_scores itself — the dense reference route's scorer, ONE
+    2D bf16 gemm (per-query batching would change f32 accumulation
+    order) with the similarity transform in the same place — and each
+    query then extracts its own C columns, so a surviving score is the
+    reference score on any backend (to the gemm tiling's last ulp)."""
     Q = qf.shape[0]
-    vb = rows.astype(jnp.bfloat16)
-    qb = qf.astype(jnp.bfloat16)
-    dots_all = jax.lax.dot_general(
-        qb, vb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                # [Q, Q*C]
+    sc_all = knn_scores(qf, rows, nrmg.reshape(-1),
+                        jnp.ones((Q * C,), bool),
+                        similarity=similarity)             # [Q, Q*C]
     idx = (jnp.arange(Q, dtype=jnp.int32)[:, None] * C
            + jnp.arange(C, dtype=jnp.int32)[None, :])
-    dots = jnp.take_along_axis(dots_all, idx, axis=1)      # [Q, C]
-    if similarity == "cosine":
-        # rows are unit vectors (upload-time normalization)
-        qn = jnp.linalg.norm(qf, axis=-1, keepdims=True)
-        sc = (1.0 + dots / jnp.maximum(qn, 1e-20)) / 2.0
-    elif similarity == "dot_product":
-        sc = (1.0 + dots) / 2.0
-    else:   # l2_norm
-        qq = jnp.sum(qf * qf, axis=-1, keepdims=True)
-        d2 = jnp.maximum(qq + nrmg * nrmg - 2.0 * dots, 0.0)
-        sc = 1.0 / (1.0 + jnp.sqrt(d2))
+    sc = jnp.take_along_axis(sc_all, idx, axis=1)          # [Q, C]
     sc = jnp.where(okg, sc, -jnp.inf)
     ns, no = jax.lax.sort((-sc, ordg), num_keys=2)
     top_s = -ns[:, :k]
@@ -412,7 +402,9 @@ class KnnEngine:
         self.NCp = -(-max(len(c) for c, _, _ in preps) // 8) * 8
         DPg = self.nw * KNN_W
         q8h = np.zeros((self.Sp, self.nw, self.dimsP, KNN_W), np.int8)
-        metah = np.zeros((self.Sp, 4, self.nw, KNN_W), np.float32)
+        # window-major [nw, 4, KNN_W] per partition: the kernel fetches one
+        # window's four meta rows as a block
+        metah = np.zeros((self.Sp, self.nw, 4, KNN_W), np.float32)
         centh = np.zeros((self.Sp, self.NCp, self.dimsP), np.float32)
         cvalh = np.zeros((self.Sp, self.NCp), np.float32)
         ovh = np.zeros((self.Sp, self.NCp, self.nw), np.float32)
@@ -441,10 +433,10 @@ class KnnEngine:
             for w in range(-(-n // KNN_W)):
                 lo, hi = w * KNN_W, min((w + 1) * KNN_W, n)
                 q8h[i, w, :self.dims, :hi - lo] = vi8[lo:hi].T
-                metah[i, 0, w, :hi - lo] = s_r[lo:hi].astype(np.float32)
-                metah[i, 1, w, :hi - lo] = row_l1[lo:hi].astype(np.float32)
-                metah[i, 2, w, :hi - lo] = nrm[lo:hi]
-                metah[i, 3, w, :hi - lo] = okf[lo:hi]
+                metah[i, w, 0, :hi - lo] = s_r[lo:hi].astype(np.float32)
+                metah[i, w, 1, :hi - lo] = row_l1[lo:hi].astype(np.float32)
+                metah[i, w, 2, :hi - lo] = nrm[lo:hi]
+                metah[i, w, 3, :hi - lo] = okf[lo:hi]
         self._q8_host = q8h
         self._meta_host = metah
         self._cent_host = centh
@@ -545,9 +537,9 @@ class KnnEngine:
         if n:
             okf[:n] = ok[self._perm[i][:n]].astype(np.float32)
         okw = okf.reshape(self.nw, KNN_W)
-        self._meta_host[i, 3] = okw
+        self._meta_host[i, :, 3] = okw
         with faults.device_dispatch("column_upload", part=i):
-            upd = self.d_meta.at[i, 3].set(jnp.asarray(okw))
+            upd = self.d_meta.at[i, :, 3].set(jnp.asarray(okw))
             if self._fused:
                 upd = jax.device_put(upd, self._sharding)
             self.d_meta = upd
@@ -617,18 +609,20 @@ class KnnEngine:
         return qi8, qm
 
     def _filter_mask(self, i: int, chunk, QC: int) -> np.ndarray:
-        """Per-query doc filters permuted to STORED row order, [QC, nw,
-        KNN_W] i8. Pad rows may alias doc 0 through the pad permutation
-        entries — the kernel's okf gate keeps them dead regardless."""
+        """Per-query doc filters permuted to STORED row order, window-
+        major [nw, QC, KNN_W] i8 (the kernel's block layout). Pad rows may
+        alias doc 0 through the pad permutation entries — the kernel's okf
+        gate keeps them dead regardless."""
         n = self.n_docs[i]
-        fm = np.ones((QC, self.nw * KNN_W), np.int8)
+        fm = np.ones((self.nw, QC, KNN_W), np.int8)
         perm_c = np.minimum(self._perm[i], max(n - 1, 0))
         for j, wk in enumerate(chunk):
             flt = wk.filters[i] if wk.filters is not None else None
             if flt is None or n == 0:
                 continue
-            fm[j] = np.asarray(flt, bool)[perm_c].astype(np.int8)
-        return fm.reshape(QC, self.nw, KNN_W)
+            fm[:, j, :] = np.asarray(flt, bool)[perm_c].reshape(
+                self.nw, KNN_W)
+        return fm
 
     def _dense_chunk(self, i: int, qf: np.ndarray, chunk, QC: int, k: int):
         """The f32 brute-force route for one partition (solo dispatch)."""
@@ -692,7 +686,7 @@ class KnnEngine:
         if self._fused:
             fmasks = None
             if masked:
-                fmasks = np.zeros((self.Sp, QC, self.nw, KNN_W), np.int8)
+                fmasks = np.zeros((self.Sp, self.nw, QC, KNN_W), np.int8)
                 for i in range(S):
                     fmasks[i] = self._filter_mask(i, chunk, QC)
                 fmasks = jnp.asarray(fmasks)

@@ -1918,6 +1918,7 @@ class _Handlers:
     def _msearch_inner(self, req: RestRequest) -> RestResponse:
         lines = [ln for ln in req.raw_body.decode().split("\n") if ln.strip()]
         slots = []   # (index_names | None, body, error | None)
+        search_types = []   # per slot: header line's, else the URL's
         ccs_exprs: dict = {}   # slot -> `remote:pattern` expression (PR 20)
         i = 0
         while i + 1 <= len(lines) - 1 or (i < len(lines)):
@@ -1925,6 +1926,8 @@ class _Handlers:
             body = json.loads(lines[i + 1]) if i + 1 < len(lines) else {}
             i += 2
             index = header.get("index", req.param("index", "_all"))
+            search_types.append(header.get(
+                "search_type", req.param("search_type", "query_then_fetch")))
             # a `remote:index` line fans out per cluster instead of
             # resolving locally — a line targeting only dead
             # skip_unavailable remotes must come back empty-but-well-formed
@@ -1942,11 +1945,13 @@ class _Handlers:
         by_index: dict = {}
         for si, (names, body, err) in enumerate(slots):
             if err is None and names is not None and len(names) == 1:
-                by_index.setdefault(names[0], []).append(si)
+                by_index.setdefault((names[0], search_types[si]),
+                                    []).append(si)
         batched: dict = {}
-        for name, idxs in by_index.items():
+        for (name, search_type), idxs in by_index.items():
             try:
-                rs = self.node.indices.get(name).msearch([slots[i][1] for i in idxs])
+                rs = self.node.indices.get(name).msearch(
+                    [slots[i][1] for i in idxs], search_type)
                 for si, r in zip(idxs, rs):
                     if isinstance(r, ElasticsearchTpuError):
                         batched[si] = {"error": r.to_dict(), "status": r.status}

@@ -518,12 +518,13 @@ def fastpath_profile_nodes(request, eng, dur_ms: float,
 
 
 def _turbo_mesh(n_partitions: int):
-    """Mesh for the fused multi-partition Turbo path: partitions spread
-    data-parallel over the 'shard' axis of a dp=1 mesh covering up to
-    ES_TPU_TURBO_MESH devices (default: all visible; more devices than
-    partitions are left idle). None disables fusion entirely — for S < 2
-    there is nothing to fuse, and ES_TPU_TURBO_MESH=0 is the explicit
-    escape hatch back to the sequential + host-_merge3 path."""
+    """Mesh for the fused multi-partition paths (ShardedTurbo and the
+    kNN engine): partitions spread data-parallel over the 'shard' axis of
+    a dp=1 mesh covering up to ES_TPU_TURBO_MESH devices (default: all
+    visible; more devices than partitions are left idle). None disables
+    fusion entirely — for S < 2 there is nothing to fuse, and
+    ES_TPU_TURBO_MESH=0 is the explicit escape hatch back to the
+    sequential per-partition path + host merge, for both engines."""
     if n_partitions < 2:
         return None
     import jax
@@ -1045,8 +1046,11 @@ class ServingSnapshot:
                 cols[i] = VectorColumn(
                     np.zeros((n, dims), np.float32), np.zeros(n, np.float32),
                     np.zeros(n, bool), dims, sim)
+        # same mesh policy (and knob, ES_TPU_TURBO_MESH) as the fused Turbo
+        # path: partitions spread over the visible devices — the snapshot's
+        # own mesh is one device wide
         return KnnEngine(cols, lives=[p.live for p in self.partitions],
-                         mesh=self.mesh if len(cols) > 1 else None)
+                         mesh=_turbo_mesh(len(cols)))
 
 
 # --------------------------------------------------------------------------

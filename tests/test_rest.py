@@ -148,6 +148,30 @@ def test_msearch(api):
     assert rs[2]["status"] == 404
 
 
+@pytest.mark.parametrize("where", ["url", "header"])
+def test_msearch_passes_search_type_on(api, where):
+    """A multi-shard index scores with global stats only under dfs; the
+    search_type of the URL or of a header line must reach the index."""
+    call, node = api
+    call("PUT", "/two", {"settings": {"number_of_shards": 2}})
+    seen = []
+    svc = node.indices.get("two")
+    real = svc.msearch
+    svc.msearch = lambda reqs, st="query_then_fetch": (
+        seen.append(st), real(reqs, st))[1]
+    header = {"index": "two"}
+    params = {}
+    if where == "url":
+        params["search_type"] = "dfs_query_then_fetch"
+    else:
+        header["search_type"] = "dfs_query_then_fetch"
+    body = json.dumps(header) + "\n" + json.dumps(
+        {"query": {"match_all": {}}}) + "\n"
+    r = call("POST", "/_msearch", body, params=params)
+    assert r.body["responses"][0]["status"] == 200
+    assert seen == ["dfs_query_then_fetch"]
+
+
 def test_multi_index_and_wildcard_search(api):
     call, _ = api
     call("PUT", "/logs-1/_doc/1", {"msg": "error one"}, params={"refresh": "true"})

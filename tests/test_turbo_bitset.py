@@ -165,6 +165,31 @@ def test_bitset_fused_bit_identical(monkeypatch):
                                + st.hbm_bytes())
 
 
+def test_bitset_fused_after_disjunctive_traffic(monkeypatch):
+    """The order a served index sees: match traffic builds and syncs the
+    columns first, the first bool packs the bitsets later. The fused
+    stack must pick the bitsets up although its columns are in sync
+    (it used to skip them and raise TypeError on `bits[i]` — which the
+    serving layer contained by answering every bool from the dense
+    executor)."""
+    monkeypatch.setenv("ES_TPU_BITSET", "1")
+    monkeypatch.setenv("ES_TPU_BITSET_HOST_DF", "0")
+    eng = _fused([(1500, _pcorpus(1500, 40, 1)),
+                  (900, _pcorpus(900, 56, 2))])
+    st = eng._fused()
+    terms = sorted({t for spec in SPECS
+                    for t, _ in spec.get("must", []) + spec.get("should", [])}
+                   | {t for spec in SPECS
+                      for t in spec.get("filter", []) + spec.get("must_not", [])})
+    st.search_many([[[(t, 1.0)] for t in terms]], k=K)
+    assert st.bits is None
+    per = st.search_bool(SPECS, k=K)
+    for si, t in enumerate(st.turbos):
+        _assert_identical(per[si], t.search_bool_host(SPECS, k=K),
+                          f"fused partition {si} vs host")
+    assert st.bits is not None
+
+
 def test_bitset_gallop_host_fallback(monkeypatch):
     """A threshold above every df routes every bool query to the
     galloping host intersection — same bits, counter moves."""
