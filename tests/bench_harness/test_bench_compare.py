@@ -1,0 +1,186 @@
+"""The comparison that decides `correct`, at tiny size on the CPU, with no
+node: the plain reference answers the cell's own requests; a served side
+built from the reference itself passes, and one computed one precision
+step below what the configuration states (the control) has to fail."""
+
+import json
+
+import numpy as np
+import pytest
+
+from benchmark import compare, datagen, run
+from benchmark.manifest import Manifest
+from benchmark.reference import (TOTAL_CAP, BM25Reference, KnnReference,
+                                 bf16_round, top_hits)
+from benchmark.traffic import Mix
+
+import bench_tiny
+
+CELLS = {"msmarco-passage-bm25": "msmarco-bm25.search-open",
+         "msmarco-passage-knn": "msmarco-knn.search-open"}
+
+
+@pytest.fixture(scope="module")
+def manifest(tmp_path_factory):
+    return bench_tiny.tiny_manifest(str(tmp_path_factory.mktemp("tiny")))
+
+
+def _stats(moved=0, host=0):
+    s = {}
+    for path in compare.ZERO_COUNTERS:
+        sec, key = path.split(".")
+        s.setdefault(sec, {})[key] = 0
+    s["tpu_health"]["fastpath_reject_error"] = host
+    s["tpu_turbo"]["fused_dispatches"] = moved
+    s["tpu_knn"]["knn_int8_dispatches"] = moved
+    return s
+
+
+def _as_response(answer, k):
+    """What a server that computed `answer` would send."""
+    total = ({"value": TOTAL_CAP, "relation": "gte"}
+             if answer["total"] > TOTAL_CAP
+             else {"value": answer["total"], "relation": "eq"})
+    return {"timed_out": False, "_shards": {"failed": 0},
+            "hits": {"total": total, "hits": [
+                {"_id": str(int(o)), "_score": float(np.float32(s))}
+                for o, s in zip(answer["ords"][:k], answer["top"][:k])]}}
+
+
+def _served_and_reference(manifest, config, served_precision, n=32):
+    cell = manifest.cell(CELLS[config])
+    cfg = cell.config
+    seed = 1556403449
+    if cfg["kind"] == "text":
+        parts = datagen.make_text(cfg["corpus"], seed, 3)
+        mk = lambda p: BM25Reference(parts, 1.2, 0.75, precision=p)  # noqa: E731
+    else:
+        parts = datagen.make_vectors(cfg["corpus"], seed, 3)
+        mk = lambda p: KnnReference(parts, precision=p)  # noqa: E731
+    mix = Mix(cell.traffic, cfg, seed, parts)
+    reqs = [mix.request(i) for i in range(n)]
+    k = run.top_k(cell)
+
+    def answers(ref):
+        if cfg["kind"] == "text":
+            return [ref.answer(q, k) for q in reqs]
+        return ref.answers(reqs, k)
+
+    served = [_as_response(a, k) for a in answers(mk(served_precision))]
+    return cell, list(zip(served, answers(mk(None)))), k
+
+
+@pytest.mark.parametrize("config", sorted(CELLS))
+def test_reference_against_itself_is_correct(manifest, config):
+    cell, pairs, k = _served_and_reference(manifest, config, None)
+    checked = compare.verdict(pairs, cell.config["limits"], _stats(),
+                              _stats(), _stats(moved=5),
+                              cell.config["device_counter"], k)
+    assert compare.is_correct(checked), compare.lines(checked)
+    # the float32 the wire carries is all that separates the two sides
+    assert checked["score_err"]["value"] < 2e-7
+
+
+@pytest.mark.parametrize("config", sorted(CELLS))
+def test_lower_precision_served_side_is_not_correct(manifest, config):
+    cell, pairs, k = _served_and_reference(
+        manifest, config, manifest.cell(CELLS[config]).config[
+            "precision"]["control"])
+    checked = compare.verdict(pairs, cell.config["limits"], _stats(),
+                              _stats(), _stats(moved=5),
+                              cell.config["device_counter"], k)
+    assert not compare.is_correct(checked)
+    # by a wide margin, not by luck: the score gap alone is 10x its limit
+    assert checked["score_err"]["value"] > 10 * checked["score_err"]["limit"]
+
+
+def test_host_tier_answer_or_idle_device_is_not_correct(manifest):
+    cell, pairs, k = _served_and_reference(manifest, "msmarco-passage-bm25",
+                                           None)
+    lim, dc = cell.config["limits"], cell.config["device_counter"]
+    host = compare.verdict(pairs, lim, _stats(), _stats(),
+                           _stats(moved=5, host=1), dc, k)
+    assert not host["host_tier_answers"]["ok"]
+    idle = compare.verdict(pairs, lim, _stats(), _stats(moved=5),
+                           _stats(moved=5), dc, k)
+    assert not idle["device_dispatches"]["ok"]
+    few = compare.verdict(pairs[:3], lim, _stats(), _stats(),
+                          _stats(moved=5), dc, k)
+    assert not few["compared"]["ok"]
+    assert not compare.is_correct(host) and not compare.is_correct(idle)
+
+
+def test_an_altered_answer_is_caught(manifest):
+    cell, pairs, k = _served_and_reference(manifest, "msmarco-passage-bm25",
+                                           None)
+    lim, dc = cell.config["limits"], cell.config["device_counter"]
+
+    def verdict_with(change):
+        resp = json.loads(json.dumps(pairs[0][0]))
+        change(resp["hits"])
+        return compare.verdict([(resp, pairs[0][1])] + pairs[1:], lim,
+                               _stats(), _stats(), _stats(moved=1), dc, k)
+
+    def swap_id(h):       # a document that is not among the best
+        worst = int(np.argmin(pairs[0][1]["scores"]))
+        h["hits"][0]["_id"] = str(worst)
+
+    def drop_hit(h):
+        h["hits"].pop()
+
+    def wrong_total(h):
+        h["total"]["value"] += 1
+
+    def unsorted(h):
+        h["hits"][0], h["hits"][-1] = h["hits"][-1], h["hits"][0]
+
+    assert not verdict_with(swap_id)["rank_gap"]["ok"]
+    assert not verdict_with(drop_hit)["hits_wrong"]["ok"]
+    assert not verdict_with(wrong_total)["hits_wrong"]["ok"]
+    assert not verdict_with(unsorted)["order_err"]["ok"]
+
+
+def test_exact_ties_may_come_in_either_order():
+    scores = np.array([0.0, 2.0, 2.0, 1.0])
+    ords, top = top_hits(scores, 2)
+    assert list(ords) == [1, 2]
+    ref = {"scores": scores, "ords": ords, "top": top, "total": 3}
+    resp = {"hits": {"total": {"value": 3, "relation": "eq"}, "hits": [
+        {"_id": "2", "_score": 2.0}, {"_id": "1", "_score": 2.0}]}}
+    one = compare.compare_one(resp, ref, 2)
+    assert one == {"score_err": 0.0, "rank_gap": 0.0, "order_err": 0.0,
+                   "hits_wrong": 0}
+
+
+def test_bf16_round_is_round_to_nearest_even():
+    x = np.array([1.0, 1.0 + 2 ** -8, 1.0 + 3 * 2 ** -8, -3.1415927],
+                 np.float32)
+    got = bf16_round(x)
+    assert got[0] == 1.0 and got[1] == 1.0          # tie -> even mantissa
+    assert got[2] == np.float32(1.0 + 2 ** -6)      # tie -> even (up)
+    assert abs(got[3] + 3.140625) < 1e-6
+    assert np.all((got.view(np.uint32) & 0xFFFF) == 0)
+
+
+def test_dotted_takes_keys_that_hold_dots():
+    stats = {"tpu_search_latency": {"queue_wait.search": {"count": 3}},
+             "tpu_knn": {"knn_queries": 7}}
+    assert compare.dotted(stats, "tpu_knn.knn_queries") == 7
+    assert compare.dotted(
+        stats, "tpu_search_latency.queue_wait.search.count") == 3
+    with pytest.raises(KeyError):
+        compare.dotted(stats, "tpu_knn.nope")
+
+
+def test_well_formed_counts_errors_not_slowness():
+    ok = {"timed_out": False, "_shards": {"failed": 0},
+          "hits": {"total": {"value": 0, "relation": "eq"}, "hits": []}}
+    assert compare.well_formed(json.dumps(ok).encode(), 1) == [ok]
+    assert compare.well_formed(
+        json.dumps({"responses": [ok, ok]}).encode(), 2) == [ok, ok]
+    assert compare.well_formed(json.dumps({"responses": [ok]}).encode(),
+                               2) is None
+    assert compare.well_formed(b"{not json", 1) is None
+    assert compare.well_formed(json.dumps({"error": "x"}).encode(), 1) is None
+    bad = dict(ok, _shards={"failed": 1})
+    assert compare.well_formed(json.dumps(bad).encode(), 1) is None

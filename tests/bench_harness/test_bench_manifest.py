@@ -1,0 +1,176 @@
+"""BENCHMARK.json and the data files it names: the character rules, the
+files found by name, the chip-time limit, and a cell that exists only as
+new files."""
+
+import json
+import os
+import shutil
+
+import pytest
+
+from benchmark import readers, validate
+from benchmark.manifest import (ROOT, Manifest, ManifestError, NAME_RE,
+                                name_faults)
+
+import bench_tiny
+
+M = Manifest(ROOT)
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+def test_names_and_units_pass_the_character_rules():
+    assert name_faults(M.doc) == []
+
+
+def test_bad_names_and_units_are_found():
+    doc = json.loads(json.dumps(M.doc))
+    doc["end_to_end"][0]["unit"] = "tokens per second"
+    doc["per_layer"][0]["name"] = "late ms"
+    doc["workloads"][0]["traffic"] = "a/b"
+    faults = name_faults(doc)
+    assert len(faults) == 3
+
+
+@pytest.mark.parametrize("key", ["configs", "workloads", "end_to_end",
+                                 "per_layer"])
+def test_entries_have_just_the_contract_keys(key):
+    allowed = {
+        "configs": {"name", "source", "file", "reduced", "why"},
+        "workloads": {"name", "config", "traffic", "chips", "why"},
+        "end_to_end": {"name", "unit", "better", "bound", "source",
+                       "workloads"},
+        "per_layer": {"name", "unit", "better", "source", "layer", "moves",
+                      "workloads"},
+    }[key]
+    names = set()
+    for entry in M.doc[key]:
+        assert set(entry) <= allowed, entry
+        assert entry["name"] not in names
+        names.add(entry["name"])
+        for text in ("why", "source", "layer"):
+            if text in entry and key != "end_to_end" and text != "source":
+                assert 1 <= len(entry[text]) <= 200 and "\n" not in entry[text]
+    assert set(M.doc) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+
+
+def test_metric_entries_are_sound():
+    e2e = {m["name"]: m for m in M.doc["end_to_end"]}
+    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25
+    for m in M.doc["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    cells = set(M.cell_names())
+    for m in M.doc["per_layer"]:
+        assert m["source"] in SOURCES
+        assert m["moves"] in e2e
+        assert set(m.get("workloads", ())) <= cells
+        if "roofline" in m["name"]:
+            assert m["unit"] == "%"
+
+
+def test_every_cell_reports_setup_one_more_metric_and_a_layer_metric():
+    for cell in M.cell_names():
+        e2e = [m["name"] for m in M.declared(cell, 0)]
+        assert "setup_s" in e2e and len(e2e) >= 2
+        per = M.declared(cell, 1)
+        assert per
+        for m in per:                   # each moves something the cell reports
+            assert m["moves"] in e2e, (cell, m["name"])
+
+
+def test_every_declared_metric_has_a_reader_file_of_a_known_kind():
+    for cell in M.cell_names():
+        for trace in (0, 1):
+            for m in M.declared(cell, trace):
+                spec = M.metric_spec(m["name"])
+                assert spec["kind"] in readers.KINDS, m["name"]
+
+
+def test_files_lie_under_paths_and_configs_are_used():
+    paths = M.doc["paths"]
+    assert all(os.path.isdir(os.path.join(ROOT, p)) for p in paths)
+    used = {w["config"] for w in M.doc["workloads"]}
+    files = set()
+    for c in M.doc["configs"]:
+        assert c["name"] in used
+        assert c["file"].startswith(paths[0] + "/") and c["file"] not in files
+        files.add(c["file"])
+        with open(os.path.join(ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        assert cfg["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
+        assert all(NAME_RE.match(k) for k in c["reduced"])
+        assert cfg["guarantees"] and cfg["assumed"] and cfg["source"]
+    pairs = [(w["config"], w["traffic"]) for w in M.doc["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+
+
+def test_the_check_fits_its_chip_time_with_all_24_cells():
+    rs = M.doc["run_seconds"]
+    assert isinstance(rs, int) and 1 <= rs <= 51
+    assert (2 + 14 * 24) * (rs + 60) + 24 * 2 * 90 + 1200 <= 43200
+    four = sum(w["chips"] == 4 for w in M.doc["workloads"])
+    assert four <= max(1, len(M.doc["workloads"]) // 2)
+
+
+def test_command_names_nothing_outside_paths():
+    cmd = M.doc["command"]
+    assert cmd[:3] == ["python3", "-m", "benchmark"] and len(cmd) <= 32
+    assert not any(w.startswith("/") or ".." in w for w in cmd)
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
+
+
+def test_unknown_workload_is_an_error():
+    with pytest.raises(ManifestError, match="no workload"):
+        M.cell("nope.search-open")
+
+
+def test_a_cell_defined_only_by_new_files_is_found_by_name(tmp_path):
+    """A later PR adds a configuration, a traffic mix, a counter-backed
+    metric and a cell with new files and new entries only."""
+    root = bench_tiny.tiny_root(str(tmp_path / "root"))
+    bdir = os.path.join(root, "benchmark")
+    shutil.copy(os.path.join(bdir, "configs", "msmarco-passage-bm25.json"),
+                os.path.join(bdir, "configs", "later-config.json"))
+    with open(os.path.join(bdir, "traffic", "later-mix.json"), "w") as f:
+        json.dump({"loop": "open", "rate_per_s": 5.0, "batch": 1,
+                   "plan_seed": 7, "request": {
+                       "kind": "match", "size": 10, "terms_cycle": [3],
+                       "term_zipf_s": 1.0}}, f)
+    with open(os.path.join(bdir, "metrics", "sparse_queries.later.json"),
+              "w") as f:
+        json.dump({"kind": "counter_delta",
+                   "paths": ["tpu_turbo.sparse_queries"]}, f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    doc["configs"].append({
+        "name": "later-config", "source": "s",
+        "file": "benchmark/configs/later-config.json", "reduced": [],
+        "why": "w"})
+    doc["workloads"].append({"name": "later.cell", "config": "later-config",
+                             "traffic": "later-mix", "chips": 1, "why": "w"})
+    doc["per_layer"].append({
+        "name": "sparse_queries.later", "unit": "count", "better": "lower",
+        "source": "program_counter", "layer": "engines",
+        "moves": "search_p50_ms", "workloads": ["later.cell"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(doc, f)
+    m = Manifest(root)
+    cell = m.cell("later.cell")
+    assert cell.traffic["rate_per_s"] == 5.0
+    assert cell.config["kind"] == "text"
+    declared = [x["name"] for x in m.declared("later.cell", 1)]
+    assert "sparse_queries.later" in declared
+    # with no `workloads` list an end-to-end metric is every cell's, and a
+    # per-layer metric follows the end-to-end metric it moves
+    assert "search_p50_ms" in [x["name"] for x in m.declared("later.cell", 0)]
+    assert "sched_wait_ms.search" in declared
+    assert "knn_uncertified_pct.search" not in declared
+    assert m.metric_spec("sparse_queries.later")["kind"] == "counter_delta"
+    # and the validator holds the new cell to its own list
+    line = {"correct": True, "attempted": 1, "failed": 0, "metrics": {},
+            "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                       "memory_peak_bytes": 1, "busy_s": 1.0,
+                       "window_s": 2.0}}
+    faults = validate.line_faults(line, m, "later.cell", 1)
+    assert "metrics lacks sparse_queries.later" in faults
