@@ -19,12 +19,19 @@ def start_node(host: str = "127.0.0.1", port: int = 9200, data=None,
     """Wire one node to its HTTP frontend and start serving; port 0 binds
     a free port (read it back from server.port). Returns (node, server) —
     the caller owns shutdown (server.stop(), node.close())."""
+    from elasticsearch_tpu.common import hbm_ledger, tracing
     from elasticsearch_tpu.common.compile_cache import configure_compile_cache
     from elasticsearch_tpu.common.settings import Settings
     from elasticsearch_tpu.node import Node
     from elasticsearch_tpu.rest import HttpServer, RestController, register_handlers
 
     configure_compile_cache()
+    # what the program says of itself beyond its own spans (both
+    # idempotent): the programs JAX builds (`tpu_compile.jit_builds`, ring
+    # entries by fun_name and open span) and the interpreter's collector
+    # (`jvm.gc.collectors`)
+    hbm_ledger.install_jit_listener()
+    tracing.install_gc_hook()
     node = Node(Settings({"cluster.name": cluster_name}),
                 data_path=data, node_name=name)
     rc = RestController()

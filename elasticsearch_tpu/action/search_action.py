@@ -45,7 +45,7 @@ from elasticsearch_tpu.common.errors import (
 )
 from elasticsearch_tpu.cluster.remote import ACTION_REMOTE_SEARCH
 from elasticsearch_tpu.cluster.state import ClusterState
-from elasticsearch_tpu.common import metrics, tracing
+from elasticsearch_tpu.common import tracing
 from elasticsearch_tpu.common.settings import knob
 from elasticsearch_tpu.indices.shard_service import DistributedShardService
 from elasticsearch_tpu.search.fetch_phase import execute_fetch_phase
@@ -371,9 +371,10 @@ class SearchActionService:
                                      node=self.shards.node_name,
                                      kind="shard_query")
         child = self._register_child(ACTION_QUERY, p, tc)
-        t0 = time.monotonic()
         try:
             with tracing.activate(tc), \
+                    tracing.phase("query", index=p["index"],
+                                  shard=p["shard_id"]) as ph, \
                     scheduler.activate_tier(p.get("_sla")), \
                     _taskmgr.activate(child):
                 if child is not None:
@@ -384,10 +385,8 @@ class SearchActionService:
         finally:
             if child is not None:
                 self.tasks.unregister(child)
-        q_ms = (time.monotonic() - t0) * 1e3
-        metrics.observe("query", q_ms)
+        q_ms = ph.ms
         if tc is not None:
-            tc.add_span("query", q_ms, index=p["index"], shard=p["shard_id"])
             tracing.record_trace(tc)
             out["_trace_spans"] = tc.span_dicts()
         self._shard_slowlog("query", p["index"], p["shard_id"], q_ms,
@@ -494,9 +493,11 @@ class SearchActionService:
                          score=h["score"], global_ord=h["global_ord"],
                          sort_values=h.get("sort_values"))
                 for h in p["hits"]]
-        t0 = time.monotonic()
         try:
-            with tracing.activate(tc), _taskmgr.activate(child):
+            with tracing.activate(tc), \
+                    tracing.phase("fetch", index=ctx.index,
+                                  hits=len(hits)) as ph, \
+                    _taskmgr.activate(child):
                 if child is not None:
                     child.check()
                     child.note_dispatch(phase="fetch")
@@ -505,11 +506,9 @@ class SearchActionService:
         finally:
             if child is not None:
                 self.tasks.unregister(child)
-        f_ms = (time.monotonic() - t0) * 1e3
-        metrics.observe("fetch", f_ms)
+        f_ms = ph.ms
         out = {"hits": fetched}
         if tc is not None:
-            tc.add_span("fetch", f_ms, index=ctx.index, hits=len(hits))
             tracing.record_trace(tc)
             out["_trace_spans"] = tc.span_dicts()
         self._shard_slowlog("fetch", ctx.index, None, f_ms, p["body"], tc)
@@ -1038,13 +1037,8 @@ class SearchActionService:
             # ---- reduce (ref: SearchPhaseController.reducedQueryPhase) ----
             # the incremental consumer already merged/deduped/truncated as
             # results arrived; finish() folds any remainder
-            t_merge = time.monotonic()
-            window_entries, agg_state = consumer.finish()
-            merge_ms = (time.monotonic() - t_merge) * 1e3
-            metrics.observe("merge", merge_ms)
-            tc = tracing.current()
-            if tc is not None:
-                tc.add_span("merge", merge_ms, shards=len(shard_results))
+            with tracing.phase("merge", shards=len(shard_results)):
+                window_entries, agg_state = consumer.finish()
 
             window = [(si, h, shard_results[si])
                       for si, h in window_entries][from_: from_ + size]
@@ -1174,11 +1168,8 @@ class SearchActionService:
                                        "rewrite_time": 0, "collector": []}]}
                 spans = r.get("_trace_spans")
                 if spans:
-                    phases: Dict[str, float] = {}
-                    for s in spans:
-                        phases[s["name"]] = round(
-                            phases.get(s["name"], 0.0) + s["duration_ms"], 3)
-                    entry["tpu"] = {"node": r["_node"], "phases": phases,
+                    entry["tpu"] = {"node": r["_node"],
+                                    "phases": tracing.self_times(spans),
                                     "spans": spans}
                 shards_prof.append(entry)
             profile = {"shards": shards_prof}

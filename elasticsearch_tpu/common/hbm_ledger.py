@@ -17,6 +17,17 @@ time — that IS the trace cost), later dispatches as *hits*, and
 fraction of dispatches that landed on an already-traced shape — is the
 number the scheduler bucket-ladder autotuning work needs.
 
+The proxy only sees the three engine call sites that key a dispatch by
+(engine kind, QC). The programs that actually stall a serving window — the
+op-by-op ``jnp`` calls, gathers, pool updates and mask programs whose
+shapes follow a request's data — never pass through them, so a third set
+of books listens to JAX itself: ``install_jit_listener`` registers one
+``jax.monitoring`` duration listener for the ``/jax/core/compile/*``
+events, and every program JAX builds (traced, lowered, then compiled or
+read from the persistent cache) counts into ``jit_builds`` /
+``jit_build_ms`` and leaves a ring entry with its ``fun_name``, its phase
+seconds and the ``tracing.phase`` open on the thread that built it.
+
 Everything here is plain host bookkeeping guarded by one lock; nothing on
 the device dispatch path blocks on device state.
 """
@@ -27,7 +38,7 @@ import threading
 import weakref
 from typing import Dict, List, Optional, Tuple
 
-from elasticsearch_tpu.common import metrics
+from elasticsearch_tpu.common import metrics, tracing
 from elasticsearch_tpu.common.settings import knob
 
 # gauges/counters live in the shared metric registry so the Prometheus
@@ -64,6 +75,14 @@ metrics.declare_counter("tpu_compile.retraces",
                         "misses whose shape was never primed — unplanned "
                         "serving-time traces")
 
+metrics.declare_counter("tpu_compile.jit_builds",
+                        "programs JAX built in this process (one per "
+                        "backend_compile_duration event: compiled, or "
+                        "read from the persistent cache)")
+metrics.declare_counter("tpu_compile.jit_build_ms",
+                        "ms JAX spent building them: trace + lower + "
+                        "backend of every build")
+
 _LOCK = threading.RLock()
 
 _ENGINES: Dict[int, "_EngineEntry"] = {}  # guarded by: _LOCK
@@ -81,6 +100,14 @@ _COMPILE_MISSES = [0]                     # guarded by: _LOCK
 _COMPILE_RETRACES = [0]                   # guarded by: _LOCK
 _COMPILE_EVENTS: List[dict] = []          # guarded by: _LOCK
 _COMPILE_EVENT_CAP = 256
+_JIT_BUILDS = [0]                         # guarded by: _LOCK
+_JIT_BUILD_MS = [0.0]                     # guarded by: _LOCK
+_JIT_REGISTERED = [False]                 # guarded by: _LOCK
+_JIT_LISTENING = [False]                  # guarded by: _LOCK
+_JIT_PREFIX = "/jax/core/compile/"
+# trace and lower seconds of the build in progress on this thread: the
+# outermost trace / lowering reports last, just before its backend event
+_jit_tls = threading.local()
 
 _ROUTING_LOG: List[dict] = []             # guarded by: _LOCK
 _ROUTING_CAP = 64
@@ -266,6 +293,71 @@ def note_compile_done(kind: str, shape, wall_s: float) -> None:
         del _COMPILE_EVENTS[: max(0, len(_COMPILE_EVENTS) - _COMPILE_EVENT_CAP)]
 
 
+# --- programs built under traffic, by the program --------------------------------
+
+def _on_jit_event(event: str, secs: float, **kw) -> None:
+    """One `/jax/core/compile/*` duration event, on the thread that
+    built. `jaxpr_trace_duration` and `jaxpr_to_mlir_module_duration` are
+    held (nested traces report before the outermost one, which includes
+    them) until `backend_compile_duration` closes the build."""
+    if not _JIT_LISTENING[0] or not event.startswith(_JIT_PREFIX):
+        return
+    step = event[len(_JIT_PREFIX):]
+    if step == "jaxpr_trace_duration":
+        _jit_tls.trace_s = secs
+        return
+    if step == "jaxpr_to_mlir_module_duration":
+        _jit_tls.lower_s = secs
+        return
+    if step != "backend_compile_duration":
+        return
+    trace_s = getattr(_jit_tls, "trace_s", 0.0)
+    lower_s = getattr(_jit_tls, "lower_s", 0.0)
+    _jit_tls.trace_s = _jit_tls.lower_s = 0.0
+    total_ms = (trace_s + lower_s + secs) * 1e3
+    span = tracing.open_phase()
+    fun_name = str(kw.get("fun_name", "?"))
+    with _LOCK:
+        _JIT_BUILDS[0] += 1
+        _JIT_BUILD_MS[0] += total_ms
+        _COMPILE_EVENTS.append({
+            "fun_name": fun_name,
+            "trace_s": round(trace_s, 4), "lower_s": round(lower_s, 4),
+            "backend_s": round(secs, 4),
+            "wall_ms": round(total_ms, 3),
+            "span": span,
+        })
+        del _COMPILE_EVENTS[: max(0, len(_COMPILE_EVENTS) - _COMPILE_EVENT_CAP)]
+    metrics.counter_add("tpu_compile.jit_builds")
+    metrics.counter_add("tpu_compile.jit_build_ms", total_ms)
+    if span is not None:
+        # a marker at the build's END (the profiler takes no event after
+        # the fact); the build's length is in its stats
+        with tracing.annotation("jit_build", fun_name=fun_name,
+                                ms=round(total_ms, 1)):
+            pass
+
+
+def install_jit_listener() -> None:
+    """Register the one `jax.monitoring` duration listener (idempotent;
+    the node calls it at start)."""
+    with _LOCK:
+        _JIT_LISTENING[0] = True
+        if _JIT_REGISTERED[0]:
+            return
+        _JIT_REGISTERED[0] = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jit_event)
+
+
+def stop_jit_listener() -> None:
+    """`jax.monitoring` has no unregister: the listener stays and goes
+    deaf (tests; `install_jit_listener` wakes it)."""
+    with _LOCK:
+        _JIT_LISTENING[0] = False
+
+
 # --- routing explainability ---------------------------------------------------
 
 def note_routing(index: str, eligible: bool, reason: str,
@@ -338,6 +430,8 @@ def compile_stats() -> dict:
             "misses": misses,
             "retraces": _COMPILE_RETRACES[0],
             "warmup_coverage_ratio": round(hits / total, 4) if total else 0.0,
+            "jit_builds": _JIT_BUILDS[0],
+            "jit_build_ms": round(_JIT_BUILD_MS[0], 3),
             "events": [dict(e) for e in _COMPILE_EVENTS],
         }
 
@@ -356,4 +450,7 @@ def reset_for_tests() -> None:
         _COMPILE_MISSES[0] = 0
         _COMPILE_RETRACES[0] = 0
         _COMPILE_EVENTS.clear()
+        _JIT_BUILDS[0] = 0
+        _JIT_BUILD_MS[0] = 0.0
+        _JIT_LISTENING[0] = False
         _ROUTING_LOG.clear()

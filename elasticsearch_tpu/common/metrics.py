@@ -10,8 +10,10 @@ Design constraints:
   observation. Span recording (tracing.py) is the gated/off-by-default part;
   histograms are the standing node-level distributions.
 - Every histogram name must be declared here via ``declare_histogram`` so
-  tpulint TPU005 can verify observation sites against the registry and the
-  whole set surfaces in ``search_latency_stats()``.
+  tpulint TPU005 can verify observation sites (``observe`` and
+  ``tracing.phase`` / ``tracing.record``, which observe under the span's
+  own name) against the registry and the whole set surfaces in
+  ``search_latency_stats()``.
 """
 
 from __future__ import annotations
@@ -506,3 +508,30 @@ declare_histogram("task_duration.msearch", "ms", "task lifetime, msearch coordin
 declare_histogram("task_duration.bulk", "ms", "task lifetime, bulk-family actions")
 declare_histogram("task_duration.async_search", "ms", "task lifetime, async-search actions")
 declare_histogram("task_duration.reindex", "ms", "task lifetime, reindex actions")
+# the steps of one engine call (PR 27), children of `device`: recorded by
+# tracing.phase inside a tracing.steps accumulator, so each takes ONE
+# observation per engine call holding the step's total inside the call
+# (0.0 when it did not run). The four top-level steps sum to `device`.
+DISPATCH_TOP_STEPS = ("dispatch.prep", "dispatch.launch",
+                      "dispatch.device_wait", "dispatch.finish")
+declare_histogram("dispatch.prep", "ms", "engine call, host side before the launch: flatten, column / slice residency, weight packing (BM25); query matrix, quantise, filter masks (kNN)")
+declare_histogram("dispatch.launch", "ms", "engine call, the device program's call returning (async; holds trace + lower + compile when the program is new)")
+declare_histogram("dispatch.device_wait", "ms", "engine call, host blocked fetching the first pass's output")
+declare_histogram("dispatch.finish", "ms", "engine call, host side after the fetch: per (partition, query) collect / rescore / merge")
+declare_histogram("dispatch.slice_build", "ms", "within prep (BM25): host build of a fresh query's cold-term sparse slices and the device pool update")
+declare_histogram("dispatch.mask", "ms", "within prep (kNN): per-partition filter masks, stacking, upload")
+declare_histogram("dispatch.sparse_gather", "ms", "within finish (BM25): the cold side's sparse gather with its device round trip")
+declare_histogram("dispatch.rescore", "ms", "within finish: exact rescore (BM25 _exact_scores; kNN host row gather + rescore program + fetch)")
+declare_histogram("dispatch.cert_fallback", "ms", "within finish (BM25): full exact merge after a failed certificate")
+declare_histogram("dispatch.dense_rerun", "ms", "within finish (kNN): dense route re-run of the uncertified queries")
+# engine build steps (PR 27): they run in set-up or under the first
+# request of a field, so no benchmark reader (window deltas) sees them;
+# GET /_nodes/stats after start-up does
+declare_histogram("engine_build.stack", "ms", "BM25 engine build: build_stacked_bm25 of one partition")
+declare_histogram("engine_build.lanes", "ms", "BM25 engine build: one partition's host block scores, lane arrays and their upload")
+declare_histogram("engine_build.columns", "ms", "BM25 engine: one ensure_columns pass's int8 column build on the device")
+declare_histogram("engine_build.fused", "ms", "BM25 engine build: the fused multi-partition column cache allocation")
+declare_histogram("engine_build.kmeans", "ms", "kNN engine build: k-means of one partition")
+declare_histogram("engine_build.int8_windows", "ms", "kNN engine build: int8 quantisation and window layout of all partitions")
+declare_histogram("engine_build.upload", "ms", "kNN engine build: upload of the int8 shards, meta and centroids")
+declare_histogram("engine_build.dense_mirror", "ms", "kNN engine: one partition's bf16 dense mirror uploaded on first use")

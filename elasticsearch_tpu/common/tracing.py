@@ -20,27 +20,93 @@ untraced build (differential-tested). It turns on per request when:
 Completed traces land in a bounded in-memory ring (``ES_TPU_TRACE_RING``);
 over-threshold queries additionally append structured records to the slowlog
 ring (``ES_TPU_SLOWLOG_RING``) served at ``GET /_tpu/slowlog``.
+
+ONE span primitive serves all three observers: ``phase(name, **meta)``
+records its duration into the declared histogram ``name`` (always on), adds
+a span to the active context (when one is), and enters a
+``jax.profiler.TraceAnnotation("es." + name)`` so that, whenever a profiler
+session runs, the span sits on the host plane of the same xplane as the
+device ops. Spans carry ``id`` / ``parent`` / ``start_ns`` / ``end_ns`` on
+one process-wide clock (``time.monotonic_ns``); the parent is the enclosing
+span on the thread or, across a pool / scheduler hop, the span that
+submitted the work (``activate(tc, parent)``).
 """
 
 from __future__ import annotations
 
+import gc
+import itertools
 import threading
 import time
 import uuid
 from collections import deque
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
+from elasticsearch_tpu.common import metrics
 from elasticsearch_tpu.common.settings import knob, parse_time_value
 
 _tls = threading.local()
+_clock = time.monotonic_ns
+_span_ids = itertools.count(1)     # next() is atomic under the GIL
+
+
+class _ThreadState:
+    """What one thread knows about where it is: the active context, the
+    span its work descends from across a hop, the open phases, and the
+    per-engine-call step accumulator."""
+
+    __slots__ = ("trace", "parent", "base", "stack", "steps")
+
+    def __init__(self):
+        self.trace = None          # TraceContext | _Fanout | None
+        self.parent = 0            # span id inherited across a hop
+        self.base = 0              # stack depth at the last activate()
+        self.stack: List["phase"] = []
+        self.steps: Optional[Dict[str, float]] = None
+
+
+def _state() -> _ThreadState:
+    st = getattr(_tls, "st", None)
+    if st is None:
+        st = _tls.st = _ThreadState()
+    return st
+
+
+def self_times(spans: Iterable[dict]) -> Dict[str, float]:
+    """Self time by span name (ms): a span's duration minus what its
+    children cover (the union of their intervals, clipped to the span), so
+    nested spans partition their root instead of double-counting it.
+    ``rest_total`` is left out: it envelopes the request, and its own
+    remainder is what no span names."""
+    spans = list(spans)
+    kids: Dict[int, List[Tuple[int, int]]] = {}
+    for s in spans:
+        if s.get("parent"):
+            kids.setdefault(s["parent"], []).append(
+                (s["start_ns"], s["end_ns"]))
+    out: Dict[str, float] = {}
+    for s in spans:
+        if s["name"] == "rest_total":
+            continue
+        lo, hi = s["start_ns"], s["end_ns"]
+        covered, edge = 0, lo
+        for a, b in sorted(kids.get(s.get("id"), ())):
+            a, b = max(a, edge), min(b, hi)
+            if b > a:
+                covered += b - a
+                edge = b
+        own = max(0, (hi - lo) - covered) / 1e6
+        out[s["name"]] = out.get(s["name"], 0.0) + own
+    return {k: round(v, 3) for k, v in out.items()}
 
 
 class TraceContext:
     """Spans for one search request on one node. Thread-safe: spans arrive
-    from pool workers, coalescer leaders and RPC threads concurrently."""
+    from pool workers, coalescer leaders, scheduler lanes and RPC threads
+    concurrently."""
 
-    __slots__ = ("trace_id", "opaque_id", "node", "kind", "t0", "spans",
+    __slots__ = ("trace_id", "opaque_id", "node", "kind", "t0_ns", "spans",
                  "_lock")
 
     def __init__(self, trace_id: Optional[str] = None,
@@ -50,19 +116,19 @@ class TraceContext:
         self.opaque_id = opaque_id
         self.node = node
         self.kind = kind
-        self.t0 = time.monotonic()
+        self.t0_ns = _clock()
         self._lock = threading.Lock()
         self.spans: List[dict] = []  # guarded by: _lock
 
-    def add_span(self, name: str, duration_ms: float, **meta: Any) -> None:
-        end_ms = (time.monotonic() - self.t0) * 1e3
-        span = {"name": name,
-                "start_ms": round(max(0.0, end_ms - duration_ms), 3),
-                "duration_ms": round(duration_ms, 3)}
-        if meta:
-            span["meta"] = meta
+    def _link(self, span: dict) -> None:
         with self._lock:
             self.spans.append(span)
+
+    def add_span(self, name: str, duration_ms: float, **meta: Any) -> None:
+        """A span measured by the caller, ending now (an RPC attempt with
+        its error, a wait that ended on another thread's clock read).
+        Sites that also feed a histogram use ``phase`` / ``record``."""
+        self._link(_make_span(name, _open_span(self), duration_ms, meta))
 
     @contextmanager
     def span(self, name: str, **meta: Any):
@@ -74,17 +140,18 @@ class TraceContext:
 
     def span_dicts(self) -> List[dict]:
         with self._lock:
-            return [dict(s) for s in self.spans]
+            spans = list(self.spans)
+        return [dict(s, trace_id=self.trace_id,
+                     start_ms=round(
+                         max(0, s["start_ns"] - self.t0_ns) / 1e6, 3))
+                for s in spans]
 
     def phase_totals(self) -> Dict[str, float]:
-        """Aggregate span durations by name (ms). rest_total is excluded —
-        it envelopes every other phase and would double the sum."""
-        out: Dict[str, float] = {}
-        for s in self.span_dicts():
-            if s["name"] == "rest_total":
-                continue
-            out[s["name"]] = round(out.get(s["name"], 0.0) + s["duration_ms"], 3)
-        return out
+        """Self time by span name (ms), see ``self_times``: the phases of
+        ``profile.tpu`` and of a slowlog record stay a partition of the
+        request once spans nest."""
+        with self._lock:
+            return self_times(self.spans)
 
     def wire(self) -> dict:
         """What crosses the RPC boundary (payload `_trace` key)."""
@@ -96,23 +163,256 @@ class TraceContext:
                 "spans": self.span_dicts()}
 
 
-def current() -> Optional[TraceContext]:
-    return getattr(_tls, "trace", None)
+def _open_span(tc) -> int:
+    """Id of the innermost span open on this thread under ``tc`` (0 when
+    ``tc`` is not this thread's active context)."""
+    st = getattr(_tls, "st", None)
+    if st is None or st.trace is not tc or tc is None:
+        return 0
+    return st.stack[-1].span_id if len(st.stack) > st.base else st.parent
+
+
+def _make_span(name: str, parent: int, duration_ms: float,
+               meta: dict) -> dict:
+    """A span measured by its caller: it ends now."""
+    end_ns = _clock()
+    span = {"id": next(_span_ids), "parent": parent, "name": name,
+            "start_ns": end_ns - int(max(0.0, duration_ms) * 1e6),
+            "end_ns": end_ns, "duration_ms": round(duration_ms, 3)}
+    if meta:
+        span["meta"] = meta
+    return span
+
+
+class _Fanout:
+    """The context of work done once on behalf of several requests (one
+    scheduler or coalescer batch): every span recorded under it is one
+    record, linked into the context of each traced waiter. A span opened
+    at the top of the activation hangs off the span each waiter was in
+    when it submitted."""
+
+    __slots__ = ("targets", "trace_id")
+
+    def __init__(self, targets: Sequence[Tuple[TraceContext, int]]):
+        self.targets = list(targets)
+        self.trace_id = self.targets[0][0].trace_id   # the annotation's stat
+
+    def _link(self, span: dict) -> None:
+        for tc, parent in self.targets:
+            tc._link(span if span["parent"] or not parent
+                     else dict(span, parent=parent))
+
+
+def fanout(targets: Iterable[Tuple[Optional[TraceContext], int]]):
+    """``activate()``-able context over the traced (context, submitting
+    span) pairs of one batch; None when no waiter is traced."""
+    traced = [(tc, parent) for tc, parent in targets if tc is not None]
+    return _Fanout(traced) if traced else None
+
+
+def current():
+    """The thread's active context (a ``TraceContext``; on a batch's
+    dispatch thread the fan-out over its traced waiters), or None."""
+    st = getattr(_tls, "st", None)
+    return st.trace if st is not None else None
+
+
+def current_span() -> int:
+    """Id of the innermost span open on this thread under the active
+    context (what a `_Task` / `_Waiter` captures beside the context, so
+    the work it submits descends from it), 0 when untraced."""
+    return _open_span(current())
+
+
+def open_phase() -> Optional[str]:
+    """Name of the innermost ``phase`` open on this thread, traced or not
+    (the jit-build listener files a build under it)."""
+    st = getattr(_tls, "st", None)
+    return st.stack[-1].name if st is not None and st.stack else None
 
 
 @contextmanager
-def activate(tc: Optional[TraceContext]):
-    """Install ``tc`` as the thread's current trace. activate(None) is a
-    no-op pass-through so call sites need no branching."""
+def activate(tc, parent: int = 0):
+    """Install ``tc`` as the thread's current trace; spans opened inside
+    descend from ``parent`` (the submitter's span across a thread hop).
+    activate(None) is a no-op pass-through so call sites need no
+    branching."""
     if tc is None:
         yield None
         return
-    prev = getattr(_tls, "trace", None)
-    _tls.trace = tc
+    st = _state()
+    prev = (st.trace, st.parent, st.base)
+    st.trace, st.parent, st.base = tc, int(parent or 0), len(st.stack)
     try:
         yield tc
     finally:
-        _tls.trace = prev
+        st.trace, st.parent, st.base = prev
+
+
+# --- the span primitive -------------------------------------------------------
+
+_ANNOTATION = None     # jax.profiler.TraceAnnotation, resolved on first use
+
+
+def _annotation_cls():
+    """Imported on first use: this module is imported long before anything
+    needs jax, and importing jax is not free."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+def annotation(name: str, **stats: Any):
+    """A bare profiler annotation ``es.<name>`` (context manager): live
+    exactly while a profiler session is, no histogram and no span. For
+    what is not a phase of a request: a lane parked on its queue, a
+    collection, a program being built."""
+    return _annotation_cls()("es." + name, **stats)
+
+
+class phase:
+    """``with tracing.phase("dispatch.prep", batch=n):`` — the one span
+    primitive. On exit the duration goes into the declared histogram
+    ``name`` (inside ``steps()``, into that call's accumulator instead);
+    with a context active a span ``name`` is linked into it; and the
+    block runs under the profiler annotation ``es.<name>`` carrying
+    ``trace_id`` and the meta as stats. ``.ms`` holds the duration after
+    the block. tpulint TPU005 ties literal names to ``declare_histogram``."""
+
+    __slots__ = ("name", "meta", "ms", "span_id", "_st", "_tc", "_parent",
+                 "_ann", "_t0")
+
+    def __init__(self, name: str, **meta: Any):
+        self.name = name
+        self.meta = meta
+        self.ms = 0.0
+        self.span_id = 0
+
+    def __enter__(self) -> "phase":
+        st = self._st = _state()
+        tc = self._tc = st.trace
+        if tc is not None:
+            stack = st.stack
+            self._parent = (stack[-1].span_id if len(stack) > st.base
+                            else st.parent)
+            self.span_id = next(_span_ids)
+            ann = _annotation_cls()("es." + self.name, trace_id=tc.trace_id,
+                                    **self.meta)
+        else:
+            ann = _annotation_cls()("es." + self.name, **self.meta)
+        st.stack.append(self)
+        self._ann = ann
+        ann.__enter__()
+        self._t0 = _clock()
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        t1 = _clock()
+        self._ann.__exit__(et, ev, tb)
+        st = self._st
+        st.stack.pop()
+        self.ms = ms = (t1 - self._t0) / 1e6
+        steps = st.steps
+        if steps is not None and self.name in steps:
+            steps[self.name] += ms
+        else:
+            metrics.observe(self.name, ms)
+        tc = self._tc
+        if tc is not None:
+            span = {"id": self.span_id, "parent": self._parent,
+                    "name": self.name, "start_ns": self._t0, "end_ns": t1,
+                    "duration_ms": round(ms, 3)}
+            if et is not None:
+                span["meta"] = dict(self.meta, error=et.__name__)
+            elif self.meta:
+                span["meta"] = self.meta
+            tc._link(span)
+        return False
+
+
+def record(name: str, duration_ms: float, tc=None, parent: int = 0,
+           **meta: Any) -> None:
+    """A phase measured by the caller, ending now: the histogram ``name``
+    (skipped when undeclared: composed names of ad-hoc pools) and a span
+    in ``tc`` (default: the thread's context). No annotation: the
+    profiler takes no event after the fact."""
+    metrics.observe_if_declared(name, duration_ms)
+    if tc is None:
+        tc, parent = current(), current_span()
+    if tc is not None:
+        tc._link(_make_span(name, int(parent or 0), duration_ms, meta))
+
+
+class steps:
+    """Per-engine-call accumulator: inside the block a ``phase`` whose
+    name is in ``names`` adds its duration here, and on exit every name
+    gets ONE histogram observation holding its total inside the call (0.0
+    when the step did not run). The step means are then per dispatch,
+    children sum under parents, and the top-level steps sum to `device`.
+    Nested (a fused engine calling a partition's engine) the outer call
+    owns the accumulator."""
+
+    __slots__ = ("names", "_st", "_own")
+
+    def __init__(self, names: Sequence[str]):
+        self.names = names
+
+    def __enter__(self) -> "steps":
+        st = self._st = _state()
+        self._own = st.steps is None
+        if self._own:
+            st.steps = dict.fromkeys(self.names, 0.0)
+        return self
+
+    def __exit__(self, et, ev, tb) -> bool:
+        if self._own:
+            acc, self._st.steps = self._st.steps, None
+            for name, ms in acc.items():
+                metrics.observe(name, ms)
+        return False
+
+
+# --- the interpreter's collector ----------------------------------------------
+# `jvm.gc.collectors.{young,old}` of GET /_nodes/stats (old = generation 2;
+# the keys an Elasticsearch dashboard already reads). A full collection
+# walks everything a 1.1M-document shard keeps on the host, with every
+# thread stopped: it is also an annotation `es.gc`.
+
+_GC_NS = [0, 0]        # young, old: collections run under the GIL
+_GC_COUNT = [0, 0]
+_GC_T0 = [0]
+_GC_ANN: List[Any] = []
+
+
+def _on_gc(when: str, info: dict) -> None:
+    if when == "start":
+        if info["generation"] == 2:
+            ann = annotation("gc", generation=2)
+            ann.__enter__()
+            _GC_ANN.append(ann)
+        _GC_T0[0] = _clock()
+        return
+    dt = _clock() - _GC_T0[0]
+    old = info["generation"] == 2
+    _GC_NS[old] += dt
+    _GC_COUNT[old] += 1
+    if old and _GC_ANN:
+        _GC_ANN.pop().__exit__(None, None, None)
+
+
+def install_gc_hook() -> None:
+    """Idempotent; the node process calls it at start."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_stats() -> dict:
+    """``jvm.gc.collectors`` of GET /_nodes/stats."""
+    return {gen: {"collection_count": _GC_COUNT[i],
+                  "collection_time_in_millis": _GC_NS[i] // 1_000_000}
+            for i, gen in enumerate(("young", "old"))}
 
 
 def child_from_wire(wire: Optional[dict], node: str = "",
@@ -256,5 +556,6 @@ def reset_for_tests() -> None:
             _SLOWLOG_COUNTS[k] = 0
     with _SAMPLE_LOCK:
         _SAMPLE["n"] = 0
-    if getattr(_tls, "trace", None) is not None:
-        _tls.trace = None
+    _tls.st = None
+    _GC_NS[:] = [0, 0]
+    _GC_COUNT[:] = [0, 0]

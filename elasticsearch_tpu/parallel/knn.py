@@ -78,7 +78,9 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from elasticsearch_tpu.common import faults, hbm_ledger, integrity, metrics
+from elasticsearch_tpu.common import (
+    faults, hbm_ledger, integrity, metrics, tracing,
+)
 from elasticsearch_tpu.common.faults import DeviceFaultError, FaultRecord
 from elasticsearch_tpu.common.health import EngineHealth
 from elasticsearch_tpu.common.settings import knob
@@ -328,6 +330,12 @@ class KnnWork:
 # the engine
 # --------------------------------------------------------------------------
 
+# the steps of one engine call (tracing.steps): each takes one histogram
+# observation per call, see common/metrics.py
+DISPATCH_STEPS = metrics.DISPATCH_TOP_STEPS + (
+    "dispatch.mask", "dispatch.rescore", "dispatch.dense_rerun")
+
+
 class KnnEngine:
     """Quantized sharded kNN over one vector field's partitions.
 
@@ -382,7 +390,8 @@ class KnnEngine:
                     if lives is not None and lives[i] is not None
                     else np.ones(n, bool))
             if n >= KNN_IVF_MIN_DOCS:
-                cent, labels = _kmeans(v)
+                with tracing.phase("engine_build.kmeans", partition=i):
+                    cent, labels = _kmeans(v)
                 order = np.argsort(labels, kind="stable")
                 counts = np.bincount(labels, minlength=len(cent))
             else:
@@ -400,43 +409,44 @@ class KnnEngine:
 
         self.nw = max(1, max(-(-n // KNN_W) for n in self.n_docs))
         self.NCp = -(-max(len(c) for c, _, _ in preps) // 8) * 8
-        DPg = self.nw * KNN_W
-        q8h = np.zeros((self.Sp, self.nw, self.dimsP, KNN_W), np.int8)
-        # window-major [nw, 4, KNN_W] per partition: the kernel fetches one
-        # window's four meta rows as a block
-        metah = np.zeros((self.Sp, self.nw, 4, KNN_W), np.float32)
-        centh = np.zeros((self.Sp, self.NCp, self.dimsP), np.float32)
-        cvalh = np.zeros((self.Sp, self.NCp), np.float32)
-        ovh = np.zeros((self.Sp, self.NCp, self.nw), np.float32)
-        for i, (cent, order, counts) in enumerate(preps):
-            n = self.n_docs[i]
-            perm = np.zeros(DPg, np.int32)
-            perm[:n] = order
-            self._perm.append(perm)
-            nc = len(cent)
-            centh[i, :nc, :self.dims] = cent
-            cvalh[i, :nc] = 1.0
-            starts = np.concatenate([[0], np.cumsum(counts)])
-            for c in range(nc):
-                s0, s1 = int(starts[c]), int(starts[c + 1])
-                if s1 > s0:
-                    ovh[i, c, s0 // KNN_W:(s1 - 1) // KNN_W + 1] = 1.0
-            if n == 0:
-                continue
-            vi = self._vecs[i][order]                      # stored order
-            s_r = np.maximum(np.abs(vi).max(axis=1), 1e-12) / 127.0
-            vi8 = np.clip(np.round(vi / s_r[:, None]), -127, 127) \
-                .astype(np.int8)
-            row_l1 = s_r * np.abs(vi8.astype(np.float32)).sum(axis=1)
-            nrm = np.linalg.norm(vi, axis=1).astype(np.float32)
-            okf = self._ok[i][order].astype(np.float32)
-            for w in range(-(-n // KNN_W)):
-                lo, hi = w * KNN_W, min((w + 1) * KNN_W, n)
-                q8h[i, w, :self.dims, :hi - lo] = vi8[lo:hi].T
-                metah[i, w, 0, :hi - lo] = s_r[lo:hi].astype(np.float32)
-                metah[i, w, 1, :hi - lo] = row_l1[lo:hi].astype(np.float32)
-                metah[i, w, 2, :hi - lo] = nrm[lo:hi]
-                metah[i, w, 3, :hi - lo] = okf[lo:hi]
+        with tracing.phase("engine_build.int8_windows", partitions=S):
+            DPg = self.nw * KNN_W
+            q8h = np.zeros((self.Sp, self.nw, self.dimsP, KNN_W), np.int8)
+            # window-major [nw, 4, KNN_W] per partition: the kernel fetches one
+            # window's four meta rows as a block
+            metah = np.zeros((self.Sp, self.nw, 4, KNN_W), np.float32)
+            centh = np.zeros((self.Sp, self.NCp, self.dimsP), np.float32)
+            cvalh = np.zeros((self.Sp, self.NCp), np.float32)
+            ovh = np.zeros((self.Sp, self.NCp, self.nw), np.float32)
+            for i, (cent, order, counts) in enumerate(preps):
+                n = self.n_docs[i]
+                perm = np.zeros(DPg, np.int32)
+                perm[:n] = order
+                self._perm.append(perm)
+                nc = len(cent)
+                centh[i, :nc, :self.dims] = cent
+                cvalh[i, :nc] = 1.0
+                starts = np.concatenate([[0], np.cumsum(counts)])
+                for c in range(nc):
+                    s0, s1 = int(starts[c]), int(starts[c + 1])
+                    if s1 > s0:
+                        ovh[i, c, s0 // KNN_W:(s1 - 1) // KNN_W + 1] = 1.0
+                if n == 0:
+                    continue
+                vi = self._vecs[i][order]                      # stored order
+                s_r = np.maximum(np.abs(vi).max(axis=1), 1e-12) / 127.0
+                vi8 = np.clip(np.round(vi / s_r[:, None]), -127, 127) \
+                    .astype(np.int8)
+                row_l1 = s_r * np.abs(vi8.astype(np.float32)).sum(axis=1)
+                nrm = np.linalg.norm(vi, axis=1).astype(np.float32)
+                okf = self._ok[i][order].astype(np.float32)
+                for w in range(-(-n // KNN_W)):
+                    lo, hi = w * KNN_W, min((w + 1) * KNN_W, n)
+                    q8h[i, w, :self.dims, :hi - lo] = vi8[lo:hi].T
+                    metah[i, w, 0, :hi - lo] = s_r[lo:hi].astype(np.float32)
+                    metah[i, w, 1, :hi - lo] = row_l1[lo:hi].astype(np.float32)
+                    metah[i, w, 2, :hi - lo] = nrm[lo:hi]
+                    metah[i, w, 3, :hi - lo] = okf[lo:hi]
         self._q8_host = q8h
         self._meta_host = metah
         self._cent_host = centh
@@ -446,7 +456,8 @@ class KnnEngine:
                           if self._fused else None)
         # translation only (device_errors, no fault_point): construction
         # runs outside the serving containment ladder
-        with faults.device_errors("column_upload"):
+        with tracing.phase("engine_build.upload", bytes=q8h.nbytes), \
+                faults.device_errors("column_upload"):
             self.d_q8 = _put_sharded(q8h, self.mesh)
             self.d_meta = _put_sharded(metah, self.mesh)
             self.d_cent = _put_sharded(centh, self.mesh)
@@ -518,7 +529,8 @@ class KnnEngine:
         bitwise-equal bf16 values."""
         if self._dense[i] is not None:
             return
-        with faults.device_errors("column_upload"):
+        with tracing.phase("engine_build.dense_mirror", partition=i), \
+                faults.device_errors("column_upload"):
             trip = (jnp.asarray(self._vecs[i]).astype(jnp.bfloat16),
                     jnp.asarray(self._norms[i]),
                     jnp.asarray(self._exists[i]))
@@ -651,54 +663,67 @@ class KnnEngine:
         nprobe = max(0, int(knob("ES_TPU_KNN_NPROBE")))
         mult = max(1, int(knob("ES_TPU_KNN_RESCORE_MULT")))
         C = min(k * mult, self.nw * KNN_CANDW - 1)
-        qf = np.zeros((QC, self.dims), np.float32)
-        for j, wk in enumerate(chunk):
-            qf[j, :len(wk.vector)] = wk.vector
         s_out = np.zeros((S, n, k), np.float32)
         o_out = np.zeros((S, n, k), np.int32)
+        with tracing.phase("dispatch.prep", qc=QC):
+            qf = np.zeros((QC, self.dims), np.float32)
+            for j, wk in enumerate(chunk):
+                qf[j, :len(wk.vector)] = wk.vector
+            if use_int8 and k <= C:
+                qi8, qmeta = self._quantize_queries(qf)
+                qfd = jnp.asarray(qf)
 
         if not use_int8 or k > C:
-            # the f32 brute-force A/B path, verbatim per partition
+            # the f32 brute-force A/B path, verbatim per partition: one
+            # `dispatch.launch` holds its calls and their fetches
             t0 = time.monotonic()
             first = hbm_ledger.note_dispatch("knn_dense", QC)
-            for i in range(S):
-                try:
-                    ds, do = self._dense_chunk(i, qf, chunk, QC, k)
-                    s_out[i], o_out[i] = ds[:n], do[:n]
-                except DeviceFaultError as e:
-                    local_faults.append(FaultRecord.from_error(e, partition=i))
-                    _count("knn_host_fallbacks", n)
-                    self.health.record_fallback(n)
-                    s_out[i], o_out[i] = self._host_chunk(i, chunk, k)
+            with tracing.phase("dispatch.launch", qc=QC, route="dense"):
+                for i in range(S):
+                    try:
+                        ds, do = self._dense_chunk(i, qf, chunk, QC, k)
+                        s_out[i], o_out[i] = ds[:n], do[:n]
+                    except DeviceFaultError as e:
+                        local_faults.append(
+                            FaultRecord.from_error(e, partition=i))
+                        _count("knn_host_fallbacks", n)
+                        self.health.record_fallback(n)
+                        s_out[i], o_out[i] = self._host_chunk(i, chunk, k)
             if first:
                 hbm_ledger.note_compile_done(
                     "knn_dense", QC, time.monotonic() - t0)
             return s_out, o_out
 
         _count("knn_int8_dispatches", 1)
-        qi8, qmeta = self._quantize_queries(qf)
         masked = any(wk.filters is not None for wk in chunk)
         t0 = time.monotonic()
         first = hbm_ledger.note_dispatch("knn", QC)
-        qfd = jnp.asarray(qf)
         pass1: Dict[int, tuple] = {}
         failed: Dict[int, DeviceFaultError] = {}
         if self._fused:
             fmasks = None
             if masked:
-                fmasks = np.zeros((self.Sp, self.nw, QC, KNN_W), np.int8)
-                for i in range(S):
-                    fmasks[i] = self._filter_mask(i, chunk, QC)
-                fmasks = jnp.asarray(fmasks)
+                with tracing.phase("dispatch.prep", qc=QC), \
+                        tracing.phase("dispatch.mask", partitions=S):
+                    fmasks = np.zeros((self.Sp, self.nw, QC, KNN_W), np.int8)
+                    for i in range(S):
+                        fmasks[i] = self._filter_mask(i, chunk, QC)
+                    fmasks = jnp.asarray(fmasks)
             try:
                 with faults.device_dispatch("knn_score"):
-                    rr = _pass1_fused(
-                        qfd, jnp.asarray(qi8), jnp.asarray(qmeta),
-                        self.d_q8, self.d_meta, self.d_cent,
-                        self.d_cvalid, self.d_overlap, fmasks,
-                        mesh=self.mesh, similarity=self.similarity,
-                        C=C, nprobe=nprobe)
-                    cr, cok, ux, fr = (np.asarray(a) for a in rr)
+                    # the call returning, not the pass: the launch is
+                    # async, and holds trace + lower + compile when the
+                    # program is new
+                    with tracing.phase("dispatch.launch", qc=QC,
+                                       partitions=S):
+                        rr = _pass1_fused(
+                            qfd, jnp.asarray(qi8), jnp.asarray(qmeta),
+                            self.d_q8, self.d_meta, self.d_cent,
+                            self.d_cvalid, self.d_overlap, fmasks,
+                            mesh=self.mesh, similarity=self.similarity,
+                            C=C, nprobe=nprobe)
+                    with tracing.phase("dispatch.device_wait"):
+                        cr, cok, ux, fr = (np.asarray(a) for a in rr)
                 for i in range(S):
                     pass1[i] = (cr[i], cok[i], ux[i], fr[i])
             except DeviceFaultError as e:
@@ -707,24 +732,32 @@ class KnnEngine:
                 local_faults.append(FaultRecord.from_error(e))
                 _count("knn_host_fallbacks", n * S)
                 self.health.record_fallback(n * S)
-                for i in range(S):
-                    s_out[i], o_out[i] = self._host_chunk(i, chunk, k)
+                with tracing.phase("dispatch.finish", host_tier=True):
+                    for i in range(S):
+                        s_out[i], o_out[i] = self._host_chunk(i, chunk, k)
                 if first:
                     hbm_ledger.note_compile_done(
                         "knn", QC, time.monotonic() - t0)
                 return s_out, o_out
         else:
             for i in range(S):
-                fmask = (jnp.asarray(self._filter_mask(i, chunk, QC))
-                         if masked else None)
+                fmask = None
+                if masked:
+                    with tracing.phase("dispatch.prep", qc=QC), \
+                            tracing.phase("dispatch.mask", partition=i):
+                        fmask = jnp.asarray(self._filter_mask(i, chunk, QC))
                 try:
                     with faults.device_dispatch("knn_score", part=i):
-                        rr = _pass1_solo(
-                            qfd, jnp.asarray(qi8), jnp.asarray(qmeta),
-                            self.d_q8[i], self.d_meta[i], self.d_cent[i],
-                            self.d_cvalid[i], self.d_overlap[i], fmask,
-                            similarity=self.similarity, C=C, nprobe=nprobe)
-                        pass1[i] = tuple(np.asarray(a) for a in rr)
+                        with tracing.phase("dispatch.launch", qc=QC,
+                                           partition=i):
+                            rr = _pass1_solo(
+                                qfd, jnp.asarray(qi8), jnp.asarray(qmeta),
+                                self.d_q8[i], self.d_meta[i], self.d_cent[i],
+                                self.d_cvalid[i], self.d_overlap[i], fmask,
+                                similarity=self.similarity, C=C,
+                                nprobe=nprobe)
+                        with tracing.phase("dispatch.device_wait"):
+                            pass1[i] = tuple(np.asarray(a) for a in rr)
                 except DeviceFaultError as e:
                     failed[i] = e
         if first:
@@ -732,60 +765,65 @@ class KnnEngine:
 
         cand_hist = np.zeros(n, np.int64)
         frac_hist = np.zeros(n, np.float64)
-        for i in range(S):
-            if check is not None:
-                check()
-            if i in failed:
-                local_faults.append(
-                    FaultRecord.from_error(failed[i], partition=i))
-                _count("knn_host_fallbacks", n)
-                self.health.record_fallback(n)
-                s_out[i], o_out[i] = self._host_chunk(i, chunk, k)
-                continue
-            if self.n_docs[i] == 0:
-                continue
-            cand_r, cand_ok, u_excl, frac = pass1[i]
-            cand_hist += cand_ok[:n].sum(axis=1)
-            frac_hist += frac[:n]
-            ords = self._perm[i][cand_r]
-            ords = np.where(cand_ok, ords, 0).astype(np.int32)
-            _count("knn_rescore_docs", int(cand_ok[:n].sum()))
-            try:
-                rows = self._vecs[i][ords.reshape(-1)]
-                nrmg = self._norms[i][ords]
-                with faults.device_dispatch("knn_rescore", part=i):
-                    ts, to, cert = _rescore_program(
-                        qfd, jnp.asarray(rows), jnp.asarray(nrmg),
-                        jnp.asarray(cand_ok), jnp.asarray(ords),
-                        jnp.asarray(u_excl),
-                        similarity=self.similarity, C=C, k=k)
-                    ts, to, cert = (np.asarray(ts), np.asarray(to),
-                                    np.asarray(cert))
-            except DeviceFaultError as e:
-                local_faults.append(FaultRecord.from_error(e, partition=i))
-                _count("knn_host_fallbacks", n)
-                self.health.record_fallback(n)
-                s_out[i], o_out[i] = self._host_chunk(i, chunk, k)
-                continue
-            s_out[i], o_out[i] = ts[:n], to[:n]
-            bad = np.nonzero(~cert[:n])[0]
-            if len(bad):
-                # certificate miss: the candidate set may not cover the
-                # true top-k — re-run those queries on the dense route,
-                # which restores bit-identity unconditionally
-                _count("knn_uncertified", len(bad))
-                try:
-                    ds, do = self._dense_chunk(i, qf, chunk, QC, k)
-                    s_out[i][bad] = ds[bad]
-                    o_out[i][bad] = do[bad]
-                except DeviceFaultError as e:
+        with tracing.phase("dispatch.finish", queries=n):
+            for i in range(S):
+                if check is not None:
+                    check()
+                if i in failed:
                     local_faults.append(
-                        FaultRecord.from_error(e, partition=i))
-                    _count("knn_host_fallbacks", len(bad))
-                    self.health.record_fallback(len(bad))
-                    hs, ho = self._host_chunk(i, chunk, k)
-                    s_out[i][bad] = hs[bad]
-                    o_out[i][bad] = ho[bad]
+                        FaultRecord.from_error(failed[i], partition=i))
+                    _count("knn_host_fallbacks", n)
+                    self.health.record_fallback(n)
+                    s_out[i], o_out[i] = self._host_chunk(i, chunk, k)
+                    continue
+                if self.n_docs[i] == 0:
+                    continue
+                cand_r, cand_ok, u_excl, frac = pass1[i]
+                cand_hist += cand_ok[:n].sum(axis=1)
+                frac_hist += frac[:n]
+                ords = self._perm[i][cand_r]
+                ords = np.where(cand_ok, ords, 0).astype(np.int32)
+                _count("knn_rescore_docs", int(cand_ok[:n].sum()))
+                try:
+                    # host row gather, the rescore program, its fetch
+                    with tracing.phase("dispatch.rescore", partition=i):
+                        rows = self._vecs[i][ords.reshape(-1)]
+                        nrmg = self._norms[i][ords]
+                        with faults.device_dispatch("knn_rescore", part=i):
+                            ts, to, cert = _rescore_program(
+                                qfd, jnp.asarray(rows), jnp.asarray(nrmg),
+                                jnp.asarray(cand_ok), jnp.asarray(ords),
+                                jnp.asarray(u_excl),
+                                similarity=self.similarity, C=C, k=k)
+                            ts, to, cert = (np.asarray(ts), np.asarray(to),
+                                            np.asarray(cert))
+                except DeviceFaultError as e:
+                    local_faults.append(FaultRecord.from_error(e, partition=i))
+                    _count("knn_host_fallbacks", n)
+                    self.health.record_fallback(n)
+                    s_out[i], o_out[i] = self._host_chunk(i, chunk, k)
+                    continue
+                s_out[i], o_out[i] = ts[:n], to[:n]
+                bad = np.nonzero(~cert[:n])[0]
+                if len(bad):
+                    # certificate miss: the candidate set may not cover the
+                    # true top-k — re-run those queries on the dense route,
+                    # which restores bit-identity unconditionally
+                    _count("knn_uncertified", len(bad))
+                    try:
+                        with tracing.phase("dispatch.dense_rerun", partition=i,
+                                           queries=len(bad)):
+                            ds, do = self._dense_chunk(i, qf, chunk, QC, k)
+                        s_out[i][bad] = ds[bad]
+                        o_out[i][bad] = do[bad]
+                    except DeviceFaultError as e:
+                        local_faults.append(
+                            FaultRecord.from_error(e, partition=i))
+                        _count("knn_host_fallbacks", len(bad))
+                        self.health.record_fallback(len(bad))
+                        hs, ho = self._host_chunk(i, chunk, k)
+                        s_out[i][bad] = hs[bad]
+                        o_out[i][bad] = ho[bad]
         for j in range(n):
             metrics.observe("knn_candidates_per_query", float(cand_hist[j]))
             metrics.observe("knn_nprobe_ratio",
@@ -829,6 +867,10 @@ class KnnEngine:
         ords [Q, k] i32); empty slots are (0, 0, 0). Chunks ride the
         qc_sizes bucket ladder; contained faults append FaultRecords
         and feed the health circuit (open circuit = host tier)."""
+        with tracing.steps(DISPATCH_STEPS):
+            return self._search_many(batches, k, check, fault_log)
+
+    def _search_many(self, batches, k, check, fault_log):
         spans = []
         flat: List[KnnWork] = []
         for b in batches:
@@ -847,9 +889,10 @@ class KnnEngine:
             # circuit open: the whole batch serves from the host tier
             _count("knn_host_fallbacks", Q * self.S)
             self.health.record_fallback(Q * self.S)
-            for i in range(self.S):
-                s_all[i], o_all[i] = self._host_chunk(i, flat, k)
-            ms, mp, mo = self._merge(s_all, o_all, k)
+            with tracing.phase("dispatch.finish", host_tier=True):
+                for i in range(self.S):
+                    s_all[i], o_all[i] = self._host_chunk(i, flat, k)
+                ms, mp, mo = self._merge(s_all, o_all, k)
         else:
             off = 0
             while off < Q:
@@ -868,7 +911,8 @@ class KnnEngine:
                 self.health.record_fault(local_faults[-1].error)
             else:
                 self.health.record_success()
-            ms, mp, mo = self._merge(s_all, o_all, k)
+            with tracing.phase("dispatch.finish", merge=self.S):
+                ms, mp, mo = self._merge(s_all, o_all, k)
         if fault_log is not None:
             fault_log.extend(local_faults)
         return [(ms[o:o + nn], mp[o:o + nn], mo[o:o + nn])

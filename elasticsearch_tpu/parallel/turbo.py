@@ -79,6 +79,13 @@ _MAX_REQ = 126         # coverage counts fit int8 with the must_not weight
 from functools import partial as _partial  # noqa: E402
 
 
+# the steps of one engine call (tracing.steps): each takes one histogram
+# observation per call, see common/metrics.py
+DISPATCH_STEPS = metrics.DISPATCH_TOP_STEPS + (
+    "dispatch.slice_build", "dispatch.sparse_gather", "dispatch.rescore",
+    "dispatch.cert_fallback")
+
+
 @_partial(jax.jit, static_argnames=("n_rows",))
 def _pick_rows(rm, rr, *, n_rows: int):
     """Device-side global candidate-row pick (was a per-query host loop
@@ -634,14 +641,23 @@ class TurboBM25:
             r, n, b, s = self._term_groups(info, slot)
             self._tile_bases[t] = b
             rows_l.append(r); n_l.append(n); base_l.append(b); slot_l.append(s)
+        self._build_columns(rows_l, n_l, base_l, slot_l,
+                            self.lane_docs, self.lane_scores, len(need))
+        self._register_hbm_regions()
+
+    def _build_columns(self, rows_l, n_l, base_l, slot_l, lane_docs,
+                       lane_scores, n_built: int) -> None:
+        """The device side of `ensure_columns` / `ensure_phrases`: the
+        int8 column build of the given row groups over the given lane
+        arrays, giant (cold-start) builds split into bounded dispatches."""
         rows = np.concatenate(rows_l)
         nrows = np.concatenate(n_l)
         bases = np.concatenate(base_l)
         slots = np.concatenate(slot_l)
         t0 = time.monotonic()
         try:
-            with faults.device_errors("column_upload", self.part_id):
-                # split giant (cold-start) builds into bounded dispatches
+            with tracing.phase("engine_build.columns", built=n_built), \
+                    faults.device_errors("column_upload", self.part_id):
                 for off in range(0, len(rows), _BUILD_BUCKETS[-1]):
                     part = slice(off, off + _BUILD_BUCKETS[-1])
                     r_p, n_p, b_p, s_p = (rows[part], nrows[part],
@@ -657,15 +673,14 @@ class TurboBM25:
                             [b_p, np.zeros(pad, np.int32)])),
                         jnp.asarray(np.concatenate(
                             [s_p, np.full(pad, self.Hp, np.int32)])),
-                        self.lane_docs, self.lane_scores,
+                        lane_docs, lane_scores,
                         self.cols_hi, self.cols_lo, n_groups=ng)
         except DeviceFaultError:
             self._reset_columns()
             raise
         self.cols_epoch += 1
-        self.stats["builds"] += len(need)
+        self.stats["builds"] += n_built
         self.stats["build_s"] += time.monotonic() - t0
-        self._register_hbm_regions()
 
     # ---------------- phrase columns ----------------
 
@@ -785,37 +800,9 @@ class TurboBM25:
         dvals.append(np.zeros((pad_rows, 128), np.float32))
         lane_docs = jnp.asarray(np.concatenate(drows, axis=0))
         lane_scores = jnp.asarray(np.concatenate(dvals, axis=0))
-        rows = np.concatenate(rows_l)
-        nrows = np.concatenate(n_l)
-        bases = np.concatenate(base_l)
-        slots = np.concatenate(slot_l)
-        t0 = time.monotonic()
-        try:
-            with faults.device_errors("column_upload", self.part_id):
-                for off in range(0, len(rows), _BUILD_BUCKETS[-1]):
-                    part = slice(off, off + _BUILD_BUCKETS[-1])
-                    r_p, n_p, b_p, s_p = (rows[part], nrows[part],
-                                          bases[part], slots[part])
-                    ng = _bucket(len(r_p))
-                    pad = ng - len(r_p)
-                    self.cols_hi, self.cols_lo = build_columns(
-                        jnp.asarray(np.concatenate(
-                            [r_p, np.zeros(pad, np.int32)])),
-                        jnp.asarray(np.concatenate(
-                            [n_p, np.zeros(pad, np.int32)])),
-                        jnp.asarray(np.concatenate(
-                            [b_p, np.zeros(pad, np.int32)])),
-                        jnp.asarray(np.concatenate(
-                            [s_p, np.full(pad, self.Hp, np.int32)])),
-                        lane_docs, lane_scores,
-                        self.cols_hi, self.cols_lo, n_groups=ng)
-        except DeviceFaultError:
-            self._reset_columns()
-            raise
-        self.cols_epoch += 1
-        self.stats["builds"] += len(need)
+        self._build_columns(rows_l, n_l, base_l, slot_l,
+                            lane_docs, lane_scores, len(need))
         self.stats["phrase_builds"] += len(need)
-        self.stats["build_s"] += time.monotonic() - t0
 
     def _cold_contrib(self, cold_terms):
         """(docs i64 unique-sorted, contrib f64) — the cold terms' summed
@@ -936,6 +923,12 @@ class TurboBM25:
             need.append((t, info, w))
         if not need:
             return True
+        with tracing.phase("dispatch.slice_build", terms=len(need)):
+            return self._build_slices(need, protect)
+
+    def _build_slices(self, need, protect) -> bool:
+        """The work of `_ensure_sparse` for the terms that have no slice
+        yet: the host slice build and the device pool update."""
         fp = self.fp
         idx_l, upd_l = [], []
         try:
@@ -1168,6 +1161,12 @@ class TurboBM25:
             total = total + w * self._impacts_at(info, docs)
         return total
 
+    def _rescore(self, qterms, docs: np.ndarray) -> np.ndarray:
+        """`_exact_scores` as the `dispatch.rescore` step of a query's
+        finish (the candidate side and the cold side's survivors)."""
+        with tracing.phase("dispatch.rescore", docs=len(docs)):
+            return self._exact_scores(qterms, docs)
+
     # ---------------- search ----------------
 
     def search_many(self, batches: Sequence[List], k: int = 10, check=None):
@@ -1175,15 +1174,20 @@ class TurboBM25:
         (scores [Q, k] f32, ords [Q, k] i32). Queries are term lists or
         (term, boost) lists. check: optional cooperative-cancellation
         callable invoked between dispatches (tasks/task_manager)."""
+        with tracing.steps(DISPATCH_STEPS):
+            return self._search_many(batches, k, check)
+
+    def _search_many(self, batches, k, check):
         flat, spans = _flatten_queries(batches)
         if not flat:
             return [(np.zeros((n, k), np.float32), np.zeros((n, k), np.int32))
                     for _, n in spans]
         # cold terms ride along: ensure_columns builds their eager sparse
         # slices in the same upload pass the columns use
-        self.ensure_columns(
-            [t for q in flat for t, _ in q
-             if self._term(t) is not None])
+        with tracing.phase("dispatch.prep", queries=len(flat)):
+            self.ensure_columns(
+                [t for q in flat for t, _ in q
+                 if self._term(t) is not None])
 
         # pass 1: sweep -> row pick, both on device, dispatched async per
         # chunk; only the packed [QC, n_rows+1] pick output crosses to the
@@ -1206,7 +1210,8 @@ class TurboBM25:
             first_trace = hbm_ledger.note_dispatch("turbo", take)
             tc0 = time.monotonic()
             wq, qscale, (rm, rr) = self._sweep(chunk, take)
-            with faults.device_errors("turbo_sweep", self.part_id):
+            with tracing.phase("dispatch.launch", qc=take), \
+                    faults.device_errors("turbo_sweep", self.part_id):
                 picked = _pick_rows(rm, rr, n_rows=n_rows)
             if first_trace:
                 hbm_ledger.note_compile_done(
@@ -1223,16 +1228,18 @@ class TurboBM25:
         for off, n, packed_dev in pending:
             if check is not None:
                 check()
-            with faults.device_errors("turbo_sweep", self.part_id):
+            with tracing.phase("dispatch.device_wait"), \
+                    faults.device_errors("turbo_sweep", self.part_id):
                 packed = np.asarray(packed_dev)    # [QC, n_rows + 1]
-            rows_all = packed[:, :n_rows].astype(np.int64)
-            bounds = packed[:, n_rows]
-            for qi in range(n):
-                docs = self._collect_docs(rows_all[qi])
-                s, d = self._finish_query(
-                    flat[off + qi], docs, float(bounds[qi]), k)
-                out_s[off + qi, : len(s)] = s
-                out_d[off + qi, : len(d)] = d
+            with tracing.phase("dispatch.finish", queries=n):
+                rows_all = packed[:, :n_rows].astype(np.int64)
+                bounds = packed[:, n_rows]
+                for qi in range(n):
+                    docs = self._collect_docs(rows_all[qi])
+                    s, d = self._finish_query(
+                        flat[off + qi], docs, float(bounds[qi]), k)
+                    out_s[off + qi, : len(s)] = s
+                    out_d[off + qi, : len(d)] = d
         return [(out_s[o: o + n], out_d[o: o + n]) for o, n in spans]
 
     def search(self, queries: List[List], k: int = 10):
@@ -1278,8 +1285,10 @@ class TurboBM25:
         return wq, qscale
 
     def _sweep(self, chunk, QC):
-        wq, qscale = self._sweep_weights(chunk, QC)
-        with faults.device_dispatch("turbo_sweep", self.part_id):
+        with tracing.phase("dispatch.prep", qc=QC):
+            wq, qscale = self._sweep_weights(chunk, QC)
+        with tracing.phase("dispatch.launch", qc=QC), \
+                faults.device_dispatch("turbo_sweep", self.part_id):
             out = sweep_rowmax(jnp.asarray(qscale), self.cols_hi,
                                self.cols_lo, jnp.asarray(wq), self.live,
                                QC=QC, nsw=self.nsw)
@@ -1335,7 +1344,7 @@ class TurboBM25:
         cand_s = np.empty(0, np.float32)
         if len(cand_docs):
             cand_docs = np.asarray(cand_docs, np.int64)
-            cand_s = self._exact_scores(qterms, cand_docs)
+            cand_s = self._rescore(qterms, cand_docs)
             keep = cand_s > 0
             cand_docs, cand_s = cand_docs[keep], cand_s[keep]
 
@@ -1351,7 +1360,9 @@ class TurboBM25:
             if self._sp_ok and bool(knob("ES_TPU_SPARSE")):
                 self.stats["sparse_queries"] += 1
                 _node_sparse_add("sparse_queries", 1)
-                docs_c, contrib, slack = self._sparse_contrib(cold_terms)
+                with tracing.phase("dispatch.sparse_gather",
+                                   terms=len(cold_terms)):
+                    docs_c, contrib, slack = self._sparse_contrib(cold_terms)
             else:
                 self.stats["cold_queries"] += 1
                 docs_c, contrib = self._cold_contrib(cold_terms)
@@ -1373,11 +1384,11 @@ class TurboBM25:
                                    >= kth_0]
                 if len(survivors):
                     cold_docs = survivors
-                    cold_s = self._exact_scores(qterms, cold_docs)
+                    cold_s = self._rescore(qterms, cold_docs)
             else:
                 # cold-only query: the exact path IS the full merge
                 cold_docs = docs_c
-                cold_s = self._exact_scores(qterms, cold_docs)
+                cold_s = self._rescore(qterms, cold_docs)
 
         if not len(cand_docs) and not len(cold_docs):
             return np.empty(0, np.float32), np.empty(0, np.int32)
@@ -1404,9 +1415,10 @@ class TurboBM25:
             short = len(out_s) < k and uncollected > 0
             if short or (len(out_s) >= k and kth < limit and uncollected > 0):
                 self.stats["fallbacks"] += 1
-                if self.fallback is not None:
-                    return self.fallback(terms, k)
-                return self._exact_merge(qterms, k)
+                with tracing.phase("dispatch.cert_fallback"):
+                    if self.fallback is not None:
+                        return self.fallback(terms, k)
+                    return self._exact_merge(qterms, k)
         return out_s, out_d
 
     # ---------------- bool / phrase search ----------------
@@ -1962,18 +1974,25 @@ class TurboBM25:
         scores are dropped (the BlockMax search_bool contract). Device
         and host routes return bit-identical results — both rescore
         through _exact_bool."""
+        with tracing.steps(DISPATCH_STEPS):
+            return self._search_bool(queries, k, check)
+
+    def _search_bool(self, queries, k, check):
+        # the bool / phrase / bitset routes take the four top-level steps
+        # and nothing finer (no cell reads them yet)
         Q = len(queries)
         out_s = np.zeros((Q, k), np.float32)
         out_d = np.zeros((Q, k), np.int32)
-        resolved = [self._resolve_bool(spec) for spec in queries]
-        self._ensure_bool(resolved)
-        device_idx, host_idx = self._bool_routes(resolved)
         use_bits = bool(knob("ES_TPU_BITSET"))
-        if use_bits:
-            device_idx, host_idx = self._gallop_routes(
-                resolved, device_idx, host_idx)
-            if device_idx:
-                self._ensure_bits()
+        with tracing.phase("dispatch.prep", queries=Q):
+            resolved = [self._resolve_bool(spec) for spec in queries]
+            self._ensure_bool(resolved)
+            device_idx, host_idx = self._bool_routes(resolved)
+            if use_bits:
+                device_idx, host_idx = self._gallop_routes(
+                    resolved, device_idx, host_idx)
+                if device_idx:
+                    self._ensure_bits()
         self.stats["bool_device"] += len(device_idx)
 
         # device pipeline (same two-pass shape as search_many)
@@ -1988,16 +2007,18 @@ class TurboBM25:
             if check is not None:
                 check()
             counts = None
-            if use_bits:
-                first_trace = hbm_ledger.note_dispatch("turbo_bitset", take)
-                tc0 = time.monotonic()
-                rm, rr, counts = self._sweep_bool_bits(
-                    [resolved[i] for i in sel], take)
-            else:
-                rm, rr = self._sweep_bool([resolved[i] for i in sel],
-                                          take)
-            with faults.device_errors("turbo_sweep", self.part_id):
-                picked = _pick_rows(rm, rr, n_rows=n_rows)
+            with tracing.phase("dispatch.launch", qc=take):
+                if use_bits:
+                    first_trace = hbm_ledger.note_dispatch(
+                        "turbo_bitset", take)
+                    tc0 = time.monotonic()
+                    rm, rr, counts = self._sweep_bool_bits(
+                        [resolved[i] for i in sel], take)
+                else:
+                    rm, rr = self._sweep_bool([resolved[i] for i in sel],
+                                              take)
+                with faults.device_errors("turbo_sweep", self.part_id):
+                    picked = _pick_rows(rm, rr, n_rows=n_rows)
             if use_bits and first_trace:
                 hbm_ledger.note_compile_done(
                     "turbo_bitset", take, time.monotonic() - tc0)
@@ -2008,26 +2029,30 @@ class TurboBM25:
         for sel, packed_dev, counts in pending:
             if check is not None:
                 check()
-            with faults.device_errors("turbo_sweep", self.part_id):
-                packed = np.asarray(packed_dev)
-            if counts is not None:
-                with faults.device_errors("bitset_intersect", self.part_id):
-                    self._note_bitset_counts(
-                        np.asarray(counts)[: len(sel)])
-            rows_all = packed[:, :n_rows].astype(np.int64)
-            bounds = packed[:, n_rows]
-            for j, qi in enumerate(sel):
-                docs = self._collect_docs(rows_all[j])
-                s, d = self._finish_bool(resolved[qi], docs,
-                                         float(bounds[j]), k)
+            with tracing.phase("dispatch.device_wait"):
+                with faults.device_errors("turbo_sweep", self.part_id):
+                    packed = np.asarray(packed_dev)
+                if counts is not None:
+                    with faults.device_errors("bitset_intersect",
+                                              self.part_id):
+                        self._note_bitset_counts(
+                            np.asarray(counts)[: len(sel)])
+            with tracing.phase("dispatch.finish", queries=len(sel)):
+                rows_all = packed[:, :n_rows].astype(np.int64)
+                bounds = packed[:, n_rows]
+                for j, qi in enumerate(sel):
+                    docs = self._collect_docs(rows_all[j])
+                    s, d = self._finish_bool(resolved[qi], docs,
+                                             float(bounds[j]), k)
+                    out_s[qi, : len(s)] = s
+                    out_d[qi, : len(d)] = d
+        with tracing.phase("dispatch.finish", queries=len(host_idx)):
+            for qi in host_idx:
+                if check is not None:
+                    check()
+                s, d = self._bool_host_exact(resolved[qi], k)
                 out_s[qi, : len(s)] = s
                 out_d[qi, : len(d)] = d
-        for qi in host_idx:
-            if check is not None:
-                check()
-            s, d = self._bool_host_exact(resolved[qi], k)
-            out_s[qi, : len(s)] = s
-            out_d[qi, : len(d)] = d
         return out_s, out_d
 
     def search_phrase(self, phrases: Sequence[Sequence[str]], k: int = 10,
@@ -2348,31 +2373,24 @@ class ShardedTurbo:
 
     # ---------------- fused dispatches ----------------
 
-    def _trace_chunk(self, QC: int, t0: float) -> None:
-        """Flight-recorder span per fused launch (spans only — the device
-        histogram is recorded once per dispatch at the coalescer/serving
-        layer; recording here too would double-count). The duration covers
-        the async launch, not the sweep itself — the caller's device span
-        includes the materializing fetch."""
-        tc = tracing.current()
-        if tc is not None:
-            tc.add_span("device.fused_chunk",
-                        (time.monotonic() - t0) * 1e3,
-                        partitions=len(self.turbos), qc=QC)
-
     def _dispatch_disj(self, chunk, QC: int, n_rows: int):
-        wq = np.zeros((self.Sp, 2, QC, self.Hp + 1), np.int8)
-        qs = np.ones((self.Sp, QC, 1), np.float32)
-        for i, t in enumerate(self.turbos):
-            w, q = t._sweep_weights(chunk, QC)
-            wq[i, :, :, : w.shape[2]] = w
-            qs[i] = q
+        with tracing.phase("dispatch.prep", qc=QC):
+            wq = np.zeros((self.Sp, 2, QC, self.Hp + 1), np.int8)
+            qs = np.ones((self.Sp, QC, 1), np.float32)
+            for i, t in enumerate(self.turbos):
+                w, q = t._sweep_weights(chunk, QC)
+                wq[i, :, :, : w.shape[2]] = w
+                qs[i] = q
         # the counter moves AFTER the launch so a faulted dispatch is not
         # counted — the circuit tests pin "zero device dispatches" while
         # open by watching it
         t0 = time.monotonic()
         first_trace = hbm_ledger.note_dispatch("fused_turbo", QC)
-        with faults.device_dispatch("fused_dispatch"):
+        # the call returning, not the sweep: the launch is async, and
+        # holds trace + lower + compile when the program is new
+        with tracing.phase("dispatch.launch", qc=QC,
+                           partitions=len(self.turbos)), \
+                faults.device_dispatch("fused_dispatch"):
             out = _fused_sweep_disj(
                 jnp.asarray(qs), self.cols_hi, self.cols_lo,
                 jnp.asarray(wq), self.live, mesh=self.mesh, QC=QC,
@@ -2381,7 +2399,6 @@ class ShardedTurbo:
         if first_trace:
             hbm_ledger.note_compile_done(
                 "fused_turbo", QC, time.monotonic() - t0)
-        self._trace_chunk(QC, t0)
         return out
 
     def _dispatch_bool(self, resolved, dev_sets, sel, QC: int,
@@ -2391,31 +2408,34 @@ class ShardedTurbo:
         host-routes rides the fused launch with inert inputs: all-zero
         weights on both engines, and on the bitset engine every clause
         slot pointed at that partition's zero sentinel (empty mask)."""
-        wq = np.zeros((self.Sp, 2, QC, self.Hp + 1), np.int8)
-        wp = np.zeros((self.Sp, QC, self.Hp + 1), np.int8)
-        nreq = np.zeros((self.Sp, QC, 1), np.int32)
-        qs = np.ones((self.Sp, QC, 1), np.float32)
-        if use_bits:
-            # padded partitions keep slot 0: their bits slice is all-zero,
-            # so every mask word is 0 and every chunk skips
-            q_slots = np.zeros((self.Sp, QC, BITSET_CLAUSES), np.int32)
-            q_neg = np.zeros((self.Sp, QC, BITSET_NEGS), np.int32)
-        for i, t in enumerate(self.turbos):
-            chunk = [resolved[i][qi] if qi in dev_sets[i] else None
-                     for qi in sel]
-            w, p, nr, q = t._bool_weights(chunk, QC)
-            hp = w.shape[2]
-            wq[i, :, :, :hp] = w
-            wp[i, :, :hp] = p
-            nreq[i] = nr
-            qs[i] = q
+        with tracing.phase("dispatch.prep", qc=QC):
+            wq = np.zeros((self.Sp, 2, QC, self.Hp + 1), np.int8)
+            wp = np.zeros((self.Sp, QC, self.Hp + 1), np.int8)
+            nreq = np.zeros((self.Sp, QC, 1), np.int32)
+            qs = np.ones((self.Sp, QC, 1), np.float32)
             if use_bits:
-                q_slots[i], q_neg[i] = t._bitset_prefetch(chunk, QC)
+                # padded partitions keep slot 0: their bits slice is all-zero,
+                # so every mask word is 0 and every chunk skips
+                q_slots = np.zeros((self.Sp, QC, BITSET_CLAUSES), np.int32)
+                q_neg = np.zeros((self.Sp, QC, BITSET_NEGS), np.int32)
+            for i, t in enumerate(self.turbos):
+                chunk = [resolved[i][qi] if qi in dev_sets[i] else None
+                         for qi in sel]
+                w, p, nr, q = t._bool_weights(chunk, QC)
+                hp = w.shape[2]
+                wq[i, :, :, :hp] = w
+                wp[i, :, :hp] = p
+                nreq[i] = nr
+                qs[i] = q
+                if use_bits:
+                    q_slots[i], q_neg[i] = t._bitset_prefetch(chunk, QC)
         t0 = time.monotonic()
         kind = "fused_turbo_bitset" if use_bits else "fused_turbo_bool"
         first_trace = hbm_ledger.note_dispatch(kind, QC)
         cnts = None
-        with faults.device_dispatch("fused_dispatch"):
+        with tracing.phase("dispatch.launch", qc=QC,
+                           partitions=len(self.turbos)), \
+                faults.device_dispatch("fused_dispatch"):
             if use_bits:
                 out, cnts = _fused_sweep_bitset(
                     jnp.asarray(qs), jnp.asarray(q_slots),
@@ -2432,7 +2452,6 @@ class ShardedTurbo:
         if first_trace:
             hbm_ledger.note_compile_done(
                 kind, QC, time.monotonic() - t0)
-        self._trace_chunk(QC, t0)
         return out, cnts
 
     # ---------------- search ----------------
@@ -2449,6 +2468,10 @@ class ShardedTurbo:
         still completes. Contained faults append `FaultRecord`s to
         fault_log (when given) so the serving layer can report
         failed-then-recovered shards."""
+        with tracing.steps(DISPATCH_STEPS):
+            return self._search_many(batches, k, check, fault_log)
+
+    def _search_many(self, batches, k, check, fault_log):
         flat, spans = _flatten_queries(batches)
         S = len(self.turbos)
         if not flat:
@@ -2456,14 +2479,15 @@ class ShardedTurbo:
                       np.zeros((n, k), np.int32)) for _, n in spans]
                     for _ in range(S)]
         failed: Dict[int, DeviceFaultError] = {}
-        for i, t in enumerate(self.turbos):
-            try:
-                t.ensure_columns(
-                    [tm for q in flat for tm, _ in q
-                     if t._term(tm) is not None])
-                self._refresh_part(i)
-            except DeviceFaultError as e:
-                failed[i] = e
+        with tracing.phase("dispatch.prep", queries=len(flat)):
+            for i, t in enumerate(self.turbos):
+                try:
+                    t.ensure_columns(
+                        [tm for q in flat for tm, _ in q
+                         if t._term(tm) is not None])
+                    self._refresh_part(i)
+                except DeviceFaultError as e:
+                    failed[i] = e
         n_rows = max(_GLOBAL_ROWS, k + 5)
         pending = []
         fused_err: Optional[DeviceFaultError] = None
@@ -2489,24 +2513,26 @@ class ShardedTurbo:
             packed = None
             if packed_dev is not None:
                 try:
-                    with faults.device_errors("fused_dispatch"):
+                    with tracing.phase("dispatch.device_wait"), \
+                            faults.device_errors("fused_dispatch"):
                         packed = np.asarray(packed_dev)
                 except DeviceFaultError as e:     # async fault at fetch
                     packed, fused_err = None, e
-            for si, t in enumerate(self.turbos):
-                host_only = si in failed or packed is None
-                if not host_only:
-                    rows_all = packed[si, :, :n_rows].astype(np.int64)
-                    bounds = packed[si, :, n_rows]
-                for qi in range(n):
-                    if host_only:
-                        s, d = t._exact_query(flat[off + qi], k)
-                    else:
-                        docs = t._collect_docs(rows_all[qi])
-                        s, d = t._finish_query(flat[off + qi], docs,
-                                               float(bounds[qi]), k)
-                    out_s[si, off + qi, : len(s)] = s
-                    out_d[si, off + qi, : len(d)] = d
+            with tracing.phase("dispatch.finish", queries=n):
+                for si, t in enumerate(self.turbos):
+                    host_only = si in failed or packed is None
+                    if not host_only:
+                        rows_all = packed[si, :, :n_rows].astype(np.int64)
+                        bounds = packed[si, :, n_rows]
+                    for qi in range(n):
+                        if host_only:
+                            s, d = t._exact_query(flat[off + qi], k)
+                        else:
+                            docs = t._collect_docs(rows_all[qi])
+                            s, d = t._finish_query(flat[off + qi], docs,
+                                                   float(bounds[qi]), k)
+                        out_s[si, off + qi, : len(s)] = s
+                        out_d[si, off + qi, : len(d)] = d
         if fault_log is not None:
             for i, e in sorted(failed.items()):
                 fault_log.append(FaultRecord.from_error(e, partition=i))
@@ -2527,31 +2553,37 @@ class ShardedTurbo:
         Fault containment mirrors search_many: a faulted partition (or a
         faulted fused chunk) serves its queries through _bool_host_exact,
         which every device bool result is bit-identical to anyway."""
+        with tracing.steps(DISPATCH_STEPS):
+            return self._search_bool(queries, k, check, fault_log)
+
+    def _search_bool(self, queries, k, check, fault_log):
         Q = len(queries)
         S = len(self.turbos)
         out_s = np.zeros((S, Q, k), np.float32)
         out_d = np.zeros((S, Q, k), np.int32)
-        resolved = [[t._resolve_bool(spec) for spec in queries]
-                    for t in self.turbos]
         use_bits = bool(knob("ES_TPU_BITSET"))
         failed: Dict[int, DeviceFaultError] = {}
         routes = []
-        for si, t in enumerate(self.turbos):
-            try:
-                t._ensure_bool(resolved[si])
-                if use_bits:
-                    t._ensure_bits()
-                self._refresh_part(si)
-                rt = t._bool_routes(resolved[si])
-                if use_bits:
-                    rt = t._gallop_routes(resolved[si], *rt)
-                routes.append(rt)
-            except DeviceFaultError as e:
-                failed[si] = e
-                # every resolvable query host-routes for this partition
-                routes.append(([], [qi for qi, r in enumerate(resolved[si])
-                                    if r is not None]))
-            t.stats["bool_device"] += len(routes[si][0])
+        with tracing.phase("dispatch.prep", queries=Q):
+            resolved = [[t._resolve_bool(spec) for spec in queries]
+                        for t in self.turbos]
+            for si, t in enumerate(self.turbos):
+                try:
+                    t._ensure_bool(resolved[si])
+                    if use_bits:
+                        t._ensure_bits()
+                    self._refresh_part(si)
+                    rt = t._bool_routes(resolved[si])
+                    if use_bits:
+                        rt = t._gallop_routes(resolved[si], *rt)
+                    routes.append(rt)
+                except DeviceFaultError as e:
+                    failed[si] = e
+                    # every resolvable query host-routes for this partition
+                    routes.append(
+                        ([], [qi for qi, r in enumerate(resolved[si])
+                              if r is not None]))
+                t.stats["bool_device"] += len(routes[si][0])
         dev_sets = [set(dev) for dev, _ in routes]
         union = sorted({qi for ds in dev_sets for qi in ds})
         n_rows = max(_GLOBAL_ROWS, k + 5)
@@ -2579,40 +2611,43 @@ class ShardedTurbo:
             packed = cc = None
             if packed_dev is not None:
                 try:
-                    with faults.device_errors("fused_dispatch"):
+                    with tracing.phase("dispatch.device_wait"), \
+                            faults.device_errors("fused_dispatch"):
                         packed = np.asarray(packed_dev)
                         if cnts_dev is not None:
                             cc = np.asarray(cnts_dev)
                 except DeviceFaultError as e:
                     packed, cc, fused_err = None, None, e
+            with tracing.phase("dispatch.finish", queries=len(sel)):
+                for si, t in enumerate(self.turbos):
+                    if packed is not None:
+                        rows_all = packed[si, :, :n_rows].astype(np.int64)
+                        bounds = packed[si, :, n_rows]
+                    if cc is not None:
+                        act = [j for j, qi in enumerate(sel)
+                               if qi in dev_sets[si]]
+                        if act:
+                            t._note_bitset_counts(
+                                cc[si, act], total=self.nsw * N_CHUNKS)
+                    for j, qi in enumerate(sel):
+                        if qi not in dev_sets[si]:
+                            continue
+                        if packed is None:
+                            s, d = t._bool_host_exact(resolved[si][qi], k)
+                        else:
+                            docs = t._collect_docs(rows_all[j])
+                            s, d = t._finish_bool(resolved[si][qi], docs,
+                                                  float(bounds[j]), k)
+                        out_s[si, qi, : len(s)] = s
+                        out_d[si, qi, : len(d)] = d
+        with tracing.phase("dispatch.finish", host_routed=True):
             for si, t in enumerate(self.turbos):
-                if packed is not None:
-                    rows_all = packed[si, :, :n_rows].astype(np.int64)
-                    bounds = packed[si, :, n_rows]
-                if cc is not None:
-                    act = [j for j, qi in enumerate(sel)
-                           if qi in dev_sets[si]]
-                    if act:
-                        t._note_bitset_counts(
-                            cc[si, act], total=self.nsw * N_CHUNKS)
-                for j, qi in enumerate(sel):
-                    if qi not in dev_sets[si]:
-                        continue
-                    if packed is None:
-                        s, d = t._bool_host_exact(resolved[si][qi], k)
-                    else:
-                        docs = t._collect_docs(rows_all[j])
-                        s, d = t._finish_bool(resolved[si][qi], docs,
-                                              float(bounds[j]), k)
+                for qi in routes[si][1]:
+                    if check is not None:
+                        check()
+                    s, d = t._bool_host_exact(resolved[si][qi], k)
                     out_s[si, qi, : len(s)] = s
                     out_d[si, qi, : len(d)] = d
-        for si, t in enumerate(self.turbos):
-            for qi in routes[si][1]:
-                if check is not None:
-                    check()
-                s, d = t._bool_host_exact(resolved[si][qi], k)
-                out_s[si, qi, : len(s)] = s
-                out_d[si, qi, : len(d)] = d
         if fault_log is not None:
             for i, e in sorted(failed.items()):
                 fault_log.append(FaultRecord.from_error(e, partition=i))

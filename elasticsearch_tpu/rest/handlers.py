@@ -1034,7 +1034,7 @@ class _Handlers:
         TraceContext when the flight recorder is on (the `rest_total`
         histogram records regardless). Traced profile responses gain a
         `profile.tpu` section with the trace id and per-phase totals."""
-        from elasticsearch_tpu.common import metrics, tracing
+        from elasticsearch_tpu.common import tracing
         from elasticsearch_tpu.threadpool import (
             activate_tier, tier_for_request,
         )
@@ -1045,16 +1045,14 @@ class _Handlers:
             tc = tracing.TraceContext(
                 opaque_id=req.headers.get("x-opaque-id"),
                 node=self.node.node_name, kind="rest")
-        t0 = time.monotonic()
         # SLA tier for the dispatch scheduler: classifier + optional
         # `sla` request param, bound for the whole request like the trace
         tier = tier_for_request(req.method, req.path, req.params)
-        with tracing.activate(tc), activate_tier(tier):
+        with tracing.activate(tc), \
+                tracing.phase("rest_total", path=req.path), \
+                activate_tier(tier):
             rr = self._search_inner(req)
-        total_ms = (time.monotonic() - t0) * 1e3
-        metrics.observe("rest_total", total_ms)
         if tc is not None:
-            tc.add_span("rest_total", total_ms, path=req.path)
             tracing.record_trace(tc)
             if isinstance(rr.body, dict) and isinstance(
                     rr.body.get("profile"), dict):
@@ -2215,7 +2213,8 @@ class _Handlers:
             "tpu_integrity": _tpu_integrity_stats(),
             "tpu_ccs": self.node.remotes.stats(),
             "tpu_ccr": self.node.ccr.stats(),
-            "jvm": {"uptime_in_millis": int((time.time() - _START_TIME) * 1000)},
+            "jvm": {"uptime_in_millis": int((time.time() - _START_TIME) * 1000),
+                    "gc": {"collectors": _gc_stats()}},
         }
 
     def nodes_stats(self, req: RestRequest) -> RestResponse:
@@ -2724,6 +2723,14 @@ def _tpu_knn_stats() -> dict:
     from elasticsearch_tpu.parallel import knn
 
     return knn.knn_node_stats()
+
+
+def _gc_stats() -> dict:
+    """`jvm.gc.collectors.{young,old}`: the interpreter's collector by
+    generation (old = generation 2), counts and milliseconds."""
+    from elasticsearch_tpu.common import tracing
+
+    return tracing.gc_stats()
 
 
 def _tpu_compile_stats() -> dict:

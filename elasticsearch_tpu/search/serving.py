@@ -43,7 +43,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from elasticsearch_tpu.common import faults, hbm_ledger, metrics, tracing
+from elasticsearch_tpu.common import faults, hbm_ledger, tracing
 from elasticsearch_tpu.common.errors import (
     DeviceFaultError, SearchPhaseExecutionError,
 )
@@ -51,7 +51,9 @@ from elasticsearch_tpu.common.faults import FaultRecord
 from elasticsearch_tpu.index.positions import phrase_freqs
 from elasticsearch_tpu.ops import bm25_idf
 from elasticsearch_tpu.common.settings import knob
-from elasticsearch_tpu.threadpool.coalescer import record_device
+from elasticsearch_tpu.threadpool.coalescer import (
+    device_phase, record_pad_waste,
+)
 from elasticsearch_tpu.search import queries as q
 from elasticsearch_tpu.search.queries import parse_query
 from elasticsearch_tpu.tasks.task_manager import (
@@ -593,7 +595,9 @@ class TurboEngine:
             from elasticsearch_tpu.common import integrity
             from elasticsearch_tpu.parallel.turbo import ShardedTurbo
 
-            self._sharded = ShardedTurbo(self.turbos, self.mesh)
+            with tracing.phase("engine_build.fused",
+                               partitions=len(self.turbos)):
+                self._sharded = ShardedTurbo(self.turbos, self.mesh)
             integrity.attach_scrub_health(self._sharded, self.health)
         return self._sharded
 
@@ -635,10 +639,11 @@ class TurboEngine:
         """Whole-engine host-exact tier (circuit open / catastrophic
         fault): zero device dispatches, merged via the _merge3 host
         reference — bit-identical to the device route."""
-        per = [t.search_many_host(batches, k=k, check=check)
-               for t in self.turbos]
-        return [self._merge3([p[bi] for p in per], len(batch), k)
-                for bi, batch in enumerate(batches)]
+        with tracing.phase("dispatch.finish", host_tier=True):
+            per = [t.search_many_host(batches, k=k, check=check)
+                   for t in self.turbos]
+            return [self._merge3([p[bi] for p in per], len(batch), k)
+                    for bi, batch in enumerate(batches)]
 
     def _health_account(self, log, n0: int) -> None:
         """One dispatch's containment outcome -> circuit state: any NEW
@@ -653,6 +658,14 @@ class TurboEngine:
 
     def search_many(self, batches: Sequence[List], k: int = 10, check=None,
                     fault_log=None):
+        from elasticsearch_tpu.parallel.turbo import DISPATCH_STEPS
+
+        # one accumulator per ENGINE call: the partitions' (or the fused
+        # engine's) steps and the merge below add up under it
+        with tracing.steps(DISPATCH_STEPS):
+            return self._search_many(batches, k, check, fault_log)
+
+    def _search_many(self, batches, k, check, fault_log):
         log = fault_log if fault_log is not None else []
         n0 = len(log)
         nq = sum(len(b) for b in batches)
@@ -686,9 +699,10 @@ class TurboEngine:
             self.health.record_fault(e)
             self.health.record_fallback(nq)
             return self._host_tier_many(batches, k, check)
-        out = [self._merge_parts([p[bi] for p in per], len(batch), k,
-                                 device=fused is not None, fault_log=log)
-               for bi, batch in enumerate(batches)]
+        with tracing.phase("dispatch.finish", merge=len(per)):
+            out = [self._merge_parts([p[bi] for p in per], len(batch), k,
+                                     device=fused is not None, fault_log=log)
+                   for bi, batch in enumerate(batches)]
         self._health_account(log, n0)
         return out
 
@@ -748,6 +762,12 @@ class TurboEngine:
         (scores [Q,k], partition [Q,k], ord [Q,k]). Fault containment
         mirrors search_many (circuit-open / catastrophic -> the
         _bool_host_exact tier, per-partition isolation otherwise)."""
+        from elasticsearch_tpu.parallel.turbo import DISPATCH_STEPS
+
+        with tracing.steps(DISPATCH_STEPS):
+            return self._search_bool(queries, k, check, fault_log)
+
+    def _search_bool(self, queries, k, check, fault_log):
         log = fault_log if fault_log is not None else []
         n0 = len(log)
         if not self.health.allow_device():
@@ -781,8 +801,9 @@ class TurboEngine:
             per = [t.search_bool_host(queries, k=k, check=check)
                    for t in self.turbos]
             return self._merge3(per, len(queries), k)
-        out = self._merge_parts(per, len(queries), k,
-                                device=fused is not None, fault_log=log)
+        with tracing.phase("dispatch.finish", merge=len(per)):
+            out = self._merge_parts(per, len(queries), k,
+                                    device=fused is not None, fault_log=log)
         self._health_account(log, n0)
         return out
 
@@ -905,10 +926,12 @@ def select_bm25_engine(segments, field: str, live_masks, mesh, *,
         cdf = COLD_DF if cold_df is None else cold_df
         turbos = []
         for i, seg in enumerate(segments):
-            stacked = build_stacked_bm25(
-                [seg], field,
-                live_masks=None if live_masks is None else [live_masks[i]],
-                mesh=mesh, serve_only=True, device_arrays=False)
+            with tracing.phase("engine_build.stack", partition=i):
+                stacked = build_stacked_bm25(
+                    [seg], field,
+                    live_masks=None if live_masks is None
+                    else [live_masks[i]],
+                    mesh=mesh, serve_only=True, device_arrays=False)
             kwargs = {} if cold_df is None else {"cold_df": cold_df}
             # budget proportional to this partition's NEED (eligibility
             # already validated the sum fits): an equal split would starve
@@ -917,10 +940,11 @@ def select_bm25_engine(segments, field: str, live_masks, mesh, *,
             n_col = 0 if fp is None else int((fp.doc_freq >= cdf).sum())
             dp = -(-max(seg.n_docs, 1) // SW) * SW
             need_bytes = 2 * dp * (n_col + 8)
-            turbos.append(TurboBM25(
-                stacked, hbm_budget_bytes=need_bytes,
-                total_docs=total_docs, avgdl=avgdl,
-                df_of=lambda t: df_map.get(t, 0), **kwargs))
+            with tracing.phase("engine_build.lanes", partition=i):
+                turbos.append(TurboBM25(
+                    stacked, hbm_budget_bytes=need_bytes,
+                    total_docs=total_docs, avgdl=avgdl,
+                    df_of=lambda t: df_map.get(t, 0), **kwargs))
         # the fused S > 1 path builds its OWN dp=1 partition mesh over the
         # visible devices — the caller's mesh keeps its (dp, shard) layout
         # for the BlockMax/SPMD programs and is not reused here
@@ -1386,16 +1410,14 @@ class ServingContext:
             if spec is None:
                 return None
             try:
-                t_dev = time.monotonic()
-                scores, parts, ords = eng.search_bool(
-                    [spec], k=k, check=check, fault_log=flog)
-                dev_ms = (time.monotonic() - t_dev) * 1e3
                 # search_bool bypasses the scheduler, so the conjunctive
-                # path's single authoritative device-histogram site is
-                # here (batch shape + pad waste ride along in the shared
-                # helper)
-                record_device(eng, 1, dev_ms,
-                              engine_name=engine_desc(eng)[0])
+                # path's single authoritative `device` site is here
+                # (batch shape + pad waste ride along)
+                with device_phase(eng, 1, engine_desc(eng)[0]) as ph:
+                    scores, parts, ords = eng.search_bool(
+                        [spec], k=k, check=check, fault_log=flog)
+                dev_ms = ph.ms
+                record_pad_waste(eng, 1)
             except DispatchDeadlineError:
                 _count_serving("fastpath_timed_out")
                 return timed_out
@@ -1406,24 +1428,19 @@ class ServingContext:
             return None
         if flog:
             _count_serving("shard_fault_recoveries", len(flog))
-        t_demux = time.monotonic()
         hits = []
         max_score = None
-        for j in range(k):
-            s = float(scores[0, j])
-            if s <= 0 or not np.isfinite(s):
-                break
-            part = snap.partitions[int(parts[0, j])]
-            o = int(ords[0, j])
-            hits.append(ShardHit(leaf_idx=part.leaf_idx, ord=o, score=s,
-                                 global_ord=part.base + o))
-            max_score = s if max_score is None else max(max_score, s)
-        total, relation = total_rel(plan, snap, request, len(hits))
-        demux_ms = (time.monotonic() - t_demux) * 1e3
-        metrics.observe("demux", demux_ms)
-        tc = tracing.current()
-        if tc is not None:
-            tc.add_span("demux", demux_ms)
+        with tracing.phase("demux"):
+            for j in range(k):
+                s = float(scores[0, j])
+                if s <= 0 or not np.isfinite(s):
+                    break
+                part = snap.partitions[int(parts[0, j])]
+                o = int(ords[0, j])
+                hits.append(ShardHit(leaf_idx=part.leaf_idx, ord=o, score=s,
+                                     global_ord=part.base + o))
+                max_score = s if max_score is None else max(max_score, s)
+            total, relation = total_rel(plan, snap, request, len(hits))
         return QuerySearchResult(
             total=total, relation=relation, hits=hits, max_score=max_score,
             timed_out=bool(deadline is not None and deadline.expired),
@@ -1508,22 +1525,18 @@ class ServingContext:
             health.record_success()
         if flog:
             _count_serving("shard_fault_recoveries", len(flog))
-        t_demux = time.monotonic()
         extracted = []
-        for qi, (plan, request) in enumerate(zip(plans, requests)):
-            hits = []
-            for j in range(k):
-                if scores[qi, j] <= 0 or not np.isfinite(scores[qi, j]):
-                    break
-                hits.append((int(parts[qi, j]), int(ords[qi, j]),
-                             float(scores[qi, j])))
-            total, relation = self._disj_total(plan, snap, request, len(hits))
-            extracted.append((hits, total, relation))
-        demux_ms = (time.monotonic() - t_demux) * 1e3
-        metrics.observe("demux", demux_ms)
-        tc = tracing.current()
-        if tc is not None:
-            tc.add_span("demux", demux_ms, batch=len(requests))
+        with tracing.phase("demux", batch=len(requests)):
+            for qi, (plan, request) in enumerate(zip(plans, requests)):
+                hits = []
+                for j in range(k):
+                    if scores[qi, j] <= 0 or not np.isfinite(scores[qi, j]):
+                        break
+                    hits.append((int(parts[qi, j]), int(ords[qi, j]),
+                                 float(scores[qi, j])))
+                total, relation = self._disj_total(plan, snap, request,
+                                                   len(hits))
+                extracted.append((hits, total, relation))
         results = []
         for qi, request in enumerate(requests):
             hits, total, relation = extracted[qi]
@@ -1582,22 +1595,17 @@ class ServingContext:
             return [None] * len(requests)
         if flog:
             _count_serving("shard_fault_recoveries", len(flog))
-        t_demux = time.monotonic()
         extracted = []
-        for qi, kp in enumerate(kplans):
-            hits = []
-            for j in range(min(k, kp.k)):
-                if scores[qi, j] <= 0 or not np.isfinite(scores[qi, j]):
-                    break
-                hits.append((int(parts[qi, j]), int(ords[qi, j]),
-                             float(scores[qi, j])))
-            # kNN totals are the k nearest by definition, always exact
-            extracted.append((hits, len(hits), "eq"))
-        demux_ms = (time.monotonic() - t_demux) * 1e3
-        metrics.observe("demux", demux_ms)
-        tc = tracing.current()
-        if tc is not None:
-            tc.add_span("demux", demux_ms, batch=len(requests))
+        with tracing.phase("demux", batch=len(requests)):
+            for qi, kp in enumerate(kplans):
+                hits = []
+                for j in range(min(k, kp.k)):
+                    if scores[qi, j] <= 0 or not np.isfinite(scores[qi, j]):
+                        break
+                    hits.append((int(parts[qi, j]), int(ords[qi, j]),
+                                 float(scores[qi, j])))
+                # kNN totals are the k nearest by definition, always exact
+                extracted.append((hits, len(hits), "eq"))
         results = []
         for qi, request in enumerate(requests):
             hits, total, relation = extracted[qi]
@@ -1677,15 +1685,14 @@ class ServingContext:
             check = self._combined_check(task, [deadline])
             flog: List[FaultRecord] = []
             try:
-                t_dev = time.monotonic()
-                scores, parts, ords = eng.search_bool(
-                    [spec], k=k, check=check, fault_log=flog)
-                dev_ms = (time.monotonic() - t_dev) * 1e3
                 # search_bool bypasses the scheduler: this is the
-                # conjunctive path's device-histogram site (shape + pad
-                # waste included via the shared helper)
-                record_device(eng, 1, dev_ms,
-                              engine_name=engine_desc(eng)[0])
+                # conjunctive path's `device` site (shape + pad waste
+                # included)
+                with device_phase(eng, 1, engine_desc(eng)[0]) as ph:
+                    scores, parts, ords = eng.search_bool(
+                        [spec], k=k, check=check, fault_log=flog)
+                dev_ms = ph.ms
+                record_pad_waste(eng, 1)
             except DispatchDeadlineError:
                 _count_serving("fastpath_timed_out")
                 return self._timed_out_response(request, snap, start)
@@ -1814,22 +1821,18 @@ class ServingContext:
         window = hits[from_: from_ + size]
         max_score = hits[0][2] if hits else None
         out_hits = []
-        t_fetch = time.monotonic()
-        for pi, ord_, score in window:
-            part = snap.partitions[pi]
-            sh = ShardHit(leaf_idx=part.leaf_idx, ord=ord_, score=score,
-                          global_ord=part.base + ord_)
-            fetched = execute_fetch_phase(
-                snap.searchers[part.shard_id], [sh], request, self.svc.name)
-            hit = fetched[0]
-            if hit.get("_score") is None:
-                hit["_score"] = score
-            out_hits.append(hit)
-        fetch_ms = (time.monotonic() - t_fetch) * 1e3
-        metrics.observe("fetch", fetch_ms)
-        tc = tracing.current()
-        if tc is not None:
-            tc.add_span("fetch", fetch_ms, hits=len(out_hits))
+        with tracing.phase("fetch", hits=len(window)):
+            for pi, ord_, score in window:
+                part = snap.partitions[pi]
+                sh = ShardHit(leaf_idx=part.leaf_idx, ord=ord_, score=score,
+                              global_ord=part.base + ord_)
+                fetched = execute_fetch_phase(
+                    snap.searchers[part.shard_id], [sh], request,
+                    self.svc.name)
+                hit = fetched[0]
+                if hit.get("_score") is None:
+                    hit["_score"] = score
+                out_hits.append(hit)
         took = int((time.monotonic() - start) * 1000)
         resp = {
             "took": took,

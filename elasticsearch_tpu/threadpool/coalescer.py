@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import itertools
 import threading
-import time
 from typing import Dict, List, Optional, Tuple
 
 from elasticsearch_tpu.common import metrics, tracing
@@ -68,21 +67,26 @@ def _env_window_us() -> float:
     return knob("ES_TPU_COALESCE_US")
 
 
-def record_device(engine, n_queries: int, dt_ms: float,
-                  engine_name: Optional[str] = None) -> None:
-    """Flight recorder: one device dispatch. Every dispatch path funnels
-    through its single authoritative call site of this helper (coalescer
-    direct + leader, scheduler, serving's search_bool sites), so latency
-    AND batch-shape/pad-waste land together — including direct and fused
-    ShardedTurbo dispatches that the old leader-only pad accounting
-    missed."""
-    metrics.observe("device", dt_ms)
-    record_pad_waste(engine, n_queries)
-    tc = tracing.current()
-    if tc is not None:
-        tc.add_span("device", dt_ms,
-                    engine=engine_name or getattr(engine, "kind", "?"),
-                    batch=n_queries)
+def device_phase(engine, n_queries: int,
+                 engine_name: Optional[str] = None) -> tracing.phase:
+    """Flight recorder: the `device` phase around one device dispatch.
+    Every dispatch path (coalescer direct + leader, scheduler direct +
+    lane through `run_device`, serving's search_bool sites) runs its
+    engine call under it and then calls `record_pad_waste`, so latency
+    AND batch shape land together; the engines' `dispatch.*` steps are
+    its children."""
+    return tracing.phase(
+        "device", engine=engine_name or getattr(engine, "kind", "?"),
+        batch=n_queries)
+
+
+def run_device(engine, queries: List, k: int, check=None, fault_log=None):
+    """One `search_many` dispatch under the `device` phase."""
+    with device_phase(engine, len(queries)):
+        out = DispatchCoalescer._run(engine, queries, k, check=check,
+                                     fault_log=fault_log)
+    record_pad_waste(engine, len(queries))
+    return out
 
 
 def record_pad_waste(engine, n: int) -> None:
@@ -231,12 +235,8 @@ class DispatchCoalescer:
         if window_s <= 0 or len(queries) > self.small_batch_max:
             with self._lock:
                 self._direct_dispatches += 1
-            t_dev = time.monotonic()
-            out = self._run(engine, queries, k, check=check,
-                            fault_log=fault_log)
-            record_device(engine, len(queries),
-                          (time.monotonic() - t_dev) * 1e3)
-            return out
+            return run_device(engine, queries, k, check=check,
+                              fault_log=fault_log)
 
         with self._lock:
             # key under the lock so one engine gets exactly one serial
@@ -255,28 +255,24 @@ class DispatchCoalescer:
                 batch.fill.set()
 
         if leader:
-            t_wait = time.monotonic()
-            batch.fill.wait(window_s)
-            with self._lock:
-                # close the window: late arrivals start a fresh batch
-                batch.closed = True
-                if self._pending.get(key) is batch:
-                    del self._pending[key]
-                n = len(batch.queries)
-                self._coalesced_dispatches += 1
-                self._coalesced_queries += n
-                if n > self._largest_batch:
-                    self._largest_batch = n
-            wait_ms = (time.monotonic() - t_wait) * 1e3
-            metrics.observe("coalesce_wait", wait_ms)
-            tc = tracing.current()
-            if tc is not None:
-                tc.add_span("coalesce_wait", wait_ms, role="leader", batch=n)
+            with tracing.phase("coalesce_wait", role="leader") as ph:
+                batch.fill.wait(window_s)
+                with self._lock:
+                    # close the window: late arrivals start a fresh batch
+                    batch.closed = True
+                    if self._pending.get(key) is batch:
+                        del self._pending[key]
+                    n = len(batch.queries)
+                    self._coalesced_dispatches += 1
+                    self._coalesced_queries += n
+                    if n > self._largest_batch:
+                        self._largest_batch = n
+                ph.meta["batch"] = n
             try:
-                t_dev = time.monotonic()
-                batch.results = self._run(engine, batch.queries, batch.k,
-                                          fault_log=batch.fault_log)
-                record_device(engine, n, (time.monotonic() - t_dev) * 1e3)
+                # the leader's own context is the one active here: the
+                # batch's `device` span lands on its flight record
+                batch.results = run_device(engine, batch.queries, batch.k,
+                                           fault_log=batch.fault_log)
                 from elasticsearch_tpu.common.overload import (
                     default_overload,
                 )
@@ -293,13 +289,8 @@ class DispatchCoalescer:
             finally:
                 batch.done.set()
         else:
-            t_wait = time.monotonic()
-            batch.done.wait()
-            wait_ms = (time.monotonic() - t_wait) * 1e3
-            metrics.observe("coalesce_wait", wait_ms)
-            tc = tracing.current()
-            if tc is not None:
-                tc.add_span("coalesce_wait", wait_ms, role="follower")
+            with tracing.phase("coalesce_wait", role="follower"):
+                batch.done.wait()
         if check is not None:
             check()
         if ct is not None:

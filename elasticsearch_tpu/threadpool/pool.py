@@ -24,7 +24,7 @@ import time
 from collections import deque
 from typing import Callable, Dict, Optional
 
-from elasticsearch_tpu.common import metrics, tracing
+from elasticsearch_tpu.common import tracing
 from elasticsearch_tpu.common.errors import ElasticsearchTpuError
 from elasticsearch_tpu.common.settings import knob
 from elasticsearch_tpu.tasks import task_manager as _taskmgr
@@ -50,7 +50,7 @@ class _Task:
     """Submission handle: a tiny future (result or raised error)."""
 
     __slots__ = ("fn", "args", "kwargs", "result", "error", "_done",
-                 "submitted", "trace", "tier", "taskref")
+                 "submitted", "trace", "span", "tier", "taskref")
 
     def __init__(self, fn, args, kwargs):
         self.fn = fn
@@ -65,6 +65,7 @@ class _Task:
         # worker (flight recorder + scheduler-tier + cancellation
         # propagation)
         self.trace = tracing.current()
+        self.span = tracing.current_span()
         self.tier = _sched.current_tier()
         self.taskref = _taskmgr.current_task()
 
@@ -161,10 +162,9 @@ class FixedExecutor:
                     (1 - _EWMA_ALPHA) * self.queue_ewma_ms \
                     + _EWMA_ALPHA * qw_ms
             # composed name: ad-hoc test pools fall outside the registry
-            metrics.observe_if_declared(f"queue_wait.{self.name}", qw_ms)
-            if task.trace is not None:
-                task.trace.add_span(f"queue_wait.{self.name}", qw_ms)
-            with tracing.activate(task.trace), \
+            tracing.record(f"queue_wait.{self.name}", qw_ms,
+                           tc=task.trace, parent=task.span)
+            with tracing.activate(task.trace, task.span), \
                     _sched.activate_tier(task.tier), \
                     _taskmgr.activate(task.taskref):
                 task.run()

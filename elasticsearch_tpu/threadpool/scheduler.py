@@ -57,8 +57,8 @@ from elasticsearch_tpu.common import metrics, tracing
 from elasticsearch_tpu.common.settings import knob
 from elasticsearch_tpu.tasks import task_manager as _taskmgr
 from elasticsearch_tpu.threadpool.coalescer import (
-    SMALL_BATCH_MAX, DispatchCoalescer, _engine_key, default_coalescer,
-    record_device, retry_batch_solo,
+    SMALL_BATCH_MAX, _engine_key, default_coalescer, retry_batch_solo,
+    run_device,
 )
 
 TIER_INTERACTIVE = "interactive"
@@ -164,7 +164,7 @@ class _Waiter:
     """One dispatch() call parked in a lane queue."""
 
     __slots__ = ("queries", "tier", "enqueued", "done", "batch", "base",
-                 "trace", "error")
+                 "trace", "span", "error")
 
     def __init__(self, queries: List, tier: str):
         self.queries = queries
@@ -173,7 +173,10 @@ class _Waiter:
         self.done = threading.Event()
         self.batch: Optional[_SchedBatch] = None   # set at flush
         self.base = 0                              # row offset in the batch
+        # the submitter's context and the span it is in (its
+        # `sched_tier_wait.*`): the batch's spans descend from it
         self.trace = tracing.current()
+        self.span = tracing.current_span()
         self.error: Optional[BaseException] = None  # lane-thread crash only
 
     def age(self, now: float) -> float:
@@ -198,9 +201,10 @@ class _SchedBatch:
         self.error: Optional[BaseException] = None
         self.fault_log: List = []
         self.query_errors: Dict[int, BaseException] = {}
-        # the first waiter's trace plays the coalescer-leader role: the
-        # device span lands on exactly one requester's flight record
-        self.trace = waiters[0].trace if waiters else None
+        # what the lane thread records for this batch (`device` and its
+        # `dispatch.*` children) is recorded once and linked into the
+        # context of EVERY traced waiter
+        self.trace = tracing.fanout((w.trace, w.span) for w in waiters)
         self._lock = threading.Lock()
         self._remaining = len(waiters)  # guarded by: _lock
         for w in waiters:
@@ -275,6 +279,9 @@ class AdaptiveDispatchScheduler:
         self._bucket_counts: Dict[int, int] = {}        # guarded by: _lock
         self._tier_counts: Dict[str, int] = {}          # guarded by: _lock
         self._tier_wait_ms: Dict[str, float] = {}       # guarded by: _lock
+        # ms the lane threads spent parked on an EMPTY queue (no work
+        # offered): window = lane_idle_ms + dispatches x `device`
+        self._lane_idle_ms = 0.0                        # guarded by: _lock
         # per-lane in-flight batches, the raw series behind the sampler's
         # per-lane device busy fraction (PR 12)
         self._lane_inflight: Dict[Tuple[int, int], int] = {}  # guarded by: _lock
@@ -356,33 +363,25 @@ class AdaptiveDispatchScheduler:
             with self._lock:
                 self._direct_dispatches += 1
                 self._tier_counts[tier] = self._tier_counts.get(tier, 0) + 1
-            t_dev = time.monotonic()
-            out = DispatchCoalescer._run(engine, queries, k, check=check,
-                                         fault_log=fault_log)
-            record_device(engine, len(queries),
-                          (time.monotonic() - t_dev) * 1e3)
-            return out
+            return run_device(engine, queries, k, check=check,
+                              fault_log=fault_log)
 
         tier = tier if tier in _TIERS else current_tier()
-        w = _Waiter(list(queries), tier)
-        lane = self._enqueue(engine, k, w)
-        t0 = time.monotonic()
-        w.done.wait()
-        wait_ms = (time.monotonic() - t0) * 1e3
         # composed name: exactly the declared sched_tier_wait.* pair
-        metrics.observe_if_declared(f"sched_tier_wait.{tier}", wait_ms)
+        with tracing.phase(f"sched_tier_wait.{tier}") as ph:
+            w = _Waiter(list(queries), tier)
+            lane = self._enqueue(engine, k, w)
+            w.done.wait()
+            batch = w.batch
+            if batch is not None:
+                ph.meta.update(batch=len(batch.queries), bucket=batch.bucket)
         with self._lock:
             self._tier_counts[tier] = self._tier_counts.get(tier, 0) + 1
             self._tier_wait_ms[tier] = \
-                self._tier_wait_ms.get(tier, 0.0) + wait_ms
-        batch = w.batch
+                self._tier_wait_ms.get(tier, 0.0) + ph.ms
         if batch is None:          # lane thread crashed before the flush
             raise w.error if w.error is not None else \
                 RuntimeError("scheduler lane failed before dispatch")
-        tc = tracing.current()
-        if tc is not None:
-            tc.add_span("sched_wait", wait_ms, tier=tier,
-                        batch=len(batch.queries), bucket=batch.bucket)
         try:
             if check is not None:
                 check()
@@ -472,7 +471,15 @@ class AdaptiveDispatchScheduler:
             while True:
                 with lane.lock:
                     if not lane.queue:
-                        notified = lane.cond.wait(self._idle_s)
+                        # no work offered: the lane parks on its EMPTY
+                        # queue (against `sched.fill`, `es.device`: host
+                        # work inside a dispatch)
+                        t_idle = time.monotonic()
+                        with tracing.annotation("sched.idle"):
+                            notified = lane.cond.wait(self._idle_s)
+                        idle_ms = (time.monotonic() - t_idle) * 1e3
+                        with self._lock:
+                            self._lane_idle_ms += idle_ms
                         if not lane.queue:
                             if notified:
                                 continue      # spurious wakeup
@@ -490,7 +497,8 @@ class AdaptiveDispatchScheduler:
                         # until the oldest waiter's tier budget expires
                         due_at = min(w.enqueued + self.budget_s(w.tier)
                                      for w in lane.queue)
-                        lane.cond.wait(max(due_at - now, 1e-4))
+                        with tracing.annotation("sched.fill", queued=depth):
+                            lane.cond.wait(max(due_at - now, 1e-4))
                         continue
                 # device work happens OUTSIDE the lane lock: late
                 # arrivals keep queueing into the next batch while this
@@ -578,12 +586,9 @@ class AdaptiveDispatchScheduler:
         metrics.counter_add("sched_flushes")
         try:
             with tracing.activate(batch.trace):
-                t_dev = time.monotonic()
-                batch.results = DispatchCoalescer._run(
+                batch.results = run_device(
                     batch.engine, batch.queries, batch.k,
                     fault_log=batch.fault_log)
-                record_device(batch.engine, n,
-                              (time.monotonic() - t_dev) * 1e3)
                 from elasticsearch_tpu.common.overload import (
                     default_overload,
                 )
@@ -640,6 +645,7 @@ class AdaptiveDispatchScheduler:
                 "largest_batch": self._largest_batch,
                 "mean_batch": round(merged / flushes, 3) if flushes
                 else 0.0,
+                "lane_idle_ms": round(self._lane_idle_ms, 3),
                 "sched_batch_retries": self._batch_retries,
                 "inflight": self._inflight,
                 "max_inflight": self._max_inflight,

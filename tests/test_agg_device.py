@@ -279,6 +279,10 @@ def test_ledger_reconciles_and_knobs_declared(monkeypatch):
     assert knob("ES_TPU_AGG") is True
     assert knob("ES_TPU_AGG_HBM_FRAC") == 0.25
 
+    # a fresh process-default engine: another test file of this worker may
+    # have reset the ledger (tests/test_telemetry.py does) and orphaned the
+    # old one's handle
+    monkeypatch.setattr(agg_device, "_ENGINE", None)
     svc = _make_service(n=1200, seed=19)
     monkeypatch.setattr(agg_mod, "AGG_DEVICE_MIN_DOCS", 1)
     svc._search_dense(

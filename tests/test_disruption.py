@@ -224,7 +224,9 @@ def test_transport_circuit_opens_then_recovers(monkeypatch):
     """Consecutive transport failures to one node open its circuit (routing
     quarantine); after the backoff a half-open probe against the revived
     node closes it again."""
-    monkeypatch.setenv("ES_TPU_HEALTH_BACKOFF_MS", "50")
+    # long enough that a loaded machine (six test workers) cannot spend the
+    # whole backoff between the search that opens the circuit and the next
+    monkeypatch.setenv("ES_TPU_HEALTH_BACKOFF_MS", "400")
     nodes, store, channels = make_cluster(n_data=2)
     master, a, b = nodes
     a.create_index("docs", index_body(2, 0))
@@ -248,7 +250,7 @@ def test_transport_circuit_opens_then_recovers(monkeypatch):
     assert delta(before, "node_circuit_open") >= 1
 
     channels.revive(victim)
-    time.sleep(0.07)   # past the 50ms backoff -> half-open probe admitted
+    time.sleep(0.45)   # past the 400ms backoff -> half-open probe admitted
     r = master.search("docs", BODY)
     assert r["_shards"]["failed"] == 0
     assert h.state == "closed"

@@ -425,6 +425,28 @@ def record(ms, pool):
     assert lint_sources([_METRICS_TWIN, ok], select={"TPU005"}) == []
 
 
+@pytest.mark.parametrize("call", ["tracing.phase", "tracing.record"])
+def test_tpu005_span_primitive_names_are_tied_to_the_registry(call):
+    """`tracing.phase("x")` / `tracing.record("x", ms)` observe under the
+    span's own name (PR 27): a literal name must be declared; a composed
+    one, and a `phase` / `record` of some other object, are left alone."""
+    src = (_TPU005_PATH, f'''
+from elasticsearch_tpu.common import tracing
+
+def serve(ms, pool, health, hist):
+    with {call}("devcie", 1.0):
+        pass
+    with {call}("device", 1.0):
+        pass
+    {call}(f"queue_wait.{{pool}}", ms)
+    health.record("not_a_histogram")
+    hist.phase("moon")
+''')
+    findings = lint_sources([_METRICS_TWIN, src], select={"TPU005"})
+    assert rules_of(findings) == ["TPU005"]
+    assert "devcie" in findings[0].message and call in findings[0].message
+
+
 def test_tpu005_observe_pass_needs_registry_in_scope():
     """Without metrics.py in the lint scope there is no declaration set, so
     the rule must stay silent (fixture snippets would otherwise light up)."""

@@ -313,6 +313,10 @@ def test_sampling_differential_bit_identity(monkeypatch):
     master = nodes[0]
     r_off = master.search("docs", BODY)
     assert tracing.recent_traces() == []      # untraced by default
+    # with no context (and no profiler) the one primitive still feeds the
+    # standing histograms: that half of it is always on
+    assert metrics.summary("query")["count"] >= 2
+    assert metrics.summary("merge")["count"] >= 1
 
     monkeypatch.setenv("ES_TPU_TRACE_SAMPLE", "1")
     r_on = master.search("docs", BODY)
@@ -423,3 +427,342 @@ def test_profile_response_carries_rest_trace(env):
     # the profile query tree is still the classic shape next to the
     # tpu section
     assert r["profile"]["shards"][0]["searches"][0]["query"]
+
+
+
+# --------------------------------------------------------------------------
+# the one span primitive (PR 27): ids, parents, self time, batches, builds
+# --------------------------------------------------------------------------
+
+
+def _by_name(tc):
+    out = {}
+    for s in tc.span_dicts():
+        out.setdefault(s["name"], []).append(s)
+    return out
+
+
+def test_phase_spans_have_ids_parents_and_one_clock():
+    tc = tracing.TraceContext(node="n1", kind="rest")
+    assert tracing.current_span() == 0 and tracing.open_phase() is None
+    with tracing.activate(tc):
+        with tracing.phase("query", shard=0) as outer:
+            assert tracing.open_phase() == "query"
+            assert tracing.current_span() == outer.span_id != 0
+            with tracing.phase("device", batch=1) as mid:
+                with tracing.phase("dispatch.prep"):
+                    pass
+                with tracing.phase("dispatch.finish"):
+                    with tracing.phase("dispatch.rescore"):
+                        pass
+            tc.add_span("rpc_query", 0.5, node="d0")   # measured by the caller
+    assert outer.ms >= mid.ms > 0
+    spans = _by_name(tc)
+    q, dev = spans["query"][0], spans["device"][0]
+    assert q["parent"] == 0 and q["meta"] == {"shard": 0}
+    assert dev["parent"] == q["id"]
+    assert spans["dispatch.prep"][0]["parent"] == dev["id"]
+    assert spans["dispatch.rescore"][0]["parent"] == \
+        spans["dispatch.finish"][0]["id"]
+    assert spans["rpc_query"][0]["parent"] == q["id"]
+    ids = [s["id"] for s in tc.span_dicts()]
+    assert len(set(ids)) == len(ids) and all(ids)
+    for s in tc.span_dicts():
+        assert s["trace_id"] == tc.trace_id
+        assert s["start_ns"] <= s["end_ns"]
+        assert s["duration_ms"] == pytest.approx(
+            (s["end_ns"] - s["start_ns"]) / 1e6, abs=2e-3)
+        assert s["start_ms"] >= 0
+    # children lie inside parents on the one process-wide clock
+    assert q["start_ns"] <= dev["start_ns"] and dev["end_ns"] <= q["end_ns"]
+    # the histograms took the same durations, with no context needed
+    for name in ("query", "device", "dispatch.prep", "dispatch.finish",
+                 "dispatch.rescore"):
+        assert metrics.summary(name)["count"] == 1
+    with tracing.phase("dispatch.prep"):
+        pass
+    assert metrics.summary("dispatch.prep")["count"] == 2
+    assert len(tc.span_dicts()) == 6            # ... and no span without one
+    with pytest.raises(metrics.UndeclaredHistogramError):
+        with tracing.phase("not_a_histogram"):
+            pass
+
+
+def test_phase_totals_are_self_time_and_sum_to_the_root():
+    import time as _time
+
+    tc = tracing.TraceContext(kind="shard_query")
+    with tracing.activate(tc):
+        with tracing.phase("query") as root:
+            _time.sleep(0.002)
+            with tracing.phase("device"):
+                with tracing.phase("dispatch.prep"):
+                    _time.sleep(0.002)
+                with tracing.phase("dispatch.finish"):
+                    _time.sleep(0.001)
+                    with tracing.phase("dispatch.rescore"):
+                        _time.sleep(0.002)
+                    with tracing.phase("dispatch.rescore"):
+                        _time.sleep(0.001)
+            with tracing.phase("fetch"):
+                _time.sleep(0.001)
+    totals = tc.phase_totals()
+    assert set(totals) == {"query", "device", "dispatch.prep",
+                           "dispatch.finish", "dispatch.rescore", "fetch"}
+    assert sum(totals.values()) == pytest.approx(root.ms, abs=0.02)
+    # self time: what the children cover is theirs, not the parent's
+    assert totals["dispatch.rescore"] >= 3.0
+    assert 1.0 <= totals["dispatch.finish"] < 3.0
+    assert totals["device"] < 1.0
+    assert totals["query"] >= 2.0
+    assert tracing.self_times(tc.span_dicts()) == totals
+
+
+def test_spans_cross_a_pool_hop_with_their_parent():
+    from elasticsearch_tpu.threadpool.pool import FixedExecutor
+
+    pool = FixedExecutor("search", size=1, queue_size=8)
+    tc = tracing.TraceContext()
+    seen = {}
+
+    def work():
+        seen["tc"] = tracing.current()
+        with tracing.phase("fetch"):
+            pass
+
+    try:
+        with tracing.activate(tc), tracing.phase("query") as q:
+            pool.submit(work).get(timeout=10)
+    finally:
+        pool.shutdown()
+    assert seen["tc"] is tc
+    spans = _by_name(tc)
+    assert spans["queue_wait.search"][0]["parent"] == q.span_id
+    assert spans["fetch"][0]["parent"] == q.span_id
+    assert metrics.summary("queue_wait.search")["count"] == 1
+
+
+class _SteppedEngine:
+    """search_many stub that records the steps a real engine does."""
+
+    kind = "stub"
+    STEPS = metrics.DISPATCH_TOP_STEPS + ("dispatch.rescore",)
+
+    def search_many(self, batches, k=10, check=None):
+        import numpy as np
+
+        qs = batches[0]
+        with tracing.steps(self.STEPS):
+            with tracing.phase("dispatch.prep", queries=len(qs)):
+                pass
+            with tracing.phase("dispatch.launch"):
+                pass
+            with tracing.phase("dispatch.device_wait"):
+                pass
+            with tracing.phase("dispatch.finish"):
+                for _ in qs:
+                    with tracing.phase("dispatch.rescore"):
+                        pass
+        z = np.zeros((len(qs), k), np.int32)
+        s = np.zeros((len(qs), k), np.float32)
+        s[:, 0] = 1.0
+        return [(s, z, z.copy())]
+
+
+def test_a_batch_of_traced_waiters_each_see_its_device_span():
+    import threading
+
+    from elasticsearch_tpu.threadpool.scheduler import (
+        AdaptiveDispatchScheduler,
+    )
+
+    n = 4
+    sched = AdaptiveDispatchScheduler(buckets=(n,), interactive_us=2e6,
+                                      inflight=1)
+    eng = _SteppedEngine()
+    tcs = [tracing.TraceContext(node=f"w{i}") for i in range(n)]
+    errors = []
+
+    def waiter(i):
+        try:
+            # the fourth waiter rides untraced: it gets its rows, no spans
+            with tracing.activate(tcs[i] if i < n - 1 else None), \
+                    tracing.phase("query", shard=i):
+                sched.dispatch(eng, [[f"q{i}"]], 10)
+        except BaseException as e:  # noqa: BLE001 — asserted below
+            errors.append(e)
+
+    threads = [threading.Thread(target=waiter, args=(i,)) for i in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+    assert not errors
+    assert sched.stats()["sched_dispatches"] == 1        # ONE batch of 4
+    device_ids = set()
+    for tc in tcs[:n - 1]:
+        spans = _by_name(tc)
+        wait = spans["sched_tier_wait.interactive"][0]
+        assert wait["parent"] == spans["query"][0]["id"]
+        assert wait["meta"]["batch"] == n and wait["meta"]["bucket"] == n
+        dev = spans["device"][0]
+        # the batch's span hangs off the span THIS waiter submitted from
+        assert dev["parent"] == wait["id"]
+        assert dev["meta"] == {"engine": "stub", "batch": n}
+        assert wait["start_ns"] <= dev["start_ns"] <= dev["end_ns"] \
+            <= wait["end_ns"]
+        device_ids.add(dev["id"])
+        for name in metrics.DISPATCH_TOP_STEPS:
+            assert [s["parent"] for s in spans[name]] == [dev["id"]]
+        assert len(spans["dispatch.rescore"]) == n
+        assert {s["parent"] for s in spans["dispatch.rescore"]} == \
+            {spans["dispatch.finish"][0]["id"]}
+        # a partition of the waiter's request: its queueing is the wait's
+        # own time, the dispatch is the device's and its children's
+        totals = tc.phase_totals()
+        assert sum(totals.values()) == pytest.approx(
+            spans["query"][0]["duration_ms"], abs=0.05)
+    assert len(device_ids) == 1           # recorded once, linked thrice
+    assert tcs[n - 1].span_dicts() == []
+    # one observation per ENGINE CALL for every step, whatever ran inside
+    assert metrics.summary("device")["count"] == 1
+    for name in _SteppedEngine.STEPS:
+        assert metrics.summary(name)["count"] == 1, name
+
+
+def test_step_histograms_take_one_observation_per_call_and_zeros():
+    names = ("dispatch.prep", "dispatch.finish", "dispatch.rescore",
+             "dispatch.cert_fallback")
+    with tracing.steps(names):
+        with tracing.phase("dispatch.prep"):
+            pass
+        with tracing.steps(names):              # a partition's engine inside
+            with tracing.phase("dispatch.prep"):
+                pass
+        with tracing.phase("dispatch.finish"):
+            for _ in range(3):
+                with tracing.phase("dispatch.rescore"):
+                    pass
+            with tracing.phase("demux"):        # not a step: its own record
+                pass
+    for name in names:
+        assert metrics.summary(name)["count"] == 1, name
+    assert metrics.summary("dispatch.cert_fallback")["max"] == 0.0
+    assert metrics.summary("demux")["count"] == 1
+    assert metrics.raw_dump("dispatch.finish")["total"] >= \
+        metrics.raw_dump("dispatch.rescore")["total"]
+
+
+def test_untraced_responses_are_bit_identical_and_the_steps_still_move(
+        env, monkeypatch):
+    """The served Turbo path with no context and no profiler: the same
+    bytes as with every request traced, and the `dispatch.*` histograms
+    move either way."""
+    node, call = env
+    monkeypatch.setenv("ES_TPU_FORCE_TURBO", "1")
+    call("PUT", "/s", {"mappings": {"properties": {"body": {"type": "text"}}}})
+    for i in range(48):
+        call("PUT", f"/s/_doc/{i}", {"body": f"w{i % 4} w{i % 7} common"})
+    call("POST", "/s/_refresh")
+    body = {"query": {"match": {"body": "common w3"}}, "size": 10}
+    st, r_off = call("POST", "/s/_search", body)
+    assert st == 200 and r_off["hits"]["hits"]
+    assert tracing.recent_traces() == []
+    st, stats = call("GET", "/_nodes/stats")
+    lat = stats["nodes"][node.node_id]["tpu_search_latency"]
+    assert lat["device"]["count"] == 1
+    for name in metrics.DISPATCH_TOP_STEPS + ("dispatch.rescore",):
+        assert lat[name]["count"] == 1, name
+    steps = sum(lat[n]["mean"] for n in metrics.DISPATCH_TOP_STEPS)
+    assert steps <= lat["device"]["mean"] * 1.001 + 0.01
+
+    monkeypatch.setenv("ES_TPU_TRACE_SAMPLE", "1")
+    st, r_on = call("POST", "/s/_search", body)
+    assert normalized(r_on) == normalized(r_off)
+    traced = tracing.recent_traces()[-1]
+    names = {s["name"] for s in traced["spans"]}
+    assert {"rest_total", "device", "demux", "fetch"} <= names
+    assert set(metrics.DISPATCH_TOP_STEPS) <= names
+    assert "device.fused_chunk" not in names
+
+
+def test_a_program_built_inside_a_span_is_counted_and_named():
+    import jax
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.common import hbm_ledger
+
+    def pr27_fresh_program(x):
+        return x * 3 + 1
+
+    # (the ledger's own reset would also drop the engines other test files
+    # of this worker hold registered: stop and start the listener only)
+    hbm_ledger.stop_jit_listener()
+    n0 = hbm_ledger.compile_stats()["jit_builds"]
+    jax.jit(pr27_fresh_program)(jnp.ones(5))   # not listening: not counted
+    assert hbm_ledger.compile_stats()["jit_builds"] == n0
+    hbm_ledger.install_jit_listener()
+    hbm_ledger.install_jit_listener()          # idempotent
+    try:
+        before = hbm_ledger.compile_stats()
+        with tracing.phase("dispatch.finish"):
+            jax.jit(pr27_fresh_program)(jnp.ones(7))      # a new shape
+        after = hbm_ledger.compile_stats()
+        assert after["jit_builds"] - before["jit_builds"] >= 1
+        assert after["jit_build_ms"] > before["jit_build_ms"]
+        mine = [e for e in after["events"]
+                if "pr27_fresh_program" in e.get("fun_name", "")]
+        assert len(mine) == 1
+        assert mine[0]["span"] == "dispatch.finish"
+        assert mine[0]["backend_s"] > 0 and mine[0]["trace_s"] > 0
+        assert mine[0]["wall_ms"] == pytest.approx(
+            1e3 * (mine[0]["trace_s"] + mine[0]["lower_s"]
+                   + mine[0]["backend_s"]), abs=0.5)
+        assert metrics.counter_values()["tpu_compile.jit_builds"] >= 1
+        # the same shape again is no build
+        n = after["jit_builds"]
+        jax.jit(pr27_fresh_program)(jnp.ones(7))
+        with tracing.phase("dispatch.finish"):
+            pass
+        outside = [e for e in hbm_ledger.compile_stats()["events"]
+                   if "fun_name" in e and e["span"] is None]
+        assert hbm_ledger.compile_stats()["jit_builds"] >= n
+        assert all("pr27_fresh_program" not in e["fun_name"]
+                   for e in outside)
+    finally:
+        hbm_ledger.stop_jit_listener()
+
+
+def test_the_collector_is_counted_by_generation():
+    import gc
+
+    tracing.install_gc_hook()
+    tracing.install_gc_hook()                  # idempotent
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    before = tracing.gc_stats()
+    gc.collect(0)
+    gc.collect()                               # generation 2
+    after = tracing.gc_stats()
+    assert after["young"]["collection_count"] \
+        == before["young"]["collection_count"] + 1
+    assert after["old"]["collection_count"] \
+        == before["old"]["collection_count"] + 1
+    for gen in ("young", "old"):
+        assert after[gen]["collection_time_in_millis"] \
+            >= before[gen]["collection_time_in_millis"]
+
+
+def test_node_stats_carry_the_new_sections(env):
+    node, call = env
+    st, stats = call("GET", "/_nodes/stats")
+    sec = stats["nodes"][node.node_id]
+    assert {"young", "old"} == set(sec["jvm"]["gc"]["collectors"])
+    assert "collection_time_in_millis" in sec["jvm"]["gc"]["collectors"]["old"]
+    assert sec["tpu_scheduler"]["lane_idle_ms"] >= 0
+    assert {"jit_builds", "jit_build_ms"} <= set(sec["tpu_compile"])
+    for name in ("dispatch.prep", "dispatch.launch", "dispatch.device_wait",
+                 "dispatch.finish", "dispatch.slice_build", "dispatch.mask",
+                 "dispatch.sparse_gather", "dispatch.rescore",
+                 "dispatch.cert_fallback", "dispatch.dense_rerun",
+                 "engine_build.columns", "engine_build.kmeans"):
+        assert name in sec["tpu_search_latency"]

@@ -464,12 +464,14 @@ class CounterHygieneRule:
     dashboard or differential test can see it move).
 
     Same hygiene for the flight-recorder histograms: a literal
-    ``metrics.observe("name", …)`` site must name a histogram declared in
-    common/metrics.py — declared histograms all surface through
+    ``metrics.observe("name", …)`` site — and a literal
+    ``tracing.phase("name", …)`` / ``tracing.record("name", …)`` site,
+    which observes under the span's own name — must name a histogram
+    declared in common/metrics.py — declared histograms all surface through
     ``search_latency_stats()``, so an undeclared name is a metric that can
     never reach `_nodes/stats` (and raises UndeclaredHistogramError the
-    first time the line runs). Dynamically composed names go through
-    ``observe_if_declared`` which this rule deliberately ignores.
+    first time the line runs). Dynamically composed names (f-strings,
+    ``observe_if_declared``) this rule deliberately ignores.
 
     And for telemetry gauges (PR 12): a module that calls
     ``declare_gauge("section.tail", …)`` outside the central registry
@@ -481,10 +483,24 @@ class CounterHygieneRule:
 
     name = "TPU005"
     summary = ("counters a stats()-bearing class increments (`self.x += …`) "
-               "must appear in its stats() surface; literal observe(...) "
-               "sites must name a histogram declared in common/metrics.py; "
+               "must appear in its stats() surface; literal observe(...) / "
+               "tracing.phase(...) / tracing.record(...) sites must name a "
+               "histogram declared in common/metrics.py; "
                "declare_gauge names outside the registry must surface in a "
                "*stats() function in the declaring file")
+
+    @staticmethod
+    def _observes(func: ast.AST) -> Optional[str]:
+        """`observe` for any `….observe(` call; `phase` / `record` only as
+        `tracing.phase(` / `tracing.record(` (both names are common)."""
+        tail = dotted_tail(func)
+        if tail == "observe":
+            return tail
+        if tail in ("phase", "record") and isinstance(func, ast.Attribute) \
+                and isinstance(func.value, ast.Name) \
+                and func.value.id == "tracing":
+            return "tracing." + tail
+        return None
 
     @staticmethod
     def _self_attr(expr: ast.AST) -> Optional[str]:
@@ -505,14 +521,15 @@ class CounterHygieneRule:
                 and not ctx.path.endswith("common/metrics.py"):
             for node in ast.walk(ctx.tree):
                 if isinstance(node, ast.Call) \
-                        and dotted_tail(node.func) == "observe" \
+                        and self._observes(node.func) \
                         and node.args \
                         and isinstance(node.args[0], ast.Constant) \
                         and isinstance(node.args[0].value, str) \
                         and node.args[0].value not in project.histogram_names:
                     f = ctx.finding(
                         self.name, node,
-                        f"observe({node.args[0].value!r}) names a histogram "
+                        f"{self._observes(node.func)}"
+                        f"({node.args[0].value!r}) names a histogram "
                         f"that is not declared in common/metrics.py — it "
                         f"never surfaces in `tpu_search_latency` and raises "
                         f"UndeclaredHistogramError at runtime")
