@@ -515,12 +515,12 @@ declare_histogram("task_duration.reindex", "ms", "task lifetime, reindex actions
 DISPATCH_TOP_STEPS = ("dispatch.prep", "dispatch.launch",
                       "dispatch.device_wait", "dispatch.finish")
 declare_histogram("dispatch.prep", "ms", "engine call, host side before the launch: flatten, column / slice residency, weight packing (BM25); query matrix, quantise, filter masks (kNN)")
-declare_histogram("dispatch.launch", "ms", "engine call, the device program's call returning (async; holds trace + lower + compile when the program is new)")
+declare_histogram("dispatch.launch", "ms", "engine call, the device programs' calls returning (async; holds trace + lower + compile when a program is new); BM25: the sweep, then the plan + launch of the chunk's cold-side gathers behind it")
 declare_histogram("dispatch.device_wait", "ms", "engine call, host blocked fetching the first pass's output")
 declare_histogram("dispatch.finish", "ms", "engine call, host side after the fetch: per (partition, query) collect / rescore / merge")
 declare_histogram("dispatch.slice_build", "ms", "within prep (BM25): host build of a fresh query's cold-term sparse slices and the device pool update")
 declare_histogram("dispatch.mask", "ms", "within prep (kNN): per-partition filter masks, stacking, upload")
-declare_histogram("dispatch.sparse_gather", "ms", "within finish (BM25): the cold side's sparse gather with its device round trip")
+declare_histogram("dispatch.sparse_gather", "ms", "within finish (BM25): collecting the cold side's sparse gather, launched behind the sweep, from its copy on the host")
 declare_histogram("dispatch.rescore", "ms", "within finish: exact rescore (BM25 _exact_scores; kNN host row gather + rescore program + fetch)")
 declare_histogram("dispatch.cert_fallback", "ms", "within finish (BM25): full exact merge after a failed certificate")
 declare_histogram("dispatch.dense_rerun", "ms", "within finish (kNN): dense route re-run of the uncertified queries")

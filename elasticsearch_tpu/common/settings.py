@@ -328,8 +328,9 @@ declare_knob("ES_TPU_BITSET_HOST_DF", "int", 512,
              "device bitset sweep (0 disables the fallback)")
 declare_knob("ES_TPU_SPARSE", "flag", True,
              "Eager sparse impact slices: cold (df < COLD_DF) terms score "
-             "on device via the sparse_gather kernel instead of the host "
-             "cold path (0 restores the host fork for A/B)")
+             "on device via the sparse_gather kernel, launched with each "
+             "dispatch chunk's sweep and collected in finish, instead of "
+             "the host cold path (0 restores the host fork for A/B)")
 declare_knob("ES_TPU_SPARSE_WIDTHS", "str", "1024,4096,16384",
              "Comma-separated slice-width ladder for eager sparse cold-"
              "term slices (each rung rounds up to a 1024-posting granule; "

@@ -43,7 +43,7 @@ entirely as matmuls and tiled vector ops:
   dense per-tile accumulator with the SAME outer-product trick as the
   column builder, then gathers the accumulated per-doc totals back at the
   slice's own lanes — so cold terms are scored on device too, and the
-  host only bound-prunes + exact-rescores (turbo.py `_sparse_contrib`).
+  host only bound-prunes + exact-rescores (turbo.py `_collect_gather`).
 """
 
 from __future__ import annotations
@@ -883,7 +883,7 @@ def sparse_gather(coff, cw, ct0, ct1, pool, *, n_tiles: int):
     Because slices from different terms scatter into the SAME accumulator,
     the value read back at any lane is the doc's FULL cold contribution
     for this dispatch — the host needs no posting-list walk, only the
-    bound-prune + exact top-k rescore (turbo.py `_sparse_contrib`).
+    bound-prune + exact top-k rescore (turbo.py `_collect_gather`).
 
     coff [n_rc] i32 — pool granule index per 1024-lane chunk; granule 0 is
         the reserved all-zero granule, where padding chunks point
@@ -941,6 +941,17 @@ def sparse_gather(coff, cw, ct0, ct1, pool, *, n_tiles: int):
         interpret=_interpret(),
     )
     return fn(coff, cw, ct0, ct1, pool, acc)
+
+
+@functools.partial(jax.jit, static_argnames=("n_tiles",))
+def sparse_gather_packed(desc, pool, *, n_tiles: int):
+    """`sparse_gather` with its four per-chunk descriptor rows in ONE
+    i32 [4, n_rc] array — rows coff, cw (the f32's bits), ct0, ct1 — so a
+    launch costs one host->device transfer, not four. Same shape set as
+    `sparse_gather`: (n_rc, pool granules, n_tiles)."""
+    return sparse_gather(
+        desc[0], jax.lax.bitcast_convert_type(desc[1], jnp.float32),
+        desc[2], desc[3], pool, n_tiles=n_tiles)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

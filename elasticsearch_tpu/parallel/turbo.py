@@ -64,8 +64,8 @@ from elasticsearch_tpu.parallel.kernels import (
     BITSET_CLAUSES, BITSET_NEGS, COLSCALE, COLSCALE2, MAX_GROUP_ROWS,
     N_CHUNKS, NCAND, ROWS_PER_STEP, SPARSE_GRAN, SPARSE_IMP_MAX, SW,
     SW_WORD_ROWS, TILE, build_columns, intersect_bitset, mask_chunk_counts,
-    pack_presence_bits, sparse_gather, sparse_pool_update, sweep_rowmax,
-    sweep_rowmax_bitset, sweep_rowmax_conj,
+    pack_presence_bits, sparse_gather_packed, sparse_pool_update,
+    sweep_rowmax, sweep_rowmax_bitset, sweep_rowmax_conj,
 )
 from elasticsearch_tpu.parallel.spmd import StackedBM25
 
@@ -257,10 +257,22 @@ def node_bitset_stats() -> dict:
 # host-backed HBM region (scrubbed + repairable like the lane arrays),
 # and kernels.sparse_gather serves the cold side of every query from it,
 # retiring the _cold_contrib host fork from the serving path.
+#
+# A (partition, query)'s gather has three parts (_ColdGather): PLAN (host
+# only: slice residency, the packed chunk descriptors, the posting spans,
+# the bound's slack), LAUNCH (one i32 [4, rcb] upload + the program, async,
+# with the result's copy to the host started at once) and COLLECT (the
+# gathered totals mapped back onto posting order). Nothing the gather reads
+# depends on the sweep's output, so search_many plans and launches every
+# gather of a dispatch chunk right after the chunk's sweep and BEFORE it
+# waits for the sweep: the gather runs on the device behind the sweep
+# while the host waits and rescores, and finish only collects. There is
+# no blocking device round trip inside _finish_query.
 
 _SPARSE_DOC_LIMIT = 1 << 23          # packed doc-id headroom in an int32
 _SPARSE_RC_BUCKETS = (2, 4, 8, 16, 32, 64, 128, 256)   # dispatch chunk
-#   counts are bucketed so kernels.sparse_gather sees a bounded shape set
+#   counts are bucketed so kernels.sparse_gather sees a bounded shape set,
+#   every member instantiated when the pool reaches its cap (_sp_grow)
 _SPARSE_UP_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128)     # granule-upload
 #   batch sizes (sparse_pool_update), padded toward the zero granule
 
@@ -285,6 +297,8 @@ def _sparse_widths() -> Tuple[int, ...]:
 # (evictions subtract), the rest are cumulative
 _NODE_SPARSE_STATS = {"sparse_slices": 0, "sparse_bytes": 0,
                       "sparse_queries": 0,
+                      "sparse_gather_launches": 0,
+                      "sparse_gather_overlapped": 0,
                       "sparse_fallbacks": 0}  # guarded by: _NODE_SPARSE_LOCK
 _NODE_SPARSE_LOCK = threading.Lock()
 
@@ -299,6 +313,33 @@ def _node_sparse_add(key: str, n: int) -> None:
 def node_sparse_stats() -> dict:
     with _NODE_SPARSE_LOCK:
         return dict(_NODE_SPARSE_STATS)
+
+
+def _gather_desc(rcb: int) -> np.ndarray:
+    """An all-padding i32 [4, rcb] descriptor block for
+    kernels.sparse_gather_packed — rows coff, cw (f32 bits), ct0, ct1:
+    every chunk reads the zero granule with weight 0.0 over the empty
+    tile range (1, 0)."""
+    desc = np.zeros((4, rcb), np.int32)
+    desc[2] = 1
+    return desc
+
+
+class _ColdGather:
+    """One (partition, query)'s cold side between plan and collect.
+    `out` is the launched program's result with its copy to the host
+    under way; None = not launched (unsliceable batch, or a fault), and
+    collect then host-scores this pair alone. `early` = launched before
+    the dispatch waited for its sweep."""
+
+    __slots__ = ("cold_terms", "early", "spans", "slack", "out")
+
+    def __init__(self, cold_terms, early: bool):
+        self.cold_terms = cold_terms
+        self.early = early
+        self.spans: List[Tuple[int, int, int]] = []
+        self.slack = 0.0
+        self.out = None
 
 
 class TurboBM25:
@@ -417,13 +458,16 @@ class TurboBM25:
         self._sp_cap = max(2, min(int(hbm_budget_bytes) // 4, 64 << 20)
                            // (SPARSE_GRAN * 4))
         self._sp_ok = self.Dp <= _SPARSE_DOC_LIMIT
+        self._sp_inflight = 0   # gathers launched and not yet collected
         self.stats = {"builds": 0, "build_s": 0.0, "fallbacks": 0,
                       "cold_queries": 0, "dispatches": 0, "degraded": 0,
                       "phrase_builds": 0, "bool_host": 0, "bool_device": 0,
                       "bitset_packs": 0, "bitset_gallop": 0,
                       "bitset_blocks_skipped": 0, "bitset_bytes": 0,
                       "sparse_queries": 0, "sparse_slices": 0,
-                      "sparse_bytes": 0, "sparse_fallbacks": 0}
+                      "sparse_bytes": 0, "sparse_fallbacks": 0,
+                      "sparse_gather_launches": 0,
+                      "sparse_gather_overlapped": 0}
         # HBM residency ledger: regions mirror hbm_bytes() exactly so the
         # telemetry cross-check can hold ledger == engine to the byte
         self._hbm = hbm_ledger.register_engine(self, "turbo")
@@ -837,6 +881,16 @@ class TurboBM25:
         self._sp_host = host
         with faults.device_errors("sparse_gather", self.part_id):
             self._sp_pool = jnp.asarray(host)
+            if new_g >= self._sp_cap:
+                # the pool's size is part of the gather program's shape,
+                # and this size is final: instantiate every chunk-count
+                # bucket now (all-padding descriptors, every chunk
+                # skipped) so that no dispatch builds one later, whatever
+                # traffic reaches. Below the cap the pool is still
+                # doubling and its programs would be thrown away.
+                for rcb in _SPARSE_RC_BUCKETS:
+                    sparse_gather_packed(_gather_desc(rcb), self._sp_pool,
+                                         n_tiles=self.Dp // TILE)
         if old is None:
             integrity.register_scrub_region(
                 self, "sparse_pool", lambda o: o._sp_pool,
@@ -905,7 +959,7 @@ class TurboBM25:
         Impacts are uint8-quantized on a per-term scale smax/255; rounding
         is forced to >= 1 so a real posting never vanishes, which widens
         the per-posting error to one full quant step (the lo >= 1 idiom of
-        the column build, mirrored in _sparse_contrib's slack)."""
+        the column build, mirrored in _plan_gather's slack)."""
         if not self._sp_ok:
             return False
         widths = _sparse_widths()
@@ -931,12 +985,18 @@ class TurboBM25:
         yet: the host slice build and the device pool update."""
         fp = self.fp
         idx_l, upd_l = [], []
+        fits = True
         try:
             for t, info, w in need:
                 n_g = w // SPARSE_GRAN
                 g0 = self._sp_alloc(n_g, protect)
                 if g0 < 0:
-                    return False
+                    # pool pressure with everything protected: the slices
+                    # built so far are in the mirror and in _sp_of, so
+                    # they still go up (a resident slice the device never
+                    # received would gather stale granules, silently)
+                    fits = False
+                    break
                 lo = int(fp.post_start[info.ord])
                 hi = int(fp.post_start[info.ord + 1])
                 docs = np.asarray(fp.post_doc[lo:hi], np.int64)
@@ -960,6 +1020,8 @@ class TurboBM25:
                 _node_sparse_add("sparse_slices", 1)
                 _node_sparse_add("sparse_bytes", w * 4)
                 metrics.observe("sparse_slice_width", w)
+            if not idx_l:
+                return False
             idx = np.concatenate(idx_l)
             upd = np.concatenate(upd_l, axis=0)
             nb = next((b for b in _SPARSE_UP_BUCKETS if b >= len(idx)),
@@ -978,19 +1040,20 @@ class TurboBM25:
             self._reset_sparse()
             raise
         self._hbm.set_region("sparse_pool", self._sp_pool.nbytes)
-        return True
+        return fits
 
-    def _sparse_gather_dispatch(self, cold_terms):
-        """Device cold-side scoring: ensure slices, assemble the chunk
-        dispatch, run kernels.sparse_gather, and map the gathered totals
-        back onto each term's posting order. Returns None when the batch
-        cannot be sliced; raises DeviceFaultError on device faults (the
-        caller contains both). Otherwise (docs, contrib, slack) where
-        docs/contrib mirror _cold_contrib's unique-doc enumeration and
-        slack bounds |contrib - exact| (quantization + f32 accumulation,
-        the e_q certificate style)."""
-        if not self._sp_ok:
-            return None
+    def _sparse_on(self) -> bool:
+        return self._sp_ok and bool(knob("ES_TPU_SPARSE"))
+
+    def _plan_gather(self, h: _ColdGather) -> Optional[np.ndarray]:
+        """PLAN (host only): ensure the cold terms' slices, then the
+        packed i32 [4, rcb] chunk descriptors (returned), the posting
+        spans collect reads the totals back at, and `slack`, which bounds
+        |contrib - exact| (quantization + f32 accumulation, the e_q
+        certificate style). None when the batch cannot be sliced;
+        DeviceFaultError from the slice build passes through (the caller
+        contains both)."""
+        cold_terms = h.cold_terms
         if not self._ensure_sparse([(t, i) for t, _b, i in cold_terms]):
             return None
         fp = self.fp
@@ -998,7 +1061,6 @@ class TurboBM25:
         cw: List[float] = []
         ct0: List[int] = []
         ct1: List[int] = []
-        spans: List[Tuple[int, int, int]] = []
         slack = 1e-7
         for t, b, info in cold_terms:
             g0, n_g, _w, sscale = self._sp_of[t]
@@ -1013,30 +1075,110 @@ class TurboBM25:
                 cw.append(wt * sscale)
                 ct0.append(int(fp.post_doc[s]) // TILE)
                 ct1.append(int(fp.post_doc[e - 1]) // TILE)
-            spans.append((c0, info.df, lo))
+            h.spans.append((c0, info.df, lo))
             # one posting per (term, doc): quantization error <= one full
             # step per term, plus a generous f32-accumulation margin
             slack += abs(wt) * (sscale
                                 + 3e-6 * max(float(info.smax), sscale))
-        if len(coff) > _SPARSE_RC_BUCKETS[-1]:
+        n = len(coff)
+        if n > _SPARSE_RC_BUCKETS[-1]:
             return None
-        rcb = next(b for b in _SPARSE_RC_BUCKETS if b >= len(coff))
-        pad = rcb - len(coff)
+        desc = _gather_desc(next(b for b in _SPARSE_RC_BUCKETS if b >= n))
+        desc[0, :n] = coff
+        desc[1, :n] = np.asarray(cw, np.float32).view(np.int32)
+        desc[2, :n] = ct0
+        desc[3, :n] = ct1
+        h.slack = float(slack)
+        return desc
+
+    def _launch_gather(self, h: _ColdGather, desc: np.ndarray) -> None:
+        """LAUNCH: one packed descriptor upload + the gather program,
+        asynchronous, and the result's copy to the host started at once.
+        The launch keeps the pool it read alive; a later slice build
+        (sparse_pool_update donates) is ordered behind it on the device."""
+        rcb = desc.shape[1]
         first = hbm_ledger.note_dispatch("turbo_sparse", rcb)
         t0 = time.monotonic()
         with faults.device_errors("sparse_gather", self.part_id):
-            out = sparse_gather(
-                jnp.asarray(np.asarray(coff + [0] * pad, np.int32)),
-                jnp.asarray(np.asarray(cw + [0.0] * pad, np.float32)),
-                jnp.asarray(np.asarray(ct0 + [1] * pad, np.int32)),
-                jnp.asarray(np.asarray(ct1 + [0] * pad, np.int32)),
-                self._sp_pool, n_tiles=self.Dp // TILE)
-            flat = np.asarray(out).reshape(rcb * SPARSE_GRAN)
+            out = sparse_gather_packed(desc, self._sp_pool,
+                                       n_tiles=self.Dp // TILE)
+            out.copy_to_host_async()
         if first:
             hbm_ledger.note_compile_done("turbo_sparse", rcb,
                                          time.monotonic() - t0)
+        h.out = out
+        self._sp_inflight += 1
+        self.stats["sparse_gather_launches"] += 1
+        _node_sparse_add("sparse_gather_launches", 1)
+
+    def _start_gather(self, cold_terms, early: bool) -> _ColdGather:
+        """Plan + launch one (partition, query)'s cold side, contained:
+        an unsliceable batch or a fault leaves the handle un-launched and
+        `_collect_gather` host-scores that pair alone."""
+        h = _ColdGather(cold_terms, early)
+        try:
+            faults.fault_point("sparse_gather", self.part_id)
+            desc = self._plan_gather(h)
+            if desc is not None:
+                self._launch_gather(h, desc)
+        except DeviceFaultError:
+            pass
+        return h
+
+    def _chunk_gathers(self, chunk) -> Dict[int, _ColdGather]:
+        """`_start_gather` for every query of a dispatch chunk that has a
+        cold term here, ahead of its finish and in finish's order:
+        {query index: gather} (empty with the sparse tier off). The cold /
+        colized split is `_finish_query`'s own: `_slot_of` does not move
+        between a dispatch's prep and its finish."""
+        gathers: Dict[int, _ColdGather] = {}
+        if not self._sparse_on():
+            return gathers
+        for qi, terms in enumerate(chunk):
+            cold_terms = []
+            for t, b in terms or ():
+                info = self._term(t)
+                if info is not None and t not in self._slot_of:
+                    cold_terms.append((t, b, info))
+            if cold_terms:
+                gathers[qi] = self._start_gather(cold_terms, True)
+        return gathers
+
+    def _discard_gather(self, h: Optional[_ColdGather]) -> None:
+        """Drop a launched gather nobody will collect (its chunk is being
+        host-scored after a sweep fault, or the call is unwinding)."""
+        if h is not None and h.out is not None:
+            h.out = None
+            self._sp_inflight -= 1
+
+    def _collect_gather(self, h: _ColdGather):
+        """COLLECT: (docs, contrib, slack), docs/contrib mirroring
+        _cold_contrib's unique-doc enumeration. A pair that was not
+        launched, or whose fetch faults, falls back to the exact host
+        enumeration with slack 0 — downstream pruning then evaluates the
+        IDENTICAL expression the host path uses, so containment is
+        bit-identical by construction."""
+        out, h.out = h.out, None
+        flat = None
+        if out is not None:
+            self._sp_inflight -= 1
+            if h.early:
+                self.stats["sparse_gather_overlapped"] += 1
+                _node_sparse_add("sparse_gather_overlapped", 1)
+            try:
+                faults.fault_point("sparse_gather", self.part_id)
+                with faults.device_errors("sparse_gather", self.part_id):
+                    flat = np.asarray(out).reshape(-1)
+            except DeviceFaultError:
+                pass
+        if flat is None:
+            self.stats["sparse_fallbacks"] += 1
+            _node_sparse_add("sparse_fallbacks", 1)
+            u, acc = self._cold_contrib(h.cold_terms)
+            return u, acc, 0.0
+        fp = self.fp
         docs_l, vals_l = [], []
-        for c0, df, lo in spans:
+        for c0, df, lo in h.spans:
             docs_l.append(np.asarray(fp.post_doc[lo: lo + df], np.int64))
             base = c0 * SPARSE_GRAN
             vals_l.append(flat[base: base + df])
@@ -1046,25 +1188,7 @@ class TurboBM25:
         # accumulator cell at every occurrence — first occurrence wins,
         # exactly _cold_contrib's unique-doc enumeration
         u, fidx = np.unique(docs, return_index=True)
-        return u, vals[fidx], float(slack)
-
-    def _sparse_contrib(self, cold_terms):
-        """Device twin of _cold_contrib with per-partition containment:
-        (docs, contrib, slack). Any fault or unsliceable batch falls back
-        to the exact host enumeration with slack 0 — downstream pruning
-        then evaluates the IDENTICAL expression the host path uses, so
-        containment is bit-identical by construction."""
-        try:
-            faults.fault_point("sparse_gather", self.part_id)
-            res = self._sparse_gather_dispatch(cold_terms)
-        except DeviceFaultError:
-            res = None
-        if res is None:
-            self.stats["sparse_fallbacks"] += 1
-            _node_sparse_add("sparse_fallbacks", 1)
-            u, acc = self._cold_contrib(cold_terms)
-            return u, acc, 0.0
-        return res
+        return u, vals[fidx], h.slack
 
     def sparse_hot_terms(self) -> List[str]:
         """Terms with a resident sparse slice — the warm-handoff payload a
@@ -1195,6 +1319,40 @@ class TurboBM25:
         # is ~100x larger, and the transfer is a sync per batch)
         n_rows = max(_GLOBAL_ROWS, k + 5)
         pending = []
+        out_s = np.zeros((len(flat), k), np.float32)
+        out_d = np.zeros((len(flat), k), np.int32)
+        try:
+            self._sweep_chunks(flat, n_rows, check, pending)
+            # pass 2: fetch the tiny row sets; EXACT host rescore of every
+            # doc in the collected rows (33 rows x 128 lanes x a binary
+            # search per query term — ~1ms/query), merged with the cold
+            # side, whose gathers are already on their way to the host
+            for off, n, packed_dev, gathers in pending:
+                if check is not None:
+                    check()
+                with tracing.phase("dispatch.device_wait"), \
+                        faults.device_errors("turbo_sweep", self.part_id):
+                    packed = np.asarray(packed_dev)    # [QC, n_rows + 1]
+                with tracing.phase("dispatch.finish", queries=n):
+                    rows_all = packed[:, :n_rows].astype(np.int64)
+                    bounds = packed[:, n_rows]
+                    for qi in range(n):
+                        docs = self._collect_docs(rows_all[qi])
+                        s, d = self._finish_query(
+                            flat[off + qi], docs, float(bounds[qi]), k,
+                            gathers.pop(qi, None))
+                        out_s[off + qi, : len(s)] = s
+                        out_d[off + qi, : len(d)] = d
+        finally:
+            for *_, gathers in pending:    # a fault or a cancel unwinding
+                for h in gathers.values():
+                    self._discard_gather(h)
+        return [(out_s[o: o + n], out_d[o: o + n]) for o, n in spans]
+
+    def _sweep_chunks(self, flat, n_rows: int, check, pending: list) -> None:
+        """Pass 1 of `_search_many`: per chunk the sweep and the row pick,
+        then every query's cold-side gather behind them, all asynchronous.
+        Appends (offset, n, picked, {query: gather}) to `pending`."""
         off = 0
         while off < len(flat):
             rem = len(flat) - off
@@ -1210,37 +1368,17 @@ class TurboBM25:
             first_trace = hbm_ledger.note_dispatch("turbo", take)
             tc0 = time.monotonic()
             wq, qscale, (rm, rr) = self._sweep(chunk, take)
-            with tracing.phase("dispatch.launch", qc=take), \
-                    faults.device_errors("turbo_sweep", self.part_id):
-                picked = _pick_rows(rm, rr, n_rows=n_rows)
-            if first_trace:
-                hbm_ledger.note_compile_done(
-                    "turbo", take, time.monotonic() - tc0)
-            pending.append((off, len(chunk), picked))
+            with tracing.phase("dispatch.launch", qc=take) as ph:
+                with faults.device_errors("turbo_sweep", self.part_id):
+                    picked = _pick_rows(rm, rr, n_rows=n_rows)
+                if first_trace:
+                    hbm_ledger.note_compile_done(
+                        "turbo", take, time.monotonic() - tc0)
+                gathers = self._chunk_gathers(chunk)
+                ph.meta["gathers"] = len(gathers)
+            pending.append((off, len(chunk), picked, gathers))
             off += len(chunk)
         self.stats["dispatches"] += len(pending)
-
-        # pass 2: fetch the tiny row sets; EXACT host rescore of every doc
-        # in the collected rows (33 rows x 128 lanes x a binary search per
-        # query term — ~1ms/query), merged with the cold side
-        out_s = np.zeros((len(flat), k), np.float32)
-        out_d = np.zeros((len(flat), k), np.int32)
-        for off, n, packed_dev in pending:
-            if check is not None:
-                check()
-            with tracing.phase("dispatch.device_wait"), \
-                    faults.device_errors("turbo_sweep", self.part_id):
-                packed = np.asarray(packed_dev)    # [QC, n_rows + 1]
-            with tracing.phase("dispatch.finish", queries=n):
-                rows_all = packed[:, :n_rows].astype(np.int64)
-                bounds = packed[:, n_rows]
-                for qi in range(n):
-                    docs = self._collect_docs(rows_all[qi])
-                    s, d = self._finish_query(
-                        flat[off + qi], docs, float(bounds[qi]), k)
-                    out_s[off + qi, : len(s)] = s
-                    out_d[off + qi, : len(d)] = d
-        return [(out_s[o: o + n], out_d[o: o + n]) for o, n in spans]
 
     def search(self, queries: List[List], k: int = 10):
         return self.search_many([queries], k)[0]
@@ -1294,14 +1432,16 @@ class TurboBM25:
                                QC=QC, nsw=self.nsw)
         return wq, qscale, out
 
-    def _finish_query(self, terms, cand_docs, bound, k):
-        """Merge device-collected candidates + host cold side into exact
+    def _finish_query(self, terms, cand_docs, bound, k,
+                      gather: Optional[_ColdGather] = None):
+        """Merge device-collected candidates + the cold side into exact
         top-k.
 
         cand_docs [C] live doc ids from the collected rows — every one is
         rescored EXACTLY here, so quantization error only matters for
         UNCOLLECTED rows; bound — the max approximate score any of those
-        could hold (device pick output)."""
+        could hold (device pick output); gather — the query's cold side
+        as `_chunk_gathers` launched it behind the sweep."""
         qterms = []
         cold_terms = []
         col_terms = []
@@ -1357,12 +1497,14 @@ class TurboBM25:
         cold_docs = np.empty(0, np.int64)
         cold_s = np.empty(0, np.float32)
         if cold_terms:
-            if self._sp_ok and bool(knob("ES_TPU_SPARSE")):
+            if self._sparse_on():
                 self.stats["sparse_queries"] += 1
                 _node_sparse_add("sparse_queries", 1)
+                if gather is None:     # not hoisted: back to back, here
+                    gather = self._start_gather(cold_terms, False)
                 with tracing.phase("dispatch.sparse_gather",
                                    terms=len(cold_terms)):
-                    docs_c, contrib, slack = self._sparse_contrib(cold_terms)
+                    docs_c, contrib, slack = self._collect_gather(gather)
             else:
                 self.stats["cold_queries"] += 1
                 docs_c, contrib = self._cold_contrib(cold_terms)
@@ -1921,10 +2063,12 @@ class TurboBM25:
         cold_docs = np.empty(0, np.int64)
         cold_s = np.empty(0, np.float32)
         if cold_should:
-            if self._sp_ok and bool(knob("ES_TPU_SPARSE")):
+            if self._sparse_on():
                 self.stats["sparse_queries"] += 1
                 _node_sparse_add("sparse_queries", 1)
-                docs_c, contrib, slack = self._sparse_contrib(cold_should)
+                # the same plan / launch / collect, back to back
+                docs_c, contrib, slack = self._collect_gather(
+                    self._start_gather(cold_should, False))
             else:
                 self.stats["cold_queries"] += 1
                 docs_c, contrib = self._cold_contrib(cold_should)
@@ -2373,7 +2517,12 @@ class ShardedTurbo:
 
     # ---------------- fused dispatches ----------------
 
-    def _dispatch_disj(self, chunk, QC: int, n_rows: int):
+    def _dispatch_disj(self, chunk, QC: int, n_rows: int, skip=()):
+        """One chunk's fused sweep, and behind it — before anything
+        waits for the sweep — the cold side of every (partition, query)
+        of the chunk, in the order finish collects them. Returns (packed
+        rows, {partition: {query: gather}}); partitions in `skip` are
+        host-scored and launch none."""
         with tracing.phase("dispatch.prep", qc=QC):
             wq = np.zeros((self.Sp, 2, QC, self.Hp + 1), np.int8)
             qs = np.ones((self.Sp, QC, 1), np.float32)
@@ -2389,17 +2538,21 @@ class ShardedTurbo:
         # the call returning, not the sweep: the launch is async, and
         # holds trace + lower + compile when the program is new
         with tracing.phase("dispatch.launch", qc=QC,
-                           partitions=len(self.turbos)), \
-                faults.device_dispatch("fused_dispatch"):
-            out = _fused_sweep_disj(
-                jnp.asarray(qs), self.cols_hi, self.cols_lo,
-                jnp.asarray(wq), self.live, mesh=self.mesh, QC=QC,
-                nsw=self.nsw, n_rows=n_rows)
-        self.fused_dispatches += 1
-        if first_trace:
-            hbm_ledger.note_compile_done(
-                "fused_turbo", QC, time.monotonic() - t0)
-        return out
+                           partitions=len(self.turbos)) as ph:
+            with faults.device_dispatch("fused_dispatch"):
+                out = _fused_sweep_disj(
+                    jnp.asarray(qs), self.cols_hi, self.cols_lo,
+                    jnp.asarray(wq), self.live, mesh=self.mesh, QC=QC,
+                    nsw=self.nsw, n_rows=n_rows)
+            self.fused_dispatches += 1
+            if first_trace:
+                hbm_ledger.note_compile_done(
+                    "fused_turbo", QC, time.monotonic() - t0)
+            gathers = {si: t._chunk_gathers(chunk)
+                       for si, t in enumerate(self.turbos)
+                       if si not in skip}
+            ph.meta["gathers"] = sum(len(g) for g in gathers.values())
+        return out, gathers
 
     def _dispatch_bool(self, resolved, dev_sets, sel, QC: int,
                        n_rows: int, use_bits: bool = False):
@@ -2489,50 +2642,38 @@ class ShardedTurbo:
                 except DeviceFaultError as e:
                     failed[i] = e
         n_rows = max(_GLOBAL_ROWS, k + 5)
+        # per chunk (offset, n, the sweep's packed rows or None: faulted,
+        # {partition: {query: its cold side's gather}})
         pending = []
         fused_err: Optional[DeviceFaultError] = None
-        off = 0
-        while off < len(flat):
-            rem = len(flat) - off
-            take = next((s for s in self.qc_sizes if s >= rem),
-                        self.qc_sizes[-1])
-            chunk = flat[off: off + take]
-            if check is not None:
-                check()
-            try:
-                packed_dev = self._dispatch_disj(chunk, take, n_rows)
-            except DeviceFaultError as e:
-                packed_dev, fused_err = None, e
-            pending.append((off, len(chunk), packed_dev))
-            off += len(chunk)
         out_s = np.zeros((S, len(flat), k), np.float32)
         out_d = np.zeros((S, len(flat), k), np.int32)
-        for off, n, packed_dev in pending:
-            if check is not None:
-                check()
-            packed = None
-            if packed_dev is not None:
+        try:
+            off = 0
+            while off < len(flat):
+                rem = len(flat) - off
+                take = next((s for s in self.qc_sizes if s >= rem),
+                            self.qc_sizes[-1])
+                chunk = flat[off: off + take]
+                if check is not None:
+                    check()
                 try:
-                    with tracing.phase("dispatch.device_wait"), \
-                            faults.device_errors("fused_dispatch"):
-                        packed = np.asarray(packed_dev)
-                except DeviceFaultError as e:     # async fault at fetch
-                    packed, fused_err = None, e
-            with tracing.phase("dispatch.finish", queries=n):
-                for si, t in enumerate(self.turbos):
-                    host_only = si in failed or packed is None
-                    if not host_only:
-                        rows_all = packed[si, :, :n_rows].astype(np.int64)
-                        bounds = packed[si, :, n_rows]
-                    for qi in range(n):
-                        if host_only:
-                            s, d = t._exact_query(flat[off + qi], k)
-                        else:
-                            docs = t._collect_docs(rows_all[qi])
-                            s, d = t._finish_query(flat[off + qi], docs,
-                                                   float(bounds[qi]), k)
-                        out_s[si, off + qi, : len(s)] = s
-                        out_d[si, off + qi, : len(d)] = d
+                    packed_dev, gathers = self._dispatch_disj(
+                        chunk, take, n_rows, skip=failed)
+                except DeviceFaultError as e:
+                    packed_dev, gathers, fused_err = None, {}, e
+                pending.append((off, len(chunk), packed_dev, gathers))
+                off += len(chunk)
+            for entry in pending:
+                if check is not None:
+                    check()
+                fused_err = self._finish_chunk(
+                    flat, k, n_rows, entry, failed, out_s, out_d) or fused_err
+        finally:
+            for *_, gathers in pending:    # a cancel unwinding
+                for si, hs in gathers.items():
+                    for h in hs.values():
+                        self.turbos[si]._discard_gather(h)
         if fault_log is not None:
             for i, e in sorted(failed.items()):
                 fault_log.append(FaultRecord.from_error(e, partition=i))
@@ -2540,6 +2681,42 @@ class ShardedTurbo:
                 fault_log.append(FaultRecord.from_error(fused_err))
         return [[(out_s[si, o: o + n], out_d[si, o: o + n])
                  for o, n in spans] for si in range(S)]
+
+    def _finish_chunk(self, flat, k: int, n_rows: int, entry, failed,
+                      out_s, out_d) -> Optional[DeviceFaultError]:
+        """Pass 2 for one chunk: wait for its sweep, then per (partition,
+        query) the exact rescore merged with the cold side, whose gather
+        is already on its way to the host. A partition in `failed`, or a
+        chunk whose sweep faulted (at its launch, or here at the fetch:
+        returned), is host-scored and drops its gathers."""
+        off, n, packed_dev, gathers = entry
+        packed = err = None
+        if packed_dev is not None:
+            try:
+                with tracing.phase("dispatch.device_wait"), \
+                        faults.device_errors("fused_dispatch"):
+                    packed = np.asarray(packed_dev)
+            except DeviceFaultError as e:     # async fault at fetch
+                err = e
+        with tracing.phase("dispatch.finish", queries=n):
+            for si, t in enumerate(self.turbos):
+                host_only = si in failed or packed is None
+                if not host_only:
+                    rows_all = packed[si, :, :n_rows].astype(np.int64)
+                    bounds = packed[si, :, n_rows]
+                mine = gathers.get(si, {})
+                for qi in range(n):
+                    h = mine.pop(qi, None)
+                    if host_only:
+                        t._discard_gather(h)
+                        s, d = t._exact_query(flat[off + qi], k)
+                    else:
+                        docs = t._collect_docs(rows_all[qi])
+                        s, d = t._finish_query(flat[off + qi], docs,
+                                               float(bounds[qi]), k, h)
+                    out_s[si, off + qi, : len(s)] = s
+                    out_d[si, off + qi, : len(d)] = d
+        return err
 
     def search_bool(self, queries: Sequence[dict], k: int = 10,
                     check=None, fault_log=None):

@@ -152,6 +152,14 @@ def test_sparse_gather(chip, n_rc):
               n_tiles=DOCS // K.TILE)
 
 
+@pytest.mark.parametrize("n_rc", [2, 256])
+def test_sparse_gather_packed(chip, n_rc):
+    """The served entry point: the four descriptor rows in one upload."""
+    _compiled(K.sparse_gather_packed, chip((4, n_rc), jnp.int32),
+              chip((4096, K.SPARSE_GRAN // 128, 128), jnp.int32),
+              n_tiles=DOCS // K.TILE)
+
+
 @pytest.mark.parametrize("Q", [1, 16])
 def test_agg_segment_counts(chip, Q):
     p = DOCS                              # one (doc, bucket) pair per doc

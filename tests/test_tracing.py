@@ -686,6 +686,50 @@ def test_untraced_responses_are_bit_identical_and_the_steps_still_move(
     assert "device.fused_chunk" not in names
 
 
+def test_the_cold_gather_goes_out_before_the_wait_and_the_steps_still_sum(
+        env, monkeypatch):
+    """PR 28: the cold side's gather is planned and launched under
+    `dispatch.launch`, behind the sweep and before `dispatch.device_wait`;
+    `dispatch.sparse_gather` is its collect, inside `dispatch.finish`;
+    the four steps are still what `device` is made of."""
+    node, call = env
+    monkeypatch.setenv("ES_TPU_FORCE_TURBO", "1")
+    monkeypatch.setenv("ES_TPU_TRACE_SAMPLE", "1")
+    call("PUT", "/s", {"mappings": {"properties": {"body": {"type": "text"}}}})
+    for i in range(48):
+        call("PUT", f"/s/_doc/{i}", {"body": f"w{i % 4} w{i % 7} common"})
+    call("POST", "/s/_refresh")
+    body = {"query": {"match": {"body": "common w3"}}, "size": 10}
+    call("POST", "/s/_search", body)           # engine build, first traces
+    st, s0 = call("GET", "/_nodes/stats")
+    st, r = call("POST", "/s/_search", body)
+    assert st == 200 and r["hits"]["hits"]
+    st, s1 = call("GET", "/_nodes/stats")
+    t0 = s0["nodes"][node.node_id]["tpu_turbo"]
+    t1 = s1["nodes"][node.node_id]["tpu_turbo"]
+    rise = {key: t1[key] - t0[key] for key in (
+        "sparse_queries", "sparse_gather_launches",
+        "sparse_gather_overlapped", "sparse_fallbacks")}
+    assert rise == {"sparse_queries": 1, "sparse_gather_launches": 1,
+                    "sparse_gather_overlapped": 1, "sparse_fallbacks": 0}
+
+    spans = {}
+    for sp in tracing.recent_traces()[-1]["spans"]:
+        spans.setdefault(sp["name"], []).append(sp)
+    (dev,), (wait,) = spans["device"], spans["dispatch.device_wait"]
+    (gather,) = [sp for sp in spans["dispatch.launch"]
+                 if sp.get("meta", {}).get("gathers")]
+    (collect,) = spans["dispatch.sparse_gather"]
+    assert gather["parent"] == dev["id"]
+    assert gather["end_ns"] <= wait["start_ns"]
+    assert collect["parent"] in {sp["id"] for sp in spans["dispatch.finish"]}
+    assert collect["start_ns"] >= wait["end_ns"]
+    top = [sp for name in metrics.DISPATCH_TOP_STEPS for sp in spans[name]]
+    assert {sp["parent"] for sp in top} == {dev["id"]}
+    steps = sum(sp["duration_ms"] for sp in top)
+    assert 0.8 * dev["duration_ms"] <= steps <= dev["duration_ms"] + 0.01
+
+
 def test_a_program_built_inside_a_span_is_counted_and_named():
     import jax
     import jax.numpy as jnp

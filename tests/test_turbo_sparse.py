@@ -13,6 +13,14 @@ every route — solo, fused S > 1, bool with cold clauses, the host A/B
 (`ES_TPU_SPARSE=0`), certificate fallback, injected `sparse_gather`
 faults, and an HBM scrub cycle repairing a corrupted slice pool.
 
+PR 28: a dispatch chunk's gathers are planned and launched behind the
+chunk's sweep, before anything waits for the sweep, and `_finish_query`
+only collects them. The second half of this file holds that path to the
+same bits at batch widths 1, 4 and 16, its two fault points to one
+(partition, query), a pool smaller than a chunk's cold terms to zero
+fallbacks, its two counters against `sparse_queries`, and a faulted
+fused sweep to leaving no gather in flight.
+
 Runs on the host-simulated 8-device CPU mesh from tests/conftest.py
 (Pallas kernels interpret on CPU)."""
 
@@ -21,7 +29,11 @@ import pytest
 
 from elasticsearch_tpu.common import faults, integrity
 from elasticsearch_tpu.common.settings import knob
-from elasticsearch_tpu.parallel.turbo import SPARSE_GRAN, _sparse_widths
+from elasticsearch_tpu.common.errors import DeviceFaultError
+from elasticsearch_tpu.parallel import turbo as turbo_mod
+from elasticsearch_tpu.parallel.turbo import (
+    SPARSE_GRAN, _SPARSE_RC_BUCKETS, _sparse_widths,
+)
 
 from test_turbo_bitset import _pcorpus, _turbo, _fused, _assert_identical
 
@@ -253,3 +265,293 @@ def test_sparse_prewarm_and_hot_terms():
 def test_sparse_knob_defaults():
     assert bool(knob("ES_TPU_SPARSE")) is True
     assert _sparse_widths() == (1024, 4096, 16384)
+
+
+# --------------------------------------------------------------------------
+# PR 28: the gathers go out with the sweep and are collected in finish
+# --------------------------------------------------------------------------
+
+_SPARSE_KEYS = ("sparse_queries", "sparse_fallbacks",
+                "sparse_gather_launches", "sparse_gather_overlapped",
+                "sparse_slices", "cold_queries")
+
+
+def _snap(turbos):
+    return [{key: t.stats[key] for key in _SPARSE_KEYS} for t in turbos]
+
+
+def _rise(turbos, before):
+    return [{key: t.stats[key] - b[key] for key in _SPARSE_KEYS}
+            for t, b in zip(turbos, before)]
+
+
+def _wide_queries(width):
+    """`width` queries: neighbours share a cold term (t20..t39 are cold on
+    every corpus below), every fourth has no cold term at all."""
+    qs = []
+    for i in range(width):
+        if i % 4 == 3:
+            qs.append([("t1", 1.0), ("t2", 0.5)])
+        else:
+            qs.append([(f"t{i % 3}", 1.0), (f"t{20 + i % 16}", 1.0),
+                       (f"t{21 + i % 16}", 0.7)])
+    return qs
+
+
+def _wide_specs(width):
+    specs = []
+    for i in range(width):
+        if i % 4 == 3:
+            specs.append({"must": [("t1", 1.0)], "should": [("t2", 0.5)]})
+        else:
+            specs.append({"must": [(f"t{i % 3}", 1.0)],
+                          "should": [(f"t{20 + i % 16}", 1.0),
+                                     (f"t{21 + i % 16}", 0.7)]})
+    return specs
+
+
+@pytest.fixture(scope="module")
+def solo_engine():
+    return _turbo(_pcorpus(3000, 40, 7), 3000, cold_df=COLD_DF)
+
+
+@pytest.fixture(scope="module")
+def fused_engine():
+    return _fused([(1500, _pcorpus(1500, 40, 1)),
+                   (900, _pcorpus(900, 56, 2)),
+                   (2100, _pcorpus(2100, 40, 3))], cold_df=300)
+
+
+@pytest.mark.parametrize("width", [1, 4, 16])
+@pytest.mark.parametrize("route", ["solo", "fused", "bool"])
+def test_hoisted_gather_bit_identical_and_counted(solo_engine, fused_engine,
+                                                  route, width):
+    """(a) + (d): every width and route answers the host enumeration's
+    bits; on the match routes every gather was launched before its
+    dispatch waited (`overlapped` == `sparse_queries` == launches), on the
+    bool route the same three steps run back to back inside finish."""
+    cold_pairs = sum(1 for i in range(width) if i % 4 != 3)
+    if route == "fused":
+        st = fused_engine._fused()
+        turbos = st.turbos
+        qs = _wide_queries(width)
+        before = _snap(turbos)
+        per = st.search_many([qs], k=K)
+        for si, t in enumerate(turbos):
+            _assert_identical(per[si][0],
+                              t.search_many_host([qs], k=K)[0],
+                              f"fused partition {si}, width {width}")
+    else:
+        turbos = [solo_engine]
+        before = _snap(turbos)
+        if route == "solo":
+            qs = _wide_queries(width)
+            _assert_identical(solo_engine.search_many([qs], k=K)[0],
+                              solo_engine.search_many_host([qs], k=K)[0],
+                              f"solo, width {width}")
+        else:
+            specs = _wide_specs(width)
+            _assert_identical(solo_engine.search_bool(specs, k=K),
+                              solo_engine.search_bool_host(specs, k=K),
+                              f"bool, width {width}")
+    for t, d in zip(turbos, _rise(turbos, before)):
+        assert d["sparse_queries"] == cold_pairs, d
+        assert d["sparse_gather_launches"] == cold_pairs, d
+        assert d["sparse_gather_overlapped"] == \
+            (0 if route == "bool" else cold_pairs), d
+        assert d["sparse_fallbacks"] == 0 and d["cold_queries"] == 0, d
+        assert t._sp_inflight == 0
+
+
+@pytest.mark.faults
+@pytest.mark.parametrize("where, nth", [("launch", 1), ("launch", 2),
+                                        ("collect", 3), ("collect", 4)])
+def test_gather_fault_contained_to_its_pair_and_counted_once(where, nth):
+    """(b) `sparse_gather` fires once before a pair's plan + launch and
+    once before its collect. Two cold queries on partition 1 make calls
+    1, 2 the launches and 3, 4 the collects: whichever faults, that
+    (partition, query) alone is host-scored, once."""
+    eng = _fused([(700, _pcorpus(700, 40, 12)),
+                  (900, _pcorpus(900, 32, 13))], cold_df=250)
+    qs = [[("t20", 1.0), ("t25", 1.0)], [("t1", 1.0), ("t28", 0.5)]]
+    want = eng._merge3([t.search_many_host([qs], k=K)[0]
+                        for t in eng.turbos], len(qs), K)
+    before = _snap(eng.turbos)
+    with faults.inject(f"sparse_gather#1:raise@{nth}"):
+        got = eng.search_many([qs], k=K)[0]
+    for g, w, name in zip(got, want, ("scores", "parts", "ords")):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), name
+    d0, d1 = _rise(eng.turbos, before)
+    assert d0["sparse_fallbacks"] == 0 and d1["sparse_fallbacks"] == 1
+    assert d0["sparse_queries"] == 2 and d1["sparse_queries"] == 2
+    # a fault before the launch costs the launch; one at collect does not
+    assert d1["sparse_gather_launches"] == (1 if where == "launch" else 2)
+    assert d1["sparse_gather_overlapped"] == d1["sparse_gather_launches"]
+    assert all(t._sp_inflight == 0 for t in eng.turbos)
+
+
+@pytest.mark.faults
+@pytest.mark.parametrize("where", ["program", "fetch"])
+def test_gather_device_error_contained(monkeypatch, where):
+    """(b) an organic device error, raised by the program's call or by
+    the fetch of its result, is contained like the injected one."""
+    t = _turbo(_pcorpus(3000, 40, 7), 3000, cold_df=COLD_DF)
+    qs = [[("t0", 1.0), ("t30", 1.0)], [("t1", 1.0), ("t35", 1.0)]]
+    want = t.search_many_host([qs], k=K)[0]
+    t.search_many([qs], k=K)                   # slices and pool in place
+    real = turbo_mod.sparse_gather_packed
+    calls = []
+
+    class _Lost:
+        def copy_to_host_async(self):
+            pass
+
+        def __array__(self, *a, **kw):
+            raise RuntimeError("INTERNAL: device lost")
+
+    def flaky(desc, pool, **kw):
+        calls.append(1)
+        if len(calls) == 1:
+            if where == "program":
+                raise RuntimeError("INTERNAL: launch failed")
+            return _Lost()
+        return real(desc, pool, **kw)
+
+    monkeypatch.setattr(turbo_mod, "sparse_gather_packed", flaky)
+    before = _snap([t])
+    _assert_identical(t.search_many([qs], k=K)[0], want, where)
+    (d,) = _rise([t], before)
+    assert len(calls) == 2
+    assert d["sparse_fallbacks"] == 1 and d["sparse_queries"] == 2
+    assert t._sp_inflight == 0
+
+
+@pytest.mark.parametrize("route", ["solo", "fused"])
+def test_chunk_wider_than_the_pool_is_served_without_fallback(route):
+    """(c) a chunk whose cold terms cannot all be resident at once (room
+    for three one-granule slices, sixteen distinct cold terms): every
+    query's gather is issued before a later query's slice build recycles
+    its granules, so the sparse tier serves all of them."""
+    if route == "solo":
+        turbos = [_turbo(_pcorpus(3000, 40, 7), 3000, cold_df=COLD_DF)]
+    else:
+        eng = _fused([(1500, _pcorpus(1500, 40, 1)),
+                      (2100, _pcorpus(2100, 40, 3))], cold_df=300)
+        turbos = eng._fused().turbos
+    for t in turbos:
+        t._sp_cap = 4                   # granule 0 is the reserved zero one
+    qs = [q for q in _wide_queries(16) if len(q) == 3]
+    distinct = {term for q in qs for term, _ in q[1:]}
+    assert len(distinct) > 3
+    before = _snap(turbos)
+    if route == "solo":
+        got = [turbos[0].search_many([qs], k=K)]
+    else:
+        got = eng._fused().search_many([qs], k=K)
+    for si, t in enumerate(turbos):
+        _assert_identical(got[si][0], t.search_many_host([qs], k=K)[0],
+                          f"{route} partition {si}")
+        assert t._sp_pool.shape[0] == 4
+        assert len(t._sp_of) <= 3
+    for d in _rise(turbos, before):
+        assert d["sparse_fallbacks"] == 0, d
+        assert d["sparse_queries"] == len(qs)
+        assert d["sparse_gather_overlapped"] == len(qs)
+        assert d["sparse_slices"] >= len(distinct) > 3, "nothing recycled"
+
+
+@pytest.mark.faults
+@pytest.mark.parametrize("where", ["launch", "fetch"])
+def test_fused_sweep_fault_leaves_no_gather_in_flight(monkeypatch, where):
+    """(e) a fused sweep that faults at its launch never starts the
+    chunk's gathers; one whose fault surfaces at the fetch has started
+    them, and the chunk's host scoring drops every one."""
+    eng = _fused([(700, _pcorpus(700, 40, 12)),
+                  (900, _pcorpus(900, 32, 13))], cold_df=250)
+    st = eng._fused()
+    qs = [[("t20", 1.0), ("t25", 1.0)], [("t1", 1.0), ("t28", 0.5)]]
+    want = [t.search_many_host([qs], k=K)[0] for t in st.turbos]
+    st.search_many([qs], k=K)                  # slices and pools in place
+    before = _snap(st.turbos)
+    log = []
+    if where == "launch":
+        with faults.inject("fused_dispatch:raise@1"):
+            per = st.search_many([qs], k=K, fault_log=log)
+    else:
+        real = st._dispatch_disj
+
+        class _Lost:
+            def __array__(self, *a, **kw):
+                raise DeviceFaultError("async fault", site="fused_dispatch")
+
+        def lost(*args, **kw):
+            _packed, gathers = real(*args, **kw)
+            return _Lost(), gathers
+
+        monkeypatch.setattr(st, "_dispatch_disj", lost)
+        per = st.search_many([qs], k=K, fault_log=log)
+    for si, t in enumerate(st.turbos):
+        _assert_identical(per[si][0], want[si], f"partition {si}")
+        assert t._sp_inflight == 0
+    assert [r.site for r in log] == ["fused_dispatch"]
+    for d in _rise(st.turbos, before):
+        assert d["sparse_gather_launches"] == (0 if where == "launch" else 2)
+        # host-scored chunk: no finish ran, so nothing was collected
+        assert d["sparse_queries"] == 0 and d["sparse_fallbacks"] == 0
+        assert d["sparse_gather_overlapped"] == 0
+
+
+def test_cancel_between_launch_and_finish_drops_the_gathers(solo_engine):
+    """A cooperative cancel (`check`) raising after the gathers went out
+    unwinds through search_many with none left in flight."""
+    class _Cancelled(Exception):
+        pass
+
+    n = []
+
+    def check():
+        n.append(1)
+        if len(n) == 2:                 # 1: before the sweep, 2: before finish
+            raise _Cancelled()
+
+    qs = _wide_queries(4)
+    l0 = solo_engine.stats["sparse_gather_launches"]
+    with pytest.raises(_Cancelled):
+        solo_engine.search_many([qs], k=K, check=check)
+    assert solo_engine.stats["sparse_gather_launches"] == l0 + 3
+    assert solo_engine._sp_inflight == 0
+
+
+def test_every_gather_shape_is_instantiated_when_the_pool_is_full_size():
+    """The gather program's shape is (chunk-count bucket, pool size, tiles).
+    When the pool reaches its cap, its final size, every bucket is
+    instantiated: a dispatch at a bucket traffic has not reached yet
+    builds nothing. (Below the cap the pool still doubles, and programs
+    for a passing size are left to the traffic that needs them.)"""
+    from elasticsearch_tpu.parallel.kernels import TILE
+
+    program = turbo_mod.sparse_gather_packed
+    t = _turbo(_pcorpus(3000, 40, 7), 3000, cold_df=COLD_DF)
+    t._sp_cap = 37                       # no other test's pool size
+    t.search_many([[[("t0", 1.0), ("t30", 1.0)]]], k=K)
+    assert t._sp_pool.shape[0] == 37
+    size0 = program._cache_size()
+    for rcb in _SPARSE_RC_BUCKETS:
+        program(turbo_mod._gather_desc(rcb), t._sp_pool,
+                n_tiles=t.Dp // TILE)
+    assert program._cache_size() == size0
+
+
+def test_gather_counters_ride_the_node_stats(solo_engine):
+    from elasticsearch_tpu.search.serving import turbo_node_stats
+
+    before = turbo_node_stats()
+    assert {"sparse_gather_launches", "sparse_gather_overlapped"} \
+        <= set(before)
+    solo_engine.search_many([_wide_queries(4)], k=K)
+    after = turbo_node_stats()
+    rise = {key: after[key] - before[key] for key in (
+        "sparse_queries", "sparse_gather_launches",
+        "sparse_gather_overlapped", "sparse_fallbacks")}
+    assert rise == {"sparse_queries": 3, "sparse_gather_launches": 3,
+                    "sparse_gather_overlapped": 3, "sparse_fallbacks": 0}
