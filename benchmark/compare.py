@@ -15,10 +15,12 @@ Numbers (limits in the configuration file's `limits`, readings in PERF.md):
                 rise from one position to the next (a sorted list reads 0)
     hits_wrong  responses whose hit count, duplicate-free ids or
                 hits.total disagree with the reference
-    host_tier_answers  rise of the nine fallback / fault / reject counters
-                since the node started
-    device_dispatches  rise of the configuration's device counter over
-                the window (must move: at least 1)
+    host_tier_answers  rise of the nine fallback / fault / reject counters,
+                and of the configuration's `must_stay` counters, since the
+                node started
+    device_dispatches  smallest rise over the window among the
+                configuration's `device_counter` and its `must_rise`
+                counters (every one must move: at least 1)
     compared    responses compared (at least `min_compared`)
 """
 
@@ -102,11 +104,15 @@ def compare_one(resp: dict, ref: dict, k: int) -> Dict[str, float]:
     return out
 
 
-def verdict(pairs: Sequence, limits: dict, stats0: dict, stats1: dict,
-            stats2: dict, device_counter: str, k: int) -> Dict[str, dict]:
+def verdict(pairs: Sequence, config: dict, stats0: dict, stats1: dict,
+            stats2: dict, k: int) -> Dict[str, dict]:
     """`pairs` = (served response, reference answer) of the sampled
     requests. stats0/1/2 = node stats at node start, window start and
-    window end. Returns name -> {value, limit, ok}."""
+    window end. `config` gives the `limits`, the `device_counter` and,
+    where the deployment has more than one thing that must have run on
+    the device or may not have run on the host, `must_rise` and
+    `must_stay`. Returns name -> {value, limit, ok}."""
+    limits = config["limits"]
     worst = {"score_err": 0.0, "rank_gap": 0.0, "order_err": 0.0,
              "hits_wrong": 0}
     for resp, ref in pairs:
@@ -114,8 +120,11 @@ def verdict(pairs: Sequence, limits: dict, stats0: dict, stats1: dict,
         for name in ("score_err", "rank_gap", "order_err"):
             worst[name] = max(worst[name], one[name])
         worst["hits_wrong"] += one["hits_wrong"]
-    host = sum(dotted(stats2, c) - dotted(stats0, c) for c in ZERO_COUNTERS)
-    moved = dotted(stats2, device_counter) - dotted(stats1, device_counter)
+    host = sum(dotted(stats2, c) - dotted(stats0, c)
+               for c in ZERO_COUNTERS + tuple(config.get("must_stay", ())))
+    moved = min(dotted(stats2, c) - dotted(stats1, c)
+                for c in [config["device_counter"]]
+                + list(config.get("must_rise", ())))
     out = {}
     for name in ("score_err", "rank_gap", "order_err"):
         out[name] = {"value": worst[name], "limit": float(limits[name]),

@@ -33,10 +33,14 @@ def peaks_for(device_kind: str) -> Dict[str, float]:
 
 def sweep_rowmax(regions: Dict[str, int], qc: int) -> Tuple[float, float, str]:
     """The disjunctive sweep reads every int8 cell of the hi and lo
-    column planes once and multiplies it with `qc` query weights
-    (one multiply-add = 2 operations); it also reads the live rows."""
+    column planes once and multiplies it with BOTH int8 halves (hi, lo)
+    of each of `qc` query weights: four s8 x s8 -> s32 products a chunk
+    (`kernels._sweep_kernel`: hi.hi, hi.lo, lo.hi, lo.lo), one
+    multiply-add = 2 operations; it also reads the live rows. By the
+    v5e's peaks bytes bound it up to width 64 (the two meet at 120),
+    the int8 peak at 256."""
     cells = float(regions["cols_hi"] + regions["cols_lo"])
-    return 2.0 * qc * cells, cells + float(regions.get("live", 0)), \
+    return 2.0 * 2.0 * qc * cells, cells + float(regions.get("live", 0)), \
         "int8_ops_per_s"
 
 

@@ -121,3 +121,17 @@ def make_vectors(spec: dict, seed: int, n_segments: int) -> List[VectorSegment]:
             lambda p: _draw_vector_segment(spec, seed, p, int(b[p]),
                                            int(b[p + 1] - b[p]), centers),
             range(n_segments)))
+
+
+def n_parts(config: dict) -> int:
+    """Segments of a configuration's index in all: `index.segments` is
+    per shard, `index.shards` absent = 1."""
+    idx = config["index"]
+    return int(idx.get("shards", 1)) * int(idx["segments"])
+
+
+def make_parts(config: dict, seed: int) -> list:
+    """The seeded corpus of a configuration, one part a segment, in
+    global ordinal order."""
+    make = {"text": make_text, "vectors": make_vectors}[config["kind"]]
+    return make(config["corpus"], seed, n_parts(config))

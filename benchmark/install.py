@@ -32,7 +32,9 @@ def _sources(doc0: int, n: int) -> List[dict]:
     return [{"pid": i} for i in range(doc0, doc0 + n)]
 
 
-def text_segment(seg: TextSegment, field: str, seg_id: int):
+def text_segment(seg: TextSegment, field: str, seg_id: int, seq0: int = 0):
+    """`seq0` = the global ordinal of the shard's first document: sequence
+    numbers are the shard's own, ids and `pid` the index's."""
     from elasticsearch_tpu.index.segment import Segment, build_field_postings
 
     present = np.flatnonzero(np.bincount(seg.tokens))
@@ -46,11 +48,12 @@ def text_segment(seg: TextSegment, field: str, seg_id: int):
         seg_id=seg_id, doc_ids=_doc_ids(seg.doc0, seg.n),
         sources=_sources(seg.doc0, seg.n), postings={field: fp},
         numeric={}, keyword={}, vectors={},
-        seq_nos=np.arange(seg.doc0, seg.doc0 + seg.n, dtype=np.int64))
+        seq_nos=np.arange(seg.doc0 - seq0, seg.doc0 - seq0 + seg.n,
+                          dtype=np.int64))
 
 
 def vector_segment(seg: VectorSegment, field: str, tag_field: str,
-                   n_tags: int, seg_id: int):
+                   n_tags: int, seg_id: int, seq0: int = 0):
     from elasticsearch_tpu.index.segment import (
         KeywordColumn, Segment, VectorColumn, build_field_postings)
 
@@ -74,24 +77,48 @@ def vector_segment(seg: VectorSegment, field: str, tag_field: str,
         seg_id=seg_id, doc_ids=_doc_ids(seg.doc0, n),
         sources=_sources(seg.doc0, n), postings={tag_field: fp},
         numeric={}, keyword={tag_field: kw}, vectors={field: vc},
-        seq_nos=np.arange(seg.doc0, seg.doc0 + n, dtype=np.int64))
+        seq_nos=np.arange(seg.doc0 - seq0, seg.doc0 - seq0 + n,
+                          dtype=np.int64))
 
 
-def install(node, index: str, build, parts: Sequence) -> int:
-    """Build each part into a Segment (in threads: numpy's sorts release
-    the GIL), serialise it and install it into shard 0 of `index`.
+def install(node, config: dict, parts: Sequence) -> int:
+    """Build each part of the configuration's corpus into a Segment (in
+    threads: numpy's sorts release the GIL), serialise it and install it
+    into the configuration's index, which exists. The parts are in global
+    ordinal order and go to the index's shards by contiguous range: with
+    `per` segments a shard, part i is segment i % per of shard i // per.
     Returns the number of documents installed."""
     from elasticsearch_tpu.index.segment_io import segment_to_blob
 
-    def blob_of(item):
-        i, part = item
-        return segment_to_blob(build(part, i)), part.n
+    idx = config["index"]
+    per = int(idx["segments"])
+    engines = node.indices.get(idx["name"]).shards
+    if len(engines) * per != len(parts):
+        raise RuntimeError(f"index {idx['name']} has {len(engines)} shards "
+                           f"of {per} segments, the corpus {len(parts)} parts")
 
-    engine = node.indices.get(index).shards[0]
-    total = 0
-    with ThreadPoolExecutor(len(parts)) as pool:
-        for blob, n in pool.map(blob_of, enumerate(parts)):
-            engine.install_segment(blob, np.ones(n, bool))
-            total += n
-    engine.fill_seqno_gaps(total - 1)
-    return total
+    def blob_of(i: int) -> bytes:
+        seq0 = parts[i // per * per].doc0
+        if config["kind"] == "text":
+            seg = text_segment(parts[i], idx["field"], i % per, seq0)
+        else:
+            seg = vector_segment(parts[i], idx["field"], idx["tag_field"],
+                                 int(config["corpus"]["tags"]), i % per, seq0)
+        return segment_to_blob(seg)
+
+    def fill(s: int) -> int:
+        """Shard s takes its segments in ordinal order, as they are
+        built; the shards fill side by side (an engine a shard)."""
+        total = 0
+        for i in range(s * per, (s + 1) * per):
+            engines[s].install_segment(blobs[i].result(),
+                                       np.ones(parts[i].n, bool))
+            blobs[i] = None         # a blob is as large as its segment
+            total += parts[i].n
+        engines[s].fill_seqno_gaps(total - 1)
+        return total
+
+    with ThreadPoolExecutor(len(parts)) as build_pool, \
+            ThreadPoolExecutor(len(engines)) as shard_pool:
+        blobs = [build_pool.submit(blob_of, i) for i in range(len(parts))]
+        return sum(shard_pool.map(fill, range(len(engines))))

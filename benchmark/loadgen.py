@@ -4,17 +4,23 @@ to one process, so the clients live beside the node).
 Open loop: one pacing thread hands each call to a small pool of workers
 when it is DUE; a call is timed from when it was due to when its body was
 read, and (sent - due) is the generator's own lateness.
+
+Closed loop: `clients` callers, each posting its next `_msearch` when the
+last is answered. A call is one sample; it is due, and sent, the moment
+its client was free.
 """
 
 from __future__ import annotations
 
 import http.client
+import itertools
 import queue
 import threading
 import time
 from dataclasses import dataclass
 from typing import List, Optional
 
+from benchmark import compare
 from benchmark.traffic import Mix, Request, Schedule
 
 CLIENT_TIMEOUT_S = 60.0
@@ -29,7 +35,15 @@ class Sent:
     done: float
     status: int                 # 0 = no answer (time-out, reset)
     raw: bytes
-    request: Request
+    requests: List[Request]     # one for a _search, `batch` for an _msearch
+
+    def responses(self) -> Optional[list]:
+        """The call's search responses, one per request, or None where
+        it was not answered: anything but a 200 whose body holds a
+        well-formed response to EVERY request."""
+        if self.status != 200:
+            return None
+        return compare.well_formed(self.raw, len(self.requests))
 
 
 class Conn:
@@ -49,9 +63,12 @@ class Conn:
                     else "application/json"})
                 resp = self.c.getresponse()
                 return resp.status, resp.read()
-            except (OSError, http.client.HTTPException):
+            except (OSError, http.client.HTTPException) as e:
                 self.close()
-                if attempt:
+                # a stale keep-alive connection is worth one more try; a
+                # call that timed out is still running on the node, and a
+                # second one beside it would be load the mix never asked for
+                if attempt or isinstance(e, TimeoutError):
                     return 0, b""
         return 0, b""
 
@@ -78,7 +95,7 @@ def open_loop(port: int, mix: Mix, sched: Schedule) -> List[Sent]:
             path, data, req = mix.call(int(sched.index[i]))
             sent = time.monotonic() - t0
             status, raw = conn.post(path, data)
-            s = Sent(i, due, sent, time.monotonic() - t0, status, raw, req)
+            s = Sent(i, due, sent, time.monotonic() - t0, status, raw, [req])
             with lock:
                 out.append(s)
         conn.close()
@@ -97,5 +114,45 @@ def open_loop(port: int, mix: Mix, sched: Schedule) -> List[Sent]:
         work.put(None)
     for t in threads:
         t.join()
+    out.sort(key=lambda s: s.i)
+    return out
+
+
+def closed_loop(port: int, mix: Mix, seconds: float,
+                first: int = 0) -> List[Sent]:
+    """Each of the mix's `clients` sends call after call (`Mix.closed_call`,
+    numbered as the clients take them), none started after `seconds`;
+    returns the calls in that order once the last is answered."""
+    out: List[Sent] = []
+    errs: List[BaseException] = []
+    lock = threading.Lock()
+    numbers = itertools.count()
+
+    def client():
+        conn = Conn(port, float(mix.t.get("timeout_s", CLIENT_TIMEOUT_S)))
+        try:
+            while time.monotonic() - t0 < seconds:
+                i = next(numbers)
+                path, data, reqs = mix.msearch(mix.closed_call(i, first))
+                sent = time.monotonic() - t0
+                status, raw = conn.post(path, data, ndjson=True)
+                s = Sent(i, sent, sent, time.monotonic() - t0, status, raw,
+                         reqs)
+                with lock:
+                    out.append(s)
+        except BaseException as e:   # noqa: BLE001 — re-raised below
+            errs.append(e)
+        finally:
+            conn.close()
+
+    threads = [threading.Thread(target=client, daemon=True)
+               for _ in range(int(mix.t["clients"]))]
+    t0 = time.monotonic()
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    if errs:
+        raise errs[0]
     out.sort(key=lambda s: s.i)
     return out

@@ -5,7 +5,8 @@ per-layer metric is a file found by the NAME in the manifest:
 
     benchmark/configs/<config>.json     (the manifest gives the path)
     benchmark/traffic/<traffic>.json
-    benchmark/metrics/<metric>.json     (every metric: its reader and what it reads)
+    benchmark/metrics/<metric>.json     (every metric: its reader, what it
+                                         reads and under which loops)
 
 so a later PR adds a cell, a configuration, a mix or a counter-backed
 metric by adding files and manifest entries, and edits nothing.
@@ -62,11 +63,18 @@ class Manifest:
 
     def per_layer(self, cell: str) -> List[dict]:
         """A metric with a `workloads` list is for those cells; without
-        one, for every cell that reports the end-to-end metric it moves."""
+        one, for every cell that reports the end-to-end metric it moves.
+        A metric's own file may narrow that by what the harness can see
+        of a cell: `loops` names the traffic loops under which its source
+        takes an observation (the scheduler's lane sees no `_msearch`
+        batch of a closed loop), so a cell that comes later as data gets
+        the set that has something to read there, with no list to edit."""
         reported = {m["name"] for m in self.end_to_end(cell)}
+        loop = self.cell(cell).traffic.get("loop", "open")
         return [m for m in self.doc["per_layer"]
                 if (cell in m["workloads"] if "workloads" in m
-                    else m["moves"] in reported)]
+                    else m["moves"] in reported)
+                and loop in self.metric_spec(m["name"]).get("loops", (loop,))]
 
     def declared(self, cell: str, trace: int) -> List[dict]:
         """The metrics a run of `cell` has to print: the cell's end-to-end

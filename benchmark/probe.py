@@ -12,10 +12,10 @@ is how a figure or a fault that PERF.md states can be read again.
 
     python3 -m benchmark.probe closed <workload> <seed> <seconds> <batch> <clients>...
         the cell's configuration and requests under a CLOSED loop of
-        `_msearch` batches (driven here, not by the generator), one window
-        per client count on one set-up, each compared with the reference:
-        how PERF.md's first open question (two concurrent batches corrupt
-        the sparse tier) was shown, and how its cure can be.
+        `_msearch` batches (`loadgen.closed_loop`, the generator's own),
+        one window per client count on one set-up, each compared with the
+        reference: how PERF.md's open question (two concurrent batches
+        corrupt the sparse tier) was shown, and how its cure can be.
 """
 
 from __future__ import annotations
@@ -23,8 +23,6 @@ from __future__ import annotations
 import copy
 import json
 import sys
-import threading
-import time
 
 import numpy as np
 
@@ -93,44 +91,11 @@ def control(workload: str, seed: int, seconds: float) -> None:
         pairs = run.sample_pairs(cell, w.notes["answered"], seed,
                                  bench.parts, precision)
         checked = compare.verdict(
-            pairs, cell.config["limits"], bench.stats0, w.stats_before,
-            w.stats_after, cell.config["device_counter"], run.top_k(cell))
+            pairs, cell.config, bench.stats0, w.stats_before,
+            w.stats_after, run.top_k(cell))
         out[label] = {k: v["value"] for k, v in checked.items()}
         out[label + "_correct"] = compare.is_correct(checked)
     print(json.dumps(out), flush=True)
-
-
-def msearch_closed(port: int, mix: Mix, seconds: float, batch: int,
-                   clients: int):
-    """`clients` callers, each sending its next `_msearch` of `batch`
-    canonical requests when the last is answered, none started after
-    `seconds`. Returns ([(response, Request)] of the well-formed 200s,
-    seconds to the last answer)."""
-    done, lock, nxt = [], threading.Lock(), [0]
-    head = json.dumps({"index": mix.index})
-
-    def client():
-        conn = loadgen.Conn(port)
-        while time.monotonic() - t0 < seconds:
-            with lock:
-                i = nxt[0]
-                nxt[0] += 1
-            reqs = [mix.request(i * batch + b) for b in range(batch)]
-            nd = "".join(head + "\n" + json.dumps(r.body) + "\n"
-                         for r in reqs)
-            status, raw = conn.post("/_msearch", nd.encode(), ndjson=True)
-            resps = compare.well_formed(raw, batch) if status == 200 else None
-            with lock:
-                done.extend(zip(resps or (), reqs))
-        conn.close()
-
-    threads = [threading.Thread(target=client) for _ in range(clients)]
-    t0 = time.monotonic()
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    return done, time.monotonic() - t0
 
 
 def closed(workload: str, seed: int, seconds: float, batch: int,
@@ -139,22 +104,27 @@ def closed(workload: str, seed: int, seconds: float, batch: int,
     if bench.dev is None:
         sys.exit(2)
     cell = bench.cell
-    cell.traffic = dict(cell.traffic, warmup={"calls": 2})
+    base = dict(cell.traffic, loop="closed", warmup={"calls": 2},
+                request=dict(cell.traffic["request"], batch=batch))
+    cell.traffic = dict(base, clients=1)
     bench.setup(seed)
     try:
         for n, clients in enumerate(client_counts):
+            cell.traffic = dict(base, clients=clients)
             mix = Mix(cell.traffic, cell.config, seed + n, bench.parts)
             s1 = bench.node.stats()
-            done, took = msearch_closed(bench.node.port, mix, seconds, batch,
-                                        clients)
+            calls = loadgen.closed_loop(bench.node.port, mix, seconds)
             s2 = bench.node.stats()
+            n_answered, done = run.answered(calls)
+            took = max(s.done for s in calls)
             pairs = run.sample_pairs(cell, done, seed + n, bench.parts)
-            checked = compare.verdict(
-                pairs, cell.config["limits"], s1, s1, s2,
-                cell.config["device_counter"], run.top_k(cell))
+            checked = compare.verdict(pairs, cell.config, s1, s1, s2,
+                                      run.top_k(cell))
             print(json.dumps({
                 "probe": "closed", "clients": clients, "batch": batch,
-                "seed": seed + n, "queries_per_s": len(done) / took,
+                "seed": seed + n, "calls": len(calls),
+                "calls_answered": n_answered,
+                "queries_per_s": len(done) / took,
                 "window_s": took, "correct": compare.is_correct(checked),
                 "checked": {k: v["value"] for k, v in checked.items()}}),
                 flush=True)

@@ -4,10 +4,11 @@ kind that exists is a new file; a new kind is code.
 
 Every reader is total: it returns a number for any window, also one with
 no event of its source (a histogram with count 0 reads 0.0, a ratio over
-0 reads 0.0). The one exception is `kernel_roofline`: a share of a
-roofline is never reported as 0, so a span without an event of the
-kernel raises `NothingToRead` — the run then writes down the event names
-it did see and ends non-zero without a result line.
+0 reads 0.0). The exceptions read the device trace: `kernel_roofline` (a
+share of a roofline is never reported as 0), `module_mean_ms` and
+`busy_skew` raise `NothingToRead` on a span without an event of theirs —
+the run then writes down the event names it did see and ends non-zero
+without a result line.
 """
 
 from __future__ import annotations
@@ -64,6 +65,12 @@ def client_latency(spec: dict, w: Window) -> float:
     return float(np.percentile(arr, spec["percentile"])) if len(arr) else 0.0
 
 
+def client_rate(spec: dict, w: Window) -> float:
+    """Searches answered per second, from the window's start to its last
+    answer (a closed loop's last call ends after the window's length)."""
+    return w.queries_done / max(w.seconds, float(np.max(w.notes["done_s"])))
+
+
 def memory_peak(spec: dict, w: Window) -> float:
     return w.memory_peak_bytes / float(spec.get("divide", 1e9))
 
@@ -97,19 +104,48 @@ def device_idle(spec: dict, w: Window) -> float:
     return 100.0 * (1.0 - w.notes["busy_s"] / w.notes["window_s"])
 
 
-def engine_regions(stats: dict, kind: Optional[str] = None) -> Dict[str, int]:
-    """Summed HBM regions of the engines (of one kind) in the ledger."""
+def busy_skew(spec: dict, w: Window) -> float:
+    """The busiest device's busy seconds over the mean of the devices':
+    1.0 = even, the device count = one chip did everything. It is what
+    `device_idle`'s mean over the planes hides."""
+    busy = list(trace.busy_by_plane(w.events or []).values())
+    if not busy or sum(busy) <= 0:
+        raise NothingToRead("no device event in the traced span")
+    return max(busy) * len(busy) / sum(busy)
+
+
+def module_mean_ms(spec: dict, w: Window) -> float:
+    """Mean traced time of one run of a device program, in ms."""
+    seconds, runs = trace.module_seconds(w.events or [], spec["match"])
+    if runs <= 0:
+        raise NothingToRead(f"no run of a program named {spec['match']!r}")
+    return 1e3 * seconds / runs
+
+
+def engine_regions(stats: dict, kind: Optional[str] = None,
+                   per_device: bool = False) -> Dict[str, int]:
+    """Summed HBM regions of the engines (of one kind) in the ledger:
+    over all devices, or (`per_device`) each engine's divided by the
+    number of devices that hold it (`devices`, 1 where absent)."""
     out: Dict[str, int] = {}
     for eng in stats["tpu_hbm"]["engines"].values():
         if kind is None or eng["kind"] == kind:
+            over = int(eng.get("devices", 1)) if per_device else 1
             for k, v in eng["regions"].items():
-                out[k] = out.get(k, 0) + int(v)
+                out[k] = out.get(k, 0) + int(v) // over
     return out
 
 
 def kernel_roofline(spec: dict, w: Window) -> float:
     """100 x (least time the chip could take for the kernel passes of the
-    span) / (traced time of the kernel's device events)."""
+    span) / (traced time of the kernel's device events), both per device:
+    the regions a pass reads are one device's share of them, the traced
+    time the mean over the device planes. The dispatch width is what
+    ran: where the loop is closed, the searches the window answered over
+    the passes the program counted (a batch over the scheduler's
+    SMALL_BATCH_MAX never passes the lane, so the histogram never sees
+    it; a batch the engine split in two reads half as wide), the
+    histogram's mean otherwise."""
     seconds, n_events = trace.kernel_seconds(w.events or [], spec["match"])
     passes = _delta(w, [spec["passes"]])
     if seconds <= 0 or passes <= 0:
@@ -118,10 +154,13 @@ def kernel_roofline(spec: dict, w: Window) -> float:
             f"{seconds} s, {passes} passes counted by {spec['passes']}")
     regions = {}
     for kind in spec["engine_kinds"]:       # first kind the ledger holds
-        regions = engine_regions(w.stats_after, kind)
+        regions = engine_regions(w.stats_after, kind, per_device=True)
         if regions:
             break
-    batch = histogram_mean({"path": spec["batch_histogram"]}, w)
+    if w.traffic.get("loop") == "closed":
+        batch = w.queries_done / passes
+    else:
+        batch = histogram_mean({"path": spec["batch_histogram"]}, w)
     qc = next((s for s in spec["widths"] if s >= batch), spec["widths"][-1])
     least, bound = costs.least_seconds(spec["cost"], regions, qc,
                                        w.device_kind)
@@ -133,8 +172,9 @@ def kernel_roofline(spec: dict, w: Window) -> float:
 
 KINDS: Dict[str, Callable[[dict, Window], float]] = {
     f.__name__: f for f in (
-        client_latency, memory_peak, setup, histogram_mean, counter_delta,
-        counter_ratio, device_idle, trace_event_count, kernel_roofline)}
+        client_latency, client_rate, memory_peak, setup, histogram_mean,
+        counter_delta, counter_ratio, device_idle, busy_skew,
+        trace_event_count, module_mean_ms, kernel_roofline)}
 
 
 def read(spec: dict, w: Window) -> float:

@@ -74,13 +74,16 @@ class BM25Reference:
         top = max(need) + 1
         slot = np.full(top, -1, np.int64)
         slot[need] = np.arange(len(need))
-        keys = []
-        for s in self.segments:
+
+        def keys_of(s: TextSegment) -> np.ndarray:
             tok = s.tokens
             idx = np.flatnonzero(slot[np.minimum(tok, top - 1)] >= 0)
             idx = idx[tok[idx] < top]
             docs = np.searchsorted(s.bounds, idx, side="right") - 1 + s.doc0
-            keys.append(slot[tok[idx]] * self.n + docs)
+            return slot[tok[idx]] * self.n + docs
+
+        with ThreadPoolExecutor(len(self.segments)) as pool:
+            keys = list(pool.map(keys_of, self.segments))
         key, tf = np.unique(np.concatenate(keys), return_counts=True)
         term, doc = key // self.n, key % self.n
         cut = np.searchsorted(term, np.arange(len(need) + 1))

@@ -2,7 +2,7 @@
 
     python3 -m benchmark --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-One process, one chip: start the node as `python -m elasticsearch_tpu`
+One process, the cell's chips: start the node as `python -m elasticsearch_tpu`
 does, install the seeded corpus, warm the cell's own shapes (all of that
 is `setup_s`), drive the window over HTTP from this process's threads,
 then free the node and hold a sample of what the window's own requests
@@ -22,6 +22,7 @@ import shutil
 import sys
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional
 
 import numpy as np
@@ -72,12 +73,12 @@ def find_devices(chips: int, require_chip: bool) -> Optional[dict]:
     return dev
 
 
-def memory_peak_bytes() -> int:
+def memory_peaks() -> List[int]:
+    """`peak_bytes_in_use` of every local device, in device order."""
     import jax
 
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-             for d in jax.local_devices()]
-    return int(max(peaks)) if peaks else 0
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in jax.local_devices()]
 
 
 class Stalls:
@@ -182,25 +183,19 @@ class Node:
 
 def install_corpus(node: Node, cell: Cell, seed: int):
     """Create the index and install the seeded corpus; returns the
-    datagen segments (the reference reads them after the window)."""
+    datagen segments (the mix and the reference read them)."""
     from benchmark import install
 
     cfg = cell.config
     idx = cfg["index"]
-    node.request("PUT", "/" + idx["name"], {
-        "settings": {"number_of_shards": 1, "number_of_replicas": 0},
-        "mappings": idx["mappings"]})
-    n_seg = int(idx["segments"])
-    if cfg["kind"] == "text":
-        parts = datagen.make_text(cfg["corpus"], seed, n_seg)
-        build = lambda p, i: install.text_segment(p, idx["field"], i)  # noqa: E731
-    elif cfg["kind"] == "vectors":
-        parts = datagen.make_vectors(cfg["corpus"], seed, n_seg)
-        build = lambda p, i: install.vector_segment(  # noqa: E731
-            p, idx["field"], idx["tag_field"], int(cfg["corpus"]["tags"]), i)
-    else:
+    if cfg["kind"] not in ("text", "vectors"):
         raise ManifestError(f"configuration kind {cfg['kind']!r}")
-    n = install.install(node.node, idx["name"], build, parts)
+    node.request("PUT", "/" + idx["name"], {
+        "settings": {"number_of_shards": int(idx.get("shards", 1)),
+                     "number_of_replicas": 0},
+        "mappings": idx["mappings"]})
+    parts = datagen.make_parts(cfg, seed)
+    n = install.install(node.node, cfg, parts)
     got = node.request("GET", f"/{idx['name']}/_count")["count"]
     if got != n:
         raise RuntimeError(f"installed {n} documents, _count says {got}")
@@ -227,8 +222,12 @@ def warm_up(node: Node, mix: Mix) -> None:
     there (my chip run 1, PR 26: 21 s in the kNN cell).
 
     Last, `lead_in_s` seconds of the mix's own arrivals at its own rate:
-    what only live traffic instantiates happens before the window."""
+    what only live traffic instantiates happens before the window.
+
+    A closed mix has a warm-up of its own: `warm_up_closed`."""
     warm = mix.t.get("warmup", {})
+    if mix.closed:
+        return warm_up_closed(node, mix, int(warm.get("calls", 1)))
     lock = threading.Lock()
 
     def one(conn, variant=None):
@@ -306,15 +305,44 @@ def warm_up(node: Node, mix: Mix) -> None:
         f"{readers.engine_regions(node.stats())}")
 
 
-def answered(calls) -> list:
-    """(served response, Request) of every search of the window that got
-    a well-formed 200, in call order."""
-    done = []
+def warm_up_closed(node: Node, mix: Mix, calls: int) -> None:
+    """`calls` `_msearch` calls of the mix's own batch, one after another,
+    on set-up's requests, and nothing else: a batch over the scheduler's
+    `SMALL_BATCH_MAX` skips the lane, so there is no bucket to see, and
+    its width is the one shape the window uses. The log gives each
+    call's seconds and the engines' regions after it: `calls` is as many
+    as it takes for the regions to stop growing (a text index's pool of
+    slices doubles as it fills), and no more."""
+    took, regions = [], []
+    conn = loadgen.Conn(node.port, timeout=WARM_TIMEOUT_S)
+    try:
+        for _ in range(calls):
+            t0 = time.monotonic()
+            path, data, reqs = mix.msearch(
+                [mix.warm() for _ in range(mix.batch)])
+            status, raw = conn.post(path, data, ndjson=True)
+            if status != 200 or compare.well_formed(raw, len(reqs)) is None:
+                raise RuntimeError(f"warm-up call {path} -> HTTP {status}: "
+                                   f"{raw[:300]!r}")
+            took.append(round(time.monotonic() - t0, 1))
+            regions.append(readers.engine_regions(node.stats()))
+    finally:
+        conn.close()
+    log(f"warm-up: {calls} calls of {mix.batch}, s each {took}; HBM "
+        f"regions after each {regions}")
+
+
+def answered(calls) -> tuple:
+    """(calls answered, [(served response, Request)] of every search in
+    them, in call order). A call is answered when it got a 200 with a
+    well-formed response to each of its requests."""
+    n, done = 0, []
     for s in calls:
-        resps = compare.well_formed(s.raw, 1) if s.status == 200 else None
+        resps = s.responses()
         if resps is not None:
-            done.append((resps[0], s.request))
-    return done
+            n += 1
+            done.extend(zip(resps, s.requests))
+    return n, done
 
 
 def sample_pairs(cell: Cell, done: list, seed: int, parts, precision=None):
@@ -332,7 +360,10 @@ def sample_pairs(cell: Cell, done: list, seed: int, parts, precision=None):
         ref = BM25Reference(parts, cfg["bm25"]["k1"], cfg["bm25"]["b"],
                             precision=precision)
         ref.prepare([r for _, q in chosen for r in q.ranks])
-        return [(resp, ref.answer(q, top_k(cell))) for resp, q in chosen]
+        with ThreadPoolExecutor(8) as pool:
+            answers = list(pool.map(lambda rq: ref.answer(rq[1], top_k(cell)),
+                                    chosen))
+        return [(resp, a) for (resp, _), a in zip(chosen, answers)]
     ref = KnnReference(parts, precision=precision)
     answers = ref.answers([q for _, q in chosen], top_k(cell))
     return [(resp, a) for (resp, _), a in zip(chosen, answers)]
@@ -369,9 +400,13 @@ class Bench:
         warm_up(self.node, Mix(self.cell.traffic, self.cell.config, seed,
                                self.parts))
         self.setup_s = time.monotonic() - t_start
+        built = {k: round(v["count"] * v["mean"] / 1e3, 1) for k, v in
+                 self.node.stats()["tpu_search_latency"].items()
+                 if k.startswith("engine_build.") and v["count"]}
         log(f"set-up {self.setup_s:.1f} s (installed at "
             f"{t_inst - t_start:.1f} s, warm-up "
-            f"{time.monotonic() - t_inst:.1f} s)")
+            f"{time.monotonic() - t_inst:.1f} s; of it the program's "
+            f"engine build, s by step: {built})")
 
     def window(self, seed: int, seconds: float, trace_on: int,
                events: Optional[List[list]] = None,
@@ -383,7 +418,7 @@ class Bench:
         request the window starts from."""
         node, cell = self.node, self.cell
         mix = Mix(cell.traffic, cell.config, seed, self.parts)
-        sched = mix.window(seconds, first)
+        sched = None if mix.closed else mix.window(seconds, first)
         profile = bool(trace_on) and events is None
         stats1 = node.stats()
         t0 = time.monotonic()
@@ -399,21 +434,28 @@ class Bench:
         stalls = Stalls()
         calls: list = []
         try:
-            calls = loadgen.open_loop(node.port, mix, sched)
+            calls = (loadgen.closed_loop(node.port, mix, seconds, first)
+                     if mix.closed
+                     else loadgen.open_loop(node.port, mix, sched))
         finally:
             span_s = time.monotonic() - t0
             stalls.stop(calls)
             if profile:
                 jax.profiler.stop_trace()
         stats2 = node.stats()
-        done = answered(calls)
+        if not calls:
+            raise RuntimeError("the window sent no call")
+        n_answered, done = answered(calls)
         lat = np.asarray([(s.done - s.due) * 1e3 for s in calls])
-        head = lat[sched.due[:len(lat)] < 0.8 * seconds]
-        log(f"window: {len(calls)} calls, {len(done)} answered; ms from due "
-            f"to answer: p50 {np.percentile(lat, 50):.2f}, p95 "
-            f"{np.percentile(lat, 95):.2f}, p50 of the first four fifths "
-            f"{np.percentile(head, 50):.2f}; HBM regions now "
-            f"{readers.engine_regions(stats2)}")
+        head = lat[np.asarray([s.due for s in calls]) < 0.8 * seconds]
+        peaks = memory_peaks()
+        log(f"window: {len(calls)} calls, {n_answered} answered "
+            f"({len(done)} searches); ms from due to answer: p50 "
+            f"{np.percentile(lat, 50):.2f}, p95 {np.percentile(lat, 95):.2f}, "
+            f"p50 of the first four fifths {np.percentile(head, 50):.2f}; "
+            f"last answer at {max(s.done for s in calls):.2f} s; HBM regions "
+            f"now {readers.engine_regions(stats2)}; peak bytes per device "
+            f"{peaks}")
         w = readers.Window(
             config=cell.config, traffic=cell.traffic, seconds=float(seconds),
             setup_s=self.setup_s,
@@ -421,9 +463,10 @@ class Bench:
             late_ms=np.asarray([(s.sent - s.due) * 1e3 for s in calls]),
             queries_done=len(done),
             stats_before=stats1, stats_after=stats2,
-            memory_peak_bytes=memory_peak_bytes(),
+            memory_peak_bytes=max(peaks, default=0),
             device_kind=self.dev["kind"])
-        w.notes.update(attempted=len(calls), answered=done, seed=seed,
+        w.notes.update(attempted=len(calls), calls_answered=n_answered,
+                       answered=done, seed=seed, memory_peaks=peaks,
                        done_s=np.asarray([s.done for s in calls]))
         if trace_on:
             w.events = events if events is not None else trace.load_xplane(
@@ -444,10 +487,11 @@ class Bench:
         """(result line or None, exit code). The metrics are built by
         walking the manifest's list for the cell and trace mode."""
         attempted = w.notes["attempted"]
-        device = dict(self.dev, memory_peak_bytes=w.memory_peak_bytes)
+        device = dict(self.dev, memory_peak_bytes=w.memory_peak_bytes,
+                      memory_peak_bytes_per_device=w.notes["memory_peaks"])
         line = {"correct": False, "attempted": attempted,
-                "failed": attempted - w.queries_done, "metrics": {},
-                "device": device}
+                "failed": attempted - w.notes["calls_answered"],
+                "metrics": {}, "device": device}
         if trace_on:
             device["busy_s"] = w.notes["busy_s"]
             device["window_s"] = w.notes["window_s"]
@@ -475,9 +519,8 @@ class Bench:
         pairs = sample_pairs(self.cell, w.notes["answered"], w.notes["seed"],
                              self.parts)
         checked = compare.verdict(
-            pairs, self.cell.config["limits"], self.stats0, w.stats_before,
-            w.stats_after, self.cell.config["device_counter"],
-            top_k(self.cell))
+            pairs, self.cell.config, self.stats0, w.stats_before,
+            w.stats_after, top_k(self.cell))
         log(f"reference and comparison took {time.monotonic() - t_ref:.1f} s")
         line["correct"] = compare.is_correct(checked)
         line["checked"] = checked        # last: each number beside its limit

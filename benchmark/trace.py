@@ -113,17 +113,19 @@ def _op_events(events: Sequence[Event], plane: str) -> List[Event]:
     return [e for e in events if e[0] == plane and e[4] > 0]
 
 
+def busy_by_plane(events: Sequence[Event]) -> Dict[str, float]:
+    """Seconds in which an operation ran on each device: the union of
+    that plane's op intervals."""
+    return {p: _span_ns(_union([(e[3], e[3] + e[4])
+                                for e in _op_events(events, p)])) / 1e9
+            for p in device_planes(events)}
+
+
 def busy_seconds(events: Sequence[Event]) -> float:
-    """Seconds in which an operation ran on the device: the union of the
-    op intervals, averaged over the device planes. 0.0 = no device event."""
-    planes = device_planes(events)
-    if not planes:
-        return 0.0
-    total = 0.0
-    for p in planes:
-        total += _span_ns(_union([(e[3], e[3] + e[4])
-                                  for e in _op_events(events, p)]))
-    return total / len(planes) / 1e9
+    """`busy_by_plane`, averaged over the device planes. 0.0 = no device
+    event."""
+    busy = busy_by_plane(events)
+    return sum(busy.values()) / len(busy) if busy else 0.0
 
 
 def own_name(name: str) -> str:
@@ -145,6 +147,22 @@ def kernel_seconds(events: Sequence[Event], match: str) -> Tuple[float, int]:
         n += len(hit)
         total += _span_ns(_union([(e[3], e[3] + e[4]) for e in hit]))
     return (total / len(planes) / 1e9 if planes else 0.0), n
+
+
+def module_seconds(events: Sequence[Event], match: str) -> Tuple[float, int]:
+    """(seconds, runs) of the device programs (`XLA Modules` line: one
+    event a run, named `jit_<function>(<fingerprint>)`) whose name holds
+    `match`, both averaged over the device planes that ran it."""
+    by_plane: Dict[str, List[float]] = {}
+    for e in events:
+        if e[0].startswith(DEVICE_PLANE) and e[1] in MODULE_LINES \
+                and match in e[2]:
+            by_plane.setdefault(e[0], []).append(e[4])
+    n = len(by_plane)
+    if not n:
+        return 0.0, 0
+    return (sum(sum(d) for d in by_plane.values()) / n / 1e9,
+            round(sum(len(d) for d in by_plane.values()) / n))
 
 
 def count_host_events(events: Sequence[Event], match: str) -> int:

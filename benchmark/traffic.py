@@ -3,9 +3,13 @@
 A mix is a JSON file of parameters (benchmark/traffic/<name>.json):
 
     loop        "open": arrivals on a schedule, one POST /{index}/_search
-                per arrival (the only loop the generator drives)
-    rate_per_s  requests due per second (fixed, never searched)
-    connections worker threads, one HTTP connection each
+                per arrival.
+                "closed": `clients` callers, each posting its next
+                `_msearch` of `request.batch` searches when the last one
+                is answered (an offline evaluation job); no rate
+    rate_per_s  open: requests due per second (fixed, never searched)
+    connections open: worker threads, one HTTP connection each
+    clients     closed: callers, one HTTP connection each
     plan_seed   the mix's own seed (see below)
     request     what one search asks for:
         kind "match": `terms_cycle` (query lengths, cycled), ranks drawn
@@ -15,7 +19,12 @@ A mix is a JSON file of parameters (benchmark/traffic/<name>.json):
              `num_candidates`; `filter_cycle` (cycled): 0 = no filter,
              n = a filter on the n adjacent tag values that hold the
              source vector's own (`term` for 1, `terms` for more)
+        batch  closed: searches in one `_msearch` body
     warmup      what set-up sends before the window (run.warm_up)
+
+The configuration adds what every search path of its index carries:
+`index.search_params` (a multi-shard index reaches the device only with
+`search_type=dfs_query_then_fetch`), on `_search` and `_msearch` alike.
 
 The WORK is the same for every `--seed`. A mix has POOL canonical
 requests, numbered; query lengths, term ranks and filter widths of
@@ -26,6 +35,9 @@ quantiles of the exponential law at the mix's rate, in another order of
 the same seed. The seed also draws the corpus (so the same rank is
 another term's postings, the same number another vector). Two seeds
 therefore differ in order and in data, never in how much was asked.
+Call i of a closed span is canonical requests first + i * batch ..
+first + (i + 1) * batch - 1 in that order, for every seed: which calls a
+window holds depends only on how many it completes.
 
 Warm-up and lead-in take their requests from the upper half of the pool
 (WINDOW .. POOL - 1), a window from the lower (0 .. WINDOW - 1): what
@@ -36,7 +48,8 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass
-from typing import List, Optional
+from typing import List, Optional, Sequence
+from urllib.parse import urlencode
 
 import numpy as np
 
@@ -96,6 +109,10 @@ class Mix:
         self.t = traffic
         self.req = traffic["request"]
         self.index = config["index"]["name"]
+        self.closed = traffic.get("loop", "open") == "closed"
+        self.batch = int(self.req.get("batch", 1))
+        params = config["index"].get("search_params")
+        self.query = "?" + urlencode(params) if params else ""
         self.rng = rng_for(seed, STREAM_TRAFFIC)
         self._warm_next = 0
         kind = self.req["kind"]
@@ -166,7 +183,25 @@ class Mix:
     def call(self, j: int):
         """(path, bytes, Request) of canonical request j on the wire."""
         r = self.request(j)
-        return f"/{self.index}/_search", json.dumps(r.body).encode(), r
+        return (f"/{self.index}/_search{self.query}",
+                json.dumps(r.body).encode(), r)
+
+    def msearch(self, js: Sequence[int]):
+        """(path, ndjson bytes, [Request]) of one `_msearch` of the
+        canonical requests `js`."""
+        reqs = [self.request(int(j)) for j in js]
+        head = json.dumps({"index": self.index})
+        nd = "".join(f"{head}\n{json.dumps(r.body)}\n" for r in reqs)
+        return f"/_msearch{self.query}", nd.encode(), reqs
+
+    def closed_call(self, i: int, first: int = 0) -> np.ndarray:
+        """The canonical requests of call i of a closed span."""
+        js = first + i * self.batch + np.arange(self.batch)
+        if js[-1] >= WINDOW:
+            raise ValueError(
+                f"call {i} of {self.batch} from request {first} leaves the "
+                f"{WINDOW} requests that windows may send")
+        return js
 
     # ---- which of them a span sends, and when -----------------------------
 

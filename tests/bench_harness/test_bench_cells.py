@@ -24,12 +24,13 @@ from benchmark.traffic import WINDOW, Mix
 import bench_tiny
 
 SEEDS = (1556403449, 2147483647, 1073741827, 88172645)
-CELLS = tuple(Manifest(ROOT).cell_names())
+CELLS = bench_tiny.CELLS      # the accepted cells and the held one
 RECORDED = {
     c: os.path.join(ROOT, "benchmark", "testdata", f"trace_{c}.json")
     for c in CELLS}
-CHIP = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
-SENT = []          # the canonical request of every call a Mix put on the wire
+CHIP = {"platform": "tpu", "kind": "TPU v5 lite"}
+OPEN = tuple(Manifest(ROOT).cell_names())
+SENT = []          # every canonical request a Mix put on the wire
 
 
 @pytest.fixture(scope="module")
@@ -43,16 +44,17 @@ def benches(manifest, tmp_path_factory):
     mp = pytest.MonkeyPatch()
     bench_tiny.steer_engines(mp, str(tmp_path_factory.mktemp("jax_cache")))
     made = {}
-    call = Mix.call
-    mp.setattr(Mix, "call",
-               lambda self, j: (SENT.append(int(j)), call(self, j))[1])
+    request = Mix.request      # under `call` and `msearch` alike
+    mp.setattr(Mix, "request",
+               lambda self, j: (SENT.append(int(j)), request(self, j))[1])
 
     def get(cell):
         if cell not in made:
             del SENT[:]
             b = run.Bench(manifest, cell, require_chip=False,
                           out_dir=str(tmp_path_factory.mktemp("out")))
-            b.dev = CHIP       # the device the recorded trace was taken on
+            # the device the recorded trace was taken on
+            b.dev = dict(CHIP, count=b.cell.chips)
             b.setup(SEEDS[0])
             b.sent_in_setup = list(SENT)
             made[cell] = b
@@ -164,11 +166,12 @@ def test_set_up_sends_no_request_that_a_window_sends(benches, cell):
     assert bench.sent_in_setup and min(bench.sent_in_setup) >= WINDOW
     del SENT[:]
     w = bench.window(SEEDS[2], 1.5, 0)
-    assert len(SENT) == w.notes["attempted"] > 0
+    batch = bench.cell.traffic["request"].get("batch", 1)
+    assert len(SENT) == batch * w.notes["attempted"] > 0
     assert max(SENT) < WINDOW and len(set(SENT)) == len(SENT)
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", OPEN)
 def test_every_seed_sends_the_same_work_in_another_order(benches, cell):
     bench = benches(cell)
     c = bench.cell

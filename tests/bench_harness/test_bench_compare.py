@@ -17,7 +17,8 @@ from benchmark.traffic import Mix
 import bench_tiny
 
 CELLS = {"msmarco-passage-bm25": "msmarco-bm25.search-open",
-         "msmarco-passage-knn": "msmarco-knn.search-open"}
+         "msmarco-passage-knn": "msmarco-knn.search-open",
+         "msmarco-passage-bm25-4shard": "msmarco-bm25-4shard.msearch-closed"}
 
 
 @pytest.fixture(scope="module")
@@ -25,13 +26,16 @@ def manifest(tmp_path_factory):
     return bench_tiny.tiny_manifest(str(tmp_path_factory.mktemp("tiny")))
 
 
-def _stats(moved=0, host=0):
+def _stats(moved=0, host=0, merge_device=None, merge_host=0):
     s = {}
     for path in compare.ZERO_COUNTERS:
         sec, key = path.split(".")
         s.setdefault(sec, {})[key] = 0
     s["tpu_health"]["fastpath_reject_error"] = host
     s["tpu_turbo"]["fused_dispatches"] = moved
+    s["tpu_turbo"]["merge_device"] = moved if merge_device is None \
+        else merge_device
+    s["tpu_turbo"]["merge_host"] = merge_host
     s["tpu_knn"]["knn_int8_dispatches"] = moved
     return s
 
@@ -51,11 +55,10 @@ def _served_and_reference(manifest, config, served_precision, n=32):
     cell = manifest.cell(CELLS[config])
     cfg = cell.config
     seed = 1556403449
+    parts = datagen.make_parts(cfg, seed)
     if cfg["kind"] == "text":
-        parts = datagen.make_text(cfg["corpus"], seed, 3)
         mk = lambda p: BM25Reference(parts, 1.2, 0.75, precision=p)  # noqa: E731
     else:
-        parts = datagen.make_vectors(cfg["corpus"], seed, 3)
         mk = lambda p: KnnReference(parts, precision=p)  # noqa: E731
     mix = Mix(cell.traffic, cfg, seed, parts)
     reqs = [mix.request(i) for i in range(n)]
@@ -73,9 +76,8 @@ def _served_and_reference(manifest, config, served_precision, n=32):
 @pytest.mark.parametrize("config", sorted(CELLS))
 def test_reference_against_itself_is_correct(manifest, config):
     cell, pairs, k = _served_and_reference(manifest, config, None)
-    checked = compare.verdict(pairs, cell.config["limits"], _stats(),
-                              _stats(), _stats(moved=5),
-                              cell.config["device_counter"], k)
+    checked = compare.verdict(pairs, cell.config, _stats(), _stats(),
+                              _stats(moved=5), k)
     assert compare.is_correct(checked), compare.lines(checked)
     # the float32 the wire carries is all that separates the two sides
     assert checked["score_err"]["value"] < 2e-7
@@ -86,9 +88,8 @@ def test_lower_precision_served_side_is_not_correct(manifest, config):
     cell, pairs, k = _served_and_reference(
         manifest, config, manifest.cell(CELLS[config]).config[
             "precision"]["control"])
-    checked = compare.verdict(pairs, cell.config["limits"], _stats(),
-                              _stats(), _stats(moved=5),
-                              cell.config["device_counter"], k)
+    checked = compare.verdict(pairs, cell.config, _stats(), _stats(),
+                              _stats(moved=5), k)
     assert not compare.is_correct(checked)
     # by a wide margin, not by luck: the score gap alone is 10x its limit
     assert checked["score_err"]["value"] > 10 * checked["score_err"]["limit"]
@@ -97,29 +98,60 @@ def test_lower_precision_served_side_is_not_correct(manifest, config):
 def test_host_tier_answer_or_idle_device_is_not_correct(manifest):
     cell, pairs, k = _served_and_reference(manifest, "msmarco-passage-bm25",
                                            None)
-    lim, dc = cell.config["limits"], cell.config["device_counter"]
-    host = compare.verdict(pairs, lim, _stats(), _stats(),
-                           _stats(moved=5, host=1), dc, k)
+    cfg = cell.config
+    host = compare.verdict(pairs, cfg, _stats(), _stats(),
+                           _stats(moved=5, host=1), k)
     assert not host["host_tier_answers"]["ok"]
-    idle = compare.verdict(pairs, lim, _stats(), _stats(moved=5),
-                           _stats(moved=5), dc, k)
+    idle = compare.verdict(pairs, cfg, _stats(), _stats(moved=5),
+                           _stats(moved=5), k)
     assert not idle["device_dispatches"]["ok"]
-    few = compare.verdict(pairs[:3], lim, _stats(), _stats(),
-                          _stats(moved=5), dc, k)
+    few = compare.verdict(pairs[:3], cfg, _stats(), _stats(),
+                          _stats(moved=5), k)
     assert not few["compared"]["ok"]
     assert not compare.is_correct(host) and not compare.is_correct(idle)
+
+
+def test_a_host_merge_or_an_unmoved_device_merge_is_not_correct(manifest):
+    """The sharded deployment names more than one thing that must have
+    run on the device (`must_rise`) and one that may not have run on the
+    host (`must_stay`): the sweep without the device merge, or the host's
+    merge in its place, is a host tier like any other."""
+    cell, pairs, k = _served_and_reference(
+        manifest, "msmarco-passage-bm25-4shard", None)
+    cfg = cell.config
+    assert "tpu_turbo.merge_device" in cfg["must_rise"]
+    assert cfg["must_stay"] == ["tpu_turbo.merge_host"]
+    sound = compare.verdict(pairs, cfg, _stats(), _stats(moved=2),
+                            _stats(moved=7), k)
+    assert compare.is_correct(sound), compare.lines(sound)
+    assert sound["device_dispatches"]["value"] == 5
+    on_host = compare.verdict(pairs, cfg, _stats(), _stats(),
+                              _stats(moved=5, merge_host=5), k)
+    assert on_host["host_tier_answers"]["value"] == 5
+    assert not compare.is_correct(on_host)
+    no_merge = compare.verdict(pairs, cfg, _stats(), _stats(),
+                               _stats(moved=5, merge_device=0), k)
+    assert no_merge["device_dispatches"]["value"] == 0
+    assert not compare.is_correct(no_merge)
+    # the one-chip configuration names neither: its verdict is as before
+    one, pairs1, _ = _served_and_reference(manifest, "msmarco-passage-bm25",
+                                           None)
+    assert "must_rise" not in one.config and "must_stay" not in one.config
+    same = compare.verdict(pairs1, one.config, _stats(), _stats(),
+                           _stats(moved=5, merge_device=0, merge_host=5), k)
+    assert compare.is_correct(same)
 
 
 def test_an_altered_answer_is_caught(manifest):
     cell, pairs, k = _served_and_reference(manifest, "msmarco-passage-bm25",
                                            None)
-    lim, dc = cell.config["limits"], cell.config["device_counter"]
 
     def verdict_with(change):
         resp = json.loads(json.dumps(pairs[0][0]))
         change(resp["hits"])
-        return compare.verdict([(resp, pairs[0][1])] + pairs[1:], lim,
-                               _stats(), _stats(), _stats(moved=1), dc, k)
+        return compare.verdict([(resp, pairs[0][1])] + pairs[1:],
+                               cell.config, _stats(), _stats(),
+                               _stats(moved=1), k)
 
     def swap_id(h):       # a document that is not among the best
         worst = int(np.argmin(pairs[0][1]["scores"]))

@@ -174,3 +174,40 @@ def test_a_cell_defined_only_by_new_files_is_found_by_name(tmp_path):
                        "window_s": 2.0}}
     faults = validate.line_faults(line, m, "later.cell", 1)
     assert "metrics lacks sparse_queries.later" in faults
+    # a later CLOSED cell (the held four-chip cell, as bench_tiny adds it)
+    # gets what has something to read there by the metric files' own
+    # `loops`: the pools' wait and the dispatch's steps, not the lane's
+    # histograms (its batches skip the lane) nor the generator's lateness
+    closed = [x["name"] for x in m.declared(bench_tiny.FOUR, 1)]
+    assert {"pool_wait_ms.search", "dispatch_ms.search", "finish_ms.search",
+            "tail_p95_ms.search", "queries_per_s.search"} <= set(closed)
+    assert not {"sched_wait_ms.search", "batch_queries.search",
+                "lane_idle_ms.search", "gen_late_ms.search"} & set(closed)
+    assert "metrics lacks pool_wait_ms.search" in validate.line_faults(
+        line, m, bench_tiny.FOUR, 1)
+    assert "metrics lacks sched_wait_ms.search" not in validate.line_faults(
+        line, m, bench_tiny.FOUR, 1)
+
+
+def test_a_metric_files_loops_and_the_manifests_lists_agree():
+    """The driver holds a cell to the manifest (a metric without a
+    `workloads` list in every cell that reports what it moves), the
+    harness leaves out what a metric's file says has nothing to read
+    under the cell's loop: for every cell the manifest holds the two
+    give the same set. (The PR that lands a closed cell gives the
+    `loops: ["open"]` metrics their lists, or the driver refuses it.)"""
+    for cell in M.cell_names():
+        loop = M.cell(cell).traffic["loop"]
+        reported = {x["name"] for x in M.end_to_end(cell)}
+        by_list = [x["name"] for x in M.doc["per_layer"]
+                   if (cell in x["workloads"] if "workloads" in x
+                       else x["moves"] in reported)]
+        assert [x["name"] for x in M.per_layer(cell)] == by_list
+        for name in by_list:
+            assert loop in M.metric_spec(name).get("loops", [loop]), name
+    narrowed = sorted(x["name"] for x in M.doc["per_layer"]
+                      if "loops" in M.metric_spec(x["name"]))
+    assert narrowed == ["batch_queries.search", "gen_late_ms.search",
+                        "lane_idle_ms.search", "sched_wait_ms.search"]
+    # the search pool's queue is every loop's: /_msearch passes it too
+    assert "loops" not in M.metric_spec("pool_wait_ms.search")

@@ -8,10 +8,12 @@ top-level steps add up to `dispatch_ms.search`), and a window under a real
 children inside parents, a lane is never idle and dispatching at once,
 and `trace.breakdown` names them in `idle_gaps`.
 
-The thirteen `per_layer` entries are not in BENCHMARK.json yet
-(benchmark/per_layer_pending.json says why); this file appends them to
-its own tiny manifest, so the day they move it reads them from where they
-are.
+The thirteen `per_layer` entries waited in a file of their own until PR
+29 (a traced run of a parent without the paths would have died); they are
+in BENCHMARK.json now, as they stood. The held four-chip cell
+(bench_tiny.py) sends `_msearch` batches that skip the scheduler's lane:
+the lane's metric files say `"loops": ["open"]`, so it declares the
+dispatch's steps and none of the lane's.
 """
 
 import json
@@ -26,9 +28,9 @@ from benchmark.manifest import ROOT, Manifest
 import bench_tiny
 
 SEED = 1556403449
-CELLS = tuple(Manifest(ROOT).cell_names())
-BM25, KNN = CELLS[0], CELLS[1]
-CHIP = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+CELLS = bench_tiny.CELLS      # the accepted cells and the held one
+BM25, KNN, FOUR = CELLS
+CHIP = {"platform": "tpu", "kind": "TPU v5 lite"}
 TOP = ("prep_ms.search", "launch_ms.search", "device_wait_ms.search",
        "finish_ms.search")
 NEW = {
@@ -40,40 +42,26 @@ NEW = {
                 "dense_rerun_ms.search", "lane_idle_ms.search",
                 "jit_builds.search", "jit_build_ms.search",
                 "gc_old_ms.search"),
+    FOUR: TOP + ("rescore_ms.search", "slice_build_ms.search",
+                 "sparse_gather_ms.search", "jit_builds.search",
+                 "jit_build_ms.search", "gc_old_ms.search"),
 }
 ALL_NEW = sorted(set(NEW[BM25]) | set(NEW[KNN]))
-PENDING = os.path.join(ROOT, "benchmark", "per_layer_pending.json")
 TOP_SPANS = ("es.dispatch.prep", "es.dispatch.launch",
              "es.dispatch.device_wait", "es.dispatch.finish")
 
 
-def pending_entries() -> list:
-    if not os.path.exists(PENDING):
-        return []
-    with open(PENDING) as f:
-        return json.load(f)["per_layer"]
-
-
 @pytest.fixture(scope="module")
 def manifest(tmp_path_factory):
-    """The tiny manifest, with the pending entries appended to
-    `per_layer` exactly as a later PR will append them."""
     root = bench_tiny.tiny_root(str(tmp_path_factory.mktemp("tiny")))
-    path = os.path.join(root, "BENCHMARK.json")
-    with open(path) as f:
-        doc = json.load(f)
-    have = {m["name"] for m in doc["per_layer"]}
-    doc["per_layer"] += [e for e in pending_entries()
-                         if e["name"] not in have]
-    with open(path, "w") as f:
-        json.dump(doc, f)
     # a rate the CPU backend keeps up with (its kernels are interpreted):
     # the lane has to come to rest between dispatches to be seen idle
     tdir = os.path.join(root, "benchmark", "traffic")
     for name in os.listdir(tdir):
         with open(os.path.join(tdir, name)) as f:
             t = json.load(f)
-        t["rate_per_s"] = 4
+        if t["loop"] == "open":
+            t["rate_per_s"] = 4
         with open(os.path.join(tdir, name), "w") as f:
             json.dump(t, f)
     return Manifest(root)
@@ -89,7 +77,8 @@ def benches(manifest, tmp_path_factory):
         if cell not in made:
             b = run.Bench(manifest, cell, require_chip=False,
                           out_dir=str(tmp_path_factory.mktemp("out")))
-            b.dev = CHIP       # the device the recorded trace was taken on
+            # the device the recorded trace was taken on
+            b.dev = dict(CHIP, count=b.cell.chips)
             b.setup(SEED)
             made[cell] = b
         return made[cell]
@@ -148,7 +137,7 @@ def test_a_traced_line_holds_them_and_the_steps_add_up(benches, manifest,
     assert abs(steps - dispatch) <= 0.1 * dispatch, (steps, dispatch, got)
     # children sum under their parents
     assert got["rescore_ms.search"] <= got["finish_ms.search"]
-    if cell == BM25:
+    if cell in (BM25, FOUR):
         assert got["slice_build_ms.search"] <= got["prep_ms.search"]
         assert got["sparse_gather_ms.search"] <= got["finish_ms.search"]
     else:
@@ -158,7 +147,9 @@ def test_a_traced_line_holds_them_and_the_steps_add_up(benches, manifest,
     # the lane is never parked for more than the window (one lane a cell;
     # how long it IS parked depends on this machine's load: the profiled
     # test below makes it rest)
-    assert 0 <= got["lane_idle_ms.search"] <= 1e3 * w.notes["window_s"] * 1.05
+    if cell != FOUR:
+        assert 0 <= got["lane_idle_ms.search"] <= \
+            1e3 * w.notes["window_s"] * 1.05
     assert got["jit_builds.search"] >= 0 and got["gc_old_ms.search"] >= 0
 
 
@@ -248,10 +239,12 @@ def test_a_profiled_span_of_traffic_holds_the_programs_spans(benches, cell):
     assert "es.sched.idle" in [name for name, _s in gaps], gaps
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", (BM25, KNN))
 def test_one_batch_of_one_costs_at_most_25_spans(benches, cell):
     """The hot path is guarded by COUNT, not by a timing: one traced
-    request alone on the node is one batch-1 dispatch."""
+    request alone on the node is one batch-1 dispatch. (The cells that
+    send single requests: over twelve partitions the same dispatch holds
+    43 spans, a few a partition, and the four-chip cell sends batches.)"""
     from benchmark.traffic import Mix
     from elasticsearch_tpu.common import tracing
 

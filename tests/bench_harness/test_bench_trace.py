@@ -10,11 +10,14 @@ import pytest
 from benchmark import costs, readers, trace
 from benchmark.manifest import ROOT, Manifest
 
+import bench_tiny
+
 M = Manifest(ROOT)
 DEV, HOST = "/device:TPU:0", "/host:CPU"
 TESTDATA = os.path.join(ROOT, "benchmark", "testdata")
 RECORDED = {
-    "sweep_rowmax": ["trace_msmarco-bm25.search-open.json"],
+    "sweep_rowmax": ["trace_msmarco-bm25.search-open.json",
+                     "trace_msmarco-bm25-4shard.msearch-closed.json"],
     "knn_int8_window_topc": ["trace_msmarco-knn.search-open.json"],
 }
 
@@ -82,7 +85,8 @@ def test_seen_names_lists_what_a_refused_run_writes_down():
 def test_recorded_chip_traces_hold_their_kernel(kernel):
     for name in RECORDED[kernel]:
         events = trace.load_events(os.path.join(TESTDATA, name))
-        assert trace.device_planes(events) == [DEV]
+        assert trace.device_planes(events) == [
+            f"/device:TPU:{d}" for d in range(4 if "4shard" in name else 1)]
         busy = trace.busy_seconds(events)
         seconds, n = trace.kernel_seconds(events, kernel)
         assert n > 0 and 0 < seconds <= busy, name
@@ -95,16 +99,24 @@ def test_recorded_chip_traces_hold_their_kernel(kernel):
 
 def _window(**over):
     hist = {"count": 0, "buckets": 0, "mean": 0.0}
+    steps = ("prep", "launch", "device_wait", "finish", "rescore",
+             "slice_build", "sparse_gather", "mask", "dense_rerun")
     stats = {
         "tpu_search_latency": {
             "device": dict(hist), "demux": dict(hist), "fetch": dict(hist),
             "coalesce_batch_size": dict(hist),
             "queue_wait.search": dict(hist),
-            "sched_tier_wait.interactive": dict(hist)},
+            "sched_tier_wait.interactive": dict(hist),
+            **{"dispatch." + s: dict(hist) for s in steps}},
         "tpu_knn": {"knn_uncertified": 0, "knn_queries": 0,
                     "knn_int8_dispatches": 0},
-        "tpu_turbo": {"fused_dispatches": 0},
-        "tpu_compile": {"misses": 4, "retraces": 1},
+        "tpu_turbo": {"fused_dispatches": 0, "merge_device": 0,
+                      "merge_host": 0},
+        "tpu_scheduler": {"lane_idle_ms": 0.0},
+        "tpu_compile": {"misses": 4, "retraces": 1, "jit_builds": 0,
+                        "jit_build_ms": 0.0},
+        "jvm": {"gc": {"collectors": {"old": {
+            "collection_time_in_millis": 0}}}},
         "tpu_hbm": {"engines": {}},
     }
     kw = dict(config={"index": {"segments": 3}}, traffic={}, seconds=1.0,
@@ -114,23 +126,28 @@ def _window(**over):
               device_kind="TPU v5 lite", events=[])
     kw.update(over)
     w = readers.Window(**kw)
-    w.notes.update(busy_s=0.25, window_s=1.0)
+    w.notes.update(busy_s=0.25, window_s=1.0, done_s=np.asarray([0.5]))
     return w
 
 
 def test_every_counter_and_histogram_reader_is_total():
     """A window with zero events of every source: each reader still
-    returns a number (0.0 for an empty histogram or ratio)."""
+    returns a number (0.0 for an empty histogram or ratio). The readers
+    of the device trace are the exception: see the next test."""
     w = _window()
-    for m in M.doc["end_to_end"] + M.doc["per_layer"]:
-        spec = M.metric_spec(m["name"])
-        if spec["kind"] == "kernel_roofline":
+    names = [m["name"] for m in M.doc["end_to_end"] + M.doc["per_layer"]]
+    for name in names + sorted(bench_tiny.FOUR_METRICS):   # the held cell's
+        spec = M.metric_spec(name)
+        if spec["kind"] in ("kernel_roofline", "busy_skew",
+                            "module_mean_ms"):
+            with pytest.raises(readers.NothingToRead):
+                readers.read(spec, w)
             continue
         value = readers.read(spec, w)
-        assert isinstance(value, float) and np.isfinite(value), m["name"]
-        if spec["kind"] in ("histogram_mean", "counter_ratio",
+        assert isinstance(value, float) and np.isfinite(value), name
+        if spec["kind"] in ("histogram_mean", "counter_ratio", "client_rate",
                             "counter_delta", "trace_event_count"):
-            assert value == 0.0, m["name"]
+            assert value == 0.0, name
     assert readers.read(M.metric_spec("device_idle_pct.search"), w) == 75.0
 
 
@@ -159,11 +176,15 @@ def test_roofline_arithmetic_on_known_shapes():
     least, bound = costs.least_seconds("sweep_rowmax", regions, 8,
                                        "TPU v5 lite")
     assert bound == "memory" and least == pytest.approx(2e-3)
-    # at 256 queries a pass the same sweep is bound by the int8 peak
+    # at 256 queries a pass the same sweep is bound by the int8 peak: each
+    # int8 cell meets both int8 halves of each query weight (the kernel's
+    # four s8 x s8 products: hi.hi, hi.lo, lo.hi, lo.lo)
     least, bound = costs.least_seconds("sweep_rowmax", regions, 256,
                                        "TPU v5 lite")
     assert bound == "compute"
-    assert least == pytest.approx(2 * 256 * 1.638e9 / 393e12)
+    assert least == pytest.approx(2 * 2 * 256 * 1.638e9 / 393e12)
+    assert costs.least_seconds("sweep_rowmax", regions, 64,
+                               "TPU v5 lite")[1] == "memory"
     with pytest.raises(KeyError, match="no peaks"):
         costs.least_seconds("sweep_rowmax", regions, 8, "cpu")
 
