@@ -15,12 +15,15 @@ Per query the terms split three ways:
   candidate ROWS, globally re-ranked on device (_pick_rows) so only
   ~n_rows row ids per query ever cross the host link.
 * **cold** (df < COLD_DF): at most a few thousand postings. The host
-  computes EXACT totals for every cold-touched doc — it looks up the
-  other query terms' impacts by binary search in the posting arrays — so
-  any doc with a cold contribution is scored exactly with no device help.
-* the final top-k merges both sides: the host rescores EVERY doc in the
-  collected rows in exact f32 (term-order identical to the reference
-  scorer) and checks a per-query CERTIFICATE that bounds what the
+  computes EXACT totals for every cold-touched doc that a bound does not
+  rule out — the other query terms' impacts come from their columns'
+  host index (_column_impacts), no search — so any doc with a cold
+  contribution is scored exactly with no device help.
+* the final top-k merges both sides, once a (partition, dispatch chunk)
+  (_finish_chunk): the host rescores EVERY doc in the collected rows in
+  exact f32 (term-order identical to the reference scorer; a term's
+  postings inside a 128-doc row are one span of its list, _rescore_rows)
+  and checks a per-query CERTIFICATE that bounds what the
   quantized sweep could have hidden in rows it did NOT collect:
 
       exact_kth >= max(rowmax_{n_rows+1}, max_sw sw_NCANDth) + e_q
@@ -122,6 +125,7 @@ def _pick_rows(rm, rr, *, n_rows: int):
         jnp.maximum(beyond, sw_bound)[:, None],
     ], axis=1)
 _LANE128 = np.arange(128, dtype=np.int64)
+_LOW_BITS = (np.uint64(1) << np.arange(64, dtype=np.uint64)) - np.uint64(1)
 
 
 def _flatten_queries(batches: Sequence[List]):
@@ -141,6 +145,34 @@ def _flatten_queries(batches: Sequence[List]):
                 agg[t] = agg.get(t, 0.0) + b
             flat.append(list(agg.items()))
     return flat, spans
+
+
+def _quantize(ws: Sequence[float]):
+    """One query's column weights as the sweep takes them: ([(hi, lo)] int8
+    steps per weight, qscale, e_q). w ~ qs * hi + qs / 128 * lo; e_q bounds
+    what the quantized sweep can misjudge a doc by (the certificate's
+    margin). The ONE place this arithmetic lives: the disjunctive and the
+    bool dispatch quantize here, and their finishes read e_q from here."""
+    e_q = 1e-7
+    if not ws:
+        return [], 1.0, e_q
+    wmax = max(abs(w) for w in ws)
+    qs = max(wmax / 127.0, 1e-9)         # hi step
+    qs2 = qs / 128.0                     # lo step
+    steps = []
+    for w in ws:
+        wh = max(-127, min(127, round(w / qs)))
+        wl = max(-127, min(127, round((w - qs * wh) / qs2)))
+        steps.append((wh, wl))
+        w_approx = qs * wh + qs2 * wl
+        # a full lo step (not half): the build kernel forces lo >= 1 on
+        # presence-only cells so the conjunctive sweep's presence mask
+        # stays exact (kernels._build_kernel)
+        e_q += (abs(w - w_approx) * K1_PLUS1
+                + abs(w_approx) * COLSCALE2)
+    # f32 rounding of the in-kernel integer combine
+    e_q += 3e-7 * sum(abs(w) for w in ws) * K1_PLUS1
+    return steps, qs2 * COLSCALE2, float(e_q)
 
 
 _BUILD_BUCKETS = (256, 1024, 4096, 16384, 32768)   # last one bounded by
@@ -267,7 +299,7 @@ def node_bitset_stats() -> dict:
 # gather of a dispatch chunk right after the chunk's sweep and BEFORE it
 # waits for the sweep: the gather runs on the device behind the sweep
 # while the host waits and rescores, and finish only collects. There is
-# no blocking device round trip inside _finish_query.
+# no blocking device round trip inside _finish_chunk.
 
 _SPARSE_DOC_LIMIT = 1 << 23          # packed doc-id headroom in an int32
 _SPARSE_RC_BUCKETS = (2, 4, 8, 16, 32, 64, 128, 256)   # dispatch chunk
@@ -291,15 +323,22 @@ def _sparse_widths() -> Tuple[int, ...]:
     return tuple(sorted(ws)) or (1024, 4096, 16384)
 
 
-# node-wide sparse-tier counters, folded into GET /_nodes/stats tpu_turbo
-# by serving.turbo_node_stats next to the bitset block; sparse_bytes is a
-# gauge-like running total of currently resident padded slice bytes
-# (evictions subtract), the rest are cumulative
+# node-wide sparse-tier and finish counters, folded into GET /_nodes/stats
+# tpu_turbo by serving.turbo_node_stats next to the bitset block;
+# sparse_bytes is a gauge-like running total of currently resident padded
+# slice bytes (evictions subtract), the rest are cumulative.
+# finish_bulk_pairs = (partition, query) pairs the chunk-wide finish
+# answered; finish_pair_fallbacks = pairs that left it (a host-only
+# partition, a faulted sweep, a gather not launched or lost, a failed
+# certificate): the two sum to partitions x queries
 _NODE_SPARSE_STATS = {"sparse_slices": 0, "sparse_bytes": 0,
                       "sparse_queries": 0,
                       "sparse_gather_launches": 0,
                       "sparse_gather_overlapped": 0,
-                      "sparse_fallbacks": 0}  # guarded by: _NODE_SPARSE_LOCK
+                      "sparse_fallbacks": 0,
+                      "finish_bulk_pairs": 0,
+                      "finish_pair_fallbacks": 0
+                      }  # guarded by: _NODE_SPARSE_LOCK
 _NODE_SPARSE_LOCK = threading.Lock()
 
 
@@ -332,7 +371,7 @@ class _ColdGather:
     collect then host-scores this pair alone. `early` = launched before
     the dispatch waited for its sweep."""
 
-    __slots__ = ("cold_terms", "early", "spans", "slack", "out")
+    __slots__ = ("cold_terms", "early", "spans", "slack", "out", "host")
 
     def __init__(self, cold_terms, early: bool):
         self.cold_terms = cold_terms
@@ -340,6 +379,28 @@ class _ColdGather:
         self.spans: List[Tuple[int, int, int]] = []
         self.slack = 0.0
         self.out = None
+        self.host = False      # collect host-scored this pair
+
+
+class _ChunkPlan:
+    """One dispatch chunk resolved against one partition, ONCE: what the
+    sweep's weights, the chunk's gathers and its finish all read. Per
+    query: `qterms` (the terms this partition holds, in query order, as
+    (term, boost, info, column slot or -1)), `cold` (those without a
+    column NOW, as (term, boost, info): `_slot_of` does not move between
+    a dispatch's prep and its finish), `e_q` and `col_const` of the
+    colized ones.
+    `wq` / `qscale` are the sweep's inputs. The p* arrays hold every
+    (query, term) pair of the chunk, term POSITION major (`pos_end[j]` =
+    pairs at positions <= j), for the row-span rescore: query, column
+    slot (-1 = none), first posting, posting -> `_host_scores` offset,
+    f32 weight; `cold_pairs` = [(pair, query, first posting, one past
+    the last)] of the pairs without a column. `sparse` = the sparse tier
+    serves this chunk's cold sides (read once)."""
+
+    __slots__ = ("chunk", "qterms", "cold", "e_q", "col_const", "wq",
+                 "qscale", "sparse", "pq", "pslot", "plo", "phb", "pw",
+                 "pos_end", "cold_pairs")
 
 
 class TurboBM25:
@@ -431,6 +492,17 @@ class TurboBM25:
         # so eviction can zero exactly those tiles even for keys (phrases)
         # whose lane arrays are long gone
         self._tile_bases: Dict[str, np.ndarray] = {}
+        # the host's index of the terms that own a column, by slot, in
+        # 64-doc words: _col_bits[slot, w] bit b = doc 64 * w + b has the
+        # term, _col_cnt[slot, w] = the term's postings before doc 64 * w.
+        # A picked row r's postings are the span _col_cnt[2r] ..
+        # _col_cnt[2r + 2] of the term's list (_rescore_rows), and any
+        # doc's posting is found with a popcount, no search
+        # (_column_impacts). Written with the column (_index_column); a
+        # slot nobody owns is never read, so eviction leaves it. 3/32 of
+        # the column cache's bytes, on the host, touched pages only
+        self._col_bits = np.zeros((self.Hp, 2 * self.dp_rows), np.uint64)
+        self._col_cnt = np.zeros((self.Hp, 2 * self.dp_rows + 1), np.int32)
         self.force_cert_fail = False   # test hook: exercise the fallback
         # partition id for fault-site attribution (set by TurboEngine /
         # ShardedTurbo when this engine serves one partition of many)
@@ -467,7 +539,8 @@ class TurboBM25:
                       "sparse_queries": 0, "sparse_slices": 0,
                       "sparse_bytes": 0, "sparse_fallbacks": 0,
                       "sparse_gather_launches": 0,
-                      "sparse_gather_overlapped": 0}
+                      "sparse_gather_overlapped": 0,
+                      "finish_bulk_pairs": 0, "finish_pair_fallbacks": 0}
         # HBM residency ledger: regions mirror hbm_bytes() exactly so the
         # telemetry cross-check can hold ledger == engine to the byte
         self._hbm = hbm_ledger.register_engine(self, "turbo")
@@ -684,10 +757,20 @@ class TurboBM25:
             self._lru[t] = self._tick
             r, n, b, s = self._term_groups(info, slot)
             self._tile_bases[t] = b
+            self._index_column(slot, info)
             rows_l.append(r); n_l.append(n); base_l.append(b); slot_l.append(s)
         self._build_columns(rows_l, n_l, base_l, slot_l,
                             self.lane_docs, self.lane_scores, len(need))
         self._register_hbm_regions()
+
+    def _index_column(self, slot: int, info: _TermInfo) -> None:
+        """`_col_bits` / `_col_cnt` of the term taking `slot`."""
+        lo = int(self.fp.post_start[info.ord])
+        has = np.zeros(self.dp_rows * 128, np.uint8)
+        has[self.fp.post_doc[lo: lo + info.df]] = 1
+        bits = np.packbits(has, bitorder="little").view("<u8")
+        self._col_bits[slot] = bits
+        np.cumsum(np.bitwise_count(bits), out=self._col_cnt[slot, 1:])
 
     def _build_columns(self, rows_l, n_l, base_l, slot_l, lane_docs,
                        lane_scores, n_built: int) -> None:
@@ -849,9 +932,10 @@ class TurboBM25:
         self.stats["phrase_builds"] += len(need)
 
     def _cold_contrib(self, cold_terms):
-        """(docs i64 unique-sorted, contrib f64) — the cold terms' summed
-        contributions at their own postings, read straight off each term's
-        lane scores (no cross-term binary searches)."""
+        """(docs i64 unique-sorted, contrib f64, inv) — the cold terms'
+        summed contributions at their own postings, read straight off each
+        term's lane scores (no cross-term binary searches); inv = where
+        in docs each posting of the terms' lists, laid end to end, lies."""
         fp = self.fp
         arrs, vals = [], []
         for _, b, info in cold_terms:
@@ -866,7 +950,7 @@ class TurboBM25:
         u, inv = np.unique(docs, return_inverse=True)
         acc = np.zeros(len(u), np.float64)
         np.add.at(acc, inv, np.concatenate(vals))
-        return u, acc
+        return u, acc, inv
 
     # ---------------- eager sparse impact slices ----------------
 
@@ -1125,24 +1209,14 @@ class TurboBM25:
             pass
         return h
 
-    def _chunk_gathers(self, chunk) -> Dict[int, _ColdGather]:
+    def _chunk_gathers(self, plan: _ChunkPlan) -> Dict[int, _ColdGather]:
         """`_start_gather` for every query of a dispatch chunk that has a
         cold term here, ahead of its finish and in finish's order:
-        {query index: gather} (empty with the sparse tier off). The cold /
-        colized split is `_finish_query`'s own: `_slot_of` does not move
-        between a dispatch's prep and its finish."""
-        gathers: Dict[int, _ColdGather] = {}
-        if not self._sparse_on():
-            return gathers
-        for qi, terms in enumerate(chunk):
-            cold_terms = []
-            for t, b in terms or ():
-                info = self._term(t)
-                if info is not None and t not in self._slot_of:
-                    cold_terms.append((t, b, info))
-            if cold_terms:
-                gathers[qi] = self._start_gather(cold_terms, True)
-        return gathers
+        {query index: gather} (empty with the sparse tier off)."""
+        if not plan.sparse:
+            return {}
+        return {qi: self._start_gather(cold_terms, True)
+                for qi, cold_terms in enumerate(plan.cold) if cold_terms}
 
     def _discard_gather(self, h: Optional[_ColdGather]) -> None:
         """Drop a launched gather nobody will collect (its chunk is being
@@ -1152,12 +1226,12 @@ class TurboBM25:
             self._sp_inflight -= 1
 
     def _collect_gather(self, h: _ColdGather):
-        """COLLECT: (docs, contrib, slack), docs/contrib mirroring
-        _cold_contrib's unique-doc enumeration. A pair that was not
-        launched, or whose fetch faults, falls back to the exact host
-        enumeration with slack 0 — downstream pruning then evaluates the
-        IDENTICAL expression the host path uses, so containment is
-        bit-identical by construction."""
+        """COLLECT: (docs, contrib, slack, inv), mirroring _cold_contrib's
+        unique-doc enumeration. A pair that was not launched, or whose
+        fetch faults, falls back to the exact host enumeration with slack
+        0 (`h.host`) — downstream pruning then evaluates the IDENTICAL
+        expression the host path uses, so containment is bit-identical by
+        construction."""
         out, h.out = h.out, None
         flat = None
         if out is not None:
@@ -1172,10 +1246,11 @@ class TurboBM25:
             except DeviceFaultError:
                 pass
         if flat is None:
+            h.host = True
             self.stats["sparse_fallbacks"] += 1
             _node_sparse_add("sparse_fallbacks", 1)
-            u, acc = self._cold_contrib(h.cold_terms)
-            return u, acc, 0.0
+            u, acc, inv = self._cold_contrib(h.cold_terms)
+            return u, acc, 0.0, inv
         fp = self.fp
         docs_l, vals_l = [], []
         for c0, df, lo in h.spans:
@@ -1187,8 +1262,9 @@ class TurboBM25:
         # a doc shared by several dispatched slices reads the SAME
         # accumulator cell at every occurrence — first occurrence wins,
         # exactly _cold_contrib's unique-doc enumeration
-        u, fidx = np.unique(docs, return_index=True)
-        return u, vals[fidx], h.slack
+        u, fidx, inv = np.unique(docs, return_index=True,
+                                 return_inverse=True)
+        return u, vals[fidx], h.slack, inv
 
     def sparse_hot_terms(self) -> List[str]:
         """Terms with a resident sparse slice — the warm-handoff payload a
@@ -1255,6 +1331,21 @@ class TurboBM25:
                                          jp & 127]
         return out
 
+    def _column_impacts(self, info: _TermInfo, slot: int,
+                        word: np.ndarray, bit: np.ndarray) -> np.ndarray:
+        """`_impacts_at` for a term that owns a column, without the
+        search, at docs given as (word = doc // 64, bit = doc % 64 as
+        u64): the doc is posting _col_cnt[word] + (set bits of its word
+        below `bit`) of the term's list, where its bit is set."""
+        w = self._col_bits[slot, word]
+        at = self._col_cnt[slot, word] + np.bitwise_count(
+            w & _LOW_BITS[bit]).astype(np.int32)
+        # (a doc past the term's last posting reads that one: zeroed below)
+        out = self._host_scores.reshape(-1)[
+            info.row_start * 128 + np.minimum(at, info.df - 1)]
+        out[((w >> bit) & np.uint64(1)) == 0] = 0.0
+        return out
+
     def _exact_merge(self, qterms, k: int):
         """Full host posting merge (exact, any df) — the fallback when a
         certificate fails. Term-at-a-time f32 accumulation in query
@@ -1275,21 +1366,35 @@ class TurboBM25:
         sel = np.lexsort((docs, -totals))[:k]
         return totals[sel], docs[sel].astype(np.int32)
 
-    def _exact_scores(self, qterms: List[Tuple[str, float, _TermInfo]],
-                      docs: np.ndarray) -> np.ndarray:
+    def _exact_scores(self, qterms, docs: np.ndarray) -> np.ndarray:
         """Exact f32 totals at docs, term-at-a-time in query order — the
-        same accumulation order as the reference CPU scorer."""
+        same accumulation order as the reference CPU scorer. qterms
+        [(term, boost, info)]: every term's list is searched for the docs
+        (`_impacts_at`: the host tiers, the fallbacks). The chunk-wide
+        finish gives a fourth member where it need not search: the slot
+        of the term's column, read through the column's host index
+        (`_column_impacts`), or the term's impacts at docs as it already
+        holds them (`_survivor_terms`). And candidates that come as picked
+        ROWS are row-aligned: given a chunk's plan and its rows [n, R] the
+        totals of every doc in them are read by row span, the whole chunk
+        at once (`_rescore_rows`: the same expression a cell). Every exact
+        score a finish produces leaves through this one function."""
+        if isinstance(qterms, _ChunkPlan):
+            return self._rescore_rows(qterms, docs)
         total = np.zeros(len(docs), np.float32)
-        for _, boost, info in qterms:
+        word = None
+        for _, boost, info, *known in qterms:
             w = np.float32(info.idf * boost)
-            total = total + w * self._impacts_at(info, docs)
+            if not known:
+                impacts = self._impacts_at(info, docs)
+            elif isinstance(known[0], np.ndarray):
+                impacts = known[0]
+            else:
+                if word is None:
+                    word, bit = docs >> 6, (docs & 63).astype(np.uint64)
+                impacts = self._column_impacts(info, known[0], word, bit)
+            total = total + w * impacts
         return total
-
-    def _rescore(self, qterms, docs: np.ndarray) -> np.ndarray:
-        """`_exact_scores` as the `dispatch.rescore` step of a query's
-        finish (the candidate side and the cold side's survivors)."""
-        with tracing.phase("dispatch.rescore", docs=len(docs)):
-            return self._exact_scores(qterms, docs)
 
     # ---------------- search ----------------
 
@@ -1324,25 +1429,21 @@ class TurboBM25:
         try:
             self._sweep_chunks(flat, n_rows, check, pending)
             # pass 2: fetch the tiny row sets; EXACT host rescore of every
-            # doc in the collected rows (33 rows x 128 lanes x a binary
-            # search per query term — ~1ms/query), merged with the cold
-            # side, whose gathers are already on their way to the host
-            for off, n, packed_dev, gathers in pending:
+            # doc in the collected rows, a chunk at a time
+            # (_finish_chunk), merged with the cold side, whose gathers
+            # are already on their way to the host
+            for off, plan, packed_dev, gathers in pending:
                 if check is not None:
                     check()
                 with tracing.phase("dispatch.device_wait"), \
                         faults.device_errors("turbo_sweep", self.part_id):
                     packed = np.asarray(packed_dev)    # [QC, n_rows + 1]
+                n = len(plan.chunk)
                 with tracing.phase("dispatch.finish", queries=n):
-                    rows_all = packed[:, :n_rows].astype(np.int64)
-                    bounds = packed[:, n_rows]
-                    for qi in range(n):
-                        docs = self._collect_docs(rows_all[qi])
-                        s, d = self._finish_query(
-                            flat[off + qi], docs, float(bounds[qi]), k,
-                            gathers.pop(qi, None))
-                        out_s[off + qi, : len(s)] = s
-                        out_d[off + qi, : len(d)] = d
+                    self._finish_chunk(
+                        plan, packed[:n, :n_rows].astype(np.int64),
+                        packed[:n, n_rows], k, gathers,
+                        out_s[off: off + n], out_d[off: off + n])
         finally:
             for *_, gathers in pending:    # a fault or a cancel unwinding
                 for h in gathers.values():
@@ -1352,7 +1453,7 @@ class TurboBM25:
     def _sweep_chunks(self, flat, n_rows: int, check, pending: list) -> None:
         """Pass 1 of `_search_many`: per chunk the sweep and the row pick,
         then every query's cold-side gather behind them, all asynchronous.
-        Appends (offset, n, picked, {query: gather}) to `pending`."""
+        Appends (offset, plan, picked, {query: gather}) to `pending`."""
         off = 0
         while off < len(flat):
             rem = len(flat) - off
@@ -1367,16 +1468,22 @@ class TurboBM25:
             # IS the XLA trace, so its wall time is the compile cost
             first_trace = hbm_ledger.note_dispatch("turbo", take)
             tc0 = time.monotonic()
-            wq, qscale, (rm, rr) = self._sweep(chunk, take)
+            with tracing.phase("dispatch.prep", qc=take):
+                plan = self._plan_chunk(chunk, take)
             with tracing.phase("dispatch.launch", qc=take) as ph:
+                with faults.device_dispatch("turbo_sweep", self.part_id):
+                    rm, rr = sweep_rowmax(
+                        jnp.asarray(plan.qscale), self.cols_hi,
+                        self.cols_lo, jnp.asarray(plan.wq), self.live,
+                        QC=take, nsw=self.nsw)
                 with faults.device_errors("turbo_sweep", self.part_id):
                     picked = _pick_rows(rm, rr, n_rows=n_rows)
                 if first_trace:
                     hbm_ledger.note_compile_done(
                         "turbo", take, time.monotonic() - tc0)
-                gathers = self._chunk_gathers(chunk)
+                gathers = self._chunk_gathers(plan)
                 ph.meta["gathers"] = len(gathers)
-            pending.append((off, len(chunk), picked, gathers))
+            pending.append((off, plan, picked, gathers))
             off += len(chunk)
         self.stats["dispatches"] += len(pending)
 
@@ -1384,184 +1491,290 @@ class TurboBM25:
         return self.search_many([queries], k)[0]
 
     def _collect_docs(self, rw: np.ndarray) -> np.ndarray:
-        """Live doc ids in one query's picked rows ([n_rows] i64, -1 =
-        empty slot) — shared by the solo pass-2 loops and the fused
-        multi-partition path."""
+        """Live doc ids in one bool query's picked rows ([n_rows] i64, -1
+        = empty slot) — shared by the solo bool pass 2 and the fused one."""
         rw = rw[rw >= 0]
         docs = (rw[:, None] * 128 + _LANE128[None, :]).ravel()
         if len(docs):
             docs = docs[self._live_host[docs] > 0]
         return docs
 
-    def _sweep_weights(self, chunk, QC: int):
-        """Quantized disjunctive sweep inputs for one dispatch chunk:
-        (wq [2, QC, Hp+1] i8, qscale [QC, 1] f32). A None entry (a query
-        another partition dispatches but this one does not) leaves an
-        all-zero weight row — the kernel scores query columns
+    def _plan_chunk(self, chunk, QC: int) -> _ChunkPlan:
+        """Resolve one dispatch chunk against this partition (see
+        `_ChunkPlan`): terms, the cold / colized split, the quantized
+        disjunctive sweep inputs (wq [2, QC, Hp+1] i8, qscale [QC, 1]
+        f32) with their e_q, and the (query, term) pair arrays. A None
+        entry (a query another partition dispatches but this one does not)
+        leaves an all-zero weight row — the kernel scores query columns
         independently, so zero rows change nothing for its peers."""
-        wq = np.zeros((2, QC, self.Hp + 1), np.int8)
-        qscale = np.ones((QC, 1), np.float32)
+        plan = _ChunkPlan()
+        plan.chunk = chunk
+        plan.sparse = self._sparse_on()
+        plan.wq = wq = np.zeros((2, QC, self.Hp + 1), np.int8)
+        plan.qscale = qscale = np.ones((QC, 1), np.float32)
+        plan.qterms, plan.cold = [], []
+        plan.e_q, plan.col_const = [], []
+        slot_of, term = self._slot_of, self._term
+        by_pos: List[List[tuple]] = []
         for qi, terms in enumerate(chunk):
-            if terms is None:
-                continue
-            ws = []
-            for t, b in terms:
-                slot = self._slot_of.get(t)
-                if slot is not None:
-                    ws.append((slot, self._term(t).idf * b))
-            if not ws:
-                continue
-            wmax = max(abs(w) for _, w in ws)
-            qs = max(wmax / 127.0, 1e-9)         # hi step
-            qs2 = qs / 128.0                     # lo step
-            qscale[qi, 0] = qs2 * COLSCALE2
-            for slot, w in ws:
-                wh = max(-127, min(127, round(w / qs)))
-                wl = max(-127, min(127, round((w - qs * wh) / qs2)))
+            qterms, cold, slots, ws = [], [], [], []
+            col_const = 0.0
+            for t, b in terms or ():
+                info = term(t)
+                if info is None:
+                    continue
+                # colized = owns a column NOW (a term past cold_df may have
+                # been left cold by capacity degradation)
+                slot = slot_of.get(t, -1)
+                w = info.idf * b
+                if slot < 0:
+                    cold.append((t, b, info))
+                else:
+                    slots.append(slot)
+                    ws.append(w)
+                    col_const += w * info.smax
+                if len(by_pos) == len(qterms):
+                    by_pos.append([])
+                by_pos[len(qterms)].append(
+                    (qi, slot, info.ord, info.row_start, w))
+                qterms.append((t, b, info, slot))
+            steps, qscale[qi, 0], e_q = _quantize(ws)
+            for slot, (wh, wl) in zip(slots, steps):
                 wq[0, qi, slot] = np.int8(wh)
                 wq[1, qi, slot] = np.int8(wl)
-        return wq, qscale
+            plan.qterms.append(qterms)
+            plan.cold.append(cold)
+            plan.e_q.append(e_q)
+            plan.col_const.append(col_const)
+        pairs = [p for at in by_pos for p in at]
+        cols = list(zip(*pairs)) if pairs else [()] * 5
+        plan.pq = np.asarray(cols[0], np.int64)
+        plan.pslot = np.asarray(cols[1], np.int64)
+        ords = np.asarray(cols[2], np.int64)
+        plan.plo = self.fp.post_start[ords].astype(np.int64)
+        plan.phb = np.asarray(cols[3], np.int64) * 128 - plan.plo
+        plan.pw = np.asarray(cols[4], np.float64).astype(np.float32)
+        plan.pos_end = np.cumsum([len(at) for at in by_pos])
+        ci = np.flatnonzero(plan.pslot < 0)
+        plan.cold_pairs = list(zip(
+            ci.tolist(), plan.pq[ci].tolist(), plan.plo[ci].tolist(),
+            self.fp.post_start[ords[ci] + 1].tolist()))
+        return plan
 
-    def _sweep(self, chunk, QC):
-        with tracing.phase("dispatch.prep", qc=QC):
-            wq, qscale = self._sweep_weights(chunk, QC)
-        with tracing.phase("dispatch.launch", qc=QC), \
-                faults.device_dispatch("turbo_sweep", self.part_id):
-            out = sweep_rowmax(jnp.asarray(qscale), self.cols_hi,
-                               self.cols_lo, jnp.asarray(wq), self.live,
-                               QC=QC, nsw=self.nsw)
-        return wq, qscale, out
+    # ---------------- the chunk-wide finish ----------------
 
-    def _finish_query(self, terms, cand_docs, bound, k,
-                      gather: Optional[_ColdGather] = None):
-        """Merge device-collected candidates + the cold side into exact
-        top-k.
+    def _rescore_rows(self, plan: _ChunkPlan, rows_all: np.ndarray):
+        """Exact totals of every doc in the chunk's picked rows
+        (rows_all [n, R] i64, -1 = empty slot), as a flat [n * R * 128]
+        f32 plane: cell (q * R + slot) * 128 + (doc & 127). The 128 docs of
+        a row are consecutive, so a term's postings inside a row are ONE
+        span of its list: `_col_cnt` holds its ends for a term that owns a
+        column, a search of the row's two edges in the list finds them for
+        one that does not. Term POSITION at a time over the whole chunk, each
+        posting adding w * impact to its cell in f32: the expression
+        `_exact_scores` evaluates, in its order (a term a doc lacks adds
+        w * 0.0 there, which changes no total), so the totals are its
+        bits."""
+        n, R = rows_all.shape
+        plane = np.zeros(n * R * 128, np.float32)
+        m = len(plan.pq)
+        if not m:
+            return plane
+        fp = self.fp
+        valid = rows_all >= 0
+        rc = np.where(valid, rows_all, 0)
+        s = np.zeros((m, R), np.int64)
+        e = np.zeros((m, R), np.int64)
+        ci = np.flatnonzero(plan.pslot >= 0)
+        if len(ci):
+            rq = 2 * rc[plan.pq[ci]]
+            sl = plan.pslot[ci][:, None]
+            s[ci] = self._col_cnt[sl, rq]
+            e[ci] = self._col_cnt[sl, rq + 2]
+        # needles of the postings' dtype, or numpy casts the list a call
+        # (_impacts_at)
+        edges = (np.concatenate([rc, rc + 1], axis=1) * 128).astype(
+            fp.post_doc.dtype)
+        has_rows = valid.any(axis=1).tolist()
+        for i, qi, lo, hi in plan.cold_pairs:
+            if has_rows[qi]:
+                se = fp.post_doc[lo:hi].searchsorted(edges[qi])
+                s[i] = se[:R]
+                e[i] = se[R:]
+        # every posting of every span: p = its index in fp.post_doc
+        lens = np.where(valid[plan.pq], e - s, 0).ravel()
+        ends = np.cumsum(lens)
+        total = int(ends[-1])
+        if not total:
+            return plane
+        first = ((plan.plo[:, None] + s).ravel() - (ends - lens))
+        span = np.repeat(np.arange(m * R), lens)
+        p = first[span] + np.arange(total)
+        pair, slot = np.divmod(span, R)
+        cell = ((plan.pq[pair] * R + slot) * 128
+                + (fp.post_doc[p] & 127))
+        add = plan.pw[pair] * self._host_scores.reshape(-1)[
+            p + plan.phb[pair]]
+        a = 0
+        for b in ends[plan.pos_end * R - 1].tolist():
+            if b > a:
+                c = cell[a:b]      # one term a query: no cell twice
+                plane[c] = plane[c] + add[a:b]
+            a = b
+        return plane
 
-        cand_docs [C] live doc ids from the collected rows — every one is
-        rescored EXACTLY here, so quantization error only matters for
-        UNCOLLECTED rows; bound — the max approximate score any of those
-        could hold (device pick output); gather — the query's cold side
-        as `_chunk_gathers` launched it behind the sweep."""
-        qterms = []
-        cold_terms = []
-        col_terms = []
-        for t, b in terms:
-            info = self._term(t)
-            if info is None:
+    def _survivor_terms(self, qterms, keep: np.ndarray, inv: np.ndarray):
+        """A plan's qterms for `_exact_scores` at the cold side's
+        survivors u[keep]: a cold term's postings are all in u (`inv`
+        holds where, the terms' lists laid end to end), so its impacts at
+        the survivors are written out here and nothing is searched; a
+        term that owns a column keeps its slot."""
+        at = np.cumsum(keep) - 1           # u index -> survivor index
+        n = int(at[-1]) + 1
+        out, o = [], 0
+        for t, boost, info, known in qterms:
+            if known < 0:
+                iv = inv[o: o + info.df]
+                o += info.df
+                kept = keep[iv]
+                known = np.zeros(n, np.float32)
+                known[at[iv[kept]]] = self._host_scores[
+                    info.row_start: info.row_start + info.n_rows
+                ].reshape(-1)[: info.df][kept]
+            out.append((t, boost, info, known))
+        return out
+
+    def _finish_chunk(self, plan: _ChunkPlan, rows_all, bounds, k: int,
+                      gathers: Dict[int, _ColdGather], out_s, out_d) -> None:
+        """Pass 2 of one dispatch chunk on this partition: merge the
+        device-collected candidates + the cold side into exact top-k,
+        written to out_s / out_d [n, k].
+
+        rows_all [n, R] the rows the device picked — every live doc in
+        them is rescored EXACTLY (`_rescore_rows`, the whole chunk at
+        once), so quantization error only matters for UNCOLLECTED rows;
+        bounds [n] — the max approximate score any of those could hold
+        (device pick output); gathers — the queries' cold sides as
+        `_chunk_gathers` launched them behind the sweep. The cold side,
+        the merge and the certificate run a query at a time."""
+        n, R = rows_all.shape
+        with tracing.phase("dispatch.rescore", queries=n):
+            plane = self._exact_scores(plan, rows_all)
+            # candidates = cells with a positive total whose doc is live
+            cells = np.flatnonzero(plane > 0)
+            cdocs = rows_all.reshape(-1)[cells >> 7] * 128 + (cells & 127)
+            lv = self._live_host[cdocs] > 0
+            cells, cdocs = cells[lv], cdocs[lv]
+            cand_s = plane[cells]
+            q_off = np.searchsorted(
+                cells, np.arange(n + 1) * (R * 128)).tolist()
+        left = 0
+        for qi in range(n):
+            qterms = plan.qterms[qi]
+            if not qterms:
                 continue
-            qterms.append((t, b, info))
-            # colized = owns a column NOW (a term past cold_df may have been
-            # left cold by capacity degradation); the split must mirror what
-            # _sweep dispatched so the certificate stays sound
-            (col_terms if t in self._slot_of else cold_terms).append(
-                (t, b, info))
+            docs = cdocs[q_off[qi]: q_off[qi + 1]]
+            totals = cand_s[q_off[qi]: q_off[qi + 1]]
+            cold_terms = plan.cold[qi]
+            colized = len(cold_terms) < len(qterms)
+            host_scored = False
 
-        if not qterms:
-            return np.empty(0, np.float32), np.empty(0, np.int32)
+            # ---- cold side, bound-pruned (the 10M-doc bottleneck was
+            # exact-scoring EVERY cold-touched doc — up to 2 x cold_df of
+            # them — with binary searches into multi-million-entry
+            # colized posting lists; a doc whose cold contribution plus
+            # the colized terms' maximum possible addend cannot reach the
+            # candidate k-th score needs no lookup at all) ----
+            if cold_terms:
+                if plan.sparse:
+                    self.stats["sparse_queries"] += 1
+                    _node_sparse_add("sparse_queries", 1)
+                    h = gathers.pop(qi, None)
+                    if h is None:     # not hoisted: back to back, here
+                        h = self._start_gather(cold_terms, False)
+                    with tracing.phase("dispatch.sparse_gather",
+                                       terms=len(cold_terms)):
+                        u, contrib, slack, inv = self._collect_gather(h)
+                    host_scored = h.host
+                else:
+                    self.stats["cold_queries"] += 1
+                    u, contrib, inv = self._cold_contrib(cold_terms)
+                    slack = 0.0
+                keep = self._live_host[u] > 0
+                if colized:
+                    kth_0 = 0.0
+                    if len(totals) >= k:
+                        kth_0 = float(np.partition(
+                            totals, len(totals) - k)[len(totals) - k])
+                    # float64 contrib + margin keeps this a true upper
+                    # bound; slack covers the sparse tier's quantization so
+                    # the survivor set is a SUPERSET of the host path's —
+                    # extras are exact-rescored and provably below the
+                    # k-th score. (A cold-only query keeps every live doc:
+                    # the exact path IS the full merge.)
+                    keep &= (contrib + slack + plan.col_const[qi] + 1e-5
+                             >= kth_0)
+                cold_docs = u[keep]
+                if len(cold_docs):
+                    with tracing.phase("dispatch.rescore",
+                                       docs=len(cold_docs)):
+                        cold_s = self._exact_scores(
+                            self._survivor_terms(qterms, keep, inv),
+                            cold_docs)
+                    pos = cold_s > 0
+                    docs = np.concatenate([docs, cold_docs[pos]])
+                    totals = np.concatenate([totals, cold_s[pos]])
 
-        # quantization error bound for the device side (must mirror
-        # _dispatch's quantization exactly, including clipping)
-        e_q = 1e-7
-        ws = [(info.idf * b) for _, b, info in col_terms]
-        if ws:
-            wmax = max(abs(w) for w in ws)
-            qs = max(wmax / 127.0, 1e-9)
-            qs2 = qs / 128.0
-            for w in ws:
-                wh = max(-127, min(127, round(w / qs)))
-                wl = max(-127, min(127, round((w - qs * wh) / qs2)))
-                w_approx = qs * wh + qs2 * wl
-                # a full lo step (not half): the build kernel forces
-                # lo >= 1 on presence-only cells so the conjunctive
-                # sweep's presence mask stays exact (kernels._build_kernel)
-                e_q += (abs(w - w_approx) * K1_PLUS1
-                        + abs(w_approx) * COLSCALE2)
-            # f32 rounding of the in-kernel integer combine
-            e_q += 3e-7 * sum(abs(w) for w in ws) * K1_PLUS1
-        e_q = float(e_q)
+            if not len(docs):
+                continue
+            s, d = self._top_k(docs, totals, k)
 
-        # ---- candidate docs from collected rows: exact rescore first ----
-        cand_s = np.empty(0, np.float32)
-        if len(cand_docs):
-            cand_docs = np.asarray(cand_docs, np.int64)
-            cand_s = self._rescore(qterms, cand_docs)
-            keep = cand_s > 0
-            cand_docs, cand_s = cand_docs[keep], cand_s[keep]
+            # ---- certificate ----
+            if colized:
+                # every collected doc is EXACT; a doc outside the pool sits
+                # in an uncollected row, whose approximate rowmax bound
+                # plus the quantization error bounds its true score
+                uncollected = float(bounds[qi])
+                limit = uncollected + plan.e_q[qi]
+                kth = float(s[k - 1]) if len(s) >= k else 0.0
+                short = len(s) < k and uncollected > 0
+                if short or (len(s) >= k and kth < limit
+                             and uncollected > 0):
+                    self.stats["fallbacks"] += 1
+                    host_scored = True
+                    with tracing.phase("dispatch.cert_fallback"):
+                        if self.fallback is not None:
+                            s, d = self.fallback(plan.chunk[qi], k)
+                        else:
+                            s, d = self._exact_merge(
+                                [q[:3] for q in qterms], k)
+            left += host_scored
+            out_s[qi, : len(s)] = s
+            out_d[qi, : len(d)] = d
+        self._count_finish(n - left, left)
 
-        # ---- cold side, bound-pruned (the 10M-doc bottleneck was exact-
-        # scoring EVERY cold-touched doc — up to 2 x cold_df of them — with
-        # binary searches into multi-million-entry colized posting lists;
-        # a doc whose cold contribution plus the colized terms' maximum
-        # possible addend cannot reach the candidate k-th score needs no
-        # lookup at all) ----
-        cold_docs = np.empty(0, np.int64)
-        cold_s = np.empty(0, np.float32)
-        if cold_terms:
-            if self._sparse_on():
-                self.stats["sparse_queries"] += 1
-                _node_sparse_add("sparse_queries", 1)
-                if gather is None:     # not hoisted: back to back, here
-                    gather = self._start_gather(cold_terms, False)
-                with tracing.phase("dispatch.sparse_gather",
-                                   terms=len(cold_terms)):
-                    docs_c, contrib, slack = self._collect_gather(gather)
-            else:
-                self.stats["cold_queries"] += 1
-                docs_c, contrib = self._cold_contrib(cold_terms)
-                slack = 0.0
-            lv = self._live_host[docs_c] > 0
-            docs_c, contrib = docs_c[lv], contrib[lv]
-            if col_terms:
-                kth_0 = 0.0
-                if len(cand_s) >= k:
-                    kth_0 = float(np.partition(cand_s, len(cand_s) - k)[
-                        len(cand_s) - k])
-                col_const = sum(info.idf * b * info.smax
-                                for _, b, info in col_terms)
-                # float64 contrib + margin keeps this a true upper bound;
-                # slack covers the sparse tier's quantization so the
-                # survivor set is a SUPERSET of the host path's — extras
-                # are exact-rescored and provably below the k-th score
-                survivors = docs_c[contrib + slack + col_const + 1e-5
-                                   >= kth_0]
-                if len(survivors):
-                    cold_docs = survivors
-                    cold_s = self._rescore(qterms, cold_docs)
-            else:
-                # cold-only query: the exact path IS the full merge
-                cold_docs = docs_c
-                cold_s = self._rescore(qterms, cold_docs)
+    def _count_finish(self, bulk: int, left: int) -> None:
+        self.stats["finish_bulk_pairs"] += bulk
+        self.stats["finish_pair_fallbacks"] += left
+        _node_sparse_add("finish_bulk_pairs", bulk)
+        _node_sparse_add("finish_pair_fallbacks", left)
 
-        if not len(cand_docs) and not len(cold_docs):
-            return np.empty(0, np.float32), np.empty(0, np.int32)
-        docs = np.concatenate([cand_docs, cold_docs])
-        totals = np.concatenate([cand_s, cold_s])
-        # dedupe (both sides are exact and identical for shared docs)
+    @staticmethod
+    def _top_k(docs: np.ndarray, totals: np.ndarray, k: int):
+        """(scores, ords) of the k best by (score desc, doc asc) among
+        docs with their exact positive totals. A doc may come twice, once
+        from the picked rows and once from the cold side, with the same
+        total: the 2k-th largest total is then at most the k-th best
+        DOC's, so only what reaches it needs sorting."""
+        n = len(totals)
+        if n > 2 * k:
+            floor = np.partition(totals, n - 2 * k)[n - 2 * k]
+            top = totals >= floor
+            docs, totals = docs[top], totals[top]
         docs, first = np.unique(docs, return_index=True)
         totals = totals[first]
-        pos = totals > 0
-        docs, totals = docs[pos], totals[pos]
-        if not len(docs):
-            return np.empty(0, np.float32), np.empty(0, np.int32)
         sel = np.lexsort((docs, -totals))[:k]
-        out_s, out_d = totals[sel], docs[sel].astype(np.int32)
-
-        # ---- certificate ----
-        if col_terms:
-            # every collected doc is EXACT; a doc outside the pool sits in
-            # an uncollected row, whose approximate rowmax bound plus the
-            # quantization error bounds its true score
-            uncollected = float(bound)
-            limit = uncollected + e_q
-            kth = float(out_s[k - 1]) if len(out_s) >= k else 0.0
-            short = len(out_s) < k and uncollected > 0
-            if short or (len(out_s) >= k and kth < limit and uncollected > 0):
-                self.stats["fallbacks"] += 1
-                with tracing.phase("dispatch.cert_fallback"):
-                    if self.fallback is not None:
-                        return self.fallback(terms, k)
-                    return self._exact_merge(qterms, k)
-        return out_s, out_d
+        return totals[sel], docs[sel].astype(np.int32)
 
     # ---------------- bool / phrase search ----------------
     #
@@ -1749,15 +1962,8 @@ class TurboBM25:
                 # unreachable by any subset of +1 weights (n_req <= 126
                 # keeps this in int8)
                 wp[qi, s] = np.int8(-(len(req) + 1))
-            if not scoring:
-                continue
-            wmax = max(abs(w) for _, w, _ in scoring)
-            qs = max(wmax / 127.0, 1e-9)
-            qs2 = qs / 128.0
-            qscale[qi, 0] = qs2 * COLSCALE2
-            for slot, w, _ in scoring:
-                wh = max(-127, min(127, round(w / qs)))
-                wl = max(-127, min(127, round((w - qs * wh) / qs2)))
+            steps, qscale[qi, 0], _ = _quantize([w for _, w, _ in scoring])
+            for (slot, _, _), (wh, wl) in zip(scoring, steps):
                 wq[0, qi, slot] = np.int8(wh)
                 wq[1, qi, slot] = np.int8(wl)
         return wq, wp, nreq, qscale
@@ -2029,22 +2235,10 @@ class TurboBM25:
 
     def _finish_bool(self, r: _BoolQuery, cand_docs, bound: float, k: int):
         """Device-path merge: exact rescore of collected docs + cold-
-        SHOULD enumeration + certificate, mirroring _finish_query."""
+        SHOULD enumeration + certificate, a query at a time (the
+        disjunctive finish is `_finish_chunk`)."""
         scoring, req, mn = self._bool_slots(r)
-        e_q = 1e-7
-        if scoring:
-            wmax = max(abs(w) for _, w, _ in scoring)
-            qs = max(wmax / 127.0, 1e-9)
-            qs2 = qs / 128.0
-            for _, w, _ in scoring:
-                wh = max(-127, min(127, round(w / qs)))
-                wl = max(-127, min(127, round((w - qs * wh) / qs2)))
-                w_approx = qs * wh + qs2 * wl
-                # full lo step: presence-only cells are forced to lo = 1
-                e_q += (abs(w - w_approx) * K1_PLUS1
-                        + abs(w_approx) * COLSCALE2)
-            e_q += 3e-7 * sum(abs(w) for _, w, _ in scoring) * K1_PLUS1
-        e_q = float(e_q)
+        e_q = _quantize([w for _, w, _ in scoring])[2]
 
         cand_s = np.empty(0, np.float32)
         if len(cand_docs):
@@ -2067,11 +2261,11 @@ class TurboBM25:
                 self.stats["sparse_queries"] += 1
                 _node_sparse_add("sparse_queries", 1)
                 # the same plan / launch / collect, back to back
-                docs_c, contrib, slack = self._collect_gather(
+                docs_c, contrib, slack, _ = self._collect_gather(
                     self._start_gather(cold_should, False))
             else:
                 self.stats["cold_queries"] += 1
-                docs_c, contrib = self._cold_contrib(cold_should)
+                docs_c, contrib, _ = self._cold_contrib(cold_should)
                 slack = 0.0
             lv = self._live_host[docs_c] > 0
             docs_c, contrib = docs_c[lv], contrib[lv]
@@ -2517,19 +2711,18 @@ class ShardedTurbo:
 
     # ---------------- fused dispatches ----------------
 
-    def _dispatch_disj(self, chunk, QC: int, n_rows: int, skip=()):
-        """One chunk's fused sweep, and behind it — before anything
-        waits for the sweep — the cold side of every (partition, query)
-        of the chunk, in the order finish collects them. Returns (packed
-        rows, {partition: {query: gather}}); partitions in `skip` are
-        host-scored and launch none."""
+    def _dispatch_disj(self, plans, QC: int, n_rows: int, skip=()):
+        """One chunk's fused sweep from its partitions' `_ChunkPlan`s, and
+        behind it — before anything waits for the sweep — the cold side
+        of every (partition, query) of the chunk, in the order finish
+        collects them. Returns (packed rows, {partition: {query:
+        gather}}); partitions in `skip` are host-scored and launch none."""
         with tracing.phase("dispatch.prep", qc=QC):
             wq = np.zeros((self.Sp, 2, QC, self.Hp + 1), np.int8)
             qs = np.ones((self.Sp, QC, 1), np.float32)
-            for i, t in enumerate(self.turbos):
-                w, q = t._sweep_weights(chunk, QC)
-                wq[i, :, :, : w.shape[2]] = w
-                qs[i] = q
+            for i, plan in enumerate(plans):
+                wq[i, :, :, : plan.wq.shape[2]] = plan.wq
+                qs[i] = plan.qscale
         # the counter moves AFTER the launch so a faulted dispatch is not
         # counted — the circuit tests pin "zero device dispatches" while
         # open by watching it
@@ -2548,7 +2741,7 @@ class ShardedTurbo:
             if first_trace:
                 hbm_ledger.note_compile_done(
                     "fused_turbo", QC, time.monotonic() - t0)
-            gathers = {si: t._chunk_gathers(chunk)
+            gathers = {si: t._chunk_gathers(plans[si])
                        for si, t in enumerate(self.turbos)
                        if si not in skip}
             ph.meta["gathers"] = sum(len(g) for g in gathers.values())
@@ -2643,7 +2836,8 @@ class ShardedTurbo:
                     failed[i] = e
         n_rows = max(_GLOBAL_ROWS, k + 5)
         # per chunk (offset, n, the sweep's packed rows or None: faulted,
-        # {partition: {query: its cold side's gather}})
+        # each partition's plan, {partition: {query: its cold side's
+        # gather}})
         pending = []
         fused_err: Optional[DeviceFaultError] = None
         out_s = np.zeros((S, len(flat), k), np.float32)
@@ -2657,12 +2851,14 @@ class ShardedTurbo:
                 chunk = flat[off: off + take]
                 if check is not None:
                     check()
+                with tracing.phase("dispatch.prep", qc=take):
+                    plans = [t._plan_chunk(chunk, take) for t in self.turbos]
                 try:
                     packed_dev, gathers = self._dispatch_disj(
-                        chunk, take, n_rows, skip=failed)
+                        plans, take, n_rows, skip=failed)
                 except DeviceFaultError as e:
                     packed_dev, gathers, fused_err = None, {}, e
-                pending.append((off, len(chunk), packed_dev, gathers))
+                pending.append((off, len(chunk), packed_dev, plans, gathers))
                 off += len(chunk)
             for entry in pending:
                 if check is not None:
@@ -2684,12 +2880,14 @@ class ShardedTurbo:
 
     def _finish_chunk(self, flat, k: int, n_rows: int, entry, failed,
                       out_s, out_d) -> Optional[DeviceFaultError]:
-        """Pass 2 for one chunk: wait for its sweep, then per (partition,
-        query) the exact rescore merged with the cold side, whose gather
-        is already on its way to the host. A partition in `failed`, or a
-        chunk whose sweep faulted (at its launch, or here at the fetch:
-        returned), is host-scored and drops its gathers."""
-        off, n, packed_dev, gathers = entry
+        """Pass 2 for one chunk: wait for its sweep, then a partition at
+        a time the chunk-wide finish (`TurboBM25._finish_chunk`): the exact
+        rescore merged with the cold sides, whose gathers are already on
+        their way to the host. A partition in `failed`, or a chunk whose
+        sweep faulted (at its launch, or here at the fetch: returned), is
+        host-scored a (partition, query) at a time and drops its
+        gathers."""
+        off, n, packed_dev, plans, gathers = entry
         packed = err = None
         if packed_dev is not None:
             try:
@@ -2700,22 +2898,19 @@ class ShardedTurbo:
                 err = e
         with tracing.phase("dispatch.finish", queries=n):
             for si, t in enumerate(self.turbos):
-                host_only = si in failed or packed is None
-                if not host_only:
-                    rows_all = packed[si, :, :n_rows].astype(np.int64)
-                    bounds = packed[si, :, n_rows]
                 mine = gathers.get(si, {})
+                if si not in failed and packed is not None:
+                    t._finish_chunk(
+                        plans[si], packed[si, :n, :n_rows].astype(np.int64),
+                        packed[si, :n, n_rows], k, mine,
+                        out_s[si, off: off + n], out_d[si, off: off + n])
+                    continue
                 for qi in range(n):
-                    h = mine.pop(qi, None)
-                    if host_only:
-                        t._discard_gather(h)
-                        s, d = t._exact_query(flat[off + qi], k)
-                    else:
-                        docs = t._collect_docs(rows_all[qi])
-                        s, d = t._finish_query(flat[off + qi], docs,
-                                               float(bounds[qi]), k, h)
+                    t._discard_gather(mine.pop(qi, None))
+                    s, d = t._exact_query(flat[off + qi], k)
                     out_s[si, off + qi, : len(s)] = s
                     out_d[si, off + qi, : len(d)] = d
+                t._count_finish(0, n)
         return err
 
     def search_bool(self, queries: Sequence[dict], k: int = 10,

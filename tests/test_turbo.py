@@ -184,3 +184,150 @@ def test_qc_sizes_rounded_and_intermediate_used():
         bs, bd = _brute(fp, stacked.avgdl, stacked.total_docs, _agg(q), k=5)
         n = len(bd)
         assert np.array_equal(ords[qi][:n], bd), f"query {qi}"
+
+
+# ---------------------------------------------------------------------------
+# PR 31: the finish runs once a (partition, chunk); its exact rescore reads
+# a term's postings inside a picked row as one span of its list
+# ---------------------------------------------------------------------------
+
+def _tied_engine():
+    """A partition whose second half repeats its first (every score ties
+    with its twin's), every seventh doc deleted; head terms own a column,
+    tail terms are cold."""
+    rng = np.random.default_rng(31)
+    vocab = 60
+    probs = 1.0 / np.arange(1, vocab + 1) ** 1.1
+    probs /= probs.sum()
+    lens = rng.integers(4, 20, size=3000).astype(np.int64)
+    toks = rng.choice(vocab, size=int(lens.sum()), p=probs).astype(np.int64)
+    toks[[40, 9000, 20000]] = vocab          # t60: three docs and their twins
+    lens, toks = np.concatenate([lens, lens]), np.concatenate([toks, toks])
+    fp = build_field_postings(
+        "body", lens, np.repeat(np.arange(6000, dtype=np.int64), lens), toks,
+        [f"t{i}" for i in range(vocab + 1)])
+    live = np.ones(6000, bool)
+    live[::7] = False
+    stacked = build_stacked_bm25([_Seg(6000, fp)], "body",
+                                 live_masks=[live], serve_only=True)
+    return TurboBM25(stacked, hbm_budget_bytes=64 << 20, cold_df=200)
+
+
+# hot-only, cold-only, mixed, a term the partition lacks (beside others
+# and alone), a repeated term, boosts, a rare term (fewer hits than k)
+CHUNK_MIX = [
+    ["t0", "t1"], ["t50", "t55"], ["t0", "t40", "t58"], ["t1", "zzz"],
+    ["zzz"], ["t2", "t2", "t45"], [("t0", 2.0), ("t47", 0.5)],
+    [("t3", 0.25), ("t4", 3.0), ("t52", 1.0), ("t30", 1.0)], ["t60"],
+    ["t5", "t6", "t7", "t8", "t9", "t35", "t36", "t57"],
+]
+
+
+@pytest.fixture(scope="module")
+def tied():
+    return _tied_engine()
+
+
+@pytest.mark.parametrize("k", [10, 100])
+@pytest.mark.parametrize("width", [1, 3, 16, 40])
+def test_chunk_finish_bit_identical_to_host(tied, width, k):
+    """Widths 1 .. 40 (over SMALL_BATCH_MAX and over the narrow sweep) run
+    ONE finish: scores and ords are `search_many_host`'s bits, and every
+    pair is counted on the chunk-wide path."""
+    qs = [CHUNK_MIX[(i + width) % len(CHUNK_MIX)] for i in range(width)]
+    bulk0 = tied.stats["finish_bulk_pairs"]
+    left0 = tied.stats["finish_pair_fallbacks"]
+    fb0 = tied.stats["fallbacks"]
+    got_s, got_d = tied.search_many([qs], k=k)[0]
+    want_s, want_d = tied.search_many_host([qs], k=k)[0]
+    assert np.array_equal(got_d, want_d)
+    assert np.array_equal(got_s, want_s)          # bit for bit
+    left = tied.stats["finish_pair_fallbacks"] - left0
+    assert left == tied.stats["fallbacks"] - fb0
+    assert tied.stats["finish_bulk_pairs"] - bulk0 == width - left
+    for i, q in enumerate(qs):      # k above the hits there are: padded
+        if q == ["t60"]:
+            assert 0 < (got_s[i] > 0).sum() <= 6
+
+
+def test_chunk_finish_orders_ties_by_doc(tied):
+    s, d = tied.search([["t0", "t1"], ["t50", "t55"]], k=10)
+    for qi in range(2):
+        for j in range(9):
+            assert s[qi, j] >= s[qi, j + 1] > 0
+            if s[qi, j] == s[qi, j + 1]:
+                assert d[qi, j] < d[qi, j + 1]
+        assert len({float(x) for x in s[qi]}) < 10      # twins do tie
+
+
+SPAN_DFS = {"one": 1, "row_less_one": 127, "row": 128, "row_and_one": 129,
+            "cold_most": COLD_DF - 1, "cold_df": COLD_DF, "tail": 84}
+_SPAN_DOCS = COLD_DF + 300          # 130 full rows and one of 44 docs
+
+
+def _span_engine(cold_df):
+    """One term per df of SPAN_DFS (`tail`'s postings are the last 84
+    docs: they end inside the last, partial row) and a filler in every
+    doc."""
+    rng = np.random.default_rng(5)
+    docs_of = {"f": np.arange(_SPAN_DOCS)}
+    for name, df in SPAN_DFS.items():
+        docs_of[name] = (np.arange(_SPAN_DOCS - df, _SPAN_DOCS)
+                         if name == "tail" else
+                         np.sort(rng.choice(_SPAN_DOCS, df, replace=False)))
+    names = list(docs_of)
+    doc = np.concatenate(list(docs_of.values())).astype(np.int64)
+    tok = np.concatenate([np.full(len(d), i, np.int64)
+                          for i, d in enumerate(docs_of.values())])
+    order = np.lexsort((tok, doc))
+    fp = build_field_postings(
+        "body", np.bincount(doc, minlength=_SPAN_DOCS).astype(np.int64),
+        doc[order], tok[order], names)
+    stacked = build_stacked_bm25([_Seg(_SPAN_DOCS, fp)], "body",
+                                 serve_only=True)
+    return TurboBM25(stacked, hbm_budget_bytes=64 << 20, cold_df=cold_df)
+
+
+@pytest.fixture(scope="module")
+def span_engines():
+    # the shipped threshold (only `cold_df` and the filler own a column),
+    # and every term past df 1 owning one
+    return {"list": _span_engine(COLD_DF), "column": _span_engine(1)}
+
+
+@pytest.mark.parametrize("path", ["list", "column"])
+@pytest.mark.parametrize("name", list(SPAN_DFS))
+def test_row_span_impacts_are_impacts_at(span_engines, name, path):
+    """One term's exact impacts at the docs of 33 picked rows, read by row
+    span (`_rescore_rows`: from the column's host index where the term
+    owns a column, by a search of the row edges where not), against
+    `_impacts_at`'s needle search of the same docs: the same bits; an
+    empty slot (-1) stays 0."""
+    eng = span_engines[path]
+    eng.ensure_columns([name])
+    owns = name in eng._slot_of
+    if path == "list":
+        assert owns == (SPAN_DFS[name] >= COLD_DF)
+    else:
+        assert owns
+    info = eng._term(name)
+    assert info.df == SPAN_DFS[name]
+    lo = int(eng.fp.post_start[info.ord])
+    own_rows = np.unique(eng.fp.post_doc[lo: lo + info.df] >> 7)
+    rng = np.random.default_rng(SPAN_DFS[name])
+    rows = np.unique(np.concatenate(
+        [rng.choice(own_rows, min(len(own_rows), 28), replace=False),
+         [0, 1, eng.dp_rows - 1, (_SPAN_DOCS - 1) >> 7]]))[:32]
+    rows_all = np.full((1, 33), -1, np.int64)
+    rows_all[0, 1: 1 + len(rows)] = rng.permutation(rows)   # slot 0 empty
+    boost = 1.5
+    plan = eng._plan_chunk([[(name, boost)]], 8)
+    plane = eng._rescore_rows(plan, rows_all).reshape(33, 128)
+    w = np.float32(info.idf * boost)
+    for slot, r in enumerate(rows_all[0]):
+        want = np.zeros(128, np.float32)
+        if r >= 0:
+            want = want + w * eng._impacts_at(
+                info, r * 128 + np.arange(128, dtype=np.int64))
+        assert np.array_equal(plane[slot], want), (name, path, slot, r)
+    assert (plane > 0).any()        # the rows held postings of the term

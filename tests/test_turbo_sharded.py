@@ -350,3 +350,79 @@ def test_sharded_engine_coalescer_rows_and_keys():
     eng2 = type(eng)(eng.turbos, mesh=eng.mesh)   # refreshed wrapper
     assert _engine_key(eng2) != k1
     assert _engine_key(eng2) == _engine_key(eng2)
+
+
+# ---------------------------------------------------------------------------
+# PR 31: one finish a (partition, chunk) on the fused path
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def eng_chunk():
+    """Partitions 0 and 1 hold the SAME corpus (every hit ties across
+    them), partition 2 repeats its first half in its second (ties within),
+    lacks the tail of the vocabulary and has every fifth doc deleted."""
+    from elasticsearch_tpu.search.serving import TurboEngine, _turbo_mesh
+
+    twin = _pcorpus(1400, 48, 41)
+    rng = np.random.default_rng(43)
+    lens = rng.integers(4, 20, size=900).astype(np.int64)
+    toks = rng.choice(30, size=int(lens.sum()),
+                      p=(lambda w: w / w.sum())(
+                          1.0 / np.arange(1, 31) ** 1.1)).astype(np.int64)
+    dup = _corpus_fp(np.concatenate([lens, lens]),
+                     np.concatenate([toks, toks]), 30)
+    live = np.ones(1800, bool)
+    live[::5] = False
+    turbos = [_turbo(twin, 1400, cold_df=120), _turbo(twin, 1400, cold_df=120)]
+    stacked = build_stacked_bm25([_Seg(1800, dup)], "body",
+                                 live_masks=[live], serve_only=True)
+    turbos.append(TurboBM25(stacked, hbm_budget_bytes=64 << 20, cold_df=120))
+    return TurboEngine(turbos, mesh=_turbo_mesh(3))
+
+
+# hot-only, cold-only, mixed, terms partition 2 lacks (t30 and up) or
+# nobody has, a repeated term, boosts, a rare term (fewer hits than k)
+FUSED_MIX = [
+    ["t0", "t1"], ["t40", "t45"], ["t0", "t25", "t46"], ["t1", "zzz"],
+    ["zzz"], ["t2", "t2", "t38"], [("t0", 2.0), ("t44", 0.5)],
+    [("t3", 0.25), ("t4", 3.0), ("t33", 1.0), ("t20", 1.0)], ["t47"],
+    ["t5", "t6", "t7", "t8", "t9", "t27", "t28", "t43"],
+]
+
+
+@pytest.mark.parametrize("k", [10, 60])
+@pytest.mark.parametrize("width", [1, 3, 16, 40])
+def test_fused_chunk_finish_bit_identical_to_host(eng_chunk, width, k):
+    """Every partition of a fused dispatch answers `search_many_host`'s
+    bits at widths 1 .. 40, the merged answer is the host route's, and
+    partitions x queries pairs are counted between the chunk-wide finish
+    and the ways out of it."""
+    st = eng_chunk._fused()
+    qs = [FUSED_MIX[(i + width) % len(FUSED_MIX)] for i in range(width)]
+    keys = ("finish_bulk_pairs", "finish_pair_fallbacks", "fallbacks")
+    before = [[t.stats[key] for key in keys] for t in st.turbos]
+    per = st.search_many([qs], k=k)
+    for si, t in enumerate(st.turbos):
+        want_s, want_d = t.search_many_host([qs], k=k)[0]
+        got_s, got_d = per[si][0]
+        assert np.array_equal(got_d, want_d), (si, width)
+        assert np.array_equal(got_s, want_s), (si, width)
+        bulk, left, fb = (t.stats[key] - b
+                          for key, b in zip(keys, before[si]))
+        assert bulk + left == width and left == fb
+    _assert_rows_equal(eng_chunk.search_many([qs], k=k)[0],
+                       _host_route_many(eng_chunk, qs, k),
+                       f"merged, width {width}")
+
+
+def test_fused_chunk_finish_ties_order_by_partition_then_ord(eng_chunk):
+    s, p, o = eng_chunk.search_many([[["t0", "t1"], ["t40", "t45"]]],
+                                    k=10)[0]
+    ties = 0
+    for qi in range(2):
+        for j in range(9):
+            if s[qi, j] > 0 and s[qi, j] == s[qi, j + 1]:
+                ties += 1
+                assert (p[qi, j], o[qi, j]) < (p[qi, j + 1], o[qi, j + 1])
+    assert ties

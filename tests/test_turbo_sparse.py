@@ -555,3 +555,212 @@ def test_gather_counters_ride_the_node_stats(solo_engine):
         "sparse_gather_overlapped", "sparse_fallbacks")}
     assert rise == {"sparse_queries": 3, "sparse_gather_launches": 3,
                     "sparse_gather_overlapped": 3, "sparse_fallbacks": 0}
+
+
+# ---------------------------------------------------------------------------
+# PR 31: the finish runs once a (partition, chunk); containment stays per
+# (partition, query), and the two finish counters say who left the bulk path
+# ---------------------------------------------------------------------------
+
+_FINISH_KEYS = ("finish_bulk_pairs", "finish_pair_fallbacks", "fallbacks")
+
+
+def _finish_snap(turbos):
+    return [{key: t.stats[key] for key in _FINISH_KEYS + _SPARSE_KEYS}
+            for t in turbos]
+
+
+def _finish_rise(turbos, before):
+    return [{key: t.stats[key] - b[key] for key in b}
+            for t, b in zip(turbos, before)]
+
+
+def _three_parts(**kw):
+    return _fused([(1500, _pcorpus(1500, 40, 1)),
+                   (900, _pcorpus(900, 56, 2)),
+                   (2100, _pcorpus(2100, 40, 3))], cold_df=300, **kw)
+
+
+@pytest.mark.faults
+@pytest.mark.parametrize("where", ["launch", "collect"])
+def test_gather_fault_in_a_wide_chunk_host_scores_one_pair(where):
+    """A 16-wide chunk has 12 cold pairs a partition: `sparse_gather`
+    fires 12 times at their launches and 12 times at their collects.
+    Whichever call faults on partition 1, that (partition, query) alone
+    is host-scored and leaves the chunk-wide finish, once."""
+    eng = _three_parts()
+    qs = _wide_queries(16)
+    want = eng._merge3([t.search_many_host([qs], k=K)[0]
+                        for t in eng.turbos], len(qs), K)
+    eng.search_many([qs], k=K)               # slices and pools in place
+    before = _finish_snap(eng.turbos)
+    nth = 5 if where == "launch" else 12 + 5
+    with faults.inject(f"sparse_gather#1:raise@{nth}"):
+        got = eng.search_many([qs], k=K)[0]
+    for g, w, name in zip(got, want, ("scores", "parts", "ords")):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), name
+    for si, d in enumerate(_finish_rise(eng.turbos, before)):
+        hit = int(si == 1)
+        assert d["sparse_fallbacks"] == hit, (si, d)
+        assert d["finish_pair_fallbacks"] == hit + d["fallbacks"], (si, d)
+        assert d["finish_bulk_pairs"] == 16 - d["finish_pair_fallbacks"]
+        assert d["sparse_queries"] == 12
+        assert d["sparse_gather_launches"] == \
+            12 - (hit if where == "launch" else 0)
+    assert all(t._sp_inflight == 0 for t in eng.turbos)
+
+
+def test_one_failed_certificate_leaves_the_rest_of_the_chunk_in_bulk(
+        monkeypatch):
+    """The pick's bound for query 5 of 16 is rigged past any score: its
+    certificate fails and it alone takes `dispatch.cert_fallback`; the
+    other 15 stay on the chunk-wide path; all 16 answer the host's bits."""
+    t = _turbo(_pcorpus(3000, 40, 7), 3000, cold_df=COLD_DF)
+    qs = _wide_queries(16)
+    want = t.search_many_host([qs], k=K)[0]
+    _assert_identical(t.search_many([qs], k=K)[0], want, "sound bound")
+    real = turbo_mod._pick_rows
+
+    def rigged(rm, rr, *, n_rows):
+        return real(rm, rr, n_rows=n_rows).at[5, n_rows].set(1e9)
+
+    monkeypatch.setattr(turbo_mod, "_pick_rows", rigged)
+    before = _finish_snap([t])
+    _assert_identical(t.search_many([qs], k=K)[0], want, "rigged bound")
+    (d,) = _finish_rise([t], before)
+    assert d["fallbacks"] == 1 and d["finish_pair_fallbacks"] == 1, d
+    assert d["finish_bulk_pairs"] == 15 and d["sparse_fallbacks"] == 0, d
+
+
+@pytest.mark.parametrize("route", ["solo", "fused"])
+def test_cancel_between_chunks_leaves_no_gather_in_flight(route):
+    """20 queries at width 8 are three chunks, all swept and their 15
+    gathers launched before the first finish. A cancel after the first
+    chunk's finish unwinds with the other two chunks' gathers dropped."""
+    class _Cancelled(Exception):
+        pass
+
+    if route == "solo":
+        turbos = [_turbo(_pcorpus(3000, 40, 7), 3000, cold_df=COLD_DF,
+                         qc_sizes=(8,))]
+        search = turbos[0].search_many
+    else:
+        st = _three_parts(qc_sizes=(8,))._fused()
+        turbos, search = st.turbos, st.search_many
+    qs = _wide_queries(20)
+    search([qs], k=K)                        # slices and pools in place
+    n = []
+
+    def check():
+        n.append(1)
+        if len(n) == 5:     # 1-3: before each sweep, 4-6: before each finish
+            raise _Cancelled()
+
+    before = _finish_snap(turbos)
+    with pytest.raises(_Cancelled):
+        search([qs], k=K, check=check)
+    for t, d in zip(turbos, _finish_rise(turbos, before)):
+        assert d["sparse_gather_launches"] == 15, d
+        assert d["sparse_gather_overlapped"] == 6, d      # chunk 1's own
+        assert d["finish_bulk_pairs"] + d["finish_pair_fallbacks"] == 8
+        assert t._sp_inflight == 0
+
+
+def test_an_evicted_column_rebuilds_its_host_index():
+    """32 slots, 36 terms past cold_df: a batch of 32 other terms evicts
+    the first call's four columns, the third call rebuilds them (other
+    slots, maybe) and with them the rows' span ends and the survivors'
+    look-up (`_col_cnt`, `_col_bits`): the same bits before and after."""
+    fp = _pcorpus(3000, 60, 7)
+    by_df = sorted(range(60), key=lambda i: -int(fp.doc_freq[fp.ord(f"t{i}")]))
+    cold_df = int(fp.doc_freq[fp.ord(f"t{by_df[35]}")])
+    t = _turbo(fp, 3000, cold_df=cold_df, hbm=1)
+    assert t.Hp == 32
+    hot = [f"t{i}" for i in by_df[:36]]
+    cold = [f"t{i}" for i in by_df[36:]]
+    qs1 = [[(hot[0], 1.0), (hot[1], 0.5), (cold[0], 1.0)],
+           [(hot[2], 1.0), (hot[3], 1.0), (cold[1], 0.7), (cold[2], 1.0)]]
+    qs2 = [[(hot[i], 1.0), (hot[i + 1], 1.0), (cold[i % 20], 1.0)]
+           for i in range(4, 36, 2)]
+    want1 = t.search_many_host([qs1], k=K)[0]
+    first = t.search_many([qs1], k=K)[0]
+    _assert_identical(first, want1, "before the eviction")
+    _assert_identical(t.search_many([qs2], k=K)[0],
+                      t.search_many_host([qs2], k=K)[0], "the evicting batch")
+    assert not any(h in t._slot_of for h in hot[:4])
+    builds = t.stats["builds"]
+    _assert_identical(t.search_many([qs1], k=K)[0], first, "after the rebuild")
+    assert t.stats["builds"] == builds + 4
+    for h in hot[:4]:
+        info, slot = t._term(h), t._slot_of[h]
+        assert t._col_cnt[slot, -1] == info.df
+        assert int(np.bitwise_count(t._col_bits[slot]).sum()) == info.df
+
+
+def test_a_wide_chunk_is_one_observation_a_step_and_counts_its_pairs():
+    """One engine call over a 16-query chunk on three partitions: ONE
+    observation each of `dispatch.finish`, `dispatch.rescore` and
+    `dispatch.sparse_gather` (48 pairs inside them), rescore and the
+    collect under finish, and partitions x queries pairs on the
+    chunk-wide path, node-wide too."""
+    from elasticsearch_tpu.common import metrics
+    from elasticsearch_tpu.search.serving import turbo_node_stats
+
+    st = _three_parts()._fused()
+    qs = _wide_queries(16)
+    st.search_many([qs], k=K)                # slices and pools in place
+    names = ("dispatch.finish", "dispatch.rescore", "dispatch.sparse_gather")
+    count0 = {n: metrics.summary(n)["count"] for n in names}
+    total0 = {n: metrics.raw_dump(n)["total"] for n in names}
+    node0 = turbo_node_stats()
+    before = _finish_snap(st.turbos)
+    st.search_many([qs], k=K)
+    took = {n: metrics.raw_dump(n)["total"] - total0[n] for n in names}
+    for n in names:
+        assert metrics.summary(n)["count"] == count0[n] + 1, n
+        assert took[n] > 0, n
+    assert took["dispatch.rescore"] + took["dispatch.sparse_gather"] \
+        <= took["dispatch.finish"]
+    rise = _finish_rise(st.turbos, before)
+    left = sum(d["finish_pair_fallbacks"] for d in rise)
+    assert sum(d["finish_bulk_pairs"] for d in rise) == 3 * 16 - left
+    assert left == sum(d["fallbacks"] for d in rise)
+    node1 = turbo_node_stats()
+    assert node1["finish_bulk_pairs"] - node0["finish_bulk_pairs"] \
+        == 3 * 16 - left
+    assert node1["finish_pair_fallbacks"] - node0["finish_pair_fallbacks"] \
+        == left
+
+
+def test_finish_bulk_pct_is_declared_for_the_bm25_cells_and_reads_a_share():
+    """The benchmark's `finish_bulk_pct.search`: the manifest declares it
+    in the traced runs of the cells whose device counter is the fused
+    turbo's, and its reader gives 100 x bulk / (bulk + left), 0 where the
+    program's stats lack the counters (the parent commit's do)."""
+    from types import SimpleNamespace
+
+    from benchmark.manifest import ROOT, Manifest, load_kind
+
+    m = Manifest(ROOT)
+    name = "finish_bulk_pct.search"
+    for cell in m.cell_names():
+        declared = name in {x["name"] for x in m.declared(cell, 1)}
+        assert declared == m.cell(cell).config["device_counter"].startswith(
+            "tpu_turbo."), cell
+        assert name not in {x["name"] for x in m.declared(cell, 0)}
+    spec = m.metric_spec(name)
+    read = load_kind(m.dir, "reader", spec["kind"]).read
+
+    def window(b0, l0, b1, l1):
+        return SimpleNamespace(
+            stats_before={"tpu_turbo": {"finish_bulk_pairs": b0,
+                                        "finish_pair_fallbacks": l0}},
+            stats_after={"tpu_turbo": {"finish_bulk_pairs": b1,
+                                       "finish_pair_fallbacks": l1}})
+
+    assert read(spec, window(10, 1, 10 + 750, 1 + 18)) == \
+        pytest.approx(100 * 750 / 768)
+    assert read(spec, window(5, 0, 5, 0)) == 0.0
+    bare = SimpleNamespace(stats_before={"tpu_turbo": {}},
+                           stats_after={"tpu_turbo": {}})
+    assert read(spec, bare) == 0.0
