@@ -38,7 +38,7 @@ import numpy as np
 
 N_DOCS = 1_000_000       # `smoke`: spread over --chips shards
 N_VECTORS = 100_000      # `smoke_vec`
-VOCAB = 50_000           # bench.py's corpus shape: Zipf 1.07 over 50k terms,
+VOCAB = 50_000           # the corpus's shape: Zipf 1.07 over 50k terms,
 ZIPF_S = 1.07            # 8-40 tokens per doc
 DOC_LEN = (8, 40)
 N_TAGS = 256

@@ -18,7 +18,9 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 def configure_compile_cache() -> str:
     """Point JAX at the persistent compile cache; returns its directory.
-    Called once by every process entry point (node start-up, bench.py)."""
+    Called once, by `start_node`: the entry points `python -m
+    elasticsearch_tpu`, `python3 -m benchmark` and `chip_smoke.py` all
+    start the node through it."""
     import jax
 
     placed = os.environ.get(_ENV)

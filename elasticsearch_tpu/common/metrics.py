@@ -1,7 +1,7 @@
 """Log-bucketed latency histograms for the search flight recorder.
 
-Node-wide distributions per search phase (queue wait, coalesce wait, device
-sweep, demux, fetch, ...) plus coalescer batch-size / pad-ratio shapes.
+Node-wide distributions per search phase (queue wait, device sweep, demux,
+fetch, ...) plus the scheduler's batch-size / pad-ratio shapes.
 Design constraints:
 
 - **Fixed bucket boundaries** per kind so histograms merge across nodes by
@@ -472,8 +472,7 @@ declare_histogram("queue_wait.write", "ms", "queued->started wait, write pool")
 declare_histogram("queue_wait.get", "ms", "queued->started wait, get pool")
 declare_histogram("queue_wait.management", "ms", "queued->started wait, management pool")
 declare_histogram("queue_wait.snapshot", "ms", "queued->started wait, snapshot pool")
-declare_histogram("coalesce_wait", "ms", "wait inside DispatchCoalescer (leader fill window + follower completion wait)")
-declare_histogram("device", "ms", "one device dispatch (coalesced batch or direct search_bool/search_many)")
+declare_histogram("device", "ms", "one device dispatch (scheduler batch or direct search_bool/search_many)")
 declare_histogram("demux", "ms", "per-request hit extraction from a batched device result")
 declare_histogram("fetch", "ms", "fetch phase (doc _source materialization)")
 declare_histogram("query", "ms", "shard query phase end-to-end (data node side)")

@@ -16,7 +16,7 @@ level with hysteresis and feeds three consumers:
    (`RetryBudget`) refilled by successful requests
    (`ES_TPU_RETRY_BUDGET_RATIO` tokens per success, capped at
    `ES_TPU_RETRY_BUDGET_CAP`). The shard-failover loop, replication / bulk /
-   recovery retries and the coalescer/scheduler poison solo retries each
+   recovery retries and the scheduler's poison solo retries each
    spend one token per retry; when the bucket is empty the original error
    fails fast instead of amplifying (counter `retry_budget_exhausted`,
    per-site in `stats()`).
@@ -338,7 +338,7 @@ class OverloadController:
 
 # ---------------------------------------------------------------------------
 # process-default controller: consumers that predate per-node wiring
-# (coalescer / scheduler poison retries) share one budget per process
+# (the scheduler's poison retries) share one budget per process
 # ---------------------------------------------------------------------------
 
 _DEFAULT_LOCK = threading.Lock()

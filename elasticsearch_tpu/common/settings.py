@@ -305,8 +305,9 @@ declare_knob("ES_TPU_HEALTH_BACKOFF_MS", "int", 1000,
              "Base backoff before a half-open probe (doubles per reopen, "
              "capped at 32x)")
 declare_knob("ES_TPU_COALESCE_US", "float", 2000.0,
-             "Dispatch-coalescer flush window in microseconds "
-             "(0 disables coalescing)")
+             "On/off for dispatch batching: <= 0 sends every engine call "
+             "directly, on the caller's thread (no scheduler lanes); any "
+             "positive value leaves batching on and is otherwise unread")
 declare_knob("ES_TPU_TURBO_HBM", "int", 6 << 30,
              "HBM budget in bytes for TurboBM25's int8 column cache")
 declare_knob("ES_TPU_TURBO_COLD_DF", "int", None,
@@ -409,9 +410,6 @@ declare_knob("ES_TPU_SLOWLOG_RING", "int", 128,
              "Capacity of the in-memory search slowlog ring served at "
              "GET /_tpu/slowlog")
 # continuous-batching dispatch scheduler (PR 10)
-declare_knob("ES_TPU_SCHED_MODE", "str", "adaptive",
-             "Serving dispatch path: 'adaptive' (continuous-batching "
-             "scheduler) or 'legacy' (fixed-window coalescer)")
 declare_knob("ES_TPU_SCHED_BUCKETS", "str", "1,4,16,64,256",
              "Padded batch-size ladder for the adaptive scheduler "
              "(comma-separated, each bucket is one compiled shape); when "

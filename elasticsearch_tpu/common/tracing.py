@@ -103,7 +103,7 @@ def self_times(spans: Iterable[dict]) -> Dict[str, float]:
 
 class TraceContext:
     """Spans for one search request on one node. Thread-safe: spans arrive
-    from pool workers, coalescer leaders, scheduler lanes and RPC threads
+    from pool workers, scheduler lanes and RPC threads
     concurrently."""
 
     __slots__ = ("trace_id", "opaque_id", "node", "kind", "t0_ns", "spans",
@@ -186,7 +186,7 @@ def _make_span(name: str, parent: int, duration_ms: float,
 
 class _Fanout:
     """The context of work done once on behalf of several requests (one
-    scheduler or coalescer batch): every span recorded under it is one
+    scheduler batch): every span recorded under it is one
     record, linked into the context of each traced waiter. A span opened
     at the top of the activation hangs off the span each waiter was in
     when it submitted."""

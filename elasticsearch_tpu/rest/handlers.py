@@ -2195,7 +2195,6 @@ class _Handlers:
             "breakers": self.node.breakers.stats(),
             "indexing_pressure": self.node.indexing_pressure.stats(),
             "thread_pool": self.node.thread_pool.stats(),
-            "tpu_coalescer": _default_coalescer_stats(),
             "tpu_scheduler": _default_scheduler_stats(),
             "tpu_turbo": _turbo_merge_stats(),
             "tpu_health": _tpu_health_stats(),
@@ -2587,12 +2586,6 @@ class _Handlers:
         return names
 
 
-def _default_coalescer_stats() -> dict:
-    from elasticsearch_tpu.threadpool.coalescer import default_coalescer
-
-    return default_coalescer().stats()
-
-
 def _default_scheduler_stats() -> dict:
     from elasticsearch_tpu.threadpool.scheduler import scheduler_stats
 
@@ -2611,24 +2604,21 @@ def _turbo_merge_stats() -> dict:
 def _tpu_health_stats() -> dict:
     """Node-wide device-health section (PR 5): per-engine circuit state
     + cumulative fault/fallback counters, plus the serving layer's
-    containment counters (recovered shards, fast-path rejections/timeouts)
-    and the coalescer's poison-batch retries."""
+    containment counters (recovered shards, fast-path rejections/timeouts).
+    A poisoned batch's solo retries are `tpu_scheduler.sched_batch_retries`."""
     from elasticsearch_tpu.common.health import node_health_stats
     from elasticsearch_tpu.search.serving import serving_fault_stats
-    from elasticsearch_tpu.threadpool.coalescer import default_coalescer
 
     out = node_health_stats()
     out.update(serving_fault_stats())
-    out["coalesce_batch_retries"] = \
-        default_coalescer().stats()["coalesce_batch_retries"]
     return out
 
 
 def _tpu_search_latency_stats() -> dict:
     """Search flight-recorder section (PR 9): per-phase latency histogram
-    summaries (queue wait per pool, coalesce wait, device, demux, fetch,
+    summaries (queue wait per pool, device, demux, fetch,
     query, merge, rest_total — p50/p90/p99/max over log-spaced buckets),
-    the coalescer's batch-size/pad-ratio distributions, and the slowlog
+    the scheduler's batch-size/pad-ratio distributions, and the slowlog
     ring counters. Always on: histograms record whether or not any
     request is traced."""
     from elasticsearch_tpu.common import metrics, tracing

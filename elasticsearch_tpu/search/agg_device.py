@@ -186,7 +186,7 @@ class _AggWork:
 
 
 class AggDeviceEngine:
-    """Batched device aggregation engine. Speaks the coalescer/scheduler
+    """Batched device aggregation engine. Speaks the scheduler's
     `search_many` contract so agg collects ride the AdaptiveDispatch
     Scheduler's bulk tier like any other engine's queries; the score
     triple it returns is all zeros (results travel on the works)."""

@@ -8,8 +8,8 @@ Threading contract: this runs on whatever thread calls it — under REST
 traffic that is a worker of the node's bounded SEARCH pool
 (threadpool/pool.py; rest/http_server.py classifies requests to stages),
 never an unbounded accept thread. The serving fast path that fronts this
-executor (search/serving.py) additionally coalesces concurrent
-single-query dispatches into one device batch (threadpool/coalescer.py).
+executor (search/serving.py) additionally batches concurrent
+single-query dispatches into one device batch (threadpool/scheduler.py).
 """
 
 from __future__ import annotations

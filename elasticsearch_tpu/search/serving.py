@@ -51,7 +51,7 @@ from elasticsearch_tpu.common.faults import FaultRecord
 from elasticsearch_tpu.index.positions import phrase_freqs
 from elasticsearch_tpu.ops import bm25_idf
 from elasticsearch_tpu.common.settings import knob
-from elasticsearch_tpu.threadpool.coalescer import (
+from elasticsearch_tpu.threadpool.scheduler import (
     device_phase, record_pad_waste,
 )
 from elasticsearch_tpu.search import queries as q
@@ -426,7 +426,7 @@ def _flatten(node, plan: FlatPlan, mapper, ctx: str, weight: float) -> None:
 
 
 # --------------------------------------------------------------------------
-# BM25 engine selection (shared by the REST path and bench.py)
+# BM25 engine selection: the one selection point of the served path
 # --------------------------------------------------------------------------
 
 # HBM reserved for TurboBM25's int8 column cache when it is selected
@@ -439,7 +439,7 @@ def _env_cold_df() -> Optional[int]:
 
 # node-wide Turbo partition-merge counters (every TurboEngine increments
 # these alongside its own merge_stats; GET /_nodes/stats surfaces them
-# next to the tpu_coalescer section)
+# next to the tpu_scheduler section)
 _TURBO_NODE_STATS = {"merge_device": 0, "merge_host": 0,
                      "partition_dispatches": 0,
                      "fused_dispatches": 0}  # guarded by: _TURBO_NODE_LOCK
@@ -836,7 +836,7 @@ class TurboEngine:
             for key, v in t.stats.items():
                 agg[key] = agg.get(key, 0) + v
         agg.update(self.merge_stats)
-        # flat numeric health_* keys (bench stats_delta subtracts values)
+        # flat numeric health_* keys: every value of `stats` is a number
         agg.update(self.health.flat_stats())
         return agg
 
@@ -890,10 +890,11 @@ def select_bm25_engine(segments, field: str, live_masks, mesh, *,
                        hbm_budget_bytes: int = TURBO_HBM_BUDGET,
                        cold_df: Optional[int] = None):
     """Build the disjunctive BM25 serving engine for these partitions —
-    the ONE selection point shared by the REST path (ServingSnapshot) and
-    bench.py, so the benchmark measures exactly what the product serves
-    (VERDICT r4 weak #2; ref: the reference serves every search through
-    one stack, search/SearchService.java:370)."""
+    the ONE selection point of the served path (ServingSnapshot), so
+    `python3 -m benchmark` and `chip_smoke.py`, which drive the node over
+    HTTP, measure exactly what the product serves (ref: the reference
+    serves every search through one stack,
+    search/SearchService.java:370)."""
     from elasticsearch_tpu.parallel.blockmax import BlockMaxBM25
     from elasticsearch_tpu.parallel.spmd import build_stacked_bm25
 
@@ -1377,8 +1378,7 @@ class ServingContext:
             # single-query dispatches ride the node's adaptive scheduler:
             # concurrent shard queries on the same engine continuous-batch
             # into shared device dispatches (SLA tier from the request's
-            # thread-local class; ES_TPU_SCHED_MODE=legacy falls back to
-            # the fixed-window coalescer)
+            # thread-local class)
             from elasticsearch_tpu.threadpool.scheduler import (
                 serving_dispatch,
             )

@@ -18,7 +18,7 @@ banning the dead node's id prefix.
 The TPU twist: a dispatched XLA program itself cannot be interrupted, but
 every program here is bounded (fixed shapes, one batch chunk), so the
 check granularity is one dispatch — milliseconds, not the whole query.
-The scheduler/coalescer only honor cancellation at their flush
+The scheduler only honors cancellation at its flush
 boundaries, preserving the bit-identity contract when no cancel fires.
 """
 

@@ -1,6 +1,3 @@
-from elasticsearch_tpu.threadpool.coalescer import (
-    DispatchCoalescer, default_coalescer,
-)
 from elasticsearch_tpu.threadpool.pool import (
     EsRejectedExecutionError, FixedExecutor, ThreadPool, pool_for_request,
     tier_for_request,
@@ -10,8 +7,8 @@ from elasticsearch_tpu.threadpool.scheduler import (
     default_scheduler, scheduler_stats, serving_dispatch,
 )
 
-__all__ = ["AdaptiveDispatchScheduler", "DispatchCoalescer",
+__all__ = ["AdaptiveDispatchScheduler",
            "EsRejectedExecutionError", "FixedExecutor", "ThreadPool",
-           "activate_tier", "current_tier", "default_coalescer",
+           "activate_tier", "current_tier",
            "default_scheduler", "pool_for_request", "scheduler_stats",
            "serving_dispatch", "tier_for_request"]

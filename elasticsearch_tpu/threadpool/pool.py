@@ -70,12 +70,12 @@ class _Task:
         self.taskref = _taskmgr.current_task()
 
     def run(self) -> None:
+        """Run the task; the worker publishes it (`_done`) afterwards,
+        once its counters include it."""
         try:
             self.result = self.fn(*self.args, **self.kwargs)
         except BaseException as e:  # noqa: BLE001 — ferried to the waiter
             self.error = e
-        finally:
-            self._done.set()
 
     def done(self) -> bool:
         return self._done.is_set()
@@ -164,16 +164,22 @@ class FixedExecutor:
             # composed name: ad-hoc test pools fall outside the registry
             tracing.record(f"queue_wait.{self.name}", qw_ms,
                            tc=task.trace, parent=task.span)
-            with tracing.activate(task.trace, task.span), \
-                    _sched.activate_tier(task.tier), \
-                    _taskmgr.activate(task.taskref):
-                task.run()
-            dt_ms = (time.monotonic() - t0) * 1e3
-            with self._lock:
-                self.active -= 1
-                self.completed += 1
-                self.ewma_ms = dt_ms if self.completed == 1 else \
-                    (1 - _EWMA_ALPHA) * self.ewma_ms + _EWMA_ALPHA * dt_ms
+            try:
+                with tracing.activate(task.trace, task.span), \
+                        _sched.activate_tier(task.tier), \
+                        _taskmgr.activate(task.taskref):
+                    task.run()
+                dt_ms = (time.monotonic() - t0) * 1e3
+                with self._lock:
+                    self.active -= 1
+                    self.completed += 1
+                    self.ewma_ms = dt_ms if self.completed == 1 else \
+                        (1 - _EWMA_ALPHA) * self.ewma_ms \
+                        + _EWMA_ALPHA * dt_ms
+            finally:
+                # counters first, then the result: a caller that has its
+                # result reads stats that include it
+                task._done.set()
 
     def stats(self) -> dict:
         with self._lock:

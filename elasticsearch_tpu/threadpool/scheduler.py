@@ -1,13 +1,12 @@
-"""Continuous-batching dispatch scheduler: bucketed shapes, double
-buffering, SLA tiers.
+"""The dispatch layer: every engine `search_many` call of the served path
+goes through here (`search/serving.py` -> `serving_dispatch` -> engine).
 
-BENCH_r05 measured the wall the fixed-window coalescer hits: batch-1 p95
-is 160+ ms while batch-256 p50 is ~1 s, because ONE flush window and ONE
-padded shape force the device to alternate between starvation (tiny
-batches after a full 2 ms wait) and giant pads (a stray single riding a
-256-wide dispatch). This module is the continuous-batching discipline of
-modern inference servers applied to the search dispatch path:
-
+- **one device dispatch** — `run_device` runs one engine call under the
+  `device` phase (the span and histogram the `dispatch.*` steps hang
+  under) and records the batch's shape (`coalesce_batch_size`,
+  `coalesce_pad_ratio`). A batch of more than `SMALL_BATCH_MAX` queries
+  (an `_msearch` body) is already a good device shape and dispatches
+  directly; smaller ones are merged with concurrent peers.
 - **bucketed batch shapes** — a small ladder of padded batch sizes
   (`ES_TPU_SCHED_BUCKETS`, default 1/4/16/64/256). Each bucket is one
   compiled kernel shape (the ladder is pushed into the engine's
@@ -33,21 +32,24 @@ modern inference servers applied to the search dispatch path:
   the flush, the bucket is sized to the queries that are DUE, and bulk
   only rides along in the pad slack that would be wasted anyway.
 
-The coalescer's serving contracts are inherited, not re-invented: lanes
-are keyed by (engine serial, k) so queries never share a dispatch across
-engines or top-k depths; merged rows are bit-identical to solo rows (the
-engines score per query-row); a poisoned batch is retried solo per query
-(threadpool/coalescer.retry_batch_solo); cooperative `check()` runs only
-at the caller boundary so one cancelled task can't fail its batch peers.
+Bit-identity with solo execution is a hard requirement (the serving
+differential tests enforce it), so merging is conservative: lanes are
+keyed by (engine serial, k), so queries never share a dispatch across
+engines (a snapshot refresh swaps the engine object: late arrivals key
+onto the NEW engine, in-flight waiters finish on the snapshot they
+captured) or top-k depths; the engines score and select top-k per
+query-row, so a merged row equals its solo row bitwise; a poisoned batch
+is retried solo per query (`retry_batch_solo`); cooperative `check()`
+runs only at the caller boundary so one cancelled task can't fail its
+batch peers.
 
-`ES_TPU_COALESCE_US=0` still disables batching entirely (every call
-dispatches directly), and `ES_TPU_SCHED_MODE=legacy` routes serving
-dispatches through the old fixed-window coalescer so the differential
-suite can A/B the two schedulers bit-identically.
+`ES_TPU_COALESCE_US <= 0` disables batching entirely: every call
+dispatches directly, on the caller's thread.
 """
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from contextlib import contextmanager
@@ -56,10 +58,10 @@ from typing import Dict, List, Optional, Tuple
 from elasticsearch_tpu.common import metrics, tracing
 from elasticsearch_tpu.common.settings import knob
 from elasticsearch_tpu.tasks import task_manager as _taskmgr
-from elasticsearch_tpu.threadpool.coalescer import (
-    SMALL_BATCH_MAX, _engine_key, default_coalescer, retry_batch_solo,
-    run_device,
-)
+
+# a query batch larger than this is already a good device shape — merging
+# it would only add latency to its peers
+SMALL_BATCH_MAX = 8
 
 TIER_INTERACTIVE = "interactive"
 TIER_BULK = "bulk"
@@ -160,6 +162,131 @@ def _parse_buckets(raw) -> Tuple[int, ...]:
     return tuple(vals) if vals else DEFAULT_BUCKETS
 
 
+# ---------------------------------------------------------------------------
+# one device dispatch: the engine call under its `device` phase, the batch
+# shape it records, and the containment of a batch that fails
+# ---------------------------------------------------------------------------
+
+# monotonic engine serials for lane keying: id(engine) could be REUSED
+# by a new engine allocated after an old one is garbage-collected
+# mid-flight (a snapshot refresh drops the old TurboEngine/ShardedTurbo
+# wrapper), silently merging waiters across snapshots; a serial pinned on
+# the object can never collide
+_engine_serials = itertools.count(1)
+
+
+def _engine_key(engine) -> int:
+    s = getattr(engine, "_coalesce_serial", None)
+    if s is None:
+        s = next(_engine_serials)
+        try:
+            engine._coalesce_serial = s
+        except AttributeError:     # __slots__ engines: degrade to id()
+            return id(engine)
+    return s
+
+
+def device_phase(engine, n_queries: int,
+                 engine_name: Optional[str] = None) -> tracing.phase:
+    """Flight recorder: the `device` phase around one device dispatch.
+    Every dispatch path (the scheduler's direct and lane dispatches
+    through `run_device`, serving's search_bool sites) runs its engine
+    call under it and then calls `record_pad_waste`, so latency AND batch
+    shape land together; the engines' `dispatch.*` steps are its
+    children."""
+    return tracing.phase(
+        "device", engine=engine_name or getattr(engine, "kind", "?"),
+        batch=n_queries)
+
+
+def run_device(engine, queries: List, k: int, check=None, fault_log=None):
+    """One `search_many` dispatch under the `device` phase."""
+    with device_phase(engine, len(queries)):
+        out = _search_many(engine, queries, k, check=check,
+                           fault_log=fault_log)
+    record_pad_waste(engine, len(queries))
+    return out
+
+
+def record_pad_waste(engine, n: int) -> None:
+    """Batch-shape histograms: how many query rows the qc quantization pads
+    on top of the real batch (the pad-waste the bucket ladder exists to
+    minimize)."""
+    metrics.observe("coalesce_batch_size", n)
+    sizes = getattr(engine, "qc_sizes", None)
+    if not sizes or n <= 0:
+        return
+    cap = sizes[-1]
+    full, rem = divmod(n, cap)
+    padded = full * cap
+    if rem:
+        padded += next((s for s in sizes if s >= rem), cap)
+    if padded > 0:
+        metrics.observe("coalesce_pad_ratio", (padded - n) / padded)
+
+
+def _accepts_fault_log(engine) -> bool:
+    """Whether engine.search_many takes a fault_log kwarg (TurboEngine
+    does; BlockMax and test stubs may not). Cached on the engine."""
+    cached = getattr(engine, "_accepts_fault_log_", None)
+    if cached is None:
+        import inspect
+
+        try:
+            params = inspect.signature(engine.search_many).parameters
+            cached = "fault_log" in params or any(
+                p.kind == inspect.Parameter.VAR_KEYWORD
+                for p in params.values())
+        except (TypeError, ValueError):
+            cached = False
+        try:
+            engine._accepts_fault_log_ = cached
+        except AttributeError:
+            pass
+    return cached
+
+
+def _search_many(engine, queries: List, k: int, check=None, fault_log=None):
+    """One batch of queries -> (scores [Q,k], partition [Q,k], ord [Q,k]):
+    the engine `search_many` single-batch contract."""
+    kw = {}
+    if check is not None:
+        kw["check"] = check
+    if fault_log is not None and _accepts_fault_log(engine):
+        kw["fault_log"] = fault_log
+    return engine.search_many([list(queries)], k=k, **kw)[0]
+
+
+def retry_batch_solo(batch: "_SchedBatch", original: BaseException) -> None:
+    """Poison-batch containment: re-run each of a failed merged batch's
+    queries as its own solo dispatch (once). Slots whose retry also fails
+    carry their error to exactly their waiter; if every retry fails the
+    original batch error goes to everyone."""
+    import numpy as np
+
+    rows: List = [None] * len(batch.queries)
+    errors: Dict[int, BaseException] = {}
+    for qi, query in enumerate(batch.queries):
+        try:
+            s, p, o = _search_many(batch.engine, [query], batch.k,
+                                   fault_log=batch.fault_log)
+        except Exception as e:
+            errors[qi] = e
+            continue
+        rows[qi] = (np.asarray(s[0]), np.asarray(p[0]),
+                    np.asarray(o[0]))
+    if all(r is None for r in rows):
+        batch.error = original
+        return
+    template = next(r for r in rows if r is not None)
+    for qi, r in enumerate(rows):
+        if r is None:
+            rows[qi] = tuple(np.zeros_like(x) for x in template)
+    batch.results = tuple(np.stack([r[j] for r in rows])
+                          for j in range(3))
+    batch.query_errors = errors
+
+
 class _Waiter:
     """One dispatch() call parked in a lane queue."""
 
@@ -184,8 +311,7 @@ class _Waiter:
 
 
 class _SchedBatch:
-    """One flushed device dispatch (result surface shared with the
-    coalescer's _PendingBatch so retry_batch_solo applies to both)."""
+    """One flushed device dispatch and its result surface."""
 
     __slots__ = ("engine", "k", "queries", "waiters", "bucket", "results",
                  "error", "fault_log", "query_errors", "trace", "_lock",
@@ -595,8 +721,9 @@ class AdaptiveDispatchScheduler:
 
                 default_overload().note_success()
         except Exception as e:
-            # poison-batch containment (coalescer parity): retry each
-            # query solo so only the one tripping the fault sees it —
+            # poison-batch containment: a failed FUSED dispatch must not
+            # fail every waiter. Retry each query solo so only the one
+            # (if any) that actually trips the fault sees the error —
             # but only while the node-wide retry budget holds out; an
             # exhausted budget ferries the ORIGINAL error to the waiters
             from elasticsearch_tpu.common.overload import default_overload
@@ -677,9 +804,6 @@ class AdaptiveDispatchScheduler:
 
 _default = AdaptiveDispatchScheduler()
 
-_MODE_LOCK = threading.Lock()
-_MODE_COUNTS = {"adaptive": 0, "legacy": 0}  # guarded by: _MODE_LOCK
-
 
 def default_scheduler() -> AdaptiveDispatchScheduler:
     return _default
@@ -687,29 +811,16 @@ def default_scheduler() -> AdaptiveDispatchScheduler:
 
 def serving_dispatch(engine, queries: List, k: int, check=None,
                      fault_log=None, tier: Optional[str] = None):
-    """THE serving dispatch entry (search/serving.py call sites):
-    routes through the adaptive scheduler, or through the legacy
-    fixed-window coalescer when ES_TPU_SCHED_MODE=legacy — both honor
-    ES_TPU_COALESCE_US=0 as "no batching at all"."""
-    if knob("ES_TPU_SCHED_MODE") == "legacy":
-        with _MODE_LOCK:
-            _MODE_COUNTS["legacy"] += 1
-        return default_coalescer().dispatch(engine, queries, k,
-                                            check=check,
-                                            fault_log=fault_log)
-    with _MODE_LOCK:
-        _MODE_COUNTS["adaptive"] += 1
+    """THE serving dispatch entry (search/serving.py call sites): the
+    process-default scheduler, so concurrent searches batch across REST
+    entry points."""
     return _default.dispatch(engine, queries, k, check=check,
                              fault_log=fault_log, tier=tier)
 
 
 def scheduler_stats() -> dict:
     """The `tpu_scheduler` section of GET /_nodes/stats."""
-    with _MODE_LOCK:
-        modes = dict(_MODE_COUNTS)
-    return {"mode": knob("ES_TPU_SCHED_MODE"),
-            "mode_dispatches": modes,
-            **default_scheduler().stats()}
+    return _default.stats()
 
 
 # every metrics-history sample carries the default scheduler's per-lane

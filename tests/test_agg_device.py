@@ -142,6 +142,36 @@ def test_empty_mask_matches_host(monkeypatch):
     svc.close()
 
 
+def test_primed_batch_rungs_retrace_nothing(monkeypatch):
+    """Once the scheduler's rungs are primed through `extend_qc_sizes` and
+    one collect has built the layouts, device aggregations over other
+    query masks trace no program (`tpu_compile.retraces` stands still)."""
+    from elasticsearch_tpu.common import hbm_ledger
+
+    svc = _make_service(n=1200)
+    monkeypatch.setattr(agg_mod, "AGG_DEVICE_MIN_DOCS", 1)
+    agg_device.default_engine().extend_qc_sizes([1, 4, 16])
+    aggs = {"tags": {"terms": {"field": "tag"},
+                     "aggs": {"p": {"stats": {"field": "price"}}}},
+            "days": {"date_histogram": {"field": "ts",
+                                        "fixed_interval": "7d"}}}
+
+    def run(word):
+        return svc._search_dense({"query": {"match": {"body": word}},
+                                  "size": 0, "aggs": aggs})["aggregations"]
+
+    run("w0")                                # warm: layouts + first traces
+    before, r0 = _counts(), hbm_ledger.compile_stats()["retraces"]
+    dev = [run(w) for w in ("w1", "w2", "w3", "nosuchtoken")]
+    assert hbm_ledger.compile_stats()["retraces"] == r0
+    after = _counts()
+    assert after["agg_device_dispatches"] > before["agg_device_dispatches"]
+    assert after["agg_host_fallbacks"] == before["agg_host_fallbacks"]
+    monkeypatch.setattr(agg_mod, "AGG_DEVICE_MIN_DOCS", 1 << 60)
+    assert dev == [run(w) for w in ("w1", "w2", "w3", "nosuchtoken")]
+    svc.close()
+
+
 # ---------------------------------------------------------------------------
 # fallback + A/B + faults
 # ---------------------------------------------------------------------------

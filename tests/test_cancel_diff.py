@@ -5,8 +5,9 @@ differentially against never-cancelled references:
 
 - a cancelled task parked inside a scheduler lane raises
   TaskCancelledError at the flush boundary — it never fails the batch;
-- the co-batched peers of a cancelled waiter (scheduler AND legacy
-  coalescer) produce rows BIT-identical to solo execution;
+- the co-batched peers of a cancelled waiter, cancelled while parked or
+  while its batch is on the device, produce rows BIT-identical to solo
+  execution;
 - re-running the cancelled query under a fresh task matches the
   never-cancelled reference exactly;
 - a mixed round — injected ES_TPU_FAULTS device faults + a mid-park
@@ -23,7 +24,6 @@ import pytest
 from elasticsearch_tpu.common import faults
 from elasticsearch_tpu.tasks import TaskCancelledError, TaskManager
 from elasticsearch_tpu.tasks import task_manager as _taskmgr
-from elasticsearch_tpu.threadpool.coalescer import DispatchCoalescer
 from elasticsearch_tpu.threadpool.scheduler import AdaptiveDispatchScheduler
 
 pytestmark = [pytest.mark.multidevice]
@@ -82,9 +82,10 @@ def _rows_equal(got, want, label):
 
 
 def _run_round(dispatcher, eng, tm, cancel_idx=None, cancel_delay_s=0.05,
-               k=10):
+               k=10, canceller=None):
     """All QUERIES on their own threads under registered tasks, released
-    together; optionally cancel one task after it parks. Returns
+    together; optionally cancel one task after it parks, or hand the
+    tasks to `canceller`, which cancels when it sees fit. Returns
     (results, errors, tasks) aligned with QUERIES."""
     n = len(QUERIES)
     results, errors = [None] * n, [None] * n
@@ -109,6 +110,8 @@ def _run_round(dispatcher, eng, tm, cancel_idx=None, cancel_delay_s=0.05,
         barrier.wait(timeout=10)
         time.sleep(cancel_delay_s)      # let the waiters park in the lane
         tm.cancel(tasks[cancel_idx].id, "differential test")
+    if canceller is not None:
+        canceller(tasks)
     for t in threads:
         t.join(timeout=60)
     return results, errors, tasks
@@ -149,15 +152,41 @@ def test_cancel_parked_scheduler_waiter_spares_peers(eng, solo, monkeypatch):
     assert st["current"] == {}
 
 
-def test_cancel_in_flight_coalesced_batch_member(eng, solo, monkeypatch):
+def test_cancel_in_flight_batch_member(eng, solo, monkeypatch):
+    """A cancel that lands AFTER the flush, while the merged batch is on
+    the device: the batch runs to its end, the cancelled member alone
+    raises at its boundary, the peers read their solo rows."""
     monkeypatch.setenv("ES_TPU_COALESCE_US", "250000")
     tm = TaskManager("n")
-    co = DispatchCoalescer(window_us=250000.0)
-    results, errors, _ = _run_round(co, eng, tm, cancel_idx=1)
+    on_device, cancelled = threading.Event(), threading.Event()
+
+    class _Held:
+        """`eng`, its dispatch held on the device until the cancel lands."""
+
+        def search_many(self, batches, k=10, check=None):
+            on_device.set()
+            assert cancelled.wait(20)
+            return eng.search_many(batches, k=k)
+
+    # a bucket the round fills exactly: the flush is immediate
+    sched = AdaptiveDispatchScheduler(buckets=(len(QUERIES),),
+                                      interactive_us=250000.0,
+                                      bulk_us=250000.0)
+
+    def cancel_on_device(tasks):
+        assert on_device.wait(20)
+        tm.cancel(tasks[1].id, "differential test")
+        cancelled.set()
+
+    results, errors, _ = _run_round(sched, _Held(), tm,
+                                    canceller=cancel_on_device)
+    st = sched.stats()
+    assert st["sched_dispatches"] == 1 and st["sched_batch_retries"] == 0
     assert isinstance(errors[1], TaskCancelledError)
+    assert results[1] is None
     for i in (0, 2, 3):
         assert errors[i] is None
-        _rows_equal(results[i], solo[i], f"coalesced peer {i}")
+        _rows_equal(results[i], solo[i], f"in-flight peer {i}")
 
 
 def test_rerun_after_cancel_matches_never_cancelled_reference(

@@ -186,6 +186,28 @@ def test_ccs_skip_unavailable_dead_remote_degrades_to_skipped(two_clusters):
     assert F[0].remotes.stats()["skipped_clusters"] >= 1
 
 
+def test_ccs_skipped_remote_is_searched_again_after_heal(two_clusters):
+    """A skip_unavailable remote that was skipped while partitioned is a
+    full leg of the very next search once its nodes are back: nothing of
+    the degradation sticks."""
+    L, L_ch, F, _ = two_clusters
+    _seed_leader(L, "logs", n=8)
+    _seed_local(F, "local", n=4)
+    body = {"query": {"match_all": {}}, "size": 20}
+    for name in ("L-d0", "L-d1"):
+        L_ch.kill(name)
+    assert F[0].search("local,leader:logs", dict(body))[
+        "_clusters"]["skipped"] == 1
+    for name in ("L-d0", "L-d1"):
+        L_ch.revive(name)
+    r = F[0].search("local,leader:logs", dict(body))
+    c = r["_clusters"]
+    assert (c["total"], c["successful"], c["skipped"]) == (2, 2, 0)
+    assert r["hits"]["total"]["value"] == 12
+    assert {h["_id"] for h in r["hits"]["hits"]} == \
+        {f"l{i}" for i in range(4)} | {f"d{i}" for i in range(8)}
+
+
 def test_ccs_dead_remote_without_skip_unavailable_raises(two_clusters):
     L, L_ch, F, _ = two_clusters
     _seed_leader(L, "logs", n=4)

@@ -34,7 +34,7 @@ def test_default_is_the_checkouts_jax_cache(monkeypatch, restore_cache_dir):
         assert ".jax_cache/" in f.read().split()
 
 
-@pytest.mark.parametrize("path", ["bench.py", "chip_smoke.py",
+@pytest.mark.parametrize("path", ["__graft_entry__.py", "chip_smoke.py",
                                   "elasticsearch_tpu/__main__.py",
                                   "elasticsearch_tpu/node.py"])
 def test_entry_points_set_no_cache_path_of_their_own(path):

@@ -9,7 +9,7 @@ results.
 
 Also pins the circuit-breaker lifecycle (K consecutive faults open ->
 zero device dispatches while open -> half-open probe -> closed), the
-coalescer's poison-batch solo retry, and the serving-level
+scheduler's merged dispatch under a fault, and the serving-level
 `allow_partial_search_results` / `timeout` semantics.
 
 Runs on the host-simulated 8-device CPU mesh from tests/conftest.py
@@ -17,7 +17,6 @@ Runs on the host-simulated 8-device CPU mesh from tests/conftest.py
 """
 
 import logging
-import threading
 import time
 
 import numpy as np
@@ -291,113 +290,16 @@ def test_health_visible_in_node_stats_and_handler():
     assert mine and mine[0]["state"] == "open"
     assert node["open_circuits"] >= 1
     assert node["device_faults"] >= 1
-    from elasticsearch_tpu.rest.handlers import _tpu_health_stats
+    from elasticsearch_tpu.rest.handlers import (
+        _default_scheduler_stats, _tpu_health_stats,
+    )
 
     full = _tpu_health_stats()
     for key in ("engines", "open_circuits", "device_faults",
-                "fastpath_reject_error", "shard_fault_recoveries",
-                "coalesce_batch_retries"):
+                "fastpath_reject_error", "shard_fault_recoveries"):
         assert key in full
-
-
-# ---------------------------------------------------------------------------
-# coalescer: poison-batch solo retry
-# ---------------------------------------------------------------------------
-
-
-class _StubEngine:
-    """search_many stub: deterministic per-query rows; raises on merged
-    batches and/or on a poisoned query term."""
-
-    def __init__(self, fail_merged=False, poison=None):
-        self.fail_merged = fail_merged
-        self.poison = poison
-        self.calls = []
-
-    def search_many(self, batches, k=10, check=None):
-        qs = batches[0]
-        self.calls.append(len(qs))
-        if self.fail_merged and len(qs) > 1:
-            raise DeviceFaultError("poisoned merged batch",
-                                   site="turbo_sweep")
-        out_s = np.zeros((len(qs), k), np.float32)
-        out_p = np.zeros((len(qs), k), np.int32)
-        out_o = np.zeros((len(qs), k), np.int32)
-        for i, q in enumerate(qs):
-            if self.poison is not None and self.poison in q:
-                raise DeviceFaultError(f"query {q} is poison",
-                                       site="turbo_sweep")
-            out_s[i, 0] = float(len(q[0])) + 1.0
-            out_o[i, 0] = len(q[0])
-        return [(out_s, out_p, out_o)]
-
-
-def _concurrent(co, eng, queries, k=10):
-    results = [None] * len(queries)
-    errors = [None] * len(queries)
-    barrier = threading.Barrier(len(queries))
-
-    def worker(i, q):
-        try:
-            barrier.wait(timeout=10)
-            results[i] = co.dispatch(eng, [q], k)
-        except BaseException as e:  # noqa: BLE001 — asserted below
-            errors[i] = e
-
-    threads = [threading.Thread(target=worker, args=(i, q))
-               for i, q in enumerate(queries)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=60)
-    return results, errors
-
-
-def test_poison_batch_retries_each_waiter_solo():
-    from elasticsearch_tpu.threadpool.coalescer import DispatchCoalescer
-
-    eng = _StubEngine(fail_merged=True)
-    co = DispatchCoalescer(window_us=200000)
-    queries = [["a"], ["bb"], ["ccc"]]
-    results, errors = _concurrent(co, eng, queries)
-    assert errors == [None, None, None]
-    for q, r in zip(queries, results):
-        assert float(r[0][0, 0]) == len(q[0]) + 1.0, q
-    st = co.stats()
-    assert st["coalesce_batch_retries"] == 1
-    # one failed merged dispatch + one solo retry per waiter
-    assert sorted(eng.calls) == [1, 1, 1, 3]
-
-
-def test_poison_query_error_isolated_to_its_waiter():
-    from elasticsearch_tpu.threadpool.coalescer import DispatchCoalescer
-
-    eng = _StubEngine(poison="bad")
-    co = DispatchCoalescer(window_us=200000)
-    # the poison term kills merged AND its own solo retry; peers succeed
-    queries = [["good"], ["bad"], ["fine"]]
-    results, errors = _concurrent(co, eng, queries)
-    bad_i = queries.index(["bad"])
-    for i, (r, e) in enumerate(zip(results, errors)):
-        if i == bad_i:
-            assert isinstance(e, DeviceFaultError) and r is None
-        else:
-            assert e is None
-            assert float(r[0][0, 0]) == len(queries[i][0]) + 1.0
-    assert co.stats()["coalesce_batch_retries"] == 1
-
-
-def test_all_retries_failing_surfaces_original_error():
-    from elasticsearch_tpu.threadpool.coalescer import DispatchCoalescer
-
-    class _Dead:
-        def search_many(self, batches, k=10, check=None):
-            raise DeviceFaultError("engine is gone", site="turbo_sweep")
-
-    co = DispatchCoalescer(window_us=200000)
-    results, errors = _concurrent(co, _Dead(), [["a"], ["b"]])
-    assert results == [None, None]
-    assert all(isinstance(e, DeviceFaultError) for e in errors)
+    # a poisoned batch's solo retries are the scheduler's section's
+    assert "sched_batch_retries" in _default_scheduler_stats()
 
 
 # ---------------------------------------------------------------------------
@@ -500,19 +402,26 @@ def test_reject_errors_counted_and_logged_once(caplog):
     assert len(hits) == 1      # first occurrence logged, rest counted
 
 
-def test_coalesced_turbo_fault_bit_identical():
-    """Real engine through the coalescer under a one-shot fault: the
+def test_scheduled_turbo_fault_bit_identical(monkeypatch):
+    """Real engine through the scheduler under a one-shot fault: the
     merged dispatch contains the fault internally; rows stay identical
     to the solo host reference."""
-    from elasticsearch_tpu.threadpool.coalescer import DispatchCoalescer
+    from elasticsearch_tpu.threadpool.scheduler import (
+        AdaptiveDispatchScheduler,
+    )
+    from test_scheduler import _concurrent_sched
 
+    monkeypatch.setenv("ES_TPU_COALESCE_US", "200000")
     eng = _engine([(700, _pcorpus(700, 40, 11))], mesh=False)
     eng.search_many([BATCH], k=K)              # warm columns
-    co = DispatchCoalescer(window_us=200000)
+    sched = AdaptiveDispatchScheduler(buckets=(len(BATCH),),
+                                      interactive_us=200000.0)
     want = _host_many(eng, BATCH, K)
     with faults.inject("turbo_sweep:raise@1"):
-        results, errors = _concurrent(co, eng, BATCH)
+        results, errors = _concurrent_sched(sched, eng, BATCH)
     assert errors == [None] * len(BATCH)
+    st = sched.stats()
+    assert st["sched_dispatches"] == 1 and st["sched_batch_retries"] == 0
     for qi, r in enumerate(results):
         for j, name in enumerate(("scores", "parts", "ords")):
             assert np.array_equal(np.asarray(r[j][0]),

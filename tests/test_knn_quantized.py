@@ -132,6 +132,26 @@ def test_int8_fused_sharded_bit_identical():
     _assert_identical(got, _reference(cols, qs, K, "cosine"), "fused S=3")
 
 
+def test_int8_fused_primed_shape_retraces_nothing():
+    """Once a batch rung is primed through `extend_qc_sizes` and warmed,
+    a fused kNN dispatch inside that rung traces no program
+    (`tpu_compile.retraces` stands still) and still takes the int8 pass."""
+    from elasticsearch_tpu.common import hbm_ledger
+
+    cols = _cols([2500, 1800, 2100], seed=17)
+    works = [KnnWork(q) for q in _queries(24, seed=5)]
+    eng = KnnEngine(cols, mesh=make_mesh(4, dp=1))
+    eng.extend_qc_sizes([32])
+    want = eng.search_many([works], k=K)[0]      # warm pass: first trace
+    r0 = hbm_ledger.compile_stats()["retraces"]
+    knn_mod.reset_for_tests()
+    got = eng.search_many([works], k=K)[0]
+    assert hbm_ledger.compile_stats()["retraces"] == r0
+    assert knn_mod.knn_node_stats()["knn_int8_dispatches"] > 0
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_int8_off_ab_identical(monkeypatch):
     """ES_TPU_KNN_INT8=0 serves the same bits through the dense f32
     route with zero int8 dispatches."""

@@ -1,19 +1,20 @@
 """Continuous-batching dispatch scheduler differential suite (PR 10).
 
-The AdaptiveDispatchScheduler replaces the fixed-window coalescer as the
-serving dispatch path; the contracts under test:
+The AdaptiveDispatchScheduler is the one serving dispatch path; the
+contracts under test:
 
 - merged rows are BIT-identical to solo execution across bucket shapes,
-  engines (turbo + blockmax on the interpret-mode CPU mesh), and under
-  injected device faults (PR 5 containment semantics);
+  engines (fused and one-partition turbo + blockmax on the interpret-mode
+  CPU mesh, a stub), and under injected device faults (PR 5 containment
+  semantics);
 - SLA tiers: an interactive query never waits past its budget behind a
   deep bulk backlog (the interactive deadline triggers the flush, bulk
   rides the pad slack);
 - double buffering: a second batch dispatches while the first batch's
   waiter is still demuxing (slot-1 held), and does NOT with one slot;
-- poison-batch solo retry parity with the coalescer;
-- `ES_TPU_SCHED_MODE=legacy` routes through the old coalescer and
-  `ES_TPU_COALESCE_US=0` disables batching in both modes.
+- a poisoned batch is retried solo per query, on a stub and on a real
+  Turbo engine whose merged dispatch fails;
+- `ES_TPU_COALESCE_US=0` disables batching.
 """
 
 import threading
@@ -25,11 +26,10 @@ import pytest
 from elasticsearch_tpu.common import faults, metrics
 from elasticsearch_tpu.common.errors import DeviceFaultError
 from elasticsearch_tpu.threadpool import ThreadPool, tier_for_request
-from elasticsearch_tpu.threadpool.coalescer import default_coalescer
 from elasticsearch_tpu.threadpool.scheduler import (
     DEFAULT_BUCKETS, TIER_BULK, TIER_INTERACTIVE, AdaptiveDispatchScheduler,
     _Lane, _parse_buckets, _Waiter, activate_tier, current_tier,
-    default_scheduler, scheduler_stats, serving_dispatch,
+    default_scheduler,
 )
 
 pytestmark = [pytest.mark.multidevice]
@@ -41,7 +41,8 @@ QUERIES = [["alpha"], ["beta", "gamma"], ["delta"], ["pi", "omicron"],
            ["mu", "nu", "xi"], ["kappa"], ["theta", "iota"], ["zeta", "eta"]]
 
 
-def _build_index(monkeypatch, *, turbo: bool, uuid: str):
+def _build_index(monkeypatch, *, turbo: bool, uuid: str,
+                 one_segment: bool = False):
     from elasticsearch_tpu.cluster.state import IndexMetadata
     from elasticsearch_tpu.common.settings import Settings
     from elasticsearch_tpu.index.index_service import IndexService
@@ -57,7 +58,7 @@ def _build_index(monkeypatch, *, turbo: bool, uuid: str):
     for i in range(320):
         words = rng.choice(WORDS, size=int(rng.integers(3, 16)))
         svc.index_doc(str(i), {"body": " ".join(words)})
-        if i == 140:
+        if i == 140 and not one_segment:
             svc.refresh()
     for i in range(0, 50, 9):
         svc.delete_doc(str(i))
@@ -100,30 +101,64 @@ def _assert_rows_equal(got, want, ctx):
 
 
 class _StubEngine:
-    """search_many stub: deterministic per-query rows; optionally raises
-    on merged batches / a poisoned query term / blocks on a gate."""
+    """search_many stub: deterministic per-query rows."""
 
-    def __init__(self, fail_merged=False, poison=None):
-        self.fail_merged = fail_merged
-        self.poison = poison
+    kind = "stub"
+
+    def __init__(self):
         self.calls = []
 
     def search_many(self, batches, k=10, check=None):
         qs = batches[0]
         self.calls.append(len(qs))
-        if self.fail_merged and len(qs) > 1:
-            raise DeviceFaultError("poisoned merged batch",
-                                   site="turbo_sweep")
         out_s = np.zeros((len(qs), k), np.float32)
         out_p = np.zeros((len(qs), k), np.int32)
         out_o = np.zeros((len(qs), k), np.int32)
         for i, q in enumerate(qs):
-            if self.poison is not None and self.poison in q:
-                raise DeviceFaultError(f"query {q} is poison",
-                                       site="turbo_sweep")
             out_s[i, 0] = float(len(q[0])) + 1.0
             out_o[i, 0] = len(q[0])
         return [(out_s, out_p, out_o)]
+
+
+class _Poisoned:
+    """An engine whose device dispatch fails as `fails(queries)` says (a
+    merged batch, a poison term, always); what it does answer are the
+    wrapped engine's own rows."""
+
+    def __init__(self, inner, fails):
+        self.inner = inner
+        self.fails = fails
+        self.calls = []
+
+    def search_many(self, batches, k=10, check=None):
+        qs = batches[0]
+        self.calls.append(len(qs))
+        if self.fails(qs):
+            raise DeviceFaultError(f"poisoned dispatch of {qs}",
+                                   site="turbo_sweep")
+        return self.inner.search_many(batches, k=k)
+
+
+@pytest.fixture(scope="module")
+def turbo_eng():
+    """One fused Turbo engine (two partitions) for the module's
+    containment tests."""
+    with pytest.MonkeyPatch.context() as mp:   # the knobs: build only
+        svc = _build_index(mp, turbo=True, uuid="u_sc_mod")
+        eng = svc.serving.snapshot().engine("body")
+    try:
+        assert eng.kind == "turbo"
+        yield eng
+    finally:
+        svc.close()
+
+
+@pytest.fixture(params=["stub", "turbo"])
+def inner_eng(request):
+    """The engine under a `_Poisoned` wrapper: the stub, or the real one."""
+    if request.param == "stub":
+        return _StubEngine()
+    return request.getfixturevalue("turbo_eng")
 
 
 # ---------------------------------------------------------------------------
@@ -249,13 +284,24 @@ def test_build_batch_flush_rules():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("turbo", [True, False], ids=["turbo", "blockmax"])
-def test_scheduled_rows_bit_identical_to_solo(monkeypatch, turbo):
+@pytest.mark.parametrize("kind", ["turbo", "turbo_solo", "blockmax", "stub"])
+def test_scheduled_rows_bit_identical_to_solo(monkeypatch, kind):
+    """Eight concurrent singles merged by the scheduler read the rows
+    solo execution reads, on every engine kind: the fused two-partition
+    Turbo engine, the one-partition one, BlockMax, and a stub whose rows
+    differ by query (the demux offsets alone)."""
     monkeypatch.setenv("ES_TPU_COALESCE_US", "300000")
-    svc = _build_index(monkeypatch, turbo=turbo, uuid="u_sc1" + str(turbo))
+    svc = None if kind == "stub" else _build_index(
+        monkeypatch, turbo=kind != "blockmax", uuid="u_sc1" + kind,
+        one_segment=kind == "turbo_solo")
     try:
-        eng = svc.serving.snapshot().engine("body")
-        assert eng.kind == ("turbo" if turbo else "blockmax")
+        if svc is None:
+            eng = _StubEngine()
+        else:
+            eng = svc.serving.snapshot().engine("body")
+            assert eng.kind == ("blockmax" if kind == "blockmax" else "turbo")
+            assert len(getattr(eng, "turbos", [None])) == \
+                (2 if kind == "turbo" else 1)
         solo = [eng.search_many([[q]], k=10)[0] for q in QUERIES]
 
         # generous budgets + a ladder topping at len(QUERIES): all eight
@@ -285,7 +331,8 @@ def test_scheduled_rows_bit_identical_to_solo(monkeypatch, turbo):
         assert st0["sched_queries"] == len(QUERIES)
         assert 1 <= st0["sched_dispatches"] <= len(QUERIES)
     finally:
-        svc.close()
+        if svc is not None:
+            svc.close()
 
 
 def test_scheduler_primes_engine_bucket_shapes(monkeypatch):
@@ -431,50 +478,56 @@ def test_single_slot_serializes_behind_unconsumed_batch():
 
 
 # ---------------------------------------------------------------------------
-# poison-batch containment parity with the coalescer
+# poison-batch containment: a failed merged dispatch is retried solo, on a
+# stub and on a real Turbo engine (`inner_eng`)
 # ---------------------------------------------------------------------------
 
+POISON_QUERIES = [["alpha"], ["beta", "gamma"], ["delta"]]
 
-def test_poison_batch_retries_each_waiter_solo():
-    eng = _StubEngine(fail_merged=True)
-    sched = AdaptiveDispatchScheduler(buckets=(3,), interactive_us=400000.0,
-                                      bulk_us=400000.0)
-    queries = [["a"], ["bb"], ["ccc"]]
-    results, errors = _concurrent_sched(sched, eng, queries)
+
+def _poison_sched():
+    return AdaptiveDispatchScheduler(buckets=(len(POISON_QUERIES),),
+                                     interactive_us=400000.0,
+                                     bulk_us=400000.0)
+
+
+def test_poison_batch_retries_each_waiter_solo(inner_eng):
+    eng = _Poisoned(inner_eng, lambda qs: len(qs) > 1)
+    solo = [inner_eng.search_many([[q]], k=10)[0] for q in POISON_QUERIES]
+    sched = _poison_sched()
+    results, errors = _concurrent_sched(sched, eng, POISON_QUERIES)
     assert errors == [None, None, None]
-    for q, r in zip(queries, results):
-        assert float(r[0][0, 0]) == len(q[0]) + 1.0, q
+    for q, got, want in zip(POISON_QUERIES, results, solo):
+        _assert_rows_equal(got, want, f"solo retry {q}")
     assert sched.stats()["sched_batch_retries"] == 1
     # one failed merged dispatch + one solo retry per waiter
     assert sorted(eng.calls) == [1, 1, 1, 3]
 
 
-def test_poison_query_error_isolated_to_its_waiter():
-    eng = _StubEngine(poison="bad")
-    sched = AdaptiveDispatchScheduler(buckets=(3,), interactive_us=400000.0,
-                                      bulk_us=400000.0)
-    queries = [["good"], ["bad"], ["fine"]]
-    results, errors = _concurrent_sched(sched, eng, queries)
-    bad_i = queries.index(["bad"])
+def test_poison_query_error_isolated_to_its_waiter(inner_eng):
+    # the poison term kills merged AND its own solo retry; peers succeed
+    eng = _Poisoned(inner_eng, lambda qs: any("beta" in q for q in qs))
+    solo = [inner_eng.search_many([[q]], k=10)[0] for q in POISON_QUERIES]
+    sched = _poison_sched()
+    results, errors = _concurrent_sched(sched, eng, POISON_QUERIES)
     for i, (r, e) in enumerate(zip(results, errors)):
-        if i == bad_i:
+        if "beta" in POISON_QUERIES[i]:
             assert isinstance(e, DeviceFaultError) and r is None
         else:
             assert e is None
-            assert float(r[0][0, 0]) == len(queries[i][0]) + 1.0
+            _assert_rows_equal(r, solo[i], f"peer {POISON_QUERIES[i]}")
     assert sched.stats()["sched_batch_retries"] == 1
 
 
-def test_all_retries_failing_surfaces_original_error():
-    class _Dead:
-        def search_many(self, batches, k=10, check=None):
-            raise DeviceFaultError("engine is gone", site="turbo_sweep")
-
-    sched = AdaptiveDispatchScheduler(buckets=(2,), interactive_us=400000.0,
-                                      bulk_us=400000.0)
-    results, errors = _concurrent_sched(sched, _Dead(), [["a"], ["b"]])
-    assert results == [None, None]
+def test_all_retries_failing_surfaces_original_error(inner_eng):
+    eng = _Poisoned(inner_eng, lambda qs: True)
+    sched = _poison_sched()
+    results, errors = _concurrent_sched(sched, eng, POISON_QUERIES)
+    assert results == [None, None, None]
     assert all(isinstance(e, DeviceFaultError) for e in errors)
+    # every waiter sees the ORIGINAL (merged) failure, not its retry's
+    assert all("alpha" in str(e) and "delta" in str(e) for e in errors)
+    assert sorted(eng.calls) == [1, 1, 1, 3]
 
 
 @pytest.mark.faults
@@ -483,8 +536,7 @@ def test_scheduler_contains_injected_device_fault(monkeypatch):
     dispatch: the serving engine's fused dispatch faults AND any
     per-partition turbo_sweep fallback faults too, so PR 5 containment
     re-scores the work through the host tier — rows stay bit-identical
-    and the FaultRecords are ferried to EVERY waiter's fault_log
-    (coalescer parity)."""
+    and the FaultRecords are ferried to EVERY waiter's fault_log."""
     monkeypatch.setenv("ES_TPU_COALESCE_US", "300000")
     svc = _build_index(monkeypatch, turbo=True, uuid="u_sc_flt")
     try:
@@ -512,31 +564,8 @@ def test_scheduler_contains_injected_device_fault(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# mode routing: legacy shim + window-0 kill switch
+# the window-0 kill switch
 # ---------------------------------------------------------------------------
-
-
-def test_legacy_mode_routes_through_coalescer(monkeypatch):
-    eng = _StubEngine()
-    monkeypatch.setenv("ES_TPU_COALESCE_US", "0")   # direct: no threads
-    monkeypatch.setenv("ES_TPU_SCHED_MODE", "legacy")
-    co_before = default_coalescer().stats()["direct_dispatches"]
-    sc_before = default_scheduler().stats()["direct_dispatches"]
-    modes_before = scheduler_stats()["mode_dispatches"]
-    serving_dispatch(eng, [["a"]], 10)
-    assert default_coalescer().stats()["direct_dispatches"] == co_before + 1
-    assert default_scheduler().stats()["direct_dispatches"] == sc_before
-    st = scheduler_stats()
-    assert st["mode"] == "legacy"
-    assert st["mode_dispatches"]["legacy"] == modes_before["legacy"] + 1
-
-    monkeypatch.setenv("ES_TPU_SCHED_MODE", "adaptive")
-    serving_dispatch(eng, [["b"]], 10)
-    assert default_scheduler().stats()["direct_dispatches"] == sc_before + 1
-    assert default_coalescer().stats()["direct_dispatches"] == co_before + 1
-    assert scheduler_stats()["mode_dispatches"]["adaptive"] \
-        == modes_before["adaptive"] + 1
-    assert eng.calls == [1, 1]
 
 
 def test_window_zero_disables_batching_entirely(monkeypatch):
@@ -558,13 +587,16 @@ def test_window_zero_disables_batching_entirely(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def test_serving_path_batches_through_scheduler(monkeypatch):
-    """End to end through ServingContext.try_search in adaptive mode:
-    concurrent REST-level singles return the same responses as solo
-    execution and the process-default SCHEDULER (not the coalescer)
-    reports the merged device dispatches."""
-    svc = _build_index(monkeypatch, turbo=True, uuid="u_sc_e2e")
+@pytest.mark.parametrize("turbo", [True, False], ids=["turbo", "blockmax"])
+def test_serving_path_batches_through_scheduler(monkeypatch, turbo):
+    """End to end through ServingContext.try_search: concurrent
+    REST-level singles return the same responses as solo execution and
+    the process-default scheduler reports the merged device dispatches,
+    whichever engine the snapshot selected."""
+    svc = _build_index(monkeypatch, turbo=turbo, uuid="u_sc_e2e" + str(turbo))
     try:
+        assert svc.serving.snapshot().engine("body").kind == \
+            ("turbo" if turbo else "blockmax")
         bodies = [{"query": {"match": {"body": " ".join(q)}}}
                   for q in QUERIES]
         monkeypatch.setenv("ES_TPU_COALESCE_US", "0")
@@ -572,7 +604,6 @@ def test_serving_path_batches_through_scheduler(monkeypatch):
                 for b in bodies]
         assert all(w is not None for w in want)
 
-        monkeypatch.setenv("ES_TPU_SCHED_MODE", "adaptive")
         monkeypatch.setenv("ES_TPU_COALESCE_US", "300000")
         monkeypatch.setenv("ES_TPU_SCHED_BUCKETS", str(len(bodies)))
         monkeypatch.setenv("ES_TPU_SCHED_INTERACTIVE_US", "300000")

@@ -1,11 +1,11 @@
-"""Named bounded executors + TPU dispatch coalescer (threadpool/).
+"""Named bounded executors + the dispatch scheduler's lanes (threadpool/).
 
 Admission control: saturating one named pool rejects with 429
 `es_rejected_execution_exception` (pool name in the reason) without
-affecting the other pools. Coalescing: concurrent single-query searches
-on the same engine merge into ONE device dispatch whose de-multiplexed
-rows are BIT-identical to solo execution — across turbo and blockmax
-engines, and under a mid-window snapshot refresh (engine swap).
+affecting the other pools. Lane keying: searches never share a device
+dispatch across top-k depths or across the engine swap of a snapshot
+refresh, and each reads rows BIT-identical to solo execution (the merged
+rows' own suite is tests/test_scheduler.py).
 """
 
 import json
@@ -14,19 +14,14 @@ import time
 import urllib.error
 import urllib.request
 
-import numpy as np
 import pytest
 
 from elasticsearch_tpu.threadpool import (
-    DispatchCoalescer, EsRejectedExecutionError, ThreadPool,
-    default_coalescer, pool_for_request,
+    AdaptiveDispatchScheduler, EsRejectedExecutionError, ThreadPool,
+    pool_for_request,
 )
 
-WORDS = ["alpha", "beta", "gamma", "delta", "epsilon", "zeta", "eta",
-         "theta", "iota", "kappa", "lam", "mu", "nu", "xi", "omicron", "pi"]
-
-QUERIES = [["alpha"], ["beta", "gamma"], ["delta"], ["pi", "omicron"],
-           ["mu", "nu", "xi"], ["kappa"], ["theta", "iota"], ["zeta", "eta"]]
+from test_scheduler import _assert_rows_equal, _build_index
 
 
 def tiny_pool(**overrides):
@@ -54,6 +49,29 @@ def test_submit_executes_and_counts():
         assert st["queue"] == 0 and st["active"] == 0
         assert 1 <= st["largest"] <= 2
         assert st["ewma_ms"] >= 0.0
+    finally:
+        pool.shutdown()
+
+
+def test_a_result_in_hand_is_already_counted():
+    """The worker counts a task before it publishes the result: the
+    earliest a caller can hold a result, the counters include it."""
+    pool = ThreadPool(sizes={"search": 1})
+    at_publish = []
+
+    class _Publish(threading.Event):
+        def set(self):
+            at_publish.append(pool.stats()["search"])
+            super().set()
+
+    try:
+        gate = threading.Event()
+        task = pool.submit("search", gate.wait, 10)
+        task._done = _Publish()
+        gate.set()
+        assert task.get(timeout=10) is True
+        assert [(st["completed"], st["active"]) for st in at_publish] \
+            == [(1, 0)]
     finally:
         pool.shutdown()
 
@@ -211,106 +229,32 @@ def test_http_server_sheds_load_with_429():
 
 
 # ---------------------------------------------------------------------------
-# dispatch coalescer: bit-identity with solo execution
+# scheduler lanes: keyed by (engine, k), bit-identical to solo execution
 # ---------------------------------------------------------------------------
 
 
-def _build_index(monkeypatch, *, turbo: bool, uuid: str):
-    from elasticsearch_tpu.cluster.state import IndexMetadata
-    from elasticsearch_tpu.common.settings import Settings
-    from elasticsearch_tpu.index.index_service import IndexService
-
-    if turbo:
-        monkeypatch.setenv("ES_TPU_FORCE_TURBO", "1")
-        monkeypatch.setenv("ES_TPU_TURBO_COLD_DF", "8")
-    meta = IndexMetadata(
-        index="co_" + uuid, uuid=uuid, settings=Settings({}),
-        mappings={"properties": {"body": {"type": "text"}}})
-    svc = IndexService(meta)
-    rng = np.random.default_rng(99)
-    for i in range(320):
-        words = rng.choice(WORDS, size=int(rng.integers(3, 16)))
-        svc.index_doc(str(i), {"body": " ".join(words)})
-        if i == 140:
-            svc.refresh()
-    for i in range(0, 50, 9):
-        svc.delete_doc(str(i))
-    svc.refresh()
-    return svc
-
-
-def _concurrent_dispatch(co, eng, queries, k=10):
-    """Each query on its own thread, all released together."""
-    results = [None] * len(queries)
-    errors = []
-    barrier = threading.Barrier(len(queries))
-
-    def worker(i, q):
-        try:
-            barrier.wait(timeout=10)
-            results[i] = co.dispatch(eng, [q], k)
-        except BaseException as e:  # noqa: BLE001 — surfaced below
-            errors.append(e)
-
-    threads = [threading.Thread(target=worker, args=(i, q))
-               for i, q in enumerate(queries)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=60)
-    assert not errors, errors
-    return results
-
-
-def _assert_rows_equal(got, want, ctx):
-    gs, gp, go = got
-    ws, wp, wo = want
-    assert np.array_equal(gs, ws), ctx
-    assert np.array_equal(gp, wp), ctx
-    assert np.array_equal(go, wo), ctx
-
-
-@pytest.mark.parametrize("turbo", [True, False], ids=["turbo", "blockmax"])
-def test_coalesced_rows_bit_identical_to_solo(monkeypatch, turbo):
-    svc = _build_index(monkeypatch, turbo=turbo, uuid="u_co1" + str(turbo))
-    try:
-        eng = svc.serving.snapshot().engine("body")
-        assert eng.kind == ("turbo" if turbo else "blockmax")
-        solo = [eng.search_many([[q]], k=10)[0] for q in QUERIES]
-        co = DispatchCoalescer(window_us=500_000, max_batch=len(QUERIES))
-        results = _concurrent_dispatch(co, eng, QUERIES)
-        for q, got, want in zip(QUERIES, results, solo):
-            _assert_rows_equal(
-                (got[0][0], got[1][0], got[2][0]),
-                (want[0][0], want[1][0], want[2][0]), q)
-        st = co.stats()
-        assert st["coalesced_queries"] == len(QUERIES)
-        # merging actually happened (a full barrier + 500ms window makes
-        # fewer dispatches than queries all but certain)
-        assert st["coalesced_dispatches"] < len(QUERIES)
-        assert st["largest_batch"] > 1
-    finally:
-        svc.close()
-
-
-def test_coalescer_keys_by_k_and_window_zero_disables(monkeypatch):
+def test_scheduler_keys_by_k_and_window_zero_disables(monkeypatch):
     svc = _build_index(monkeypatch, turbo=False, uuid="u_co2")
     try:
         eng = svc.serving.snapshot().engine("body")
-        co = DispatchCoalescer(window_us=0)
-        s, p, o = co.dispatch(eng, [["alpha"]], 10)
+        monkeypatch.setenv("ES_TPU_COALESCE_US", "0")
+        sched = AdaptiveDispatchScheduler()
+        s, p, o = sched.dispatch(eng, [["alpha"]], 10)
         want_s, want_p, want_o = eng.search_many([[["alpha"]]], k=10)[0]
         _assert_rows_equal((s[0], p[0], o[0]),
                            (want_s[0], want_p[0], want_o[0]), "win0")
-        assert co.stats()["coalesced_dispatches"] == 0
-        assert co.stats()["direct_dispatches"] == 1
+        assert sched.stats()["sched_dispatches"] == 0
+        assert sched.stats()["direct_dispatches"] == 1
 
-        # different k values never share a device dispatch
-        co2 = DispatchCoalescer(window_us=50_000)
+        # different k values never share a device dispatch: each k is a
+        # lane of its own, however long the two wait side by side
+        monkeypatch.setenv("ES_TPU_COALESCE_US", "50000")
+        sched2 = AdaptiveDispatchScheduler(buckets=(2,),
+                                           interactive_us=50_000.0)
         out = {}
 
         def run(k):
-            out[k] = co2.dispatch(eng, [["beta", "gamma"]], k)
+            out[k] = sched2.dispatch(eng, [["beta", "gamma"]], k)
 
         ts = [threading.Thread(target=run, args=(k,)) for k in (5, 10)]
         for t in ts:
@@ -322,31 +266,37 @@ def test_coalescer_keys_by_k_and_window_zero_disables(monkeypatch):
             _assert_rows_equal((out[k][0][0], out[k][1][0], out[k][2][0]),
                                (want[0][0], want[1][0], want[2][0]), k)
             assert out[k][0].shape == (1, k)
+        st = sched2.stats()
+        assert st["sched_dispatches"] == 2 and st["largest_batch"] == 1
     finally:
         svc.close()
 
 
-def test_mid_window_engine_swap_keeps_batches_separate(monkeypatch):
-    """A snapshot refresh mid-window swaps the engine object: waiters on
-    the OLD engine finish on the snapshot they captured, new arrivals key
-    onto the new engine — both bit-identical to solo execution."""
+def test_mid_flight_engine_swap_keeps_lanes_separate(monkeypatch):
+    """A snapshot refresh while a waiter is parked swaps the engine
+    object: waiters on the OLD engine finish on the snapshot they
+    captured, new arrivals key onto the new engine's lane — both
+    bit-identical to solo execution."""
     svc = _build_index(monkeypatch, turbo=True, uuid="u_co3")
     try:
+        monkeypatch.setenv("ES_TPU_COALESCE_US", "400000")
         snap1 = svc.serving.snapshot()
         eng1 = snap1.engine("body")
         solo1 = eng1.search_many([[["alpha"]]], k=10)[0]
 
-        co = DispatchCoalescer(window_us=400_000)
+        # a bucket two singles would fill, budgets they have to wait out:
+        # were the two engines one lane, they would share ONE dispatch
+        sched = AdaptiveDispatchScheduler(buckets=(2,),
+                                          interactive_us=400_000.0)
         got1 = {}
 
         def old_engine_waiter():
-            got1["rows"] = co.dispatch(eng1, [["alpha"]], 10)
+            got1["rows"] = sched.dispatch(eng1, [["alpha"]], 10)
 
         t = threading.Thread(target=old_engine_waiter)
         t.start()
-        deadline = time.monotonic() + 5       # old-engine batch is pending
-        while co.stats()["coalesced_dispatches"] == 0 \
-                and not co._pending and time.monotonic() < deadline:
+        deadline = time.monotonic() + 5       # old-engine waiter is parked
+        while sched.stats()["lanes"] == 0 and time.monotonic() < deadline:
             time.sleep(0.005)
 
         # refresh swaps the serving snapshot -> NEW engine object
@@ -355,7 +305,7 @@ def test_mid_window_engine_swap_keeps_batches_separate(monkeypatch):
         snap2 = svc.serving.snapshot()
         eng2 = snap2.engine("body")
         assert eng2 is not eng1
-        rows2 = co.dispatch(eng2, [["alpha"]], 10)
+        rows2 = sched.dispatch(eng2, [["alpha"]], 10)
         t.join(timeout=60)
 
         _assert_rows_equal(
@@ -365,56 +315,7 @@ def test_mid_window_engine_swap_keeps_batches_separate(monkeypatch):
         _assert_rows_equal((rows2[0][0], rows2[1][0], rows2[2][0]),
                            (solo2[0][0], solo2[1][0], solo2[2][0]),
                            "new engine")
-        assert co.stats()["coalesced_dispatches"] == 2
-    finally:
-        svc.close()
-
-
-def test_serving_path_coalesces_concurrent_searches(monkeypatch):
-    """End to end through ServingContext.try_search: concurrent REST-level
-    singles produce the same responses as sequential solo execution, and
-    the process-default coalescer reports merged device dispatches."""
-    svc = _build_index(monkeypatch, turbo=True, uuid="u_co4")
-    try:
-        # pin the legacy fixed-window dispatch path: this test asserts the
-        # old coalescer's stats move; the adaptive scheduler has its own
-        # suite in test_scheduler.py
-        monkeypatch.setenv("ES_TPU_SCHED_MODE", "legacy")
-        bodies = [{"query": {"match": {"body": " ".join(q)}}}
-                  for q in QUERIES]
-        monkeypatch.setenv("ES_TPU_COALESCE_US", "0")
-        want = [svc.serving.try_search(b, "query_then_fetch")
-                for b in bodies]
-        assert all(w is not None for w in want)
-
-        monkeypatch.setenv("ES_TPU_COALESCE_US", "300000")
-        before = default_coalescer().stats()["coalesced_dispatches"]
-        got = [None] * len(bodies)
-        errors = []
-        barrier = threading.Barrier(len(bodies))
-
-        def worker(i, b):
-            try:
-                barrier.wait(timeout=10)
-                got[i] = svc.serving.try_search(b, "query_then_fetch")
-            except BaseException as e:  # noqa: BLE001
-                errors.append(e)
-
-        threads = [threading.Thread(target=worker, args=(i, b))
-                   for i, b in enumerate(bodies)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=60)
-        assert not errors, errors
-        merged = default_coalescer().stats()["coalesced_dispatches"] - before
-        assert 1 <= merged < len(bodies)
-        for b, g, w in zip(bodies, got, want):
-            assert g is not None, b
-            assert [h["_id"] for h in g["hits"]["hits"]] == \
-                [h["_id"] for h in w["hits"]["hits"]], b
-            assert [h["_score"] for h in g["hits"]["hits"]] == \
-                [h["_score"] for h in w["hits"]["hits"]], b
-            assert g["hits"]["total"] == w["hits"]["total"], b
+        st = sched.stats()
+        assert st["sched_dispatches"] == 2 and st["largest_batch"] == 1
     finally:
         svc.close()

@@ -153,7 +153,7 @@ def test_registry_strict_and_lenient():
     metrics.observe("device", 3.0)
     assert metrics.summary("device")["count"] == 1
     stats = metrics.search_latency_stats()
-    for name in ("queue_wait.search", "coalesce_wait", "device", "demux",
+    for name in ("queue_wait.search", "device", "demux",
                  "fetch", "query", "merge", "rest_total",
                  "coalesce_batch_size", "coalesce_pad_ratio"):
         assert name in stats and "p99" in stats[name]

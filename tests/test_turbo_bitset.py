@@ -165,6 +165,28 @@ def test_bitset_fused_bit_identical(monkeypatch):
                                + st.hbm_bytes())
 
 
+def test_bitset_fused_primed_shape_retraces_nothing(monkeypatch):
+    """Once a batch width is primed through `extend_qc_sizes` and warmed,
+    a fused bool dispatch of that width traces no program
+    (`tpu_compile.retraces` stands still): what the scheduler's ladder
+    priming promises the served path."""
+    from elasticsearch_tpu.common import hbm_ledger
+
+    monkeypatch.setenv("ES_TPU_BITSET", "1")
+    monkeypatch.setenv("ES_TPU_BITSET_HOST_DF", "0")
+    eng = _fused([(1500, _pcorpus(1500, 40, 1)),
+                  (900, _pcorpus(900, 56, 2))])
+    eng.extend_qc_sizes([len(SPECS)])
+    eng._fused()
+    eng.extend_qc_sizes([len(SPECS)])    # the lazily built fused dispatcher
+    want = eng.search_bool(SPECS, k=K)   # warm pass: packs, first trace
+    r0 = hbm_ledger.compile_stats()["retraces"]
+    got = eng.search_bool(SPECS, k=K)
+    assert hbm_ledger.compile_stats()["retraces"] == r0
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_bitset_fused_after_disjunctive_traffic(monkeypatch):
     """The order a served index sees: match traffic builds and syncs the
     columns first, the first bool packs the bitsets later. The fused

@@ -270,7 +270,7 @@ def test_fused_k_exceeds_partition_candidates():
 
 
 # ---------------------------------------------------------------------------
-# serving selection + coalescer stability for the sharded engine
+# serving selection + lane-key stability for the sharded engine
 # ---------------------------------------------------------------------------
 
 
@@ -305,20 +305,24 @@ def test_turbo_mesh_env_disable(monkeypatch):
     assert m is not None and m.devices.size == 2
 
 
-def test_sharded_engine_coalescer_rows_and_keys():
-    """Satellite 4: the coalescer serves the SHARDED TurboEngine with
-    rows bit-identical to solo dispatch, and its batch keying stays
-    stable — one serial per engine object, distinct across the engine
-    swap a mid-window snapshot refresh performs."""
-    from elasticsearch_tpu.threadpool.coalescer import (DispatchCoalescer,
-                                                        _engine_key)
+def test_sharded_engine_scheduler_rows_and_keys(monkeypatch):
+    """The scheduler serves the SHARDED TurboEngine with rows
+    bit-identical to solo dispatch, and its lane keying stays stable —
+    one serial per engine object, distinct across the engine swap a
+    snapshot refresh performs."""
+    from elasticsearch_tpu.threadpool.scheduler import (
+        AdaptiveDispatchScheduler, _engine_key,
+    )
+
+    monkeypatch.setenv("ES_TPU_COALESCE_US", "400000")
 
     eng = _fused_engine([(600, _pcorpus(600, 30, 47)),
                          (500, _pcorpus(500, 30, 53))])
     queries = [["t0", "t1"], ["t2"], ["t1", "t3"], ["t4"]]
     solo = [eng.search_many([[q]], k=10)[0] for q in queries]
 
-    co = DispatchCoalescer(window_us=400_000, max_batch=len(queries))
+    sched = AdaptiveDispatchScheduler(buckets=(len(queries),),
+                                      interactive_us=400_000.0)
     results = [None] * len(queries)
     errors = []
     barrier = threading.Barrier(len(queries))
@@ -326,7 +330,7 @@ def test_sharded_engine_coalescer_rows_and_keys():
     def worker(i, q):
         try:
             barrier.wait(timeout=10)
-            results[i] = co.dispatch(eng, [q], 10)
+            results[i] = sched.dispatch(eng, [q], 10)
         except BaseException as e:  # noqa: BLE001 — surfaced below
             errors.append(e)
 
@@ -340,11 +344,11 @@ def test_sharded_engine_coalescer_rows_and_keys():
     for q, got, want in zip(queries, results, solo):
         _assert_rows_equal((got[0][0], got[1][0], got[2][0]),
                            (want[0][0], want[1][0], want[2][0]), q)
-    assert co.stats()["largest_batch"] > 1        # merging happened
+    assert sched.stats()["largest_batch"] > 1     # merging happened
 
     # keying: stable per object, distinct across objects — a refreshed
     # snapshot's NEW engine (even one landing at the same id() after the
-    # old is collected) can never join the old engine's batch
+    # old is collected) can never join the old engine's lane
     k1, k1b = _engine_key(eng), _engine_key(eng)
     assert k1 == k1b
     eng2 = type(eng)(eng.turbos, mesh=eng.mesh)   # refreshed wrapper

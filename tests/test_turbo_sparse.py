@@ -112,6 +112,29 @@ def test_sparse_bool_bit_identical():
     assert t.stats["cold_queries"] == 0
 
 
+def test_sparse_fused_primed_shape_retraces_nothing():
+    """Once a batch width is primed through `extend_qc_sizes` and a warm
+    pass has built the cold terms' slices, a fused disjunctive dispatch
+    of that width traces no program (`tpu_compile.retraces` stands
+    still), with the sparse tier serving the cold side."""
+    from elasticsearch_tpu.common import hbm_ledger
+
+    eng = _fused([(1500, _pcorpus(1500, 40, 1)),
+                  (900, _pcorpus(900, 56, 2))], cold_df=300)
+    qs = [[("t1", 1.0), ("t20", 1.0)], [("t25", 1.0), ("t30", 0.5)],
+          [("t2", 1.0)], [("t28", 1.0), ("t31", 1.0), ("t3", 0.2)]]
+    eng.extend_qc_sizes([len(qs)])
+    eng._fused()
+    eng.extend_qc_sizes([len(qs)])       # the lazily built fused dispatcher
+    want = eng.search_many([qs], k=K)[0]     # warm pass builds the slices
+    r0 = hbm_ledger.compile_stats()["retraces"]
+    got = eng.search_many([qs], k=K)[0]
+    assert hbm_ledger.compile_stats()["retraces"] == r0
+    assert eng.stats["sparse_queries"] > 0 and eng.stats["cold_queries"] == 0
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_sparse_fused_bit_identical():
     """S=3 fused dispatch (different sizes, vocabularies, df spectra,
     therefore different per-partition slice pools) against each
