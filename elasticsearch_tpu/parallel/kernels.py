@@ -44,6 +44,8 @@ entirely as matmuls and tiled vector ops:
   column builder, then gathers the accumulated per-doc totals back at the
   slice's own lanes — so cold terms are scored on device too, and the
   host only bound-prunes + exact-rescores (turbo.py `_collect_gather`).
+  One program serves a group of queries, each with an accumulator of its
+  own that never leaves VMEM.
 """
 
 from __future__ import annotations
@@ -796,70 +798,63 @@ def build_columns(g_rows, g_nrows, g_base, g_slot,
 # --------------------------------------------------------------------------
 
 
-def _sparse_scatter_kernel():
-    def kernel(coff, cw, ct0, ct1, pool_blk, acc_ref):
-        t = pl.program_id(0)
-        rc = pl.program_id(1)
+SG_SCATTER = 1 << 16   # a step's kind and flag, OR-ed over its tile id in
+SG_PICK = 1 << 17      # the step's `meta` word (neither kind = a padding
+SG_FIRST = 1 << 18     # step); FIRST = a query's first scatter step, a
+#                        chunk's first pick step
 
-        @pl.when(rc == 0)
-        def _init():
-            acc_ref[...] = jnp.zeros((1, 128, 128), jnp.float32)
 
-        # a chunk's docs are sorted, so the host-prefetched inclusive tile
-        # range [ct0, ct1] skips every tile the chunk cannot touch (padding
-        # chunks carry the empty range (1, 0) and never scatter)
-        @pl.when((t >= ct0[rc]) & (t <= ct1[rc]))
+def _sparse_gather_kernel(n_tiles: int):
+    def kernel(coff, cw, meta, oidx, pool_blk, out_ref, acc_ref):
+        i = pl.program_id(0)
+        m = meta[i]
+        t = jnp.bitwise_and(m, SG_SCATTER - 1)
+        first = jnp.bitwise_and(m, SG_FIRST) != 0
+        base = t * TILE
+        col = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
+
+        def lanes(r):
+            v = pool_blk[0, r, :]                         # [128] i32 packed
+            doc = jax.lax.shift_right_logical(v, 8)
+            imp = jnp.bitwise_and(v, SPARSE_IMP_MAX)
+            rel = doc - base
+            ok = (imp > 0) & (rel >= 0) & (rel < TILE)
+            rel = jnp.where(ok, rel, 0)
+            hi = jax.lax.shift_right_logical(rel, 7)[:, None]
+            lo = jnp.bitwise_and(rel, 127)[:, None]
+            return ok, imp, jnp.where(col == hi, 1.0, 0.0), lo
+
+        @pl.when(jnp.bitwise_and(m, SG_SCATTER) != 0)
         def _scatter():
-            base = t * TILE
-            col = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
-            w = cw[rc]
+            @pl.when(first)
+            def _new_query():
+                def zero(tt, carry):
+                    acc_ref[tt] = jnp.zeros((128, 128), jnp.float32)
+                    return carry
+                jax.lax.fori_loop(0, n_tiles, zero, 0)
+
+            w = cw[i]
             tacc = jnp.zeros((128, 128), jnp.float32)
             for r in range(SPARSE_GRAN // 128):
-                v = pool_blk[0, r, :]                     # [128] i32 packed
-                doc = jax.lax.shift_right_logical(v, 8)
-                imp = jnp.bitwise_and(v, SPARSE_IMP_MAX)
-                rel = doc - base
-                ok = (imp > 0) & (rel >= 0) & (rel < TILE)
-                rel = jnp.where(ok, rel, 0)
+                ok, imp, A, lo = lanes(r)
                 val = jnp.where(ok, imp.astype(jnp.float32) * w, 0.0)
-                hi = jax.lax.shift_right_logical(rel, 7)[:, None]
-                lo = jnp.bitwise_and(rel, 127)[:, None]
-                A = jnp.where(col == hi, 1.0, 0.0)
                 Bm = jnp.where(col == lo, val[:, None], 0.0)
                 tacc = tacc + jax.lax.dot_general(
                     A, Bm, (((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
-            acc_ref[0, :, :] += tacc
+            acc_ref[t] += tacc
 
-    return kernel
+        @pl.when(jnp.bitwise_and(m, SG_PICK) != 0)
+        def _pick():
+            @pl.when(first)
+            def _new_chunk():
+                out_ref[...] = jnp.zeros((1, SPARSE_GRAN // 128, 128),
+                                         jnp.float32)
 
-
-def _sparse_pick_kernel():
-    def kernel(coff, cw, ct0, ct1, pool_blk, acc_blk, out_ref):
-        rc = pl.program_id(0)
-        t = pl.program_id(1)
-
-        @pl.when(t == 0)
-        def _init():
-            out_ref[...] = jnp.zeros((1, SPARSE_GRAN // 128, 128),
-                                     jnp.float32)
-
-        @pl.when((t >= ct0[rc]) & (t <= ct1[rc]))
-        def _gather():
-            base = t * TILE
-            col = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
-            acc = acc_blk[0]                              # [128, 128] f32
+            acc = acc_ref[t]                              # [128, 128] f32
             rows = []
             for r in range(SPARSE_GRAN // 128):
-                v = pool_blk[0, r, :]
-                doc = jax.lax.shift_right_logical(v, 8)
-                imp = jnp.bitwise_and(v, SPARSE_IMP_MAX)
-                rel = doc - base
-                ok = (imp > 0) & (rel >= 0) & (rel < TILE)
-                rel = jnp.where(ok, rel, 0)
-                hi = jax.lax.shift_right_logical(rel, 7)[:, None]
-                lo = jnp.bitwise_and(rel, 127)[:, None]
-                A = jnp.where(col == hi, 1.0, 0.0)
+                ok, _, A, lo = lanes(r)
                 # gather-as-matmul: G[j] = acc[hi_j, :], then mask the lo
                 # lane — the transpose of the scatter trick, MXU + VPU only
                 G = jax.lax.dot_general(
@@ -872,86 +867,63 @@ def _sparse_pick_kernel():
     return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("n_tiles",))
-def sparse_gather(coff, cw, ct0, ct1, pool, *, n_tiles: int):
-    """Cold-term eager sparse scoring: one scatter pass builds a dense
-    [n_tiles, 128, 128] per-doc accumulator from every dispatched slice
-    chunk (scatter-as-outer-product, exactly the build_columns idiom:
-    within a 16384-doc tile doc = hi*128 + lo, so A[lane, hi] and
-    B[lane, lo]*impact make the tile A^T @ B on the MXU), then a gather
-    pass reads the accumulated totals back at each chunk's own lanes.
-    Because slices from different terms scatter into the SAME accumulator,
-    the value read back at any lane is the doc's FULL cold contribution
-    for this dispatch — the host needs no posting-list walk, only the
-    bound-prune + exact top-k rescore (turbo.py `_collect_gather`).
+@functools.partial(jax.jit, static_argnames=("n_chunks", "n_tiles"))
+def sparse_gather(desc, pool, *, n_chunks: int, n_tiles: int):
+    """Cold-term eager sparse scoring for a GROUP of queries in one
+    program: a flat list of steps, each one (1024-lane slice chunk,
+    16384-doc tile) pair that the host found to meet, walked query by
+    query. A query's SCATTER steps build its dense per-doc accumulator
+    [n_tiles, 128, 128] f32, which lives in VMEM and nowhere else
+    (scatter-as-outer-product, exactly the build_columns idiom: within a
+    tile doc = hi*128 + lo, so A[lane, hi] and B[lane, lo]*impact make the
+    tile A^T @ B on the MXU); its PICK steps, the same pairs again, read
+    the accumulated totals back at each chunk's own lanes. Slices of
+    different terms of ONE query scatter into the same cells, so the value
+    read back at any lane is the doc's FULL cold contribution for that
+    query — the host needs no posting-list walk, only the bound-prune +
+    exact top-k rescore (turbo.py `_collect_gather`); the accumulator is
+    zeroed at a query's first step, so slices of different queries never
+    meet. Within a tile a query's chunks add in the order of its steps.
 
-    coff [n_rc] i32 — pool granule index per 1024-lane chunk; granule 0 is
-        the reserved all-zero granule, where padding chunks point
-    cw   [n_rc] f32 — per-chunk dequant weight (idf * boost * slice
-        quantization scale); 0.0 for padding chunks
-    ct0/ct1 [n_rc] i32 — inclusive 16384-doc tile range covered by the
-        chunk's (sorted) docs; the empty range (1, 0) skips a chunk
+    desc [4, n_steps] i32, one upload — a row per step field:
+      coff — the step's pool granule (granule 0 is the reserved all-zero
+        one; consecutive steps of one chunk fetch it once)
+      cw   — f32 bits of the chunk's dequant weight (idf * boost * slice
+        quantization scale)
+      meta — the tile id | SG_SCATTER or SG_PICK | SG_FIRST; 0 = padding
+      oidx — the output chunk a pick step adds into (steps sorted by it,
+        so a chunk is written once); a scatter or padding step repeats
+        its predecessor's so that nothing is written back in between
     pool [G, 8, 128] i32 — packed slice granules, ``doc << 8 | impact``
         (uint8 impact, so doc ids must fit 23 bits — turbo.py gates)
 
-    Returns [n_rc, 8, 128] f32 — accumulated cold totals, lane-aligned
-    with the pool granules each chunk dispatched.
+    Returns [n_chunks, 8, 128] f32 — accumulated cold totals, lane-aligned
+    with the pool granules of the group's chunks; chunks no pick step
+    names are left unwritten. Shape set: (n_steps, n_chunks, pool
+    granules, n_tiles).
     """
-    n_rc = coff.shape[0]
-    acc = pl.pallas_call(
-        _sparse_scatter_kernel(),
+    return pl.pallas_call(
+        _sparse_gather_kernel(n_tiles),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n_tiles, n_rc),
+            grid=(desc.shape[1],),
             in_specs=[
                 pl.BlockSpec(
                     (1, SPARSE_GRAN // 128, 128),
-                    lambda t, rc, coff, cw, ct0, ct1: (coff[rc], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 128, 128),
-                lambda t, rc, coff, cw, ct0, ct1: (t, 0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, 128, 128), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=100 * 1024 * 1024),
-        interpret=_interpret(),
-    )(coff, cw, ct0, ct1, pool)
-    fn = pl.pallas_call(
-        _sparse_pick_kernel(),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(n_rc, n_tiles),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, SPARSE_GRAN // 128, 128),
-                    lambda rc, t, coff, cw, ct0, ct1: (coff[rc], 0, 0)),
-                pl.BlockSpec(
-                    (1, 128, 128),
-                    lambda rc, t, coff, cw, ct0, ct1: (t, 0, 0)),
+                    lambda i, coff, cw, meta, oidx: (coff[i], 0, 0)),
             ],
             out_specs=pl.BlockSpec(
                 (1, SPARSE_GRAN // 128, 128),
-                lambda rc, t, coff, cw, ct0, ct1: (rc, 0, 0)),
+                lambda i, coff, cw, meta, oidx: (oidx[i], 0, 0)),
+            scratch_shapes=[pltpu.VMEM((n_tiles, 128, 128), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((n_rc, SPARSE_GRAN // 128, 128),
-                                       jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(
+            (n_chunks, SPARSE_GRAN // 128, 128), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_interpret(),
-    )
-    return fn(coff, cw, ct0, ct1, pool, acc)
-
-
-@functools.partial(jax.jit, static_argnames=("n_tiles",))
-def sparse_gather_packed(desc, pool, *, n_tiles: int):
-    """`sparse_gather` with its four per-chunk descriptor rows in ONE
-    i32 [4, n_rc] array — rows coff, cw (the f32's bits), ct0, ct1 — so a
-    launch costs one host->device transfer, not four. Same shape set as
-    `sparse_gather`: (n_rc, pool granules, n_tiles)."""
-    return sparse_gather(
-        desc[0], jax.lax.bitcast_convert_type(desc[1], jnp.float32),
-        desc[2], desc[3], pool, n_tiles=n_tiles)
+    )(desc[0], jax.lax.bitcast_convert_type(desc[1], jnp.float32),
+      desc[2], desc[3], pool)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

@@ -65,10 +65,10 @@ from elasticsearch_tpu.ops import bm25_idf
 from elasticsearch_tpu.parallel.blockmax import _host_block_scores
 from elasticsearch_tpu.parallel.kernels import (
     BITSET_CLAUSES, BITSET_NEGS, COLSCALE, COLSCALE2, MAX_GROUP_ROWS,
-    N_CHUNKS, NCAND, ROWS_PER_STEP, SPARSE_GRAN, SPARSE_IMP_MAX, SW,
-    SW_WORD_ROWS, TILE, build_columns, intersect_bitset, mask_chunk_counts,
-    pack_presence_bits, sparse_gather_packed, sparse_pool_update,
-    sweep_rowmax, sweep_rowmax_bitset, sweep_rowmax_conj,
+    N_CHUNKS, NCAND, ROWS_PER_STEP, SG_FIRST, SG_PICK, SG_SCATTER,
+    SPARSE_GRAN, SPARSE_IMP_MAX, SW, SW_WORD_ROWS, TILE, build_columns,
+    intersect_bitset, mask_chunk_counts, pack_presence_bits, sparse_gather,
+    sparse_pool_update, sweep_rowmax, sweep_rowmax_bitset, sweep_rowmax_conj,
 )
 from elasticsearch_tpu.parallel.spmd import StackedBM25
 
@@ -290,21 +290,37 @@ def node_bitset_stats() -> dict:
 # and kernels.sparse_gather serves the cold side of every query from it,
 # retiring the _cold_contrib host fork from the serving path.
 #
-# A (partition, query)'s gather has three parts (_ColdGather): PLAN (host
-# only: slice residency, the packed chunk descriptors, the posting spans,
-# the bound's slack), LAUNCH (one i32 [4, rcb] upload + the program, async,
-# with the result's copy to the host started at once) and COLLECT (the
-# gathered totals mapped back onto posting order). Nothing the gather reads
-# depends on the sweep's output, so search_many plans and launches every
-# gather of a dispatch chunk right after the chunk's sweep and BEFORE it
-# waits for the sweep: the gather runs on the device behind the sweep
-# while the host waits and rescores, and finish only collects. There is
-# no blocking device round trip inside _finish_chunk.
+# A gather serves a GROUP of queries of one (partition, dispatch chunk):
+# a run of consecutive queries whose cold slices are all resident at once
+# and whose steps fit one program (_GatherGroup; _start_gathers chooses
+# the groups from what it can see: the pool's residency and the ladder
+# below, no knob). It has three parts. PLAN (host only): slice residency,
+# then the program's flat step list, concatenated from what each slice
+# worked out when it was built (_slice_pairs: the (chunk, tile) that meet)
+# with the query's weight and its place in the result broadcast over them;
+# per query the posting spans and the bound's slack (_ColdGather). LAUNCH:
+# one i32 [4, steps] upload + one program, async, with the result's copy
+# to the host started at once. COLLECT: one fetch a group, and each
+# query's totals mapped back onto posting order out of it. Nothing the
+# gather reads depends on the sweep's output, so search_many plans and
+# launches every group of a dispatch chunk right after the chunk's sweep
+# and BEFORE it waits for the sweep: the gathers run on the device behind
+# the sweep while the host waits and rescores, and finish only collects.
+# There is no blocking device round trip inside _finish_chunk. A group of
+# one query (the bool route's SHOULD side, a query finish finds without a
+# gather) is the same program at its smallest rung.
 
 _SPARSE_DOC_LIMIT = 1 << 23          # packed doc-id headroom in an int32
-_SPARSE_RC_BUCKETS = (2, 4, 8, 16, 32, 64, 128, 256)   # dispatch chunk
-#   counts are bucketed so kernels.sparse_gather sees a bounded shape set,
-#   every member instantiated when the pool reaches its cap (_sp_grow)
+_SPARSE_RUNGS = ((64, 8), (512, 32), (4096, 256), (16384, 1024))
+#   (steps, result chunks) of a gather program: a group takes the first
+#   rung that holds both, so kernels.sparse_gather sees a bounded shape
+#   set, every member instantiated when the pool reaches its cap
+#   (_sp_grow). 4 x 16384 step words are a quarter of the chip's 1 MB of
+#   scalar memory; a padding step costs the chip a third of a
+#   microsecond and the interpreter of the CPU tests 17, so the first
+#   rung is a query's own size, not a chunk's
+_SPARSE_QUERY_CHUNKS = 256           # one query's own chunks: above, or
+#   with more steps than the last rung, its cold side is host-scored
 _SPARSE_UP_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128)     # granule-upload
 #   batch sizes (sparse_pool_update), padded toward the zero granule
 
@@ -354,31 +370,66 @@ def node_sparse_stats() -> dict:
         return dict(_NODE_SPARSE_STATS)
 
 
-def _gather_desc(rcb: int) -> np.ndarray:
-    """An all-padding i32 [4, rcb] descriptor block for
-    kernels.sparse_gather_packed — rows coff, cw (f32 bits), ct0, ct1:
-    every chunk reads the zero granule with weight 0.0 over the empty
-    tile range (1, 0)."""
-    desc = np.zeros((4, rcb), np.int32)
-    desc[2] = 1
-    return desc
+def _gather_desc(n_steps: int) -> np.ndarray:
+    """An all-padding i32 [4, n_steps] step list for kernels.sparse_gather
+    — rows coff, cw (f32 bits), meta, oidx: every step is of no kind,
+    reads the zero granule and stays on result chunk 0."""
+    return np.zeros((4, n_steps), np.int32)
+
+
+def _slice_pairs(docs: np.ndarray) -> np.ndarray:
+    """What a gather's plan needs of a slice, worked out once when the
+    slice is built because it depends on the slice alone: the (chunk,
+    tile) pairs that MEET, one a gather step, as chunk << 16 | tile in
+    posting order (`docs` i64, the term's sorted postings: they are the
+    distinct (posting // 1024, doc // 16384))."""
+    key = docs // TILE
+    if len(docs) <= SPARSE_GRAN:
+        return np.flatnonzero(np.bincount(key))
+    key += np.arange(len(docs)) // SPARSE_GRAN << 16
+    new = np.ones(len(key), bool)
+    np.not_equal(key[1:], key[:-1], out=new[1:])
+    return key[new]
+
+
+class _GatherGroup:
+    """The queries one gather program serves, between plan and collect.
+    `members` in the order their chunks lie in the result and `terms` =
+    the slices that must stay resident, both until the launch. `out` is the
+    launched program's result with its copy to the host under way (None
+    before the launch, and again once fetched or dropped); `flat` the
+    fetched block. A group that was not launched (a fault), or whose
+    fetch faults, leaves `flat` None and collect host-scores its queries
+    a pair at a time. `early` = launched before the dispatch waited for
+    its sweep."""
+
+    __slots__ = ("early", "members", "terms", "n_steps", "n_chunks",
+                 "launched", "out", "flat")
+
+    def __init__(self, early: bool):
+        self.early = early
+        self.members: List["_ColdGather"] = []
+        self.terms: set = set()
+        self.n_steps = self.n_chunks = 0
+        self.launched = False
+        self.out = self.flat = None
 
 
 class _ColdGather:
-    """One (partition, query)'s cold side between plan and collect.
-    `out` is the launched program's result with its copy to the host
-    under way; None = not launched (unsliceable batch, or a fault), and
-    collect then host-scores this pair alone. `early` = launched before
-    the dispatch waited for its sweep."""
+    """One (partition, query)'s cold side: its `group` (None = unsliceable,
+    host-scored), where its terms' chunks lie in the group's result
+    (`spans`: [(first chunk, df, first posting)]), the bound's `slack`,
+    and `slices` [(first granule, dequant weight, `_slice_pairs`)] for
+    the group's step list."""
 
-    __slots__ = ("cold_terms", "early", "spans", "slack", "out", "host")
+    __slots__ = ("cold_terms", "group", "slices", "spans", "slack", "host")
 
-    def __init__(self, cold_terms, early: bool):
+    def __init__(self, cold_terms):
         self.cold_terms = cold_terms
-        self.early = early
+        self.group: Optional[_GatherGroup] = None
+        self.slices: List[Tuple[int, float, np.ndarray]] = []
         self.spans: List[Tuple[int, int, int]] = []
         self.slack = 0.0
-        self.out = None
         self.host = False      # collect host-scored this pair
 
 
@@ -522,15 +573,18 @@ class TurboBM25:
         # the serving path never forks to the _cold_contrib host walk
         self._sp_pool = None                  # [G, 8, 128] i32 device pool
         self._sp_host: Optional[np.ndarray] = None   # authoritative mirror
-        self._sp_of: Dict[str, Tuple[int, int, int, float]] = {}
-        #   term -> (granule start, n granules, padded width, quant scale)
+        self._sp_of: Dict[str, tuple] = {}
+        #   term -> (granule start, n granules, padded width, quant scale,
+        #   _slice_pairs): a plain tuple of numbers and an array, which
+        #   the collector stops tracking (a pool holds thousands, and a
+        #   closed loop builds a thousand a call)
         self._sp_lru: Dict[str, int] = {}
         self._sp_free: Dict[int, List[int]] = {}     # run length -> starts
         self._sp_next = 1                     # granule 0 reserved all-zero
         self._sp_cap = max(2, min(int(hbm_budget_bytes) // 4, 64 << 20)
                            // (SPARSE_GRAN * 4))
         self._sp_ok = self.Dp <= _SPARSE_DOC_LIMIT
-        self._sp_inflight = 0   # gathers launched and not yet collected
+        self._sp_inflight = 0   # gather programs launched, not yet fetched
         self.stats = {"builds": 0, "build_s": 0.0, "fallbacks": 0,
                       "cold_queries": 0, "dispatches": 0, "degraded": 0,
                       "phrase_builds": 0, "bool_host": 0, "bool_device": 0,
@@ -629,9 +683,10 @@ class TurboBM25:
         self.qc_sizes = tuple(sorted(merged))
         hbm_ledger.note_primed("turbo", self.qc_sizes)
         hbm_ledger.note_primed("turbo_bitset", self.qc_sizes)
-        # the sparse gather's shape axis is its chunk-count bucket, whose
+        # the sparse gather's shape axis is its step-count rung, whose
         # ladder is static — priming it here keeps a cold start retrace-free
-        hbm_ledger.note_primed("turbo_sparse", _SPARSE_RC_BUCKETS)
+        hbm_ledger.note_primed("turbo_sparse",
+                               [ns for ns, _ in _SPARSE_RUNGS])
 
     # ---------------- column cache ----------------
 
@@ -967,14 +1022,14 @@ class TurboBM25:
             self._sp_pool = jnp.asarray(host)
             if new_g >= self._sp_cap:
                 # the pool's size is part of the gather program's shape,
-                # and this size is final: instantiate every chunk-count
-                # bucket now (all-padding descriptors, every chunk
-                # skipped) so that no dispatch builds one later, whatever
-                # traffic reaches. Below the cap the pool is still
-                # doubling and its programs would be thrown away.
-                for rcb in _SPARSE_RC_BUCKETS:
-                    sparse_gather_packed(_gather_desc(rcb), self._sp_pool,
-                                         n_tiles=self.Dp // TILE)
+                # and this size is final: instantiate every rung now
+                # (all-padding step lists, every step skipped) so that no
+                # dispatch builds one later, whatever traffic reaches.
+                # Below the cap the pool is still doubling and its
+                # programs would be thrown away.
+                for ns, nc in _SPARSE_RUNGS:
+                    sparse_gather(_gather_desc(ns), self._sp_pool,
+                                  n_chunks=nc, n_tiles=self.Dp // TILE)
         if old is None:
             integrity.register_scrub_region(
                 self, "sparse_pool", lambda o: o._sp_pool,
@@ -984,7 +1039,7 @@ class TurboBM25:
         self._hbm.set_region("sparse_pool", self._sp_pool.nbytes)
 
     def _sp_evict(self, term: str) -> None:
-        g0, n_g, w, _ = self._sp_of.pop(term)
+        g0, n_g, w, _, _ = self._sp_of.pop(term)
         self._sp_lru.pop(term, None)
         self._sp_free.setdefault(n_g, []).append(g0)
         # the stale granules stay in place (nothing references them, and
@@ -1033,34 +1088,40 @@ class TurboBM25:
                 return free.pop()
         return -1
 
-    def _ensure_sparse(self, pairs: Sequence[Tuple[str, _TermInfo]]) -> bool:
+    def _ensure_sparse(self, pairs: Sequence[Tuple[str, _TermInfo]],
+                       keep=()) -> bool:
         """Build device slices for the given cold (term, info) pairs:
         pack ``doc << 8 | impact`` granules on the host (the mirror is the
         scrubber's truth), then batch-write them into the donated device
-        pool. Returns False when any term cannot be sliced (df above the
-        ladder, or pool pressure with everything protected) — the caller
-        host-scores the whole batch so bound math never mixes tiers.
-        Impacts are uint8-quantized on a per-term scale smax/255; rounding
-        is forced to >= 1 so a real posting never vanishes, which widens
-        the per-posting error to one full quant step (the lo >= 1 idiom of
-        the column build, mirrored in _plan_gather's slack)."""
+        pool. `keep` = terms whose slices may not be recycled for them
+        (an open gather group's). Returns False when any term cannot be
+        sliced (df above the ladder, or pool pressure with everything
+        protected) — the caller host-scores the whole batch so bound math
+        never mixes tiers. Impacts are uint8-quantized on a per-term scale
+        smax/255; rounding is forced to >= 1 so a real posting never
+        vanishes, which widens the per-posting error to one full quant
+        step (the lo >= 1 idiom of the column build, mirrored in
+        _admit_gather's slack)."""
         if not self._sp_ok:
             return False
-        widths = _sparse_widths()
         self._tick += 1
-        need: List[Tuple[str, _TermInfo, int]] = []
-        protect = set()
+        missing = []
         for t, info in pairs:
-            protect.add(t)
             if t in self._sp_of:
                 self._sp_lru[t] = self._tick
-                continue
+            else:
+                missing.append((t, info))
+        if not missing:
+            return True
+        widths = _sparse_widths()
+        need: List[Tuple[str, _TermInfo, int]] = []
+        for t, info in missing:
             w = next((w for w in widths if w >= info.df), None)
             if w is None:
                 return False
             need.append((t, info, w))
-        if not need:
-            return True
+        protect = {t for t, _ in pairs}
+        protect.update(keep)
         with tracing.phase("dispatch.slice_build", terms=len(need)):
             return self._build_slices(need, protect)
 
@@ -1095,7 +1156,7 @@ class TurboBM25:
                 gran = buf.astype(np.int32).reshape(
                     n_g, SPARSE_GRAN // 128, 128)
                 self._sp_host[g0: g0 + n_g] = gran
-                self._sp_of[t] = (g0, n_g, w, sscale)
+                self._sp_of[t] = (g0, n_g, w, sscale, _slice_pairs(docs))
                 self._sp_lru[t] = self._tick
                 idx_l.append(np.arange(g0, g0 + n_g, dtype=np.int32))
                 upd_l.append(gran)
@@ -1129,122 +1190,184 @@ class TurboBM25:
     def _sparse_on(self) -> bool:
         return self._sp_ok and bool(knob("ES_TPU_SPARSE"))
 
-    def _plan_gather(self, h: _ColdGather) -> Optional[np.ndarray]:
-        """PLAN (host only): ensure the cold terms' slices, then the
-        packed i32 [4, rcb] chunk descriptors (returned), the posting
-        spans collect reads the totals back at, and `slack`, which bounds
-        |contrib - exact| (quantization + f32 accumulation, the e_q
-        certificate style). None when the batch cannot be sliced;
-        DeviceFaultError from the slice build passes through (the caller
-        contains both)."""
+    def _admit_gather(self, g: _GatherGroup, h: _ColdGather) -> Optional[bool]:
+        """PLAN, a query's part: make `h`'s cold slices resident beside
+        the open group's and give it its place in `g`. False = `g` cannot
+        take it (the pool cannot hold both at once, or the steps pass the
+        last rung): close `g` and offer `h` an empty group. None = no
+        group can (a term above the slice ladder, a pool smaller than the
+        query's own slices, more chunks or steps than one program holds):
+        host-scored. `slack` bounds |contrib - exact| (quantization + f32
+        accumulation, the e_q certificate style). DeviceFaultError from
+        the slice build passes through: it has dropped every slice, the
+        open group's too."""
         cold_terms = h.cold_terms
-        if not self._ensure_sparse([(t, i) for t, _b, i in cold_terms]):
-            return None
-        fp = self.fp
-        coff: List[int] = []
-        cw: List[float] = []
-        ct0: List[int] = []
-        ct1: List[int] = []
-        slack = 1e-7
+        if not self._ensure_sparse([(t, i) for t, _b, i in cold_terms],
+                                   keep=g.terms):
+            return False if g.members else None
+        slices, used, slack = [], [], 1e-7
         for t, b, info in cold_terms:
-            g0, n_g, _w, sscale = self._sp_of[t]
+            g0, _n_g, _w, sscale, pairs = self._sp_of[t]
             wt = float(info.idf * b)
-            lo = int(fp.post_start[info.ord])
-            c0 = len(coff)
-            n_used = -(-info.df // SPARSE_GRAN)
-            for j in range(n_used):
-                s = lo + j * SPARSE_GRAN
-                e = min(lo + (j + 1) * SPARSE_GRAN, lo + info.df)
-                coff.append(g0 + j)
-                cw.append(wt * sscale)
-                ct0.append(int(fp.post_doc[s]) // TILE)
-                ct1.append(int(fp.post_doc[e - 1]) // TILE)
-            h.spans.append((c0, info.df, lo))
+            slices.append((g0, wt * sscale, pairs))
+            used.append(-(-info.df // SPARSE_GRAN))
             # one posting per (term, doc): quantization error <= one full
             # step per term, plus a generous f32-accumulation margin
-            slack += abs(wt) * (sscale
-                                + 3e-6 * max(float(info.smax), sscale))
-        n = len(coff)
-        if n > _SPARSE_RC_BUCKETS[-1]:
+            slack += abs(wt) * (sscale + 3e-6 * max(float(info.smax), sscale))
+        n_chunks = sum(used)
+        n_steps = 2 * sum(len(pairs) for _g0, _cw, pairs in slices)
+        if (n_chunks > _SPARSE_QUERY_CHUNKS
+                or n_steps > _SPARSE_RUNGS[-1][0]):
             return None
-        desc = _gather_desc(next(b for b in _SPARSE_RC_BUCKETS if b >= n))
-        desc[0, :n] = coff
-        desc[1, :n] = np.asarray(cw, np.float32).view(np.int32)
-        desc[2, :n] = ct0
-        desc[3, :n] = ct1
-        h.slack = float(slack)
-        return desc
+        if (g.n_steps + n_steps > _SPARSE_RUNGS[-1][0]
+                or g.n_chunks + n_chunks > _SPARSE_RUNGS[-1][1]):
+            return False
+        fp = self.fp
+        c0 = g.n_chunks
+        for n_used, (_t, _b, info) in zip(used, cold_terms):
+            h.spans.append((c0, info.df, int(fp.post_start[info.ord])))
+            c0 += n_used
+        h.slices, h.slack, h.group = slices, float(slack), g
+        g.members.append(h)
+        g.terms.update(t for t, _b, _i in cold_terms)
+        g.n_chunks, g.n_steps = c0, g.n_steps + n_steps
+        return True
 
-    def _launch_gather(self, h: _ColdGather, desc: np.ndarray) -> None:
-        """LAUNCH: one packed descriptor upload + the gather program,
-        asynchronous, and the result's copy to the host started at once.
-        The launch keeps the pool it read alive; a later slice build
-        (sparse_pool_update donates) is ordered behind it on the device."""
-        rcb = desc.shape[1]
-        first = hbm_ledger.note_dispatch("turbo_sparse", rcb)
-        t0 = time.monotonic()
-        with faults.device_errors("sparse_gather", self.part_id):
-            out = sparse_gather_packed(desc, self._sp_pool,
-                                       n_tiles=self.Dp // TILE)
-            out.copy_to_host_async()
-        if first:
-            hbm_ledger.note_compile_done("turbo_sparse", rcb,
-                                         time.monotonic() - t0)
-        h.out = out
+    def _launch_gather(self, g: _GatherGroup) -> None:
+        """PLAN, the group's part, and LAUNCH: the step list (a query at a
+        time its scatter steps, then the same pairs as pick steps, terms
+        in query order, so a doc's cold terms add up in that order), one
+        packed upload + the gather program, asynchronous, and the
+        result's copy to the host started at once. The launch keeps the
+        pool it read alive; a later slice build (sparse_pool_update
+        donates) is ordered behind it on the device. Contained: a fault
+        leaves the group un-launched and collect host-scores its pairs."""
+        if not g.members:
+            return
+        try:
+            faults.fault_point("sparse_gather", self.part_id)
+            # a row a SEGMENT = one slice in one phase of one query:
+            # (granule run, weight, result chunk its steps start from,
+            # 1 = pick); `steps` its pairs; `firsts` = where queries begin
+            segs, steps, firsts = [], [], []
+            at = 0
+            for h in g.members:
+                firsts.append(at)
+                q0 = max(h.spans[0][0] - 1, 0)     # the result stays put
+                mine = [pairs for _, _, pairs in h.slices]
+                steps += mine + mine
+                segs += [(g0, cw, q0, 0) for g0, cw, _ in h.slices]
+                segs += [(g0, cw, c0, 1) for (g0, cw, _), (c0, _df, _lo)
+                         in zip(h.slices, h.spans)]
+                at += 2 * sum(map(len, mine))
+            ns, nc = next(r for r in _SPARSE_RUNGS
+                          if r[0] >= at and r[1] >= g.n_chunks)
+            lens = np.fromiter(map(len, steps), np.int64, len(steps))
+            g0, weight, obase, pick = (
+                np.repeat(np.asarray(col, dt), lens) for col, dt in zip(
+                    zip(*segs), (np.int32, np.float32, np.int32, np.int32)))
+            cat = np.concatenate(steps)
+            chunk = (cat >> 16).astype(np.int32)
+            new = np.ones(at, bool)             # a chunk's first pick step
+            np.not_equal(chunk[1:], chunk[:-1], out=new[1:])
+            new[np.cumsum(lens[:-1])] = True
+            desc = np.empty((4, ns), np.int32)
+            desc[0, :at] = g0 + chunk
+            desc[1, :at] = weight.view(np.int32)
+            desc[2, :at] = ((cat & 0xFFFF)
+                            | np.where(pick, SG_PICK, SG_SCATTER)
+                            | np.where(new & (pick > 0), SG_FIRST, 0))
+            desc[2, firsts] |= SG_FIRST
+            desc[3, :at] = obase + chunk * pick
+            # padding: no kind, and the last step's granule and result
+            # chunk, so that nothing is fetched or written for it
+            desc[:, at:] = desc[:, at - 1: at]
+            desc[2, at:] = 0
+            first = hbm_ledger.note_dispatch("turbo_sparse", ns)
+            t0 = time.monotonic()
+            with faults.device_errors("sparse_gather", self.part_id):
+                out = sparse_gather(desc, self._sp_pool, n_chunks=nc,
+                                    n_tiles=self.Dp // TILE)
+                out.copy_to_host_async()
+            if first:
+                hbm_ledger.note_compile_done("turbo_sparse", ns,
+                                             time.monotonic() - t0)
+        except DeviceFaultError:
+            return
+        finally:
+            # the plan is spent; the queries keep the group, not it them
+            g.members, g.terms = [], set()
+        g.out, g.launched = out, True
         self._sp_inflight += 1
         self.stats["sparse_gather_launches"] += 1
         _node_sparse_add("sparse_gather_launches", 1)
 
-    def _start_gather(self, cold_terms, early: bool) -> _ColdGather:
-        """Plan + launch one (partition, query)'s cold side, contained:
-        an unsliceable batch or a fault leaves the handle un-launched and
-        `_collect_gather` host-scores that pair alone."""
-        h = _ColdGather(cold_terms, early)
-        try:
-            faults.fault_point("sparse_gather", self.part_id)
-            desc = self._plan_gather(h)
-            if desc is not None:
-                self._launch_gather(h, desc)
-        except DeviceFaultError:
-            pass
-        return h
+    def _start_gathers(self, colds, early: bool) -> Dict[int, _ColdGather]:
+        """Plan + launch the cold sides `colds` [(query index, cold
+        terms)] of one dispatch chunk, in that order: {query index:
+        gather}. Consecutive queries share a group, and so a program, for
+        as long as `_admit_gather` finds room; a group is closed and
+        launched BEFORE the next one's slices are built, so those may
+        recycle its granules. A slice build that faults has dropped the
+        open group's slices with its own: that group is not launched."""
+        handles: Dict[int, _ColdGather] = {}
+        g = _GatherGroup(early)
+        for qi, cold_terms in colds:
+            h = handles[qi] = _ColdGather(cold_terms)
+            try:
+                took = self._admit_gather(g, h)
+                if took is False:
+                    self._launch_gather(g)
+                    g = _GatherGroup(early)
+                    self._admit_gather(g, h)
+            except DeviceFaultError:
+                h.group = g
+                g.members, g.terms = [], set()
+                g = _GatherGroup(early)
+        self._launch_gather(g)
+        return handles
 
     def _chunk_gathers(self, plan: _ChunkPlan) -> Dict[int, _ColdGather]:
-        """`_start_gather` for every query of a dispatch chunk that has a
-        cold term here, ahead of its finish and in finish's order:
-        {query index: gather} (empty with the sparse tier off)."""
+        """`_start_gathers` for every query of a dispatch chunk that has a
+        cold term here, ahead of its finish and in finish's order (empty
+        with the sparse tier off)."""
         if not plan.sparse:
             return {}
-        return {qi: self._start_gather(cold_terms, True)
-                for qi, cold_terms in enumerate(plan.cold) if cold_terms}
+        return self._start_gathers(
+            [(qi, cold) for qi, cold in enumerate(plan.cold) if cold], True)
 
     def _discard_gather(self, h: Optional[_ColdGather]) -> None:
-        """Drop a launched gather nobody will collect (its chunk is being
-        host-scored after a sweep fault, or the call is unwinding)."""
-        if h is not None and h.out is not None:
-            h.out = None
+        """Drop the launched program of a gather nobody will collect (its
+        chunk is being host-scored after a sweep fault, or the call is
+        unwinding), for every query of its group."""
+        g = None if h is None else h.group
+        if g is not None and g.out is not None:
+            g.out = None
             self._sp_inflight -= 1
 
     def _collect_gather(self, h: _ColdGather):
         """COLLECT: (docs, contrib, slack, inv), mirroring _cold_contrib's
-        unique-doc enumeration. A pair that was not launched, or whose
-        fetch faults, falls back to the exact host enumeration with slack
-        0 (`h.host`) — downstream pruning then evaluates the IDENTICAL
-        expression the host path uses, so containment is bit-identical by
-        construction."""
-        out, h.out = h.out, None
-        flat = None
-        if out is not None:
-            self._sp_inflight -= 1
-            if h.early:
+        unique-doc enumeration; the group's block is fetched by the first
+        of its queries to ask. A pair whose group was not launched, or
+        whose fetch faults, falls back to the exact host enumeration with
+        slack 0 (`h.host`) — downstream pruning then evaluates the
+        IDENTICAL expression the host path uses, so containment is
+        bit-identical by construction."""
+        g, flat = h.group, None
+        if g is not None:
+            if g.out is not None:
+                out, g.out = g.out, None
+                self._sp_inflight -= 1
+                try:
+                    faults.fault_point("sparse_gather", self.part_id)
+                    with faults.device_errors("sparse_gather", self.part_id):
+                        g.flat = np.asarray(out).reshape(-1)
+                except DeviceFaultError:
+                    pass
+            if g.launched and g.early:
                 self.stats["sparse_gather_overlapped"] += 1
                 _node_sparse_add("sparse_gather_overlapped", 1)
-            try:
-                faults.fault_point("sparse_gather", self.part_id)
-                with faults.device_errors("sparse_gather", self.part_id):
-                    flat = np.asarray(out).reshape(-1)
-            except DeviceFaultError:
-                pass
+            flat = g.flat
         if flat is None:
             h.host = True
             self.stats["sparse_fallbacks"] += 1
@@ -1691,7 +1814,8 @@ class TurboBM25:
                     _node_sparse_add("sparse_queries", 1)
                     h = gathers.pop(qi, None)
                     if h is None:     # not hoisted: back to back, here
-                        h = self._start_gather(cold_terms, False)
+                        h = self._start_gathers([(qi, cold_terms)],
+                                                False)[qi]
                     with tracing.phase("dispatch.sparse_gather",
                                        terms=len(cold_terms)):
                         u, contrib, slack, inv = self._collect_gather(h)
@@ -2262,7 +2386,7 @@ class TurboBM25:
                 _node_sparse_add("sparse_queries", 1)
                 # the same plan / launch / collect, back to back
                 docs_c, contrib, slack, _ = self._collect_gather(
-                    self._start_gather(cold_should, False))
+                    self._start_gathers([(0, cold_should)], False)[0])
             else:
                 self.stats["cold_queries"] += 1
                 docs_c, contrib, _ = self._cold_contrib(cold_should)

@@ -144,20 +144,24 @@ def test_build_columns(chip, n_groups):
               _cols(chip), _cols(chip), n_groups=n_groups)
 
 
-@pytest.mark.parametrize("n_rc", [2, 256])
-def test_sparse_gather(chip, n_rc):
-    i = chip((n_rc,), jnp.int32)
-    _compiled(K.sparse_gather, i, chip((n_rc,), jnp.float32), i, i,
-              chip((4096, K.SPARSE_GRAN // 128, 128), jnp.int32),
-              n_tiles=DOCS // K.TILE)
+@pytest.mark.parametrize("docs", [DOCS, 393_216, 1 << 23],
+                         ids=["smoke", "msmarco-segment", "doc-limit"])
+@pytest.mark.parametrize("rung", [0, -1], ids=["smallest", "largest"])
+def test_sparse_gather(chip, rung, docs):
+    """The group gather at the first and the last rung of its ladder
+    (`turbo._SPARSE_RUNGS`: the last one's four step rows are a quarter of
+    the chip's scalar memory), over the smoke's partition, a benchmark
+    segment's, and the most docs the sparse tier takes (the accumulator of
+    a query is VMEM scratch: 64 KB a 16,384-doc tile, 32 MB there)."""
+    from elasticsearch_tpu.parallel.turbo import (
+        _SPARSE_DOC_LIMIT, _SPARSE_RUNGS,
+    )
 
-
-@pytest.mark.parametrize("n_rc", [2, 256])
-def test_sparse_gather_packed(chip, n_rc):
-    """The served entry point: the four descriptor rows in one upload."""
-    _compiled(K.sparse_gather_packed, chip((4, n_rc), jnp.int32),
-              chip((4096, K.SPARSE_GRAN // 128, 128), jnp.int32),
-              n_tiles=DOCS // K.TILE)
+    assert docs <= _SPARSE_DOC_LIMIT
+    n_steps, n_chunks = _SPARSE_RUNGS[rung]
+    _compiled(K.sparse_gather, chip((4, n_steps), jnp.int32),
+              chip((5040, K.SPARSE_GRAN // 128, 128), jnp.int32),
+              n_chunks=n_chunks, n_tiles=docs // K.TILE)
 
 
 @pytest.mark.parametrize("Q", [1, 16])

@@ -21,6 +21,12 @@ same bits at batch widths 1, 4 and 16, its two fault points to one
 fallbacks, its two counters against `sparse_queries`, and a faulted
 fused sweep to leaving no gather in flight.
 
+PR 35: a gather serves a GROUP of queries of one (partition, chunk): one
+plan, one upload, one program, one fetch. The same tests, re-stated for
+groups: a fault costs its group's pairs and no other; a chunk wider than
+the pool, or than the last rung of the program's ladder, splits into
+groups; a group of N answers N groups of one, bit for bit.
+
 Runs on the host-simulated 8-device CPU mesh from tests/conftest.py
 (Pallas kernels interpret on CPU)."""
 
@@ -32,7 +38,7 @@ from elasticsearch_tpu.common.settings import knob
 from elasticsearch_tpu.common.errors import DeviceFaultError
 from elasticsearch_tpu.parallel import turbo as turbo_mod
 from elasticsearch_tpu.parallel.turbo import (
-    SPARSE_GRAN, _SPARSE_RC_BUCKETS, _sparse_widths,
+    SPARSE_GRAN, _SPARSE_RUNGS, _sparse_widths,
 )
 
 from test_turbo_bitset import _pcorpus, _turbo, _fused, _assert_identical
@@ -69,7 +75,7 @@ def test_sparse_solo_bit_identical():
     assert t._sp_pool is not None and t._sp_host is not None
     # every resident slice is granule-aligned on a declared ladder rung
     widths = _sparse_widths()
-    for g0, n_g, w, sscale in t._sp_of.values():
+    for g0, n_g, w, sscale, _pairs in t._sp_of.values():
         assert w in widths and w == n_g * SPARSE_GRAN and sscale > 0
 
 
@@ -173,7 +179,7 @@ def test_sparse_widths_ladder(monkeypatch):
     got = t.search_many([qs], k=K)[0]
     _assert_identical(got, t.search_many_host([qs], k=K)[0],
                       "custom ladder vs host")
-    assert all(w in (1024, 2048) for _, _, w, _ in t._sp_of.values())
+    assert all(sl[2] in (1024, 2048) for sl in t._sp_of.values())
     # df above the ladder: the whole batch host-falls-back, still counted
     monkeypatch.setenv("ES_TPU_SPARSE_WIDTHS", "1024")
     t2 = _turbo(fp, 3000, cold_df=2500)
@@ -345,14 +351,17 @@ def fused_engine():
                    (2100, _pcorpus(2100, 40, 3))], cold_df=300)
 
 
-@pytest.mark.parametrize("width", [1, 4, 16])
+@pytest.mark.parametrize("width", [1, 4, 16, 72])
 @pytest.mark.parametrize("route", ["solo", "fused", "bool"])
 def test_hoisted_gather_bit_identical_and_counted(solo_engine, fused_engine,
                                                   route, width):
     """(a) + (d): every width and route answers the host enumeration's
-    bits; on the match routes every gather was launched before its
-    dispatch waited (`overlapped` == `sparse_queries` == launches), on the
-    bool route the same three steps run back to back inside finish."""
+    bits; on the match routes a (partition, chunk)'s cold sides are ONE
+    group, so one program, launched before the dispatch waited
+    (`overlapped` == `sparse_queries`, one launch), with the queries that
+    have no cold term lying between its members; on the bool route the
+    same three steps run back to back inside finish, a group of one a
+    query."""
     cold_pairs = sum(1 for i in range(width) if i % 4 != 3)
     if route == "fused":
         st = fused_engine._fused()
@@ -379,7 +388,8 @@ def test_hoisted_gather_bit_identical_and_counted(solo_engine, fused_engine,
                               f"bool, width {width}")
     for t, d in zip(turbos, _rise(turbos, before)):
         assert d["sparse_queries"] == cold_pairs, d
-        assert d["sparse_gather_launches"] == cold_pairs, d
+        assert d["sparse_gather_launches"] == \
+            (cold_pairs if route == "bool" else 1), d
         assert d["sparse_gather_overlapped"] == \
             (0 if route == "bool" else cold_pairs), d
         assert d["sparse_fallbacks"] == 0 and d["cold_queries"] == 0, d
@@ -387,15 +397,22 @@ def test_hoisted_gather_bit_identical_and_counted(solo_engine, fused_engine,
 
 
 @pytest.mark.faults
-@pytest.mark.parametrize("where, nth", [("launch", 1), ("launch", 2),
-                                        ("collect", 3), ("collect", 4)])
-def test_gather_fault_contained_to_its_pair_and_counted_once(where, nth):
-    """(b) `sparse_gather` fires once before a pair's plan + launch and
-    once before its collect. Two cold queries on partition 1 make calls
-    1, 2 the launches and 3, 4 the collects: whichever faults, that
-    (partition, query) alone is host-scored, once."""
+@pytest.mark.parametrize("where, nth, cap", [
+    ("launch", 1, None), ("collect", 2, None),
+    ("launch", 2, 3), ("collect", 4, 3)])
+def test_gather_fault_contained_to_its_group_and_counted_once(where, nth,
+                                                              cap):
+    """(b) `sparse_gather` fires once before a group's launch and once
+    before its fetch. Two cold queries on partition 1 are one group:
+    call 1 its launch, call 2 its fetch, and a fault at either host-scores
+    both pairs, once each. With room for two slices in the pool (`cap` 3:
+    the queries' three cold terms cannot be resident at once) they are two
+    groups of one: calls 1, 2 the launches and 3, 4 the fetches, and the
+    faulted group's pair alone is host-scored."""
     eng = _fused([(700, _pcorpus(700, 40, 12)),
                   (900, _pcorpus(900, 32, 13))], cold_df=250)
+    if cap:
+        eng.turbos[1]._sp_cap = cap
     qs = [[("t20", 1.0), ("t25", 1.0)], [("t1", 1.0), ("t28", 0.5)]]
     want = eng._merge3([t.search_many_host([qs], k=K)[0]
                         for t in eng.turbos], len(qs), K)
@@ -405,11 +422,16 @@ def test_gather_fault_contained_to_its_pair_and_counted_once(where, nth):
     for g, w, name in zip(got, want, ("scores", "parts", "ords")):
         assert np.array_equal(np.asarray(g), np.asarray(w)), name
     d0, d1 = _rise(eng.turbos, before)
-    assert d0["sparse_fallbacks"] == 0 and d1["sparse_fallbacks"] == 1
+    groups, lost = (2, 1) if cap else (1, 2)
+    assert d0["sparse_fallbacks"] == 0 and d1["sparse_fallbacks"] == lost
     assert d0["sparse_queries"] == 2 and d1["sparse_queries"] == 2
-    # a fault before the launch costs the launch; one at collect does not
-    assert d1["sparse_gather_launches"] == (1 if where == "launch" else 2)
-    assert d1["sparse_gather_overlapped"] == d1["sparse_gather_launches"]
+    assert d0["sparse_gather_launches"] == 1
+    # a fault before the launch costs the launch; one at the fetch does not
+    assert d1["sparse_gather_launches"] == \
+        groups - (1 if where == "launch" else 0)
+    # cold sides collected from a program that went out early
+    assert d1["sparse_gather_overlapped"] == \
+        2 - (lost if where == "launch" else 0)
     assert all(t._sp_inflight == 0 for t in eng.turbos)
 
 
@@ -417,12 +439,16 @@ def test_gather_fault_contained_to_its_pair_and_counted_once(where, nth):
 @pytest.mark.parametrize("where", ["program", "fetch"])
 def test_gather_device_error_contained(monkeypatch, where):
     """(b) an organic device error, raised by the program's call or by
-    the fetch of its result, is contained like the injected one."""
+    the fetch of its result, is contained like the injected one: the
+    first group's two pairs are host-scored, the second group (the pool
+    holds two slices, the third query's term is a third) is served."""
     t = _turbo(_pcorpus(3000, 40, 7), 3000, cold_df=COLD_DF)
-    qs = [[("t0", 1.0), ("t30", 1.0)], [("t1", 1.0), ("t35", 1.0)]]
+    t._sp_cap = 3
+    qs = [[("t0", 1.0), ("t30", 1.0)], [("t1", 1.0), ("t35", 1.0)],
+          [("t2", 1.0), ("t38", 1.0)]]
     want = t.search_many_host([qs], k=K)[0]
-    t.search_many([qs], k=K)                   # slices and pool in place
-    real = turbo_mod.sparse_gather_packed
+    t.search_many([qs], k=K)                   # the pool in place
+    real = turbo_mod.sparse_gather
     calls = []
 
     class _Lost:
@@ -440,21 +466,22 @@ def test_gather_device_error_contained(monkeypatch, where):
             return _Lost()
         return real(desc, pool, **kw)
 
-    monkeypatch.setattr(turbo_mod, "sparse_gather_packed", flaky)
+    monkeypatch.setattr(turbo_mod, "sparse_gather", flaky)
     before = _snap([t])
     _assert_identical(t.search_many([qs], k=K)[0], want, where)
     (d,) = _rise([t], before)
     assert len(calls) == 2
-    assert d["sparse_fallbacks"] == 1 and d["sparse_queries"] == 2
+    assert d["sparse_fallbacks"] == 2 and d["sparse_queries"] == 3
     assert t._sp_inflight == 0
 
 
 @pytest.mark.parametrize("route", ["solo", "fused"])
 def test_chunk_wider_than_the_pool_is_served_without_fallback(route):
     """(c) a chunk whose cold terms cannot all be resident at once (room
-    for three one-granule slices, sixteen distinct cold terms): every
-    query's gather is issued before a later query's slice build recycles
-    its granules, so the sparse tier serves all of them."""
+    for three one-granule slices, sixteen distinct cold terms) splits
+    into groups of the queries whose slices can: every group's gather is
+    issued before the next group's slice build recycles its granules, so
+    the sparse tier serves all of them."""
     if route == "solo":
         turbos = [_turbo(_pcorpus(3000, 40, 7), 3000, cold_df=COLD_DF)]
     else:
@@ -480,6 +507,7 @@ def test_chunk_wider_than_the_pool_is_served_without_fallback(route):
         assert d["sparse_fallbacks"] == 0, d
         assert d["sparse_queries"] == len(qs)
         assert d["sparse_gather_overlapped"] == len(qs)
+        assert 1 < d["sparse_gather_launches"] < len(qs), d
         assert d["sparse_slices"] >= len(distinct) > 3, "nothing recycled"
 
 
@@ -518,7 +546,7 @@ def test_fused_sweep_fault_leaves_no_gather_in_flight(monkeypatch, where):
         assert t._sp_inflight == 0
     assert [r.site for r in log] == ["fused_dispatch"]
     for d in _rise(st.turbos, before):
-        assert d["sparse_gather_launches"] == (0 if where == "launch" else 2)
+        assert d["sparse_gather_launches"] == (0 if where == "launch" else 1)
         # host-scored chunk: no finish ran, so nothing was collected
         assert d["sparse_queries"] == 0 and d["sparse_fallbacks"] == 0
         assert d["sparse_gather_overlapped"] == 0
@@ -541,28 +569,99 @@ def test_cancel_between_launch_and_finish_drops_the_gathers(solo_engine):
     l0 = solo_engine.stats["sparse_gather_launches"]
     with pytest.raises(_Cancelled):
         solo_engine.search_many([qs], k=K, check=check)
-    assert solo_engine.stats["sparse_gather_launches"] == l0 + 3
+    assert solo_engine.stats["sparse_gather_launches"] == l0 + 1
     assert solo_engine._sp_inflight == 0
 
 
 def test_every_gather_shape_is_instantiated_when_the_pool_is_full_size():
-    """The gather program's shape is (chunk-count bucket, pool size, tiles).
-    When the pool reaches its cap, its final size, every bucket is
-    instantiated: a dispatch at a bucket traffic has not reached yet
+    """The gather program's shape is (rung = steps and result chunks, pool
+    size, tiles). When the pool reaches its cap, its final size, every
+    rung is instantiated: a dispatch at a rung traffic has not reached yet
     builds nothing. (Below the cap the pool still doubles, and programs
     for a passing size are left to the traffic that needs them.)"""
     from elasticsearch_tpu.parallel.kernels import TILE
 
-    program = turbo_mod.sparse_gather_packed
+    program = turbo_mod.sparse_gather
     t = _turbo(_pcorpus(3000, 40, 7), 3000, cold_df=COLD_DF)
     t._sp_cap = 37                       # no other test's pool size
     t.search_many([[[("t0", 1.0), ("t30", 1.0)]]], k=K)
     assert t._sp_pool.shape[0] == 37
     size0 = program._cache_size()
-    for rcb in _SPARSE_RC_BUCKETS:
-        program(turbo_mod._gather_desc(rcb), t._sp_pool,
+    for ns, nc in _SPARSE_RUNGS:
+        program(turbo_mod._gather_desc(ns), t._sp_pool, n_chunks=nc,
                 n_tiles=t.Dp // TILE)
     assert program._cache_size() == size0
+    # the ladder is short, rises in both members, and its last rung's
+    # step words fit the chip's scalar memory (1 MB) four times over
+    assert len(_SPARSE_RUNGS) <= 4
+    assert all(a[0] < b[0] and a[1] < b[1]
+               for a, b in zip(_SPARSE_RUNGS, _SPARSE_RUNGS[1:]))
+    assert 4 * 4 * _SPARSE_RUNGS[-1][0] <= (1 << 20) // 4
+    assert _SPARSE_RUNGS[-1][1] >= turbo_mod._SPARSE_QUERY_CHUNKS
+
+
+@pytest.mark.parametrize("route", ["solo", "fused"])
+def test_chunk_with_more_steps_than_a_program_splits_into_groups(
+        monkeypatch, route):
+    """A ladder whose last rung holds 24 steps and 4 result chunks: a
+    query's two one-chunk slices are 8 steps here (each meets one tile:
+    a scatter and a pick step), so a 16-wide chunk's twelve cold sides
+    go out as groups of two or three, each at the rung that holds it,
+    with the same bits and no fallback; a ladder too short for one query
+    host-scores every pair."""
+    if route == "solo":
+        turbos = [_turbo(_pcorpus(3000, 40, 7), 3000, cold_df=COLD_DF)]
+        search = turbos[0].search_many
+    else:
+        st = _three_parts()._fused()
+        turbos, search = st.turbos, st.search_many
+    qs = _wide_queries(16)
+    want = [t.search_many_host([qs], k=K)[0] for t in turbos]
+
+    def run():
+        got = search([qs], k=K)
+        return got if route == "fused" else [got]
+
+    monkeypatch.setattr(turbo_mod, "_SPARSE_RUNGS", ((8, 2), (24, 4)))
+    before = _snap(turbos)
+    for si, got in enumerate(run()):
+        _assert_identical(got[0], want[si], f"{route} partition {si}")
+    for t, d in zip(turbos, _rise(turbos, before)):
+        assert d["sparse_fallbacks"] == 0 and d["sparse_queries"] == 12, d
+        assert d["sparse_gather_launches"] == 6, d     # 4 chunks a program
+        assert d["sparse_gather_overlapped"] == 12 and t._sp_inflight == 0
+    monkeypatch.setattr(turbo_mod, "_SPARSE_RUNGS", ((2, 1),))
+    before = _snap(turbos)
+    for si, got in enumerate(run()):
+        _assert_identical(got[0], want[si], f"{route} partition {si}, host")
+    for t, d in zip(turbos, _rise(turbos, before)):
+        assert d["sparse_fallbacks"] == 12, d
+        assert d["sparse_gather_launches"] == 0 and t._sp_inflight == 0
+
+
+def test_a_group_reads_what_its_queries_read_alone(solo_engine):
+    """One program over five queries (shared cold terms, different
+    weights, one doc in several of a query's slices) gives every query
+    the totals, the slack and the enumeration its own group of one gives:
+    an accumulator a query, never shared."""
+    t = solo_engine
+    colds = []
+    for i, boost in enumerate((1.0, 0.3, 2.0, 0.7, 1.1)):
+        terms = [(f"t{20 + i}", boost), (f"t{21 + i}", 1.0),
+                 ("t30", 0.5 + i)]
+        colds.append((i, [(tm, b, t._term(tm)) for tm, b in terms]))
+    l0 = t.stats["sparse_gather_launches"]
+    grouped = t._start_gathers(colds, False)
+    assert t.stats["sparse_gather_launches"] == l0 + 1
+    assert len({id(h.group) for h in grouped.values()}) == 1
+    for qi, cold in colds:
+        alone = t._start_gathers([(qi, cold)], False)[qi]
+        for got, want in zip(t._collect_gather(grouped[qi]),
+                             t._collect_gather(alone)):
+            assert np.array_equal(got, want), qi
+        assert not grouped[qi].host and not alone.host
+    assert t.stats["sparse_gather_launches"] == l0 + 6
+    assert t._sp_inflight == 0
 
 
 def test_gather_counters_ride_the_node_stats(solo_engine):
@@ -576,7 +675,7 @@ def test_gather_counters_ride_the_node_stats(solo_engine):
     rise = {key: after[key] - before[key] for key in (
         "sparse_queries", "sparse_gather_launches",
         "sparse_gather_overlapped", "sparse_fallbacks")}
-    assert rise == {"sparse_queries": 3, "sparse_gather_launches": 3,
+    assert rise == {"sparse_queries": 3, "sparse_gather_launches": 1,
                     "sparse_gather_overlapped": 3, "sparse_fallbacks": 0}
 
 
@@ -606,30 +705,49 @@ def _three_parts(**kw):
 
 @pytest.mark.faults
 @pytest.mark.parametrize("where", ["launch", "collect"])
-def test_gather_fault_in_a_wide_chunk_host_scores_one_pair(where):
-    """A 16-wide chunk has 12 cold pairs a partition: `sparse_gather`
-    fires 12 times at their launches and 12 times at their collects.
-    Whichever call faults on partition 1, that (partition, query) alone
-    is host-scored and leaves the chunk-wide finish, once."""
+def test_gather_fault_in_a_wide_chunk_host_scores_its_group(where):
+    """A 16-wide chunk has 12 cold pairs a partition. Partition 1's pool
+    holds three one-granule slices, so its twelve go out as several
+    groups: `sparse_gather` fires once at each group's launch, then once
+    at each group's fetch. Whichever call faults, the pairs of THAT group
+    are host-scored and leave the chunk-wide finish, once each; every
+    other group, and every other partition, is served."""
     eng = _three_parts()
+    eng.turbos[1]._sp_cap = 4
     qs = _wide_queries(16)
     want = eng._merge3([t.search_many_host([qs], k=K)[0]
                         for t in eng.turbos], len(qs), K)
-    eng.search_many([qs], k=K)               # slices and pools in place
+    eng.search_many([qs], k=K)               # pools in place
+    sizes = []
+    real = eng.turbos[1]._launch_gather
+
+    def launch(g):
+        sizes.append(len(g.members))
+        real(g)
+
+    eng.turbos[1]._launch_gather = launch
     before = _finish_snap(eng.turbos)
-    nth = 5 if where == "launch" else 12 + 5
+    eng.search_many([qs], k=K)
+    groups = [n for n in sizes if n]
+    assert sum(groups) == 12 and len(groups) > 2
+    assert _finish_rise(eng.turbos, before)[1]["sparse_gather_launches"] \
+        == len(groups)
+    hit_group = 2                            # the third group's calls
+    before = _finish_snap(eng.turbos)
+    nth = hit_group + 1 + (0 if where == "launch" else len(groups))
     with faults.inject(f"sparse_gather#1:raise@{nth}"):
         got = eng.search_many([qs], k=K)[0]
     for g, w, name in zip(got, want, ("scores", "parts", "ords")):
         assert np.array_equal(np.asarray(g), np.asarray(w)), name
     for si, d in enumerate(_finish_rise(eng.turbos, before)):
-        hit = int(si == 1)
+        hit = groups[hit_group] if si == 1 else 0
         assert d["sparse_fallbacks"] == hit, (si, d)
         assert d["finish_pair_fallbacks"] == hit + d["fallbacks"], (si, d)
         assert d["finish_bulk_pairs"] == 16 - d["finish_pair_fallbacks"]
         assert d["sparse_queries"] == 12
         assert d["sparse_gather_launches"] == \
-            12 - (hit if where == "launch" else 0)
+            (len(groups) if si == 1 else 1) \
+            - (1 if hit and where == "launch" else 0)
     assert all(t._sp_inflight == 0 for t in eng.turbos)
 
 
@@ -658,8 +776,9 @@ def test_one_failed_certificate_leaves_the_rest_of_the_chunk_in_bulk(
 @pytest.mark.parametrize("route", ["solo", "fused"])
 def test_cancel_between_chunks_leaves_no_gather_in_flight(route):
     """20 queries at width 8 are three chunks, all swept and their 15
-    gathers launched before the first finish. A cancel after the first
-    chunk's finish unwinds with the other two chunks' gathers dropped."""
+    cold sides launched, a group a chunk, before the first finish. A
+    cancel after the first chunk's finish unwinds with the other two
+    chunks' groups dropped."""
     class _Cancelled(Exception):
         pass
 
@@ -683,7 +802,7 @@ def test_cancel_between_chunks_leaves_no_gather_in_flight(route):
     with pytest.raises(_Cancelled):
         search([qs], k=K, check=check)
     for t, d in zip(turbos, _finish_rise(turbos, before)):
-        assert d["sparse_gather_launches"] == 15, d
+        assert d["sparse_gather_launches"] == 3, d
         assert d["sparse_gather_overlapped"] == 6, d      # chunk 1's own
         assert d["finish_bulk_pairs"] + d["finish_pair_fallbacks"] == 8
         assert t._sp_inflight == 0
@@ -787,3 +906,74 @@ def test_finish_bulk_pct_is_declared_for_the_bm25_cells_and_reads_a_share():
     bare = SimpleNamespace(stats_before={"tpu_turbo": {}},
                            stats_after={"tpu_turbo": {}})
     assert read(spec, bare) == 0.0
+
+
+def test_gather_queries_per_launch_is_declared_for_the_bm25_cells_and_reads_a_ratio():
+    """The benchmark's `gather_queries_per_launch.search`: declared in the
+    traced runs of the cells whose device counter is the fused turbo's;
+    its reader gives the rise of `sparse_queries` over the rise of
+    `sparse_gather_launches` (both counters older than the group gather:
+    a tree that launches a program a (partition, query) reads 1.0), 0
+    where no gather was launched or the stats lack the counters."""
+    from types import SimpleNamespace
+
+    from benchmark.manifest import ROOT, Manifest, load_kind
+
+    m = Manifest(ROOT)
+    name = "gather_queries_per_launch.search"
+    for cell in m.cell_names():
+        declared = name in {x["name"] for x in m.declared(cell, 1)}
+        assert declared == m.cell(cell).config["device_counter"].startswith(
+            "tpu_turbo."), cell
+        assert name not in {x["name"] for x in m.declared(cell, 0)}
+    spec = m.metric_spec(name)
+    assert spec["kind"] == "counter_share" and spec["scale"] == 1
+    read = load_kind(m.dir, "reader", spec["kind"]).read
+
+    def window(q0, l0, q1, l1):
+        return SimpleNamespace(
+            stats_before={"tpu_turbo": {"sparse_queries": q0,
+                                        "sparse_gather_launches": l0}},
+            stats_after={"tpu_turbo": {"sparse_queries": q1,
+                                       "sparse_gather_launches": l1}})
+
+    assert read(spec, window(7, 7, 7 + 768, 7 + 9)) == \
+        pytest.approx(768 / 9)
+    assert read(spec, window(7, 7, 7 + 768, 7 + 768)) == 1.0
+    assert read(spec, window(5, 5, 5, 5)) == 0.0
+    bare = SimpleNamespace(stats_before={"tpu_turbo": {}},
+                           stats_after={"tpu_turbo": {}})
+    assert read(spec, bare) == 0.0
+
+
+def test_the_metric_reads_the_engines_own_counters(solo_engine):
+    """What the metric divides, on a live engine: a 16-wide chunk's twelve
+    cold sides over its one program."""
+    before = _snap([solo_engine])
+    solo_engine.search_many([_wide_queries(16)], k=K)
+    (d,) = _rise([solo_engine], before)
+    assert d["sparse_queries"] / d["sparse_gather_launches"] == 12
+
+
+def test_a_call_leaves_nothing_to_the_cycle_collector(solo_engine):
+    """A group holds its queries only until its launch and a query its
+    group after it: no reference cycle, so a call's handles and result
+    blocks go when the call returns. (With a cycle every 256-query call
+    left 768 handles to the collector, and a closed loop's window ran
+    twice the full collections.)"""
+    import gc
+
+    qs = _wide_queries(16)
+    solo_engine.search_many([qs], k=K)
+    handles = solo_engine._start_gathers(
+        [(0, [(tm, b, solo_engine._term(tm)) for tm, b in qs[0][1:]])], False)
+    assert handles[0].group.launched and not handles[0].group.members
+    solo_engine._discard_gather(handles[0])
+    del handles
+    gc.collect()
+    gc.disable()
+    try:
+        solo_engine.search_many([qs], k=K)
+        assert gc.collect() == 0
+    finally:
+        gc.enable()
