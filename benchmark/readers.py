@@ -2,15 +2,15 @@
 with one function `read(spec, window)`; a metric is a data file that names
 its kind and what to read (benchmark/metrics/<metric>.json). A metric of a
 kind that exists is a new data file; a new kind is a new file beside the
-twelve. What they share (the window, the deltas) is here.
+others. What they share (the window, the deltas) is here.
 
 Every reader is total: it returns a number for any window, also one with
 no event of its source (a histogram with count 0 reads 0.0, a ratio over
-0 reads 0.0). The exceptions read the device trace: `kernel_roofline` (a
+0 reads 0.0). The exceptions read the device: `kernel_roofline` (a
 share of a roofline is never reported as 0), `module_mean_ms` and
-`busy_skew` raise `NothingToRead` on a span without an event of theirs —
-the run then writes down the event names it did see and ends non-zero
-without a result line.
+`busy_skew` raise `NothingToRead` on a span without an event of theirs,
+`peak_skew` where no device reports a peak — the run then writes down the
+event names it did see and ends non-zero without a result line.
 """
 
 from __future__ import annotations

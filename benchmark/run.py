@@ -90,10 +90,12 @@ class Stalls:
     (a shape the warm-up did not reach), by JAX's own monitoring events."""
 
     BEAT_S = 0.02
+    GAP_MS = 300         # a heartbeat gap over this is a freeze, not jitter
 
     def __init__(self):
         self.t_gc = 0.0
         self.gc_ms: List[float] = []
+        self.gc_full_ms: List[float] = []      # generation 2 only
         self.late_beats: List[tuple] = []      # (seconds into window, ms)
         self._stop = threading.Event()
         self.t0 = time.monotonic()
@@ -108,6 +110,8 @@ class Stalls:
             self.t_gc = time.monotonic()
         else:
             self.gc_ms.append((time.monotonic() - self.t_gc) * 1e3)
+            if info.get("generation") == 2:
+                self.gc_full_ms.append(self.gc_ms[-1])
 
     def _beat(self) -> None:
         last = time.monotonic()
@@ -118,7 +122,7 @@ class Stalls:
                                         round((now - last) * 1e3)))
             last = now
 
-    def stop(self, calls) -> None:
+    def stop(self, calls) -> dict:
         self._stop.set()
         self._thread.join()
         gc.callbacks.remove(self._on_gc)
@@ -135,6 +139,23 @@ class Stalls:
         slow = sorted(calls, key=lambda s: s.due - s.done)[:10]
         log("slowest calls (due s, ms): " + str(sorted(
             (round(s.due, 2), round((s.done - s.due) * 1e3)) for s in slow)))
+        # the same, as numbers a table can be made of (the result line's
+        # `stalls`, which the driver ignores): what a whole-window rate
+        # carries and a median call does not
+        took = [(s.done - s.due) * 1e3 for s in calls] or [0.0]
+        summary = {
+            "calls": len(calls),
+            "call_mean_ms": float(np.mean(took)),
+            "call_p50_ms": float(np.median(took)),
+            "beat_gap_s": sum(ms for _, ms in self.late_beats
+                              if ms > self.GAP_MS) / 1e3,
+            "gc_full_s": sum(self.gc_full_ms) / 1e3,
+            "builds": len(backend),
+            "build_s": sum(s for _, s in builds)}
+        if len(took) <= 200:         # a closed loop's calls, one by one
+            summary["call_ms"] = [round(t) for t in took]
+        log("stalls in the window: " + json.dumps(summary))
+        return summary
 
 
 class Node:
@@ -436,7 +457,7 @@ class Bench:
                      else loadgen.open_loop(node.port, mix, sched))
         finally:
             span_s = time.monotonic() - t0
-            stalls.stop(calls)
+            stalled = stalls.stop(calls)
             if profile:
                 jax.profiler.stop_trace()
         stats2 = node.stats()
@@ -464,6 +485,7 @@ class Bench:
             device_kind=self.dev["kind"], kinds_dir=self.manifest.dir)
         w.notes.update(attempted=len(calls), calls_answered=n_answered,
                        answered=done, seed=seed, memory_peaks=peaks,
+                       stalls=stalled,
                        done_s=np.asarray([s.done for s in calls]))
         if trace_on:
             w.events = events if events is not None else trace.load_xplane(
@@ -503,6 +525,7 @@ class Bench:
                 problems.append(f"{m['name']}: {e}")
         if w.notes.get("roofline"):
             line["roofline"] = w.notes["roofline"]
+        line["stalls"] = w.notes["stalls"]
         if problems and trace_on:
             # an earlier line and a file beside the trace say what WAS seen
             seen = trace.seen_names(w.events or [])

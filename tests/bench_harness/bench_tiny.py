@@ -10,15 +10,13 @@ its data (`is_bm25` / `is_knn`: the configuration's `device_counter`;
 benchmark/testdata/trace_<cell>.json (`recorded`): the PR that adds a
 cell adds that file.
 
-Beside the manifest's cells the tiny root holds the cell PR 29 built the
-harness for and then held back (PERF.md section 7: no cut of the v5e-8's
-four-chip share fits a run's 360 s until the program builds its partition
-engines side by side): `FOUR`, four shards over a 4-device mesh under a
-closed `_msearch` loop. While the manifest lacks it, it is added the way a
-later PR will add it, as new files and new manifest entries
-(`add_four_shard_cell`), so these tests also hold the harness to taking
-shards, chips, path parameters and the loop from data; once the manifest
-holds it, nothing is added."""
+`FOUR` is the manifest's four-chip cell since PR 37 (PR 29 built the
+harness for it and held it back; until it landed the tiny root added it
+as new files and entries): four shards over a 4-device mesh under a
+closed `_msearch` loop, so these tests hold the harness to taking shards,
+chips, path parameters and the loop from data. `four_shard_config` is the
+recipe its committed configuration file is held to
+(test_bench_shards.py)."""
 
 import json
 import os
@@ -30,14 +28,11 @@ FOUR = "msmarco-bm25-4shard.msearch-closed"
 FOUR_CONFIG = "msmarco-passage-bm25-4shard"
 TWIN = "msmarco-bm25.msearch-closed"     # its one-chip twin, in the manifest
 REAL = Manifest(ROOT)
-HELD = FOUR not in REAL.cell_names()
-CELLS = tuple(REAL.cell_names()) + ((FOUR,) if HELD else ())
-# what the held cell reads beside the accepted bm25 cells' own metrics
-FOUR_METRICS = {
-    "merge_device_pct.search": ("%", "higher", "program_counter", "engines"),
-    "merge_topk_ms.search": ("ms", "lower", "device_trace", "kernels"),
-    "chip_busy_skew.search": ("ratio", "lower", "device_trace", "device"),
-}
+CELLS = tuple(REAL.cell_names())
+# `peak_bytes_in_use` a chip, as the four-chip cell's traced run read them
+# (my chip call C, PR 37; PERF.md section 5): the CPU backend reports none,
+# and a traced line of that cell holds the spread of them (`steer_engines`)
+RECORDED_PEAKS = (8344152576, 1939730432, 1939730432, 1939730432)
 
 
 def recorded(cell: str) -> str:
@@ -58,7 +53,8 @@ def _write(doc: dict, root: str, *path: str) -> None:
 def four_shard_config() -> dict:
     """The accepted bm25 configuration with `index.shards` 4 (one segment
     each), the path parameter a multi-shard index needs to reach the
-    device, and what must and must not have moved."""
+    device, and what must and must not have moved: all of the committed
+    four-shard file but its size, its cuts and the words about them."""
     cfg = _read(ROOT, "benchmark", "configs", "msmarco-passage-bm25.json")
     cfg["name"] = FOUR_CONFIG
     cfg["index"].update(shards=4, segments=1, search_params={
@@ -68,28 +64,6 @@ def four_shard_config() -> dict:
     return cfg
 
 
-def add_four_shard_cell(root: str, doc: dict) -> None:
-    """The held cell as files and entries: `four_shard_config`, the
-    committed `msearch-closed` mix, three metrics of its own. The metrics
-    whose list holds its one-chip twin take the cell into it."""
-    path = f"benchmark/configs/{FOUR_CONFIG}.json"
-    cfg = four_shard_config()
-    _write(cfg, root, path)
-    doc["configs"].append({
-        "name": FOUR_CONFIG, "source": cfg["source"], "file": path,
-        "reduced": cfg["reduced"], "why": "four shards, one a chip"})
-    doc["workloads"].append({
-        "name": FOUR, "config": FOUR_CONFIG, "traffic": "msearch-closed",
-        "chips": 4, "why": "closed loop, 1 client, _msearch of 256"})
-    for m in doc["per_layer"]:
-        if TWIN in m.get("workloads", ()):
-            m["workloads"].append(FOUR)
-    doc["per_layer"] += [
-        {"name": name, "unit": unit, "better": better, "source": source,
-         "layer": layer, "moves": "search_p50_ms", "workloads": [FOUR]}
-        for name, (unit, better, source, layer) in FOUR_METRICS.items()]
-
-
 def tiny_root(tmp: str) -> str:
     os.makedirs(os.path.join(tmp, "benchmark", "configs"))
     for d in ("metrics", "traffic", "kinds"):
@@ -97,12 +71,9 @@ def tiny_root(tmp: str) -> str:
                         os.path.join(tmp, "benchmark", d),
                         ignore=shutil.ignore_patterns("__pycache__"))
     doc = _read(ROOT, "BENCHMARK.json")
-    if HELD:
-        add_four_shard_cell(tmp, doc)
     _write(doc, tmp, "BENCHMARK.json")
     for c in doc["configs"]:
-        added = os.path.join(tmp, c["file"])
-        cfg = _read(tmp if os.path.exists(added) else ROOT, c["file"])
+        cfg = _read(ROOT, c["file"])
         cfg["corpus"].update(load_kind(
             os.path.join(tmp, "benchmark"), "corpus", cfg["kind"]).TINY)
         _write(cfg, tmp, c["file"])
@@ -149,12 +120,7 @@ def is_knn(cell: Cell) -> bool:
 def data_of(cell: str) -> Cell:
     """A cell's data as committed (not cut to tiny size), for what has to
     be known while tests are collected; a tiny root is a fixture's to
-    make. The held cell's is put together in memory."""
-    if cell == FOUR and HELD:
-        return Cell(name=FOUR, chips=4, config_name=FOUR_CONFIG,
-                    traffic_name="msearch-closed", config=four_shard_config(),
-                    traffic=_read(ROOT, "benchmark", "traffic",
-                                  "msearch-closed.json"))
+    make."""
     return REAL.cell(cell)
 
 
@@ -173,4 +139,6 @@ def steer_engines(mp, cache_dir: str) -> None:
     mp.setenv("ES_TPU_TURBO_COLD_DF", "32")
     # the four-chip cell's mesh, on the CPU's virtual devices
     mp.setenv("ES_TPU_TURBO_MESH", "4")
+    # the device the recorded traces were taken on reports its peaks
+    mp.setattr("benchmark.run.memory_peaks", lambda: list(RECORDED_PEAKS))
     mp.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)

@@ -256,8 +256,8 @@ def test_a_cell_defined_only_by_new_files_is_found_by_name(tmp_path):
                        "window_s": 2.0}}
     faults = validate.line_faults(line, m, "later.cell", 1)
     assert "metrics lacks sparse_queries.later" in faults
-    # a later CLOSED cell (the held four-chip cell, as bench_tiny adds it)
-    # gets what has something to read there by the metric files' own
+    # a CLOSED cell that came as data (the four-chip cell, PR 37) gets
+    # what has something to read there by the metric files' own
     # `loops`: the pools' wait and the dispatch's steps, not the lane's
     # histograms (its batches skip the lane) nor the generator's lateness
     closed = [x["name"] for x in m.declared(bench_tiny.FOUR, 1)]

@@ -3,7 +3,8 @@ shard count, chips, path parameters, the closed `_msearch` loop, what
 must and must not have moved — on the CPU at tiny size, over the 4-device
 mesh the four-chip cell runs on; and that the two cells accepted before it
 put the same bytes on the wire at the same times as they did. The
-four-shard cell is bench_tiny's: PR 29 held it out of BENCHMARK.json."""
+four-shard cell is the manifest's since PR 37 (PR 29 held it out): its
+committed configuration file is held to bench_tiny's recipe here."""
 
 import dataclasses
 import hashlib
@@ -80,6 +81,59 @@ def test_a_seeded_tiny_corpus_is_what_it_was_before_the_kinds_moved(config):
             h.update(np.ascontiguousarray(v).tobytes()
                      if isinstance(v, np.ndarray) else str(v).encode())
     assert (len(parts), h.hexdigest()) == GOLDEN_CORPUS[config]
+
+
+def test_the_committed_four_shard_file_is_the_recipe_plus_its_size_and_cuts():
+    """benchmark/configs/<FOUR_CONFIG>.json = `four_shard_config()` (the
+    accepted bm25 file, four shards of one segment, the path parameter,
+    what must and must not move) and exactly what landing the cell added:
+    the host's half of the collection, every cut named, the words about
+    both. The recipe and the file cannot drift, and neither can the
+    one-chip file it starts from."""
+    cell = bench_tiny.REAL.cell(FOUR)
+    assert (cell.chips, cell.config_name, cell.traffic_name) == (
+        4, bench_tiny.FOUR_CONFIG, "msearch-closed-4shard")
+    got, want = cell.config, bench_tiny.four_shard_config()
+    differs = {k for k in set(got) | set(want) if got.get(k) != want.get(k)}
+    assert differs == {"corpus", "reduced", "deployment", "guarantees"}
+    corpus = dict(got["corpus"])
+    assert corpus.pop("docs") == 4_420_912 == 4 * want["corpus"].pop("docs")
+    assert corpus == want["corpus"]          # every width of the source kept
+    assert corpus["published_docs"] == 2 * 4_420_912 - 1
+    assert got["reduced"] == ["docs", "stored_source", "segments"]
+    assert got["guarantees"].startswith(want["guarantees"])
+    for word in ("dfs_query_then_fetch", "merge_host"):
+        assert word in got["guarantees"]
+    for word in ("BASELINE.json", "collection.tsv", "segment"):
+        assert word in got["deployment"]
+    # the traffic is the twin's but for the warm-up calls (and why): the
+    # two cells send the same calls in their windows
+    twin = bench_tiny.REAL.cell(bench_tiny.TWIN).traffic
+    own = {k: v for k, v in cell.traffic.items() if k != "why"}
+    assert own == dict(twin, warmup={"calls": 8}) and cell.traffic["why"]
+
+
+def test_hbm_peak_skew_reads_the_spread_of_the_chips_peaks():
+    """The fullest chip over the mean of the chips, from what a result
+    line prints as `memory_peak_bytes_per_device`: even = 1.0, one chip of
+    four holding everything = 4.0; where no device reports a peak (the
+    CPU backend) there is nothing to read, never a 0."""
+    m = Manifest(ROOT)
+    entry = next(x for x in m.doc["per_layer"]
+                 if x["name"] == "hbm_peak_skew.search")
+    assert entry["workloads"] == [FOUR] and entry["better"] == "lower"
+    assert (entry["moves"], entry["layer"]) == ("hbm_peak_gb", "device")
+    spec = m.metric_spec("hbm_peak_skew.search")
+    w = _roofline_window([], 4, {})
+    for peaks, want in (([2 << 30] * 4, 1.0), ([8 << 30, 0, 0, 0], 4.0),
+                        (bench_tiny.RECORDED_PEAKS, 2.3565),
+                        ([5 << 30], 1.0)):
+        w.notes["memory_peaks"] = peaks
+        assert readers.read(spec, w) == pytest.approx(want, abs=1e-4)
+    for peaks in ([], [0, 0, 0, 0]):
+        w.notes["memory_peaks"] = peaks
+        with pytest.raises(readers.NothingToRead):
+            readers.read(spec, w)
 
 
 @pytest.fixture(scope="module")
