@@ -69,7 +69,9 @@ REPORTED = (
     "tpu_turbo.partition_dispatches", "tpu_turbo.fused_dispatches",
     "tpu_turbo.merge_device", "tpu_turbo.merge_host",
     "tpu_turbo.sparse_queries", "tpu_turbo.bitset_packs",
-    "tpu_turbo.bitset_gallop", "tpu_knn.knn_queries",
+    "tpu_turbo.bitset_gallop", "tpu_turbo.bool_device",
+    "tpu_turbo.bool_cold_lead", "tpu_turbo.bool_host",
+    "tpu_turbo.phrase_builds", "tpu_knn.knn_queries",
     "tpu_knn.knn_int8_dispatches", "tpu_knn.knn_uncertified",
     "tpu_agg.agg_device_dispatches",
     "tpu_compile.misses", "tpu_compile.retraces",
@@ -297,6 +299,7 @@ class Phase:
     name: str
     run: Callable[[Smoke], dict]          # returns notes for the phase line
     must_increase: Tuple[str, ...] = ()
+    must_stay: Tuple[str, ...] = ()       # over this phase's requests
 
 
 class _FirstTraceback(logging.Handler):
@@ -352,6 +355,12 @@ def run_phase(smoke: Smoke, phase: Phase) -> dict:
                 f"phase {phase.name}: counter {c} did not move "
                 f"({counter(before, c)} -> {counter(after, c)}): the device "
                 "path this phase exists for did not serve it")
+    for c in phase.must_stay:
+        if counter(after, c) != counter(before, c):
+            raise SmokeFailure(
+                f"phase {phase.name}: counter {c} moved "
+                f"({counter(before, c)} -> {counter(after, c)}): a route "
+                "this phase may not take answered")
     for c in ZERO_COUNTERS:
         moved = counter(after, c) - counter(smoke.baseline, c)
         if moved:
@@ -515,13 +524,16 @@ def phase_match(s: Smoke) -> dict:
 
 def phase_bool(s: Smoke) -> dict:
     # TurboBM25 serves a bool whose clauses share ONE postings field
-    # (serving._turbo_bool_spec); required clauses on hot terms ride the
-    # device bitset intersection
-    hot, _ = _term_ranks(s.corpus)
+    # (serving._turbo_bool_spec): the conjunction is intersected on the
+    # device, a hot clause by its column's presence bits, a cold one
+    # (every other request's must term here) by its cold row
+    hot, cold = _term_ranks(s.corpus)
     rng = np.random.default_rng(s.seed + 20)
     bodies = []
-    for _ in range(32):
+    for i in range(32):
         a, f, x = rng.choice(hot, size=3, replace=False)
+        if i % 2 and len(cold):
+            a = rng.choice(cold)
         bodies.append({"query": {"bool": {
             "must": [{"match": {"body": f"t{a}"}}],
             "filter": [{"term": {"body": f"t{f}"}}],
@@ -533,9 +545,9 @@ def phase_bool(s: Smoke) -> dict:
 
 
 def phase_phrase(s: Smoke) -> dict:
-    # the 16 commonest adjacent pairs: each segment then holds far more
-    # matches than ES_TPU_BITSET_HOST_DF (512), below which a conjunction
-    # is deliberately finished on the host and never reaches the device
+    # the 16 commonest adjacent pairs (a pair that is rare in a segment
+    # is answered from its conjunction mask, a common one by the
+    # mask-gated sweep: both on the device)
     c = s.corpus
     inside = np.ones(len(c.tokens) - 1, bool)
     inside[c.bounds[1:-1] - 1] = False         # pairs spanning two docs
@@ -631,8 +643,13 @@ PHASES = (
     Phase("ingest", phase_ingest),
     Phase("match", phase_match, ("tpu_turbo.partition_dispatches",
                                  "tpu_turbo.sparse_queries")),
-    Phase("bool", phase_bool, ("tpu_turbo.bitset_packs",)),
-    Phase("phrase", phase_phrase, ("tpu_turbo.partition_dispatches",)),
+    Phase("bool", phase_bool,
+          ("tpu_turbo.bitset_packs", "tpu_turbo.bool_device",
+           "tpu_turbo.bool_cold_lead"),
+          ("tpu_turbo.bool_host", "tpu_turbo.bitset_gallop")),
+    Phase("phrase", phase_phrase,
+          ("tpu_turbo.bool_device", "tpu_turbo.phrase_builds"),
+          ("tpu_turbo.bool_host", "tpu_turbo.bitset_gallop")),
     Phase("knn", phase_knn, ("tpu_knn.knn_int8_dispatches",
                              "tpu_knn.knn_queries")),
     Phase("aggs", phase_aggs, ("tpu_agg.agg_device_dispatches",)),

@@ -522,6 +522,9 @@ declare_histogram("dispatch.mask", "ms", "within prep (kNN): per-partition filte
 declare_histogram("dispatch.sparse_gather", "ms", "within finish (BM25): collecting the cold side's sparse gather, launched behind the sweep, from its copy on the host")
 declare_histogram("dispatch.rescore", "ms", "within finish: exact rescore (BM25 _exact_scores; kNN host row gather + rescore program + fetch)")
 declare_histogram("dispatch.cert_fallback", "ms", "within finish (BM25): full exact merge after a failed certificate")
+declare_histogram("dispatch.bool_resolve", "ms", "within prep (BM25 bool route): resolving the batch's specs against every partition's terms")
+declare_histogram("dispatch.phrase_build", "ms", "within prep (BM25 bool route): the positions scan of a phrase named for the first time and its adjacency column's build")
+declare_histogram("dispatch.bitset_pack", "ms", "within prep (BM25 bool route): packing the match-set bitsets from the column cache after it moved, and packing + writing the cold clauses' rows")
 declare_histogram("dispatch.dense_rerun", "ms", "within finish (kNN): dense route re-run of the uncertified queries")
 # engine build steps (PR 27): they run in set-up or under the first
 # request of a field, so no benchmark reader (window deltas) sees them;

@@ -323,10 +323,12 @@ declare_knob("ES_TPU_BITSET", "flag", True,
              "Packed-uint32 bitset intersection for bool queries: clause "
              "match sets AND/AND-NOT blockwise on device and the sweep "
              "skips all-zero blocks (0 = dense coverage-matmul sweep)")
-declare_knob("ES_TPU_BITSET_HOST_DF", "int", 512,
+declare_knob("ES_TPU_BITSET_HOST_DF", "int", 0,
              "Bool queries whose rarest required clause has df below this "
              "route to the galloping host intersection instead of the "
-             "device bitset sweep (0 disables the fallback)")
+             "device bitset route, for A/B (0, the default, = never: a "
+             "rare lead is answered from its conjunction mask on the "
+             "device)")
 declare_knob("ES_TPU_SPARSE", "flag", True,
              "Eager sparse impact slices: cold (df < COLD_DF) terms score "
              "on device via the sparse_gather kernel, launched with each "

@@ -86,6 +86,48 @@ def _offset_tuples(n_terms: int, slop: int):
         yield (0,) + offs
 
 
+_DENSE_PHRASE_RATIO = 8     # terms within this factor of each other in
+#   total frequency: nearly every doc of one holds the others, so cutting
+#   the position lists to the candidate docs costs more than it saves
+
+
+def _term_keys(fp: FieldPostings, ord_: int, stride: int):
+    """(keys i64 ascending, docs i64) of every occurrence of a term: key
+    = doc * stride + position. The term's occurrences are one contiguous,
+    ordered run of `pos_data` (docs ascending, positions ascending inside
+    a doc), so nothing is gathered."""
+    lo, hi = int(fp.post_start[ord_]), int(fp.post_start[ord_ + 1])
+    docs = np.repeat(fp.post_doc[lo:hi].astype(np.int64),
+                     np.diff(fp.pos_start[lo: hi + 1]))
+    pos = fp.pos_data[int(fp.pos_start[lo]): int(fp.pos_start[hi])]
+    return docs * stride + pos, docs
+
+
+def _phrase_freqs_dense(fp: FieldPostings, ords: List[int]):
+    """Slop-0 `phrase_freqs` for terms of comparable frequency, over the
+    terms' whole position runs: the rarest term's occurrences are looked
+    up, shifted to each other term's place in the phrase, in that term's
+    keys. The same (docs, freqs) as the candidate-restricted walk."""
+    max_pos = getattr(fp, "_max_pos_cache", None)
+    if max_pos is None:
+        max_pos = int(fp.pos_data.max()) if len(fp.pos_data) else 0
+        fp._max_pos_cache = max_pos   # immutable postings: compute once
+    stride = max_pos + len(ords) + 2
+    b = min(range(len(ords)), key=lambda i: int(fp.total_term_freq[ords[i]]))
+    base, docs = _term_keys(fp, ords[b], stride)
+    ok = np.ones(len(base), bool)
+    for i, o in enumerate(ords):
+        if i == b:
+            continue
+        keys, _ = _term_keys(fp, o, stride)
+        want = base + (i - b)
+        j = np.searchsorted(keys, want)
+        j[j == len(keys)] = 0
+        ok &= keys[j] == want if len(keys) else False
+    hit, freq = np.unique(docs[ok], return_counts=True)
+    return hit.astype(np.int32), freq.astype(np.float32)
+
+
 def phrase_freqs(fp: FieldPostings, terms: List[str], slop: int = 0,
                  docs_filter: np.ndarray | None = None,
                  ) -> Tuple[np.ndarray, np.ndarray]:
@@ -112,9 +154,24 @@ def phrase_freqs(fp: FieldPostings, terms: List[str], slop: int = 0,
         tf = (fp.pos_start[lo + 1: hi + 1] - fp.pos_start[lo:hi]).astype(np.float32)
         return docs, tf
 
-    cand = candidate_docs(fp, ords)
-    if docs_filter is not None and len(cand):
-        cand = cand[np.isin(cand, docs_filter, assume_unique=True)]
+    if slop == 0 and docs_filter is None:
+        ttf = [int(fp.total_term_freq[o]) for o in ords]
+        if min(ttf) * _DENSE_PHRASE_RATIO > max(ttf):
+            return _phrase_freqs_dense(fp, ords)
+
+    if docs_filter is None:
+        cand = candidate_docs(fp, ords)
+    else:
+        # the filter's docs that hold every term, looked up in each
+        # term's (sorted) docs: a filter is small beside a frequent term
+        cand = np.asarray(docs_filter, np.int32)
+        for o in ords:
+            docs = fp.post_doc[int(fp.post_start[o]): int(fp.post_start[o + 1])]
+            if not len(cand) or not len(docs):
+                cand = cand[:0]
+                break
+            j = np.minimum(np.searchsorted(docs, cand), len(docs) - 1)
+            cand = cand[docs[j] == cand]
     if len(cand) == 0:
         return np.empty(0, np.int32), np.empty(0, np.float32)
 

@@ -352,6 +352,11 @@ BITSET_CLAUSES = 8             # AND fan-in per intersect step (rarest-df
 #                                SUPERSET — the exact host rescore drops
 #                                spurious survivors, so top-k is unchanged)
 BITSET_NEGS = 4                # AND-NOT fan-in (largest-df prohibitions)
+BITSET_COLD_ROWS = 64          # rows behind the column slots' for the match
+#                                sets of COLD required / prohibited clauses
+#                                (df < COLD_DF: no column to pack from), packed
+#                                on the host from the postings when a bool
+#                                request first names the term
 
 
 @jax.jit
@@ -431,6 +436,7 @@ def intersect_bitset(q_slots, q_neg, bits, *, QC: int, nsw: int):
     )
     fn = pl.pallas_call(
         _intersect_kernel(),
+        name="intersect_bitset",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((QC, wgr, 128), jnp.uint32),
         compiler_params=pltpu.CompilerParams(
@@ -439,6 +445,33 @@ def intersect_bitset(q_slots, q_neg, bits, *, QC: int, nsw: int):
     )
     return fn(q_slots, q_neg,
               *([bits] * (BITSET_CLAUSES + BITSET_NEGS)))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def bitset_repack(bits, cols_hi, cols_lo):
+    """`pack_presence_bits` of the column cache written over the first
+    Hp + 2 rows of the (donated) bitset array; the cold rows behind them
+    stay as they are."""
+    return jax.lax.dynamic_update_slice(
+        bits, pack_presence_bits(cols_hi, cols_lo), (0, 0, 0))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def bitset_write_rows(bits, idx, rows):
+    """Write whole rows (host-packed match sets of cold clauses) into the
+    (donated) bitset array: bits [R, wgr, 128] u32, idx [n] i32, rows
+    [n, wgr, 128] u32. A padding entry repeats a real one."""
+    return bits.at[idx].set(rows)
+
+
+@jax.jit
+def mask_live_counts(mask, live_bits):
+    """Per-query population count of the intersected match set among live
+    docs: the conjunction's exact hit count on this partition. mask
+    [QC, wgr, 128] u32, live_bits [wgr, 128] u32 (the live mask packed as
+    the bitset rows are). Returns [QC] i32."""
+    return jnp.sum(jax.lax.population_count(mask & live_bits[None]),
+                   axis=(1, 2)).astype(jnp.int32)
 
 
 @jax.jit
@@ -582,6 +615,7 @@ def sweep_rowmax_bitset(qscale, cols_hi, cols_lo, wq, mask, live,
     )
     fn = pl.pallas_call(
         kernel,
+        name="sweep_rowmax_bitset",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((nsw, QC, CAND_PAD), jnp.float32),

@@ -64,11 +64,12 @@ from elasticsearch_tpu.index.segment import tf_at
 from elasticsearch_tpu.ops import bm25_idf
 from elasticsearch_tpu.parallel.blockmax import _host_block_scores
 from elasticsearch_tpu.parallel.kernels import (
-    BITSET_CLAUSES, BITSET_NEGS, COLSCALE, COLSCALE2, MAX_GROUP_ROWS,
-    N_CHUNKS, NCAND, ROWS_PER_STEP, SG_FIRST, SG_PICK, SG_SCATTER,
-    SPARSE_GRAN, SPARSE_IMP_MAX, SW, SW_WORD_ROWS, TILE, build_columns,
-    intersect_bitset, mask_chunk_counts, pack_presence_bits, sparse_gather,
-    sparse_pool_update, sweep_rowmax, sweep_rowmax_bitset, sweep_rowmax_conj,
+    BITSET_CLAUSES, BITSET_COLD_ROWS, BITSET_NEGS, COLSCALE, COLSCALE2,
+    MAX_GROUP_ROWS, N_CHUNKS, NCAND, ROWS_PER_STEP, SG_FIRST, SG_PICK,
+    SG_SCATTER, SPARSE_GRAN, SPARSE_IMP_MAX, SW, SW_WORD_ROWS, TILE,
+    bitset_repack, bitset_write_rows, build_columns, intersect_bitset,
+    mask_chunk_counts, mask_live_counts, sparse_gather, sparse_pool_update,
+    sweep_rowmax, sweep_rowmax_bitset, sweep_rowmax_conj,
 )
 from elasticsearch_tpu.parallel.spmd import StackedBM25
 
@@ -86,7 +87,8 @@ from functools import partial as _partial  # noqa: E402
 # observation per call, see common/metrics.py
 DISPATCH_STEPS = metrics.DISPATCH_TOP_STEPS + (
     "dispatch.slice_build", "dispatch.sparse_gather", "dispatch.rescore",
-    "dispatch.cert_fallback")
+    "dispatch.cert_fallback", "dispatch.bool_resolve",
+    "dispatch.phrase_build", "dispatch.bitset_pack")
 
 
 @_partial(jax.jit, static_argnames=("n_rows",))
@@ -226,6 +228,17 @@ def _pkey(terms: Sequence[str]) -> str:
     return "\x00p:" + "\x00".join(terms)
 
 
+def _once(fn: Callable):
+    """`fn`'s result, computed at the first call and kept."""
+    got: list = []
+
+    def get():
+        if not got:
+            got.append(fn())
+        return got[0]
+    return get
+
+
 def _intersect_sorted(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     """Sorted-unique intersection with a galloping gear: when one side is
     tiny relative to the other (the ultra-selective-lead regime that
@@ -255,16 +268,50 @@ class _BoolQuery:
     must_not: list    # [(term, _TermInfo)] — prohibited
     phrases: list     # [(terms, slop, boost, _PhraseInfo | None, idf_sum)]
     dev_candidate: bool
+    # set by _bool_routes for a device-routed query: the rarest required
+    # clause's df, whether that lead is a cold TERM (no column: its match
+    # set is a cold row of the bitsets), and the route its finish takes.
+    # Under cold_df every match fits the exact rescore, so the query is
+    # answered from the conjunction mask itself (`by_mask`: no sweep
+    # weights, no row pick, no certificate); above it from the sweep's
+    # picked rows
+    lead_df: int = 0
+    cold_lead: bool = False
+    by_mask: bool = False
+
+    def required(self) -> list:
+        """[(term, _TermInfo)] of the required TERM clauses."""
+        return [(t, i) for t, _, i in self.conj] + self.filters
 
 
-# node-wide bitset counters mirrored from every engine's per-instance
+# what a partition adds to a query's `totals` entry when it cannot count
+# its matches on the device (a host-routed query, a mask that is a
+# superset, a fault): the sum over partitions then stays negative
+_NO_TOTAL = -(1 << 40)
+_PHRASE_HOST_BYTES = 64 << 20    # scanned phrases kept on the host, a
+#   partition: the commonest pair of a 368,409-document segment is 0.8 MB
+_ROW_UP_BUCKETS = (8, BITSET_COLD_ROWS)   # cold rows written at once: two
+#   shapes of `kernels.bitset_write_rows`, both instantiated when the
+#   bitsets are allocated, so no dispatch builds one (a row is 46 KB at
+#   368,409 docs: padding a write to 8 rows costs a third of a megabyte)
+
+
+# node-wide bool-route counters mirrored from every engine's per-instance
 # stats so GET /_nodes/stats tpu_turbo surfaces them next to the merge
 # counters (serving.turbo_node_stats folds these in); bitset_bytes is a
-# gauge-like running total of currently packed bytes (repacks add the
-# delta), the rest are cumulative counters
+# gauge-like running total of currently packed bytes, the rest are
+# cumulative. The route counters count (partition, request) PAIRS, all
+# four of them, so their shares are consistent: bool_device = pairs the
+# device route answered, of which bool_cold_lead had a cold term as their
+# rarest required clause; bool_host = pairs `_bool_host_exact` answered
+# (the router's host route, a faulted partition or chunk); bitset_gallop =
+# pairs ES_TPU_BITSET_HOST_DF moved to the host. phrase_builds = adjacency
+# columns built
 _NODE_BITSET_STATS = {"bitset_packs": 0, "bitset_bytes": 0,
                       "bitset_blocks_skipped": 0,
-                      "bitset_gallop": 0}  # guarded by: _NODE_BITSET_LOCK
+                      "bitset_gallop": 0, "bool_device": 0, "bool_host": 0,
+                      "bool_cold_lead": 0, "phrase_builds": 0
+                      }  # guarded by: _NODE_BITSET_LOCK
 _NODE_BITSET_LOCK = threading.Lock()
 
 
@@ -539,6 +586,7 @@ class TurboBM25:
         self._tick = 0
         self._terms: Dict[str, Optional[_TermInfo]] = {}
         self._phrases: Dict[str, Optional[_PhraseInfo]] = {}
+        self._phrase_bytes = 0     # of the (docs, pf) arrays in _phrases
         # per-cache-key tile bases touched by the key's build groups, kept
         # so eviction can zero exactly those tiles even for keys (phrases)
         # whose lane arrays are long gone
@@ -567,6 +615,20 @@ class TurboBM25:
         # re-packed whenever cols_epoch moves
         self.bits = None
         self._bits_epoch = -1
+        self.bits_version = 0     # moves with every write to `bits`
+        self.live_bits = None     # the live mask packed as a bitset row
+        self._wgr = self.dp_rows // 32
+        # cold rows of `bits` (behind the Hp + 2 column rows): the match
+        # sets of COLD required / prohibited clauses, packed on the host
+        # from the postings when a bool request first names the term
+        # (`_ensure_cold_rows`), LRU over BITSET_COLD_ROWS rows. The host
+        # copy is what the device rows are written from: `_crow_dirty` =
+        # the rows whose host copy the device has not got yet
+        self._crow_of: Dict[str, int] = {}
+        self._crow_lru: Dict[str, int] = {}
+        self._crow_free = list(range(BITSET_COLD_ROWS))
+        self._crow_host: Optional[np.ndarray] = None
+        self._crow_dirty: set = set()
         # eager sparse impact slices (ES_TPU_SPARSE): cold terms keep
         # packed (doc << 8 | impact) granules in a lazily grown device
         # pool, built in the same ensure_columns pass as the columns, so
@@ -588,6 +650,7 @@ class TurboBM25:
         self.stats = {"builds": 0, "build_s": 0.0, "fallbacks": 0,
                       "cold_queries": 0, "dispatches": 0, "degraded": 0,
                       "phrase_builds": 0, "bool_host": 0, "bool_device": 0,
+                      "bool_cold_lead": 0,
                       "bitset_packs": 0, "bitset_gallop": 0,
                       "bitset_blocks_skipped": 0, "bitset_bytes": 0,
                       "sparse_queries": 0, "sparse_slices": 0,
@@ -604,8 +667,7 @@ class TurboBM25:
     def _register_hbm_regions(self) -> None:
         self._hbm.set_region("cols_hi", self.cols_hi.nbytes)
         self._hbm.set_region("cols_lo", self.cols_lo.nbytes)
-        self._hbm.set_region("cols_bits",
-                             0 if self.bits is None else self.bits.nbytes)
+        self._hbm.set_region("cols_bits", self._bits_nbytes())
         self._hbm.set_region(
             "sparse_pool",
             0 if self._sp_pool is None else self._sp_pool.nbytes)
@@ -641,9 +703,18 @@ class TurboBM25:
                 epoch=lambda o, n=name: id(getattr(o, n)),
                 repair=lambda o: o._reset_columns())
 
+    def _bits_nbytes(self) -> int:
+        return (0 if self.bits is None
+                else self.bits.nbytes + self.live_bits.nbytes)
+
+    def _count(self, key: str, n: int = 1) -> None:
+        """A bool-route counter: this engine's `stats` and the node's."""
+        self.stats[key] += n
+        _node_bitset_add(key, n)
+
     def hbm_bytes(self) -> int:
         return (self.cols_hi.nbytes + self.cols_lo.nbytes
-                + (0 if self.bits is None else self.bits.nbytes)
+                + self._bits_nbytes()
                 + (0 if self._sp_pool is None else self._sp_pool.nbytes)
                 + self.lane_docs.nbytes + self.lane_scores.nbytes
                 + self.live.nbytes)
@@ -724,10 +795,10 @@ class TurboBM25:
         # churn accounting: a slot is 2 bytes/padded-doc (hi + lo layers)
         self._hbm.note_eviction(freed_bytes=2 * self.Dp)
         self._hbm.note_zeroed_tiles(0 if bases is None else len(bases))
-        if key.startswith("\x00p:"):
-            # phrase metadata carries the (docs, pf) arrays — drop them
-            # with the column, recompute if the phrase is colized again
-            self._phrases.pop(key, None)
+        # (a phrase's metadata, its (docs, pf) arrays, outlives its column:
+        # the positions scan that made them is what a phrase costs, and
+        # the phrases users repeat are the frequent, expensive ones;
+        # `_phrase` bounds what is kept)
 
     def _reset_columns(self) -> None:
         """Drop the whole column cache. After a failed build dispatch the
@@ -874,12 +945,23 @@ class TurboBM25:
         terms = tuple(terms)
         key = _pkey(terms)
         if key in self._phrases:
-            return self._phrases[key]
+            info = self._phrases[key] = self._phrases.pop(key)   # newest
+            return info
         infos = [self._term(t) for t in terms]
         if any(i is None for i in infos):
             self._phrases[key] = None
             return None
-        docs, pf = phrase_freqs(self.fp, list(terms), slop=0)
+        within = None
+        if len(terms) > 2:
+            # every occurrence lies in a doc that holds each adjacent pair
+            # side by side: the rarest pair's docs (a phrase of its own,
+            # scanned once and kept like any) bound the walk
+            j = min(range(len(terms) - 1),
+                    key=lambda j: infos[j].df * infos[j + 1].df)
+            pair = self._phrase(terms[j: j + 2])
+            within = pair.docs if pair is not None else np.empty(0, np.int32)
+        docs, pf = phrase_freqs(self.fp, list(terms), slop=0,
+                                docs_filter=within)
         docs = np.asarray(docs, np.int32)
         pf = np.asarray(pf, np.float32)
         # idf-free phrase lane scores: same shape as a term's BM25 lane
@@ -894,6 +976,14 @@ class TurboBM25:
             key=key, terms=terms, docs=docs, pf=pf,
             idf_sum=float(sum(i.idf for i in infos)), smax=smax)
         self._phrases[key] = info
+        self._phrase_bytes += docs.nbytes + pf.nbytes
+        for old in list(self._phrases):      # oldest first
+            if self._phrase_bytes <= _PHRASE_HOST_BYTES:
+                break
+            if old not in self._slot_of and old != key:
+                gone = self._phrases.pop(old)
+                if gone is not None:
+                    self._phrase_bytes -= gone.docs.nbytes + gone.pf.nbytes
         return info
 
     def _phrase_lane(self, info: _PhraseInfo) -> np.ndarray:
@@ -984,7 +1074,7 @@ class TurboBM25:
         lane_scores = jnp.asarray(np.concatenate(dvals, axis=0))
         self._build_columns(rows_l, n_l, base_l, slot_l,
                             lane_docs, lane_scores, len(need))
-        self.stats["phrase_builds"] += len(need)
+        self._count("phrase_builds", len(need))
 
     def _cold_contrib(self, cold_terms):
         """(docs i64 unique-sorted, contrib f64, inv) — the cold terms'
@@ -1500,10 +1590,14 @@ class TurboBM25:
         holds them (`_survivor_terms`). And candidates that come as picked
         ROWS are row-aligned: given a chunk's plan and its rows [n, R] the
         totals of every doc in them are read by row span, the whole chunk
-        at once (`_rescore_rows`: the same expression a cell). Every exact
-        score a finish produces leaves through this one function."""
+        at once (`_rescore_rows`: the same expression a cell). A bool
+        query's clause sums come as the f64 array `_exact_bool` made of
+        them, for their one downcast. Every exact score a finish
+        produces leaves through this one function."""
         if isinstance(qterms, _ChunkPlan):
             return self._rescore_rows(qterms, docs)
+        if isinstance(qterms, np.ndarray):
+            return qterms.astype(np.float32)
         total = np.zeros(len(docs), np.float32)
         word = None
         for _, boost, info, *known in qterms:
@@ -1902,24 +1996,45 @@ class TurboBM25:
 
     # ---------------- bool / phrase search ----------------
     #
-    # The conjunctive sweep scores with the SAME int8 columns as the
-    # disjunctive one but multiplies in a presence mask: a doc survives
-    # only if every required slot's column is nonzero there (and no
-    # resident must_not slot's is). Presence is EXACT because the build
-    # kernel forces lo >= 1 on presence-only cells, so the device-side
-    # conjunction/filtering never needs host verification — only scores
-    # do, and the host rescores every collected doc exactly, with the
-    # certificate bounding uncollected rows just like the disjunctive
-    # path. Cold SHOULD terms ride the _cold_contrib enumeration; cold
-    # REQUIRED clauses route the whole query to the exact host path
-    # (complete: every match lies inside the rarest required clause's
-    # postings, so no certificate is needed there).
+    # A bool request is a conjunction: its matches are the docs every
+    # required clause (must, filter, slop-0 phrase) holds and no prohibited
+    # one does. The DEVICE computes that set for every (partition, query):
+    # each clause has a row of the packed bitsets (a term with a column:
+    # the column's presence, exact because the build kernel forces lo >= 1
+    # on presence-only cells; a phrase: its adjacency column's; a COLD term,
+    # df < cold_df: a cold row packed from its postings when first named,
+    # `_ensure_cold_rows`), `intersect_bitset` ANDs / AND-NOTs them, and the
+    # population count of the result among live docs is the partition's
+    # exact hit count (`mask_live_counts`: hits.total comes from the route
+    # that found the hits). The top k then come one of two ways, chosen by
+    # what the plan can see, the rarest required clause's df:
+    #
+    # * under cold_df (a cold lead, a rare phrase) every match fits an exact
+    #   rescore: the query's mask row is fetched, its set bits ARE the
+    #   candidates, `_exact_bool` scores them (`_finish_mask`). No sweep
+    #   weights, no certificate;
+    # * at or above it the mask gates the int8 sweep (`sweep_rowmax_bitset`
+    #   skips chunks without a surviving bit), the device picks candidate
+    #   rows, the host rescores every doc in them exactly and a certificate
+    #   bounds what uncollected rows could hold, just like the disjunctive
+    #   path; cold SHOULD terms ride a sparse gather. A failed certificate
+    #   falls back to the mask, not to a host intersection.
+    #
+    # Both end in `_exact_bool`, as the host route does, so all three are
+    # bit-identical. `_bool_host_exact` (sorted-array intersection on the
+    # host) is what a faulted partition or chunk falls back to, what a
+    # query the device cannot represent takes (slop != 0, no scoring
+    # clause), and what ES_TPU_BITSET=0's dense coverage engine
+    # (`sweep_rowmax_conj`) leaves cold required clauses to.
 
-    def _resolve_bool(self, spec: dict) -> Optional[_BoolQuery]:
+    def _resolve_bool(self, spec: dict,
+                      use_bits: bool = True) -> Optional[_BoolQuery]:
         """Resolve one bool spec; None means provably zero matches.
 
         spec keys (all optional): "must"/"should" [(term, boost)],
-        "filter"/"must_not" [term], "phrases" [(terms, slop, boost)]."""
+        "filter"/"must_not" [term], "phrases" [(terms, slop, boost)].
+        A phrase's `_PhraseInfo` is taken where it is cached; the scan of
+        one that is not is `_scan_phrases`' (device candidates only)."""
         conj, should, filters, must_not, phrases = [], [], [], [], []
         for t, b in spec.get("must", ()):
             info = self._term(t)
@@ -1945,7 +2060,7 @@ class TurboBM25:
         phrase_specs = [(tuple(p[0]), int(p[1]), float(p[2]))
                         for p in spec.get("phrases", ())]
         req_infos = [i for _, _, i in conj] + [i for _, i in filters]
-        dev = (all(i.df >= self.cold_df for i in req_infos)
+        dev = ((use_bits or all(i.df >= self.cold_df for i in req_infos))
                and all(s == 0 for _, s, _ in phrase_specs)
                and len(req_infos) + len(phrase_specs) <= _MAX_REQ
                and bool(any(b != 0.0 for _, b, _ in conj) or should
@@ -1955,63 +2070,92 @@ class TurboBM25:
             if any(i is None for i in infos):
                 return None          # phrase term absent: no phrase match
             idf_sum = float(sum(i.idf for i in infos))
-            pinfo = None
-            if slop == 0 and (dev or
-                              self._phrases.get(_pkey(terms)) is not None):
-                # resolve the full-corpus phrase scan only for queries
-                # headed to the device (host-routed ones verify positions
-                # docs_filter'd to the term intersection instead)
-                pinfo = self._phrase(terms)
-                if pinfo is None or not len(pinfo.docs):
-                    return None      # required phrase matches nothing
+            pinfo = self._phrases.get(_pkey(terms)) if slop == 0 else None
+            if pinfo is not None and not len(pinfo.docs):
+                return None          # required phrase matches nothing
             phrases.append((terms, slop, boost, pinfo, idf_sum))
         return _BoolQuery(conj=conj, should=should, filters=filters,
                           must_not=must_not, phrases=phrases,
                           dev_candidate=dev)
 
+    def _scan_phrases(self, resolved: List[Optional[_BoolQuery]]) -> None:
+        """The positions scan of every slop-0 phrase a device candidate
+        names for the first time (`_phrase`: cached after it); a query
+        whose required phrase matches nothing resolves to None, in
+        place. Host-routed queries verify positions docs_filter'd to the
+        term intersection instead."""
+        for qi, r in enumerate(resolved):
+            if r is None or not r.dev_candidate:
+                continue
+            for j, (terms, slop, boost, pinfo, idf_sum) in enumerate(
+                    r.phrases):
+                if pinfo is None:
+                    pinfo = self._phrase(terms)
+                    r.phrases[j] = (terms, slop, boost, pinfo, idf_sum)
+                if pinfo is None or not len(pinfo.docs):
+                    resolved[qi] = None
+                    break
+
+    def _row_of(self, t: str, info: _TermInfo) -> Optional[int]:
+        """The bitset row of a term clause NOW: its column's slot, or for
+        a cold term its cold row; None = neither is resident."""
+        if info.df >= self.cold_df:
+            return self._slot_of.get(t)
+        c = self._crow_of.get(t)
+        return None if c is None else self.Hp + 2 + c
+
     def _bool_resident(self, r: _BoolQuery) -> bool:
-        for t, _, _ in r.conj:
-            if t not in self._slot_of:
-                return False
-        for t, _ in r.filters:
-            if t not in self._slot_of:
+        for t, info in r.required():
+            if self._row_of(t, info) is None:
                 return False
         for terms, _, _, pinfo, _ in r.phrases:
             if pinfo is None or pinfo.key not in self._slot_of:
                 return False
         return True
 
-    def _ensure_bool(self, resolved: Sequence[Optional[_BoolQuery]]):
-        """Warm term + adjacency columns for the device-candidate queries
-        in a resolved batch (shared by search_bool and the fused
-        multi-partition path)."""
+    def _ensure_bool(self, resolved: List[Optional[_BoolQuery]],
+                     use_bits: bool = True) -> None:
+        """Everything the device route of a resolved batch reads, made
+        resident (shared by search_bool and the fused multi-partition
+        path; the three steps are `dispatch.prep`'s children beside
+        `dispatch.bool_resolve`): term columns (and, by ensure_columns'
+        sparse hook, the slices of cold SHOULD terms: `slice_build`);
+        the phrases' scans and adjacency columns (`phrase_build`); the
+        packed bitsets with the cold clauses' rows (`bitset_pack`)."""
         ens_terms: List[str] = []
-        ens_phr: List[Tuple[str, ...]] = []
         pkeys = set()
+        cold_df = self.cold_df
         for r in resolved:
             if r is None or not r.dev_candidate:
                 continue
-            ens_terms += [t for t, _, _ in r.conj]
-            ens_terms += [t for t, _ in r.filters]
-            # cold SHOULD terms ride along: ensure_columns skips them for
-            # the dense cache but its sparse hook slices them eagerly
-            ens_terms += [t for t, _, _ in r.should]
-            ens_terms += [t for t, i in r.must_not
-                          if i.df >= self.cold_df]
-            for terms, _, _, pinfo, _ in r.phrases:
-                if pinfo is not None:
-                    ens_phr.append(pinfo.terms)
-                    pkeys.add(pinfo.key)
+            req = r.required()
+            ens_terms += [t for t, i in req + r.must_not
+                          if i.df >= cold_df]
+            if all(i.df >= cold_df for _, i in req):
+                # (a query with a cold required term is answered from
+                # its mask: it scores nothing on the device)
+                ens_terms += [t for t, _, _ in r.should]
+            pkeys.update(_pkey(p[0]) for p in r.phrases)
         if ens_terms:
             self.ensure_columns(ens_terms, protect_extra=pkeys)
-        if ens_phr:
-            self.ensure_phrases(ens_phr,
-                                protect_extra=set(ens_terms) | pkeys)
+        with tracing.phase("dispatch.phrase_build"):
+            self._scan_phrases(resolved)
+            ens_phr = [p[3].terms for r in resolved
+                       if r is not None and r.dev_candidate
+                       for p in r.phrases if p[3] is not None]
+            if ens_phr:
+                self.ensure_phrases(ens_phr,
+                                    protect_extra=set(ens_terms) | pkeys)
+        if use_bits and any(r is not None and r.dev_candidate
+                            for r in resolved):
+            with tracing.phase("dispatch.bitset_pack"):
+                self._ensure_cold_rows(resolved)
+                self._ensure_bits()
 
     def _bool_routes(self, resolved: Sequence[Optional[_BoolQuery]]):
-        """(device_idx, host_idx) routing AFTER columns are ensured —
-        device iff the query is a device candidate and every required
-        column is resident NOW."""
+        """(device_idx, host_idx) routing AFTER `_ensure_bool` — device
+        iff the query is a device candidate and every required clause's
+        row is resident NOW; sets the device-routed queries' lead."""
         device_idx: List[int] = []
         host_idx: List[int] = []
         for qi, r in enumerate(resolved):
@@ -2019,9 +2163,19 @@ class TurboBM25:
                 continue
             if r.dev_candidate and self._bool_resident(r):
                 device_idx.append(qi)
+                leads = ([(i.df, i.df < self.cold_df)
+                          for _, i in r.required()]
+                         + [(len(p[3].docs), False) for p in r.phrases])
+                r.lead_df, r.cold_lead = min(leads, default=(1 << 60, False))
+                r.by_mask = r.lead_df < self.cold_df
             else:
                 host_idx.append(qi)
         return device_idx, host_idx
+
+    def _count_routes(self, resolved, device_idx) -> None:
+        self._count("bool_device", len(device_idx))
+        self._count("bool_cold_lead",
+                    sum(1 for qi in device_idx if resolved[qi].cold_lead))
 
     def _bool_slots(self, r: _BoolQuery):
         """(scoring [(slot, w, smax)], required slots, must_not slots)
@@ -2069,13 +2223,14 @@ class TurboBM25:
         qscale [QC, 1] f32). A None entry (a query this partition routes
         to host while a fused peer dispatches it) leaves all-zero rows:
         nreq 0 keeps the coverage test vacuous and zero weights score 0
-        (-inf after the positivity mask), so the row never surfaces."""
+        (-inf after the positivity mask), so the row never surfaces. So
+        does a query answered from its mask (`by_mask`)."""
         wq = np.zeros((2, QC, self.Hp + 1), np.int8)
         wp = np.zeros((QC, self.Hp + 1), np.int8)
         nreq = np.zeros((QC, 1), np.int32)
         qscale = np.ones((QC, 1), np.float32)
         for qi, r in enumerate(chunk):
-            if r is None:
+            if r is None or r.by_mask:
                 continue
             scoring, req, mn = self._bool_slots(r)
             nreq[qi, 0] = len(req)
@@ -2102,36 +2257,133 @@ class TurboBM25:
 
     # ---------------- packed-bitset engine (ES_TPU_BITSET) ----------------
 
+    def _doc_bits(self, docs: np.ndarray) -> np.ndarray:
+        """A doc set as one bitset row [wgr * 128] u32, in the layout
+        `kernels.pack_presence_bits` gives a column's: bit j of word
+        [g, l] = doc (32 g + j) * 128 + l."""
+        d = np.asarray(docs, np.int64)
+        word = (d >> 12) * 128 + (d & 127)
+        bit = np.left_shift(1, (d >> 7) & 31).astype(np.float64)
+        # (distinct docs: a word's bits add up below 2^32, exact in f64)
+        return np.bincount(word, weights=bit,
+                           minlength=self._wgr * 128).astype(np.uint32)
+
+    def _mask_docs(self, words: np.ndarray) -> np.ndarray:
+        """The docs (i64, ascending) of a bitset row [wgr * 128] u32."""
+        w = np.flatnonzero(words)
+        bits = np.unpackbits(words[w].view(np.uint8).reshape(-1, 4),
+                             axis=1, bitorder="little")
+        wi, j = np.nonzero(bits)
+        g, lane = np.divmod(w[wi], 128)
+        return np.sort((g * 32 + j) * 128 + lane)
+
     def _repack_bits(self) -> None:
         """Derive the per-slot match-set bitsets from the column cache
-        (presence is exact there — kernels._build_kernel forces lo >= 1).
+        (presence is exact there — kernels._build_kernel forces lo >= 1),
+        into the first Hp + 2 rows of `bits`; the cold rows behind them
+        are written from their host copy (`_sync_cold_rows`).
         device_errors only, no fault_point: callers inject through
         _ensure_bits; scrub repairs must not be separately injectable."""
         with faults.device_errors("bitset_intersect", self.part_id):
-            self.bits = pack_presence_bits(self.cols_hi, self.cols_lo)
+            if self.bits is None:
+                self.bits = jnp.zeros(
+                    (self.Hp + 2 + BITSET_COLD_ROWS, self._wgr, 128),
+                    jnp.uint32)
+                self.live_bits = jnp.asarray(self._doc_bits(
+                    np.flatnonzero(self._live_host > 0)
+                ).reshape(self._wgr, 128))
+                for n in _ROW_UP_BUCKETS:     # (zero rows over zero rows)
+                    self.bits = bitset_write_rows(
+                        self.bits,
+                        jnp.full((n,), self.Hp + 2, jnp.int32),
+                        jnp.zeros((n, self._wgr, 128), jnp.uint32))
+            self.bits = bitset_repack(self.bits, self.cols_hi, self.cols_lo)
         self._bits_epoch = self.cols_epoch
-        self.stats["bitset_packs"] += 1
-        _node_bitset_add("bitset_packs", 1)
+        self.bits_version += 1
+        self._count("bitset_packs")
         _node_bitset_add("bitset_bytes",
-                         self.bits.nbytes - self.stats["bitset_bytes"])
-        self.stats["bitset_bytes"] = self.bits.nbytes
+                         self._bits_nbytes() - self.stats["bitset_bytes"])
+        self.stats["bitset_bytes"] = self._bits_nbytes()
         self._register_hbm_regions()
 
     def _reset_bits(self) -> None:
         """Scrub repair: re-pack from the (separately scrubbed) column
-        cache — host postings remain the source of truth two hops up, so
-        a repaired bitset region serves bit-identical results."""
+        cache and rewrite every cold row from its host copy — host
+        postings remain the source of truth two hops up, so a repaired
+        bitset region serves bit-identical results."""
         self._repack_bits()
+        self._crow_dirty.update(self._crow_of.values())
+        self._sync_cold_rows()
+
+    def _ensure_cold_rows(self, resolved) -> None:
+        """A cold row for every COLD required / prohibited term of the
+        batch's device candidates: host copy only (`_sync_cold_rows`
+        writes the device rows). LRU over the rows; a batch that names
+        more cold clauses than there are rows leaves the rest without
+        one (a required clause without a row host-routes its query; a
+        prohibited one leaves the mask a superset)."""
+        need: Dict[str, _TermInfo] = {}
+        for r in resolved:
+            if r is None or not r.dev_candidate:
+                continue
+            for t, i in r.required() + r.must_not:
+                if i.df < self.cold_df:
+                    need[t] = i
+        if not need:
+            return
+        self._tick += 1
+        if self._crow_host is None:
+            self._crow_host = np.zeros(
+                (BITSET_COLD_ROWS, self._wgr * 128), np.uint32)
+        fp = self.fp
+        for t, info in need.items():
+            if t in self._crow_of:
+                self._crow_lru[t] = self._tick
+                continue
+            if self._crow_free:
+                row = self._crow_free.pop()
+            else:
+                victim = next((v for v in sorted(self._crow_lru,
+                                                 key=self._crow_lru.get)
+                               if v not in need), None)
+                if victim is None:
+                    self.stats["degraded"] += 1
+                    continue
+                row = self._crow_of.pop(victim)
+                del self._crow_lru[victim]
+            lo = int(fp.post_start[info.ord])
+            self._crow_host[row] = self._doc_bits(
+                fp.post_doc[lo: lo + info.df])
+            self._crow_dirty.add(row)
+            self._crow_of[t] = row
+            self._crow_lru[t] = self._tick
+
+    def _sync_cold_rows(self) -> None:
+        """Write the cold rows whose host copy moved into `bits`."""
+        if not self._crow_dirty:
+            return
+        dirty = sorted(self._crow_dirty)
+        n = next(b for b in _ROW_UP_BUCKETS if b >= len(dirty))
+        idx = np.asarray(dirty + dirty[:1] * (n - len(dirty)))
+        with faults.device_errors("bitset_intersect", self.part_id):
+            self.bits = bitset_write_rows(
+                self.bits, jnp.asarray((self.Hp + 2 + idx).astype(np.int32)),
+                jnp.asarray(self._crow_host[idx].reshape(n, self._wgr, 128)))
+        self._crow_dirty.clear()
+        self.bits_version += 1
 
     def _ensure_bits(self) -> None:
-        """Pack (or re-pack after a cols_epoch move) the bitsets before a
-        bitset-engine dispatch; registers the scrub region on first build
-        so the PR-15 integrity plane fingerprints the new columns."""
+        """Pack (or re-pack after a cols_epoch move) the bitsets and
+        write the cold rows that moved, before a bitset-engine dispatch;
+        registers the scrub region on first build so the PR-15 integrity
+        plane fingerprints the new columns."""
         if self.bits is not None and self._bits_epoch == self.cols_epoch:
+            self._sync_cold_rows()
             return
         faults.fault_point("bitset_intersect", self.part_id)
         first = self.bits is None
         self._repack_bits()
+        self._sync_cold_rows()
         if first:
             integrity.register_scrub_region(
                 self, "cols_bits", lambda o: o.bits,
@@ -2139,99 +2391,100 @@ class TurboBM25:
                 repair=lambda o: o._reset_bits())
 
     def _bitset_slots(self, r: _BoolQuery):
-        """(required slots rarest-df-first, must_not slots largest-first)
-        for the intersect kernel's prefetch rows. Clauses beyond the
-        BITSET_CLAUSES / BITSET_NEGS fan-in are dropped from the MASK
-        only — dropping an AND (or an AND-NOT) term leaves the mask a
-        SUPERSET of the true match set, and the exact host rescore
-        re-tests every clause, so top-k stays bit-identical (the cost is
-        spurious candidates, never missed ones)."""
+        """(required rows rarest-df-first, must_not rows largest-first,
+        exact) for the intersect kernel's prefetch rows. Clauses beyond
+        the BITSET_CLAUSES / BITSET_NEGS fan-in, and clauses without a
+        resident row, are dropped from the MASK only — dropping an AND
+        (or an AND-NOT) term leaves the mask a SUPERSET of the true match
+        set, and the exact host rescore re-tests every clause, so top-k
+        stays bit-identical (the cost is spurious candidates, never missed
+        ones). `exact` = nothing was dropped: the mask IS the match set,
+        and its population count the hit count."""
         req: Dict[int, int] = {}
-        for t, _, info in r.conj:
-            slot = self._slot_of.get(t)
-            if slot is not None:
-                req[slot] = min(req.get(slot, 1 << 60), info.df)
-        for t, info in r.filters:
-            slot = self._slot_of.get(t)
-            if slot is not None:
-                req[slot] = min(req.get(slot, 1 << 60), info.df)
+        exact = True
+        for t, info in r.required():
+            row = self._row_of(t, info)
+            if row is None:
+                exact = False
+            else:
+                req[row] = min(req.get(row, 1 << 60), info.df)
         for terms, _, _, pinfo, _ in r.phrases:
-            if pinfo is None:
-                continue
-            slot = self._slot_of.get(pinfo.key)
-            if slot is not None:
+            slot = None if pinfo is None else self._slot_of.get(pinfo.key)
+            if slot is None:
+                exact = False
+            else:
                 req[slot] = min(req.get(slot, 1 << 60), len(pinfo.docs))
-        ordered = sorted(req, key=lambda s: (req[s], s))[:BITSET_CLAUSES]
+        ordered = sorted(req, key=lambda s: (req[s], s))
         mn = []
         for t, info in r.must_not:
-            slot = self._slot_of.get(t)
-            if slot is not None and slot not in req:
-                mn.append((info.df, slot))
+            row = self._row_of(t, info)
+            if row is None:
+                exact = False
+            elif row not in req:
+                mn.append((info.df, row))
+        exact = (exact and 0 < len(ordered) <= BITSET_CLAUSES
+                 and len(mn) <= BITSET_NEGS)
         mn = [s for _, s in sorted(mn, reverse=True)[:BITSET_NEGS]]
-        return ordered, mn
+        return ordered[:BITSET_CLAUSES], mn, exact
 
     def _bitset_prefetch(self, chunk, QC: int):
         """(q_slots [QC, BITSET_CLAUSES], q_neg [QC, BITSET_NEGS]) i32 —
-        the intersect kernel's scalar-prefetch rows. Sentinels: slot Hp
-        (the build scratch slot, always zero) is the AND-NOT identity
-        and the empty mask; slot Hp + 1 is the packed all-ones row. A
-        None entry (a query a fused peer host-routes) points EVERY
-        clause at the zero sentinel so its mask is empty and its chunks
-        all skip; an active query with no resident required clause pads
-        with the ones sentinel (every live doc passes, as with nreq=0)."""
+        the intersect kernel's scalar-prefetch rows — and exact [QC]
+        bool (`_bitset_slots`). Sentinels: slot Hp (the build scratch
+        slot, always zero) is the AND-NOT identity and the empty mask;
+        slot Hp + 1 is the packed all-ones row. A None entry (a query a
+        fused peer host-routes) points EVERY clause at the zero sentinel
+        so its mask is empty and its chunks all skip; an active query
+        with no resident required clause pads with the ones sentinel
+        (every live doc passes, as with nreq=0)."""
         zero_s, ones_s = self.Hp, self.Hp + 1
         q_slots = np.full((QC, BITSET_CLAUSES), zero_s, np.int32)
         q_neg = np.full((QC, BITSET_NEGS), zero_s, np.int32)
+        exact = np.zeros(QC, bool)
         for qi, r in enumerate(chunk):
             if r is None:
                 continue
-            req, mn = self._bitset_slots(r)
+            req, mn, exact[qi] = self._bitset_slots(r)
             if not req:
                 q_slots[qi, :] = ones_s
             else:
                 for j in range(BITSET_CLAUSES):
                     q_slots[qi, j] = req[j] if j < len(req) else req[0]
             q_neg[qi, : len(mn)] = mn
-        return q_slots, q_neg
+        return q_slots, q_neg, exact
 
     def _sweep_bool_bits(self, chunk: Sequence[_BoolQuery], QC: int):
         """Bitset-engine twin of _sweep_bool: blockwise AND / AND-NOT of
         the clauses' packed match sets on device, then the mask-gated
-        sweep that skips all-zero chunks. Returns (rm, rr, counts) with
-        counts the per-query nonzero-chunk tally (telemetry)."""
+        sweep that skips all-zero chunks. Returns (rm, rr, mask, (nonzero
+        chunks, live matches) [2, QC] i32, exact [QC] bool)."""
         wq, _, _, qscale = self._bool_weights(chunk, QC)
-        q_slots, q_neg = self._bitset_prefetch(chunk, QC)
+        q_slots, q_neg, exact = self._bitset_prefetch(chunk, QC)
         with faults.device_dispatch("bitset_intersect", self.part_id):
             mask = intersect_bitset(
                 jnp.asarray(q_slots), jnp.asarray(q_neg), self.bits,
                 QC=QC, nsw=self.nsw)
-            counts = mask_chunk_counts(mask)
+            counts = jnp.stack([mask_chunk_counts(mask),
+                                mask_live_counts(mask, self.live_bits)])
         with faults.device_dispatch("turbo_sweep", self.part_id):
             rm, rr = sweep_rowmax_bitset(
                 jnp.asarray(qscale), self.cols_hi, self.cols_lo,
                 jnp.asarray(wq), mask, self.live, QC=QC, nsw=self.nsw)
-        return rm, rr, counts
+        return rm, rr, mask, counts, exact
 
     def _gallop_routes(self, resolved, device_idx, host_idx):
-        """Ultra-selective leads skip the device sweep entirely: when a
-        query's rarest required clause has df below ES_TPU_BITSET_HOST_DF,
-        the galloping sorted intersection (_intersect_sorted) finishes on
-        host faster than a full-cache sweep can launch."""
+        """ES_TPU_BITSET_HOST_DF (default 0 = off: a rare lead is a cold
+        lead, answered from its mask on the device): when a query's
+        rarest required clause has df below the knob, the galloping
+        sorted intersection (_intersect_sorted) answers it on the host."""
         thr = int(knob("ES_TPU_BITSET_HOST_DF") or 0)
         if thr <= 0:
             return device_idx, host_idx
         keep: List[int] = []
         moved: List[int] = []
         for qi in device_idx:
-            r = resolved[qi]
-            dfs = ([i.df for _, _, i in r.conj]
-                   + [i.df for _, i in r.filters]
-                   + [len(p.docs) for _, _, _, p, _ in r.phrases
-                      if p is not None])
-            (moved if dfs and min(dfs) < thr else keep).append(qi)
-        if moved:
-            self.stats["bitset_gallop"] += len(moved)
-            _node_bitset_add("bitset_gallop", len(moved))
+            (moved if resolved[qi].lead_df < thr else keep).append(qi)
+        self._count("bitset_gallop", len(moved))
         return keep, sorted(host_idx + moved)
 
     def _note_bitset_counts(self, cnt, total: Optional[int] = None) -> None:
@@ -2300,15 +2553,29 @@ class TurboBM25:
         for t, _ in r.must_not:
             _, present = tf_at(fp, t, docs)
             match &= ~present
-        return scores.astype(np.float32), match
+        return self._exact_scores(scores, docs), match
+
+    def _rank_exact(self, r: _BoolQuery, cand: np.ndarray, k: int):
+        """Exact top-k among candidate docs that hold every match (live
+        or not): the shared tail of the host route and the mask finish."""
+        empty = (np.empty(0, np.float32), np.empty(0, np.int32))
+        cand = cand[self._live_host[cand] > 0]
+        if not len(cand):
+            return empty
+        with tracing.phase("dispatch.rescore", docs=len(cand)):
+            s, m = self._exact_bool(r, cand)
+        keep = m & (s > 0)
+        cand, s = cand[keep], s[keep]
+        sel = np.lexsort((cand, -s))[:k]
+        return s[sel], cand[sel].astype(np.int32)
 
     def _bool_host_exact(self, r: _BoolQuery, k: int):
         """Exact host bool top-k: sorted-array intersection of the
         required clauses, then the shared exact rescore. Complete without
         any certificate — every match lies inside the rarest required
-        clause's postings. Serves host-routed queries AND the device
-        path's certificate-failure fallback."""
-        self.stats["bool_host"] += 1
+        clause's postings. Serves host-routed queries and what a faulted
+        partition or chunk leaves behind."""
+        self._count("bool_host")
         fp = self.fp
         empty = (np.empty(0, np.float32), np.empty(0, np.int32))
         req: List[np.ndarray] = []
@@ -2348,26 +2615,36 @@ class TurboBM25:
             if not arrs:
                 return empty
             cand = np.unique(np.concatenate(arrs))
-        cand = cand[self._live_host[cand] > 0]
-        if not len(cand):
-            return empty
-        s, m = self._exact_bool(r, cand)
-        keep = m & (s > 0)
-        cand, s = cand[keep], s[keep]
-        sel = np.lexsort((cand, -s))[:k]
-        return s[sel], cand[sel].astype(np.int32)
+        return self._rank_exact(r, cand, k)
 
-    def _finish_bool(self, r: _BoolQuery, cand_docs, bound: float, k: int):
-        """Device-path merge: exact rescore of collected docs + cold-
-        SHOULD enumeration + certificate, a query at a time (the
-        disjunctive finish is `_finish_chunk`)."""
+    def _fetch_mask(self, mask_dev) -> np.ndarray:
+        with faults.device_errors("bitset_intersect", self.part_id):
+            return np.asarray(mask_dev)
+
+    def _finish_mask(self, r: _BoolQuery, words: np.ndarray, k: int):
+        """The mask finish: the query's intersected match set as the
+        device computed it (its mask row [wgr * 128] u32, fetched) IS the
+        candidate set — a superset where `_bitset_slots` dropped a
+        clause, which `_exact_bool` re-tests. Complete, so no
+        certificate."""
+        return self._rank_exact(r, self._mask_docs(words), k)
+
+    def _finish_bool(self, r: _BoolQuery, cand_docs, bound: float, k: int,
+                     words=None):
+        """The sweep route's merge: exact rescore of collected docs +
+        cold-SHOULD enumeration + certificate, a query at a time (the
+        disjunctive finish is `_finish_chunk`). `words`: a callable that
+        fetches the query's mask row; a failed certificate finishes from
+        it (`_finish_mask`), on the dense coverage engine (None) from the
+        host intersection."""
         scoring, req, mn = self._bool_slots(r)
         e_q = _quantize([w for _, w, _ in scoring])[2]
 
         cand_s = np.empty(0, np.float32)
         if len(cand_docs):
             cand_docs = np.asarray(cand_docs, np.int64)
-            s, m = self._exact_bool(r, cand_docs)
+            with tracing.phase("dispatch.rescore", docs=len(cand_docs)):
+                s, m = self._exact_bool(r, cand_docs)
             keep = m & (s > 0)
             cand_docs, cand_s = cand_docs[keep], s[keep]
         else:
@@ -2385,8 +2662,10 @@ class TurboBM25:
                 self.stats["sparse_queries"] += 1
                 _node_sparse_add("sparse_queries", 1)
                 # the same plan / launch / collect, back to back
-                docs_c, contrib, slack, _ = self._collect_gather(
-                    self._start_gathers([(0, cold_should)], False)[0])
+                h = self._start_gathers([(0, cold_should)], False)[0]
+                with tracing.phase("dispatch.sparse_gather",
+                                   terms=len(cold_should)):
+                    docs_c, contrib, slack, _ = self._collect_gather(h)
             else:
                 self.stats["cold_queries"] += 1
                 docs_c, contrib, _ = self._cold_contrib(cold_should)
@@ -2402,7 +2681,9 @@ class TurboBM25:
             # the host path's survivors, extras exact-rescored below
             survivors = docs_c[contrib + slack + col_const + 1e-5 >= kth_0]
             if len(survivors):
-                s, m = self._exact_bool(r, survivors)
+                with tracing.phase("dispatch.rescore",
+                                   docs=len(survivors)):
+                    s, m = self._exact_bool(r, survivors)
                 keep = m & (s > 0)
                 cold_docs, cold_s = survivors[keep], s[keep]
 
@@ -2426,36 +2707,70 @@ class TurboBM25:
                 or (len(out_s) >= k and kth < limit and uncollected > 0)
                 or self.force_cert_fail):
             self.stats["fallbacks"] += 1
+            if words is not None:
+                return self._finish_mask(r, words(), k)
             return self._bool_host_exact(r, k)
         return out_s, out_d
 
+    def _finish_bool_chunk(self, resolved, sel, act, packed, masks,
+                           counts, exact, k: int, n_rows: int,
+                           out_s, out_d, totals) -> None:
+        """Pass 2 of one device chunk on this partition: `sel` the
+        chunk's query indices, `act` which of them this partition
+        dispatched (None = all), `packed` [QC, n_rows + 1] the picked
+        rows, `masks` a callable that fetches this partition's masks of
+        the chunk ([QC, >= wgr, 128] u32; None on the dense coverage
+        engine), called only if a query finishes from its mask; `counts`
+        [2, QC] and `exact` [QC] of `_sweep_bool_bits`."""
+        def row(j):
+            return masks()[j, : self._wgr].reshape(-1)
+
+        rows_all = packed[:, :n_rows].astype(np.int64)
+        bounds = packed[:, n_rows]
+        for j, qi in enumerate(sel):
+            if act is not None and qi not in act:
+                continue
+            r = resolved[qi]
+            if r.by_mask:
+                s, d = self._finish_mask(r, row(j), k)
+            else:
+                s, d = self._finish_bool(
+                    r, self._collect_docs(rows_all[j]), float(bounds[j]), k,
+                    words=None if masks is None else (lambda j=j: row(j)))
+            out_s[qi, : len(s)] = s
+            out_d[qi, : len(d)] = d
+            if totals is not None:
+                totals[qi] += (int(counts[1, j])
+                               if counts is not None and exact[j]
+                               else _NO_TOTAL)
+
     def search_bool(self, queries: Sequence[dict], k: int = 10,
-                    check=None):
+                    check=None, totals=None):
         """(scores [Q, k] f32, ords [Q, k] i32) for bool query specs (see
         _resolve_bool for the spec shape). Matches with non-positive
         scores are dropped (the BlockMax search_bool contract). Device
         and host routes return bit-identical results — both rescore
-        through _exact_bool."""
+        through _exact_bool. `totals` (optional, i64 [Q]): this
+        partition ADDS each query's exact live match count as the device
+        counted it, `_NO_TOTAL` where it did not."""
         with tracing.steps(DISPATCH_STEPS):
-            return self._search_bool(queries, k, check)
+            return self._search_bool(queries, k, check, totals)
 
-    def _search_bool(self, queries, k, check):
-        # the bool / phrase / bitset routes take the four top-level steps
-        # and nothing finer (no cell reads them yet)
+    def _search_bool(self, queries, k, check, totals=None):
         Q = len(queries)
         out_s = np.zeros((Q, k), np.float32)
         out_d = np.zeros((Q, k), np.int32)
         use_bits = bool(knob("ES_TPU_BITSET"))
         with tracing.phase("dispatch.prep", queries=Q):
-            resolved = [self._resolve_bool(spec) for spec in queries]
-            self._ensure_bool(resolved)
+            with tracing.phase("dispatch.bool_resolve", queries=Q):
+                resolved = [self._resolve_bool(spec, use_bits)
+                            for spec in queries]
+            self._ensure_bool(resolved, use_bits)
             device_idx, host_idx = self._bool_routes(resolved)
             if use_bits:
                 device_idx, host_idx = self._gallop_routes(
                     resolved, device_idx, host_idx)
-                if device_idx:
-                    self._ensure_bits()
-        self.stats["bool_device"] += len(device_idx)
+        self._count_routes(resolved, device_idx)
 
         # device pipeline (same two-pass shape as search_many)
         n_rows = max(_GLOBAL_ROWS, k + 5)
@@ -2468,13 +2783,13 @@ class TurboBM25:
             sel = device_idx[off: off + take]
             if check is not None:
                 check()
-            counts = None
+            mask = counts = exact = None
             with tracing.phase("dispatch.launch", qc=take):
                 if use_bits:
                     first_trace = hbm_ledger.note_dispatch(
                         "turbo_bitset", take)
                     tc0 = time.monotonic()
-                    rm, rr, counts = self._sweep_bool_bits(
+                    rm, rr, mask, counts, exact = self._sweep_bool_bits(
                         [resolved[i] for i in sel], take)
                 else:
                     rm, rr = self._sweep_bool([resolved[i] for i in sel],
@@ -2484,11 +2799,11 @@ class TurboBM25:
             if use_bits and first_trace:
                 hbm_ledger.note_compile_done(
                     "turbo_bitset", take, time.monotonic() - tc0)
-            pending.append((sel, picked, counts))
+            pending.append((sel, picked, mask, counts, exact))
             off += len(sel)
         self.stats["dispatches"] += len(pending)
 
-        for sel, packed_dev, counts in pending:
+        for sel, packed_dev, mask, counts, exact in pending:
             if check is not None:
                 check()
             with tracing.phase("dispatch.device_wait"):
@@ -2497,17 +2812,14 @@ class TurboBM25:
                 if counts is not None:
                     with faults.device_errors("bitset_intersect",
                                               self.part_id):
-                        self._note_bitset_counts(
-                            np.asarray(counts)[: len(sel)])
+                        counts = np.asarray(counts)
+                        self._note_bitset_counts(counts[0, : len(sel)])
             with tracing.phase("dispatch.finish", queries=len(sel)):
-                rows_all = packed[:, :n_rows].astype(np.int64)
-                bounds = packed[:, n_rows]
-                for j, qi in enumerate(sel):
-                    docs = self._collect_docs(rows_all[j])
-                    s, d = self._finish_bool(resolved[qi], docs,
-                                             float(bounds[j]), k)
-                    out_s[qi, : len(s)] = s
-                    out_d[qi, : len(d)] = d
+                self._finish_bool_chunk(
+                    resolved, sel, None, packed,
+                    None if mask is None else _once(
+                        lambda m=mask: self._fetch_mask(m)),
+                    counts, exact, k, n_rows, out_s, out_d, totals)
         with tracing.phase("dispatch.finish", queries=len(host_idx)):
             for qi in host_idx:
                 if check is not None:
@@ -2515,6 +2827,8 @@ class TurboBM25:
                 s, d = self._bool_host_exact(resolved[qi], k)
                 out_s[qi, : len(s)] = s
                 out_d[qi, : len(d)] = d
+                if totals is not None:
+                    totals[qi] += _NO_TOTAL
         return out_s, out_d
 
     def search_phrase(self, phrases: Sequence[Sequence[str]], k: int = 10,
@@ -2631,30 +2945,45 @@ def _fused_sweep_bool(qscale, nreq, cols_hi, cols_lo, wq, wp, live, *,
 
 
 @_partial(jax.jit, static_argnames=("mesh", "QC", "nsw", "n_rows"))
-def _fused_sweep_bitset(qscale, q_slots, q_neg, bits, cols_hi, cols_lo,
-                        wq, live, *, mesh, QC: int, nsw: int, n_rows: int):
+def _fused_sweep_bitset(qscale, q_slots, q_neg, bits, live_bits, cols_hi,
+                        cols_lo, wq, live, *, mesh, QC: int, nsw: int,
+                        n_rows: int):
     """Bitset twin of _fused_sweep_bool: per local partition, the packed
     clause intersection (intersect_bitset) feeds the mask-gated sweep —
     still ONE launch for every partition. Extra sharded inputs:
     q_slots [Sp, QC, BITSET_CLAUSES] i32 · q_neg [Sp, QC, BITSET_NEGS]
-    i32 · bits [Sp, Hp+2, nsw * SW_WORD_ROWS, 128] u32. Returns
-    (picked [Sp, QC, n_rows+1] f32, nonzero-chunk counts [Sp, QC] i32)."""
+    i32 · bits [Sp, Hp+2+cold rows, nsw * SW_WORD_ROWS, 128] u32 ·
+    live_bits [Sp, nsw * SW_WORD_ROWS, 128] u32. Returns (picked
+    [Sp, QC, n_rows+1] f32, (nonzero chunks, live matches) [Sp, 2, QC]
+    i32, the masks [Sp, QC, nsw * SW_WORD_ROWS, 128] u32, which stay on
+    the device unless a query finishes from its mask)."""
     spec = _P("shard")
 
-    @_partial(_shard_map, mesh=mesh, in_specs=(spec,) * 8,
-              out_specs=(spec, spec), check_vma=False)
-    def program(qs, sl, ng, bt, ch, cl, w, lv):
-        outs, cnts = [], []
+    @_partial(_shard_map, mesh=mesh, in_specs=(spec,) * 9,
+              out_specs=(spec, spec, spec), check_vma=False)
+    def program(qs, sl, ng, bt, lb, ch, cl, w, lv):
+        outs, cnts, masks = [], [], []
         for i in range(qs.shape[0]):
             mask = intersect_bitset(sl[i], ng[i], bt[i], QC=QC, nsw=nsw)
             rm, rr = sweep_rowmax_bitset(qs[i], ch[i], cl[i], w[i], mask,
                                          lv[i], QC=QC, nsw=nsw)
             outs.append(_pick_rows(rm, rr, n_rows=n_rows))
-            cnts.append(mask_chunk_counts(mask))
-        return jnp.stack(outs), jnp.stack(cnts)
+            cnts.append(jnp.stack([mask_chunk_counts(mask),
+                                   mask_live_counts(mask, lb[i])]))
+            masks.append(mask)
+        return jnp.stack(outs), jnp.stack(cnts), jnp.stack(masks)
 
-    return program(qscale, q_slots, q_neg, bits, cols_hi, cols_lo, wq,
-                   live)
+    return program(qscale, q_slots, q_neg, bits, live_bits, cols_hi,
+                   cols_lo, wq, live)
+
+
+@_partial(jax.jit, static_argnames=("i",), donate_argnums=(0,))
+def _set_part(stacked, part, *, i: int):
+    """stacked[i, :part.shape[0], :part.shape[1], ...] = part, in the
+    (donated) stacked array's own buffer: a partition's slice of the fused
+    cache re-synced without a second copy of the cache in HBM."""
+    return jax.lax.dynamic_update_slice(
+        stacked, part[None], (i,) + (0,) * part.ndim)
 
 
 class ShardedTurbo:
@@ -2707,7 +3036,8 @@ class ShardedTurbo:
         # stacked per-partition bitsets (allocated lazily on the first
         # bitset-engine refresh; padded partitions stay all-zero = empty)
         self.bits = None
-        self._bits_epochs = [-1] * S
+        self.live_bits = None
+        self._bits_versions = [-1] * S
         self.fused_dispatches = 0
         # fused cache is a separate device allocation on top of the
         # per-partition engines' own regions
@@ -2719,8 +3049,7 @@ class ShardedTurbo:
     def _register_hbm_regions(self) -> None:
         self._hbm.set_region("cols_hi", self.cols_hi.nbytes)
         self._hbm.set_region("cols_lo", self.cols_lo.nbytes)
-        self._hbm.set_region("cols_bits",
-                             0 if self.bits is None else self.bits.nbytes)
+        self._hbm.set_region("cols_bits", self._bits_nbytes())
         self._hbm.set_region("live", self.live.nbytes)
 
     def _register_scrub_regions(self) -> None:
@@ -2761,7 +3090,7 @@ class ShardedTurbo:
         zeros = np.zeros(self.bits.shape, np.uint32)
         with faults.device_errors("column_upload"):
             self.bits = jax.device_put(zeros, self._sharding)
-        self._bits_epochs = [-1] * len(self.turbos)
+        self._bits_versions = [-1] * len(self.turbos)
         for i in range(len(self.turbos)):
             self._refresh_bits_part(i)
 
@@ -2782,13 +3111,10 @@ class ShardedTurbo:
         t = self.turbos[i]
         if self._epochs[i] != t.cols_epoch:
             with faults.device_dispatch("column_upload", part=i):
-                a, b = t.cols_hi.shape[0], t.cols_hi.shape[1]
                 self.cols_hi = jax.device_put(
-                    self.cols_hi.at[i, :a, :b].set(t.cols_hi),
-                    self._sharding)
+                    _set_part(self.cols_hi, t.cols_hi, i=i), self._sharding)
                 self.cols_lo = jax.device_put(
-                    self.cols_lo.at[i, :a, :b].set(t.cols_lo),
-                    self._sharding)
+                    _set_part(self.cols_lo, t.cols_lo, i=i), self._sharding)
             self._epochs[i] = t.cols_epoch
             self._register_hbm_regions()
         # the bitsets are packed lazily (first bool dispatch), possibly
@@ -2802,22 +3128,31 @@ class ShardedTurbo:
         preserved, so each engine's own sentinels (t.Hp zeros, t.Hp + 1
         ones) land inside its slice and padding slots stay all-zero."""
         t = self.turbos[i]
-        if t.bits is None or self._bits_epochs[i] == t._bits_epoch:
+        if t.bits is None or self._bits_versions[i] == t.bits_version:
             return
         first = self.bits is None
         if first:
+            wgr = self.nsw * SW_WORD_ROWS
             zeros = np.zeros(
-                (self.Sp, self.Hp + 2, self.nsw * SW_WORD_ROWS, 128),
+                (self.Sp, self.Hp + 2 + BITSET_COLD_ROWS, wgr, 128),
                 np.uint32)
             with faults.device_errors("column_upload"):
                 self.bits = jax.device_put(zeros, self._sharding)
+                self.live_bits = jax.device_put(
+                    np.zeros((self.Sp, wgr, 128), np.uint32),
+                    self._sharding)
         with faults.device_dispatch("column_upload", part=i):
-            hb, wb = t.bits.shape[0], t.bits.shape[1]
+            if self._bits_versions[i] < 0:
+                self.live_bits = jax.device_put(
+                    _set_part(self.live_bits, t.live_bits, i=i),
+                    self._sharding)
+            # (the whole slice, cold rows and all, whatever moved: a
+            # row-level sync is what a later PR may take, ROADMAP S10)
             self.bits = jax.device_put(
-                self.bits.at[i, :hb, :wb].set(t.bits), self._sharding)
-        self._bits_epochs[i] = t._bits_epoch
+                _set_part(self.bits, t.bits, i=i), self._sharding)
+        self._bits_versions[i] = t.bits_version
         if first:
-            _node_bitset_add("bitset_bytes", self.bits.nbytes)
+            _node_bitset_add("bitset_bytes", self._bits_nbytes())
             integrity.register_scrub_region(
                 self, "cols_bits", lambda o: o.bits,
                 epoch=lambda o: id(o.bits),
@@ -2828,10 +3163,13 @@ class ShardedTurbo:
         for i in range(len(self.turbos)):
             self._refresh_part(i)
 
+    def _bits_nbytes(self) -> int:
+        return (0 if self.bits is None
+                else self.bits.nbytes + self.live_bits.nbytes)
+
     def hbm_bytes(self) -> int:
         return (self.cols_hi.nbytes + self.cols_lo.nbytes
-                + (0 if self.bits is None else self.bits.nbytes)
-                + self.live.nbytes)
+                + self._bits_nbytes() + self.live.nbytes)
 
     # ---------------- fused dispatches ----------------
 
@@ -2873,8 +3211,9 @@ class ShardedTurbo:
 
     def _dispatch_bool(self, resolved, dev_sets, sel, QC: int,
                        n_rows: int, use_bits: bool = False):
-        """Returns (packed rows, nonzero-chunk counts) — counts is None
-        on the dense (coverage-matmul) engine. A query a partition
+        """Returns (packed rows, (nonzero chunks, live matches) [Sp, 2,
+        QC], the masks on the device, exact [S, QC]) — the last three
+        None on the dense (coverage-matmul) engine. A query a partition
         host-routes rides the fused launch with inert inputs: all-zero
         weights on both engines, and on the bitset engine every clause
         slot pointed at that partition's zero sentinel (empty mask)."""
@@ -2888,6 +3227,7 @@ class ShardedTurbo:
                 # so every mask word is 0 and every chunk skips
                 q_slots = np.zeros((self.Sp, QC, BITSET_CLAUSES), np.int32)
                 q_neg = np.zeros((self.Sp, QC, BITSET_NEGS), np.int32)
+                exact = np.zeros((len(self.turbos), QC), bool)
             for i, t in enumerate(self.turbos):
                 chunk = [resolved[i][qi] if qi in dev_sets[i] else None
                          for qi in sel]
@@ -2898,19 +3238,20 @@ class ShardedTurbo:
                 nreq[i] = nr
                 qs[i] = q
                 if use_bits:
-                    q_slots[i], q_neg[i] = t._bitset_prefetch(chunk, QC)
+                    q_slots[i], q_neg[i], exact[i] = t._bitset_prefetch(
+                        chunk, QC)
         t0 = time.monotonic()
         kind = "fused_turbo_bitset" if use_bits else "fused_turbo_bool"
         first_trace = hbm_ledger.note_dispatch(kind, QC)
-        cnts = None
+        cnts = masks = None
         with tracing.phase("dispatch.launch", qc=QC,
                            partitions=len(self.turbos)), \
                 faults.device_dispatch("fused_dispatch"):
             if use_bits:
-                out, cnts = _fused_sweep_bitset(
+                out, cnts, masks = _fused_sweep_bitset(
                     jnp.asarray(qs), jnp.asarray(q_slots),
-                    jnp.asarray(q_neg), self.bits, self.cols_hi,
-                    self.cols_lo, jnp.asarray(wq), self.live,
+                    jnp.asarray(q_neg), self.bits, self.live_bits,
+                    self.cols_hi, self.cols_lo, jnp.asarray(wq), self.live,
                     mesh=self.mesh, QC=QC, nsw=self.nsw, n_rows=n_rows)
             else:
                 out = _fused_sweep_bool(
@@ -2922,7 +3263,7 @@ class ShardedTurbo:
         if first_trace:
             hbm_ledger.note_compile_done(
                 kind, QC, time.monotonic() - t0)
-        return out, cnts
+        return out, cnts, masks, exact if use_bits else None
 
     # ---------------- search ----------------
 
@@ -3038,21 +3379,22 @@ class ShardedTurbo:
         return err
 
     def search_bool(self, queries: Sequence[dict], k: int = 10,
-                    check=None, fault_log=None):
+                    check=None, fault_log=None, totals=None):
         """per[si] = (scores [Q, k] f32, ords [Q, k] i32), matching each
         turbo's solo search_bool bitwise. Partitions may route the same
         query differently (device vs host): the fused sweep dispatches
         the UNION of device-routed queries with all-zero weight rows for
         partitions that host-route one — inert because the kernels score
-        query columns independently.
+        query columns independently. `totals` as TurboBM25.search_bool's:
+        every partition adds its count.
 
         Fault containment mirrors search_many: a faulted partition (or a
         faulted fused chunk) serves its queries through _bool_host_exact,
         which every device bool result is bit-identical to anyway."""
         with tracing.steps(DISPATCH_STEPS):
-            return self._search_bool(queries, k, check, fault_log)
+            return self._search_bool(queries, k, check, fault_log, totals)
 
-    def _search_bool(self, queries, k, check, fault_log):
+    def _search_bool(self, queries, k, check, fault_log, totals=None):
         Q = len(queries)
         S = len(self.turbos)
         out_s = np.zeros((S, Q, k), np.float32)
@@ -3061,13 +3403,12 @@ class ShardedTurbo:
         failed: Dict[int, DeviceFaultError] = {}
         routes = []
         with tracing.phase("dispatch.prep", queries=Q):
-            resolved = [[t._resolve_bool(spec) for spec in queries]
-                        for t in self.turbos]
+            with tracing.phase("dispatch.bool_resolve", queries=Q):
+                resolved = [[t._resolve_bool(spec, use_bits)
+                             for spec in queries] for t in self.turbos]
             for si, t in enumerate(self.turbos):
                 try:
-                    t._ensure_bool(resolved[si])
-                    if use_bits:
-                        t._ensure_bits()
+                    t._ensure_bool(resolved[si], use_bits)
                     self._refresh_part(si)
                     rt = t._bool_routes(resolved[si])
                     if use_bits:
@@ -3079,7 +3420,7 @@ class ShardedTurbo:
                     routes.append(
                         ([], [qi for qi, r in enumerate(resolved[si])
                               if r is not None]))
-                t.stats["bool_device"] += len(routes[si][0])
+                t._count_routes(resolved[si], routes[si][0])
         dev_sets = [set(dev) for dev, _ in routes]
         union = sorted({qi for ds in dev_sets for qi in ds})
         n_rows = max(_GLOBAL_ROWS, k + 5)
@@ -3094,17 +3435,17 @@ class ShardedTurbo:
             if check is not None:
                 check()
             try:
-                packed_dev, cnts_dev = self._dispatch_bool(
+                entry = self._dispatch_bool(
                     resolved, dev_sets, sel, take, n_rows,
                     use_bits=use_bits)
             except DeviceFaultError as e:
-                packed_dev, cnts_dev, fused_err = None, None, e
-            pending.append((sel, packed_dev, cnts_dev))
+                entry, fused_err = (None,) * 4, e
+            pending.append((sel,) + entry)
             off += len(sel)
-        for sel, packed_dev, cnts_dev in pending:
+        for sel, packed_dev, cnts_dev, masks_dev, exact in pending:
             if check is not None:
                 check()
-            packed = cc = None
+            packed = cc = masks = None
             if packed_dev is not None:
                 try:
                     with tracing.phase("dispatch.device_wait"), \
@@ -3112,30 +3453,34 @@ class ShardedTurbo:
                         packed = np.asarray(packed_dev)
                         if cnts_dev is not None:
                             cc = np.asarray(cnts_dev)
+                            masks = _once(lambda m=masks_dev: self._fetch(m))
                 except DeviceFaultError as e:
                     packed, cc, fused_err = None, None, e
             with tracing.phase("dispatch.finish", queries=len(sel)):
                 for si, t in enumerate(self.turbos):
-                    if packed is not None:
-                        rows_all = packed[si, :, :n_rows].astype(np.int64)
-                        bounds = packed[si, :, n_rows]
+                    if packed is None:
+                        for qi in sel:
+                            if qi in dev_sets[si]:
+                                s, d = t._bool_host_exact(
+                                    resolved[si][qi], k)
+                                out_s[si, qi, : len(s)] = s
+                                out_d[si, qi, : len(d)] = d
+                                if totals is not None:
+                                    totals[qi] += _NO_TOTAL
+                        continue
                     if cc is not None:
                         act = [j for j, qi in enumerate(sel)
                                if qi in dev_sets[si]]
                         if act:
                             t._note_bitset_counts(
-                                cc[si, act], total=self.nsw * N_CHUNKS)
-                    for j, qi in enumerate(sel):
-                        if qi not in dev_sets[si]:
-                            continue
-                        if packed is None:
-                            s, d = t._bool_host_exact(resolved[si][qi], k)
-                        else:
-                            docs = t._collect_docs(rows_all[j])
-                            s, d = t._finish_bool(resolved[si][qi], docs,
-                                                  float(bounds[j]), k)
-                        out_s[si, qi, : len(s)] = s
-                        out_d[si, qi, : len(d)] = d
+                                cc[si, 0, act], total=self.nsw * N_CHUNKS)
+                    t._finish_bool_chunk(
+                        resolved[si], sel, dev_sets[si], packed[si],
+                        None if masks is None
+                        else (lambda si=si: masks()[si]),
+                        None if cc is None else cc[si],
+                        None if exact is None else exact[si], k, n_rows,
+                        out_s[si], out_d[si], totals)
         with tracing.phase("dispatch.finish", host_routed=True):
             for si, t in enumerate(self.turbos):
                 for qi in routes[si][1]:
@@ -3144,9 +3489,16 @@ class ShardedTurbo:
                     s, d = t._bool_host_exact(resolved[si][qi], k)
                     out_s[si, qi, : len(s)] = s
                     out_d[si, qi, : len(d)] = d
+                    if totals is not None:
+                        totals[qi] += _NO_TOTAL
         if fault_log is not None:
             for i, e in sorted(failed.items()):
                 fault_log.append(FaultRecord.from_error(e, partition=i))
             if fused_err is not None:
                 fault_log.append(FaultRecord.from_error(fused_err))
         return [(out_s[si], out_d[si]) for si in range(S)]
+
+    @staticmethod
+    def _fetch(dev) -> np.ndarray:
+        with faults.device_errors("fused_dispatch"):
+            return np.asarray(dev)

@@ -51,9 +51,7 @@ from elasticsearch_tpu.common.faults import FaultRecord
 from elasticsearch_tpu.index.positions import phrase_freqs
 from elasticsearch_tpu.ops import bm25_idf
 from elasticsearch_tpu.common.settings import knob
-from elasticsearch_tpu.threadpool.scheduler import (
-    device_phase, record_pad_waste,
-)
+from elasticsearch_tpu.threadpool.scheduler import serving_dispatch
 from elasticsearch_tpu.search import queries as q
 from elasticsearch_tpu.search.queries import parse_query
 from elasticsearch_tpu.tasks.task_manager import (
@@ -658,12 +656,44 @@ class TurboEngine:
 
     def search_many(self, batches: Sequence[List], k: int = 10, check=None,
                     fault_log=None):
+        """Per batch (scores, partition, ord). A query is a term list
+        (a disjunction) or a dict, a bool spec (`_turbo_bool_spec`): the
+        scheduler's lane batches both kinds of an engine's requests
+        together, so that no two dispatches of one engine ever run at
+        once. A batch that holds a bool spec comes back with a fourth
+        member, `totals` i64 [Q]: the exact hit count as the device
+        counted it, -1 where it did not (a disjunction's row, a
+        host-routed or faulted query)."""
         from elasticsearch_tpu.parallel.turbo import DISPATCH_STEPS
 
         # one accumulator per ENGINE call: the partitions' (or the fused
         # engine's) steps and the merge below add up under it
         with tracing.steps(DISPATCH_STEPS):
+            if any(isinstance(q, dict) for b in batches for q in b):
+                return [self._search_mixed(b, k, check, fault_log)
+                        for b in batches]
             return self._search_many(batches, k, check, fault_log)
+
+    def _search_mixed(self, batch, k, check, fault_log):
+        """One batch with bool specs in it: its disjunctions in one
+        `_search_many`, its bool specs in one `_search_bool`, row for
+        row."""
+        Q = len(batch)
+        out = (np.zeros((Q, k), np.float32), np.zeros((Q, k), np.int32),
+               np.zeros((Q, k), np.int32), np.full(Q, -1, np.int64))
+        bool_at = [i for i, q in enumerate(batch) if isinstance(q, dict)]
+        disj_at = [i for i, q in enumerate(batch) if not isinstance(q, dict)]
+        totals = np.zeros(len(bool_at), np.int64)
+        got = [(bool_at, self._search_bool(
+            [batch[i] for i in bool_at], k, check, fault_log, totals))]
+        out[3][bool_at] = totals
+        if disj_at:
+            got.append((disj_at, self._search_many(
+                [[batch[i] for i in disj_at]], k, check, fault_log)[0]))
+        for at, res in got:
+            for dst, src in zip(out, res):
+                dst[at] = src
+        return out
 
     def _search_many(self, batches, k, check, fault_log):
         log = fault_log if fault_log is not None else []
@@ -756,22 +786,36 @@ class TurboEngine:
         return out_s, out_p, out_o
 
     def search_bool(self, queries: Sequence[dict], k: int = 10,
-                    check=None, fault_log=None):
+                    check=None, fault_log=None, totals=None):
         """Batched bool top-k through the per-partition conjunctive
         sweeps — the BlockMax search_bool contract:
         (scores [Q,k], partition [Q,k], ord [Q,k]). Fault containment
         mirrors search_many (circuit-open / catastrophic -> the
-        _bool_host_exact tier, per-partition isolation otherwise)."""
+        _bool_host_exact tier, per-partition isolation otherwise).
+        `totals` (optional, i64 [Q], zeros): filled with each query's
+        exact hit count over all partitions as the device counted it, -1
+        where a partition did not count."""
         from elasticsearch_tpu.parallel.turbo import DISPATCH_STEPS
 
         with tracing.steps(DISPATCH_STEPS):
-            return self._search_bool(queries, k, check, fault_log)
+            return self._search_bool(queries, k, check, fault_log, totals)
 
-    def _search_bool(self, queries, k, check, fault_log):
+    def _search_bool(self, queries, k, check, fault_log, totals=None):
+        try:
+            return self._search_bool_parts(queries, k, check, fault_log,
+                                           totals)
+        finally:
+            if totals is not None:
+                totals[totals < 0] = -1
+
+    def _search_bool_parts(self, queries, k, check, fault_log, totals):
         log = fault_log if fault_log is not None else []
         n0 = len(log)
+        if totals is None:
+            totals = np.zeros(len(queries), np.int64)
         if not self.health.allow_device():
             self.health.record_fallback(len(queries))
+            totals[:] = -1
             per = [t.search_bool_host(queries, k=k, check=check)
                    for t in self.turbos]
             return self._merge3(per, len(queries), k)
@@ -780,7 +824,7 @@ class TurboEngine:
             if fused is not None:
                 d0 = fused.fused_dispatches
                 per = fused.search_bool(queries, k=k, check=check,
-                                        fault_log=log)
+                                        fault_log=log, totals=totals)
                 self._count("fused_dispatches", fused.fused_dispatches - d0)
                 self._count("partition_dispatches",
                             (fused.fused_dispatches - d0) * len(self.turbos))
@@ -788,16 +832,19 @@ class TurboEngine:
                 per = []
                 for t in self.turbos:
                     try:
-                        per.append(t.search_bool(queries, k=k, check=check))
+                        per.append(t.search_bool(queries, k=k, check=check,
+                                                 totals=totals))
                     except DeviceFaultError as e:
                         log.append(FaultRecord.from_error(
                             e, partition=t.part_id))
+                        totals[:] = -1 << 40
                         per.append(t.search_bool_host(queries, k=k,
                                                       check=check))
         except DeviceFaultError as e:
             log.append(FaultRecord.from_error(e))
             self.health.record_fault(e)
             self.health.record_fallback(len(queries))
+            totals[:] = -1 << 40
             per = [t.search_bool_host(queries, k=k, check=check)
                    for t in self.turbos]
             return self._merge3(per, len(queries), k)
@@ -1303,6 +1350,7 @@ class ServingContext:
 
         # group disjunctive plans by field for batched device dispatch
         by_field: Dict[str, List[int]] = {}
+        bool_by_field: Dict[str, List[int]] = {}
         for i, plan in enumerate(plans):
             if plan is None:
                 continue
@@ -1310,6 +1358,9 @@ class ServingContext:
             if plan.is_disjunctive:
                 if self._disj_servable(plan, snap, requests[i]):
                     by_field.setdefault(plan.field, []).append(i)
+                continue
+            if self._bool_spec(plan, snap) is not None:
+                bool_by_field.setdefault(plan.field, []).append(i)
                 continue
             try:
                 if task is not None:
@@ -1326,17 +1377,20 @@ class ServingContext:
             except Exception as e:
                 _note_reject_error(e, "conjunctive")
                 out[i] = None
-        for field, idxs in by_field.items():
-            try:
-                results = self._disjunctive_batch(
-                    field, [plans[i] for i in idxs],
-                    [requests[i] for i in idxs], snap, task=task)
-                for i, r in zip(idxs, results):
-                    out[i] = r
-            except TaskCancelledError:
-                raise
-            except Exception as e:
-                _note_reject_error(e, "disjunctive_batch")
+        for fields, serve, where in (
+                (bool_by_field, self._conjunctive_batch, "conjunctive"),
+                (by_field, self._disjunctive_batch, "disjunctive_batch")):
+            for field, idxs in fields.items():
+                try:
+                    results = serve(
+                        field, [plans[i] for i in idxs],
+                        [requests[i] for i in idxs], snap, task=task)
+                    for i, r in zip(idxs, results):
+                        out[i] = r
+                except TaskCancelledError:
+                    raise
+                except Exception as e:
+                    _note_reject_error(e, where)
         return out
 
     def try_query_phase(self, request: dict, task=None):
@@ -1379,10 +1433,6 @@ class ServingContext:
             # concurrent shard queries on the same engine continuous-batch
             # into shared device dispatches (SLA tier from the request's
             # thread-local class)
-            from elasticsearch_tpu.threadpool.scheduler import (
-                serving_dispatch,
-            )
-
             try:
                 t_dev = time.monotonic()
                 scores, parts, ords = serving_dispatch(
@@ -1404,26 +1454,23 @@ class ServingContext:
             # when it is Turbo (presence-mask sweep + adjacency columns);
             # otherwise the dense executor remains the query phase
             eng = snap.engine(plan.field)
-            if getattr(eng, "kind", "") != "turbo":
-                return None
-            spec = _turbo_bool_spec(plan)
+            spec = self._bool_spec(plan, snap)
             if spec is None:
                 return None
+            counted = np.zeros(1, np.int64)
             try:
-                # search_bool bypasses the scheduler, so the conjunctive
-                # path's single authoritative `device` site is here
-                # (batch shape + pad waste ride along)
-                with device_phase(eng, 1, engine_desc(eng)[0]) as ph:
-                    scores, parts, ords = eng.search_bool(
-                        [spec], k=k, check=check, fault_log=flog)
-                dev_ms = ph.ms
-                record_pad_waste(eng, 1)
+                # bool specs ride the engine's lane like disjunctions
+                t_dev = time.monotonic()
+                scores, parts, ords = serving_dispatch(
+                    eng, [spec], k, check=check, fault_log=flog,
+                    totals=counted)
+                dev_ms = (time.monotonic() - t_dev) * 1e3
             except DispatchDeadlineError:
                 _count_serving("fastpath_timed_out")
                 return timed_out
 
             def total_rel(p, sn, req, n):
-                return self._conj_total(p, sn, req)
+                return self._conj_total(p, sn, req, int(counted[0]))
         else:
             return None
         if flog:
@@ -1502,8 +1549,6 @@ class ServingContext:
         # small batches continuous-batch with concurrent dispatches on the
         # same engine (threadpool/scheduler); large msearch batches go
         # direct
-        from elasticsearch_tpu.threadpool.scheduler import serving_dispatch
-
         try:
             t_dev = time.monotonic()
             scores, parts, ords = serving_dispatch(
@@ -1577,8 +1622,6 @@ class ServingContext:
         flog: List[FaultRecord] = []
         # KnnEngine degrades itself (internal circuit + host-exact tier),
         # so unlike BlockMax no external circuit enforcement is needed
-        from elasticsearch_tpu.threadpool.scheduler import serving_dispatch
-
         try:
             t_dev = time.monotonic()
             scores, parts, ords = serving_dispatch(
@@ -1655,15 +1698,21 @@ class ServingContext:
 
     # ---- conjunctive (turbo device path or host columnar) ----
 
-    def _conj_total(self, plan, snap, request) -> Tuple[int, str]:
-        """Exact conjunctive hit count (same narrowing as the host
-        scoring path, no scoring) with the track_total_hits cap — the
-        totals side when TurboBM25 serves the hits."""
-        total = 0
-        for part in snap.partitions:
-            r = _conjunctive_candidates(plan, snap, part)
-            if r is not None:
-                total += len(r[0])
+    def _conj_total(self, plan, snap, request,
+                    counted: int = -1) -> Tuple[int, str]:
+        """The conjunctive hit count with the track_total_hits cap.
+        `counted` = the count of the route that found the hits (the
+        device's: the conjunction masks' population counts, all
+        partitions); -1 = it did not count (the host tier, a fault, a
+        mask that is a superset): the host then narrows as its scoring
+        path does, without scoring (`_conjunctive_candidates`)."""
+        total = counted
+        if total < 0:
+            total = 0
+            for part in snap.partitions:
+                r = _conjunctive_candidates(plan, snap, part)
+                if r is not None:
+                    total += len(r[0])
         track = request.get("track_total_hits", 10000)
         if track is False:
             return total, "gte"
@@ -1672,45 +1721,76 @@ class ServingContext:
             return track_n, "gte"
         return total, "eq"
 
+    @staticmethod
+    def _bool_spec(plan, snap) -> Optional[dict]:
+        """The plan as a Turbo bool spec, where the field's engine is
+        Turbo and its contract can represent the plan; else None (the
+        host columnar path / the dense executor serve it)."""
+        if not plan.is_conjunctive or plan.field is None:
+            return None
+        if getattr(snap.engine(plan.field), "kind", "") != "turbo":
+            return None
+        return _turbo_bool_spec(plan)
+
+    def _conjunctive_batch(self, field: str, plans, requests, snap,
+                           task=None):
+        """The conjunctive plans Turbo serves (`_bool_spec`), as ONE
+        batch through the dispatch scheduler, like `_disjunctive_batch`:
+        the flagship engine serves the hits (the conjunction on the
+        device, bit-identical rescore) and counts them."""
+        start = time.monotonic()
+        eng = snap.engine(field)
+        k = max(int(r.get("from", 0)) + int(r.get("size", 10))
+                for r in requests)
+        deadlines = [self._deadline_for(r) for r in requests]
+        check = self._combined_check(task, deadlines)
+        flog: List[FaultRecord] = []
+        counted = np.zeros(len(plans), np.int64)
+        try:
+            t_dev = time.monotonic()
+            scores, parts, ords = serving_dispatch(
+                eng, [_turbo_bool_spec(p) for p in plans], k, check=check,
+                fault_log=flog, totals=counted)
+            dev_ms = (time.monotonic() - t_dev) * 1e3
+        except DispatchDeadlineError:
+            _count_serving("fastpath_timed_out")
+            return [self._timed_out_response(r, snap, start)
+                    if d is not None and d.timed_out else None
+                    for r, d in zip(requests, deadlines)]
+        if flog:
+            _count_serving("shard_fault_recoveries", len(flog))
+        extracted = []
+        with tracing.phase("demux", batch=len(requests)):
+            for qi, (plan, request) in enumerate(zip(plans, requests)):
+                hits = []
+                for j in range(k):
+                    s = float(scores[qi, j])
+                    if s <= 0 or not np.isfinite(s):
+                        break
+                    hits.append((int(parts[qi, j]), int(ords[qi, j]), s))
+                extracted.append((hits,) + self._conj_total(
+                    plan, snap, request, int(counted[qi])))
+        results = []
+        for qi, request in enumerate(requests):
+            hits, total, relation = extracted[qi]
+            d = deadlines[qi]
+            try:
+                results.append(self._respond(
+                    request, snap, hits, total, relation, start,
+                    timed_out=bool(d is not None and d.expired),
+                    faults=flog,
+                    profile_nodes=fastpath_profile_nodes(
+                        request, eng, dev_ms)
+                    if request.get("profile") else None))
+            except SearchPhaseExecutionError as e:
+                results.append(e)
+        return results
+
     def _conjunctive(self, plan, snap, request, start, task=None):
+        """The host columnar path: a conjunctive plan no Turbo engine
+        serves (`_bool_spec` is None)."""
         k = int(request.get("from", 0)) + int(request.get("size", 10))
         deadline = self._deadline_for(request)
-        eng = snap.engine(plan.field) if plan.field else None
-        spec = _turbo_bool_spec(plan) \
-            if getattr(eng, "kind", "") == "turbo" else None
-        if spec is not None:
-            # the flagship engine serves the hits (conjunctive sweep over
-            # the int8 columns, bit-identical rescore); totals come from
-            # the same count the host path would have produced
-            check = self._combined_check(task, [deadline])
-            flog: List[FaultRecord] = []
-            try:
-                # search_bool bypasses the scheduler: this is the
-                # conjunctive path's `device` site (shape + pad waste
-                # included)
-                with device_phase(eng, 1, engine_desc(eng)[0]) as ph:
-                    scores, parts, ords = eng.search_bool(
-                        [spec], k=k, check=check, fault_log=flog)
-                dev_ms = ph.ms
-                record_pad_waste(eng, 1)
-            except DispatchDeadlineError:
-                _count_serving("fastpath_timed_out")
-                return self._timed_out_response(request, snap, start)
-            if flog:
-                _count_serving("shard_fault_recoveries", len(flog))
-            hits = []
-            for j in range(k):
-                s = float(scores[0, j])
-                if s <= 0 or not np.isfinite(s):
-                    break
-                hits.append((int(parts[0, j]), int(ords[0, j]), s))
-            total, relation = self._conj_total(plan, snap, request)
-            return self._respond(
-                request, snap, hits, total, relation, start,
-                timed_out=bool(deadline is not None and deadline.expired),
-                faults=flog,
-                profile_nodes=fastpath_profile_nodes(request, eng, dev_ms)
-                if request.get("profile") else None)
         all_s, all_p, all_o = [], [], []
         total = 0
         timed_out = False

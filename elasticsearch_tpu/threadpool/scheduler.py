@@ -1,5 +1,8 @@
 """The dispatch layer: every engine `search_many` call of the served path
-goes through here (`search/serving.py` -> `serving_dispatch` -> engine).
+goes through here (`search/serving.py` -> `serving_dispatch` -> engine),
+a BM25 engine's disjunctions and its bool specs alike: a lane batches both
+kinds of one engine's requests (`TurboEngine.search_many` takes a mixed
+batch), so no two dispatches of an engine ever run at once.
 
 - **one device dispatch** — `run_device` runs one engine call under the
   `device` phase (the span and histogram the `dispatch.*` steps hang
@@ -190,10 +193,9 @@ def device_phase(engine, n_queries: int,
                  engine_name: Optional[str] = None) -> tracing.phase:
     """Flight recorder: the `device` phase around one device dispatch.
     Every dispatch path (the scheduler's direct and lane dispatches
-    through `run_device`, serving's search_bool sites) runs its engine
-    call under it and then calls `record_pad_waste`, so latency AND batch
-    shape land together; the engines' `dispatch.*` steps are its
-    children."""
+    through `run_device`) runs its engine call under it and then calls
+    `record_pad_waste`, so latency AND batch shape land together; the
+    engines' `dispatch.*` steps are its children."""
     return tracing.phase(
         "device", engine=engine_name or getattr(engine, "kind", "?"),
         batch=n_queries)
@@ -268,22 +270,24 @@ def retry_batch_solo(batch: "_SchedBatch", original: BaseException) -> None:
     errors: Dict[int, BaseException] = {}
     for qi, query in enumerate(batch.queries):
         try:
-            s, p, o = _search_many(batch.engine, [query], batch.k,
-                                   fault_log=batch.fault_log)
+            out = _search_many(batch.engine, [query], batch.k,
+                               fault_log=batch.fault_log)
         except Exception as e:
             errors[qi] = e
             continue
-        rows[qi] = (np.asarray(s[0]), np.asarray(p[0]),
-                    np.asarray(o[0]))
+        rows[qi] = tuple(np.asarray(a[0]) for a in out)
     if all(r is None for r in rows):
         batch.error = original
         return
-    template = next(r for r in rows if r is not None)
+    # (scores, partition, ord) rows; a bool spec's row also has its total
+    wide = max((r for r in rows if r is not None), key=len)
     for qi, r in enumerate(rows):
         if r is None:
-            rows[qi] = tuple(np.zeros_like(x) for x in template)
+            r = ()
+        rows[qi] = r + tuple(np.zeros_like(x) for x in wide[len(r):3]) \
+            + tuple(np.full_like(x, -1) for x in wide[max(len(r), 3):])
     batch.results = tuple(np.stack([r[j] for r in rows])
-                          for j in range(3))
+                          for j in range(len(wide)))
     batch.query_errors = errors
 
 
@@ -464,12 +468,16 @@ class AdaptiveDispatchScheduler:
     # ---- the dispatch entry ----
 
     def dispatch(self, engine, queries: List, k: int, check=None,
-                 fault_log=None, tier: Optional[str] = None):
+                 fault_log=None, tier: Optional[str] = None, totals=None):
         """One batch of queries -> (scores [Q,k], partition [Q,k],
         ord [Q,k]) — the engine `search_many` single-batch contract,
         bit-identical to solo execution. Small batches continuous-batch
         with concurrent peers on the same (engine, k) lane; large ones
-        (or a zero ES_TPU_COALESCE_US) dispatch directly."""
+        (or a zero ES_TPU_COALESCE_US) dispatch directly. `totals`
+        (optional, i64 [Q]): where the engine's answer carries a fourth
+        member (a BM25 engine's, for a batch with bool specs in it: each
+        row's exact hit count, -1 = not counted), the caller's rows of
+        it."""
         if check is not None:
             # cooperative cancellation only at the caller's boundary: a
             # merged dispatch must never fail EVERY waiter because one
@@ -489,8 +497,11 @@ class AdaptiveDispatchScheduler:
             with self._lock:
                 self._direct_dispatches += 1
                 self._tier_counts[tier] = self._tier_counts.get(tier, 0) + 1
-            return run_device(engine, queries, k, check=check,
-                              fault_log=fault_log)
+            out = run_device(engine, queries, k, check=check,
+                             fault_log=fault_log)
+            if totals is not None and len(out) > 3:
+                totals[:] = out[3]
+            return out[:3]
 
         tier = tier if tier in _TIERS else current_tier()
         # composed name: exactly the declared sched_tier_wait.* pair
@@ -524,8 +535,10 @@ class AdaptiveDispatchScheduler:
                 for qi in range(w.base, w.base + len(w.queries)):
                     if qi in batch.query_errors:
                         raise batch.query_errors[qi]
-            scores, parts, ords = batch.results
             sl = slice(w.base, w.base + len(w.queries))
+            if totals is not None and len(batch.results) > 3:
+                totals[:] = batch.results[3][sl]
+            scores, parts, ords = batch.results[:3]
             return scores[sl], parts[sl], ords[sl]
         finally:
             if batch.consume():
@@ -810,12 +823,13 @@ def default_scheduler() -> AdaptiveDispatchScheduler:
 
 
 def serving_dispatch(engine, queries: List, k: int, check=None,
-                     fault_log=None, tier: Optional[str] = None):
+                     fault_log=None, tier: Optional[str] = None,
+                     totals=None):
     """THE serving dispatch entry (search/serving.py call sites): the
     process-default scheduler, so concurrent searches batch across REST
     entry points."""
     return _default.dispatch(engine, queries, k, check=check,
-                             fault_log=fault_log, tier=tier)
+                             fault_log=fault_log, tier=tier, totals=totals)
 
 
 def scheduler_stats() -> dict:

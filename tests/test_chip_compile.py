@@ -110,12 +110,30 @@ def test_pack_presence_bits(chip):
     K.pack_presence_bits.lower(_cols(chip), _cols(chip)).compile()
 
 
+def _bits(s):
+    return s((HP + 2 + K.BITSET_COLD_ROWS, NSW * K.SW_WORD_ROWS, 128),
+             jnp.uint32)
+
+
+@pytest.mark.parametrize("n", [1, 64])
+def test_bitset_rows_written_and_counted(chip, n):
+    # plain XLA too: what keeps the bitsets' rows (the re-pack over the
+    # column rows, the cold clauses' rows written from the host) and what
+    # counts a conjunction's hits
+    K.bitset_repack.lower(_bits(chip), _cols(chip), _cols(chip)).compile()
+    K.bitset_write_rows.lower(
+        _bits(chip), chip((n,), jnp.int32),
+        chip((n, NSW * K.SW_WORD_ROWS, 128), jnp.uint32)).compile()
+    K.mask_live_counts.lower(
+        chip((8 * n, NSW * K.SW_WORD_ROWS, 128), jnp.uint32),
+        chip((NSW * K.SW_WORD_ROWS, 128), jnp.uint32)).compile()
+
+
 @pytest.mark.parametrize("QC", [8, 256])
 def test_intersect_bitset(chip, QC):
     _compiled(K.intersect_bitset,
               chip((QC, K.BITSET_CLAUSES), jnp.int32),
-              chip((QC, K.BITSET_NEGS), jnp.int32),
-              chip((HP + 2, NSW * K.SW_WORD_ROWS, 128), jnp.uint32),
+              chip((QC, K.BITSET_NEGS), jnp.int32), _bits(chip),
               QC=QC, nsw=NSW)
 
 
@@ -265,11 +283,29 @@ def test_fused_sweep_bitset_four_chips(shard4, mesh4):
               shard4((SP, QC, 1), jnp.float32),
               shard4((SP, QC, K.BITSET_CLAUSES), jnp.int32),
               shard4((SP, QC, K.BITSET_NEGS), jnp.int32),
-              shard4((SP, HP4 + 2, NSW4 * K.SW_WORD_ROWS, 128), jnp.uint32),
+              shard4((SP, HP4 + 2 + K.BITSET_COLD_ROWS,
+                      NSW4 * K.SW_WORD_ROWS, 128), jnp.uint32),
+              shard4((SP, NSW4 * K.SW_WORD_ROWS, 128), jnp.uint32),
               _cols4(shard4), _cols4(shard4),
               shard4((SP, 2, QC, HP4 + 1), jnp.int8),
               shard4((SP, NSW4 * K.SW_ROWS, 128), jnp.float32),
               mesh=mesh4, QC=QC, nsw=NSW4, n_rows=K_ROWS)
+
+
+@pytest.mark.parametrize("i", [0, 7])
+def test_fused_slice_sync_four_chips(shard4, i):
+    """`turbo._set_part`: one partition's columns written into the fused
+    cache's own (donated) buffer; the cache stays sharded a partition
+    group a chip (no gather of it onto one)."""
+    from elasticsearch_tpu.parallel.turbo import _set_part
+
+    part = jax.ShapeDtypeStruct(
+        (NSW4 * K.N_CHUNKS - K.N_CHUNKS, HP4 - 31, K.CHUNK_ROWS, 128),
+        jnp.int8, sharding=NamedSharding(shard4((1,), jnp.int8).sharding.mesh,
+                                         P()))
+    exe = _set_part.lower(_cols4(shard4), part, i=i).compile()
+    assert exe.output_shardings.spec == P("shard")
+    assert "all-gather" not in exe.as_text()
 
 
 def test_partition_merge_four_chips(shard4, mesh4):

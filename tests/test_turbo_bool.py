@@ -9,8 +9,12 @@ order, one f32 downcast):
     JAX_PLATFORMS=cpu),
   * forced certificate failure: device collection discarded, exact host
     fallback (turbo.force_cert_fail test hook),
-  * all-cold: a fresh engine with cold_df above every df, so every query
-    takes the host sparse-intersection path with no columns at all.
+  * all-cold: a fresh engine with cold_df above every df, so no term has
+    a column: every required clause is a COLD row of the bitsets and the
+    query is answered from its conjunction mask, on the device (PR 38;
+    before it this was the host's sorted-array intersection),
+  * host: `search_bool_host`, the sorted-array intersection a faulted
+    partition falls back to.
 
 Ground truth is an independent numpy scorer (tf lookups shared, formula
 and phrase-position walk reimplemented here).
@@ -160,15 +164,21 @@ def corpus():
 
 
 def _run_routes(fp, n_docs, specs, live=None, k=10):
-    """(device, cert-fail fallback, all-cold host) result triples."""
+    """(device, cert-fail fallback, all-cold device) results, each held
+    to the host route's here."""
     dev, _ = _engine(fp, n_docs, live=live, cold_df=5)
     got_dev = dev.search_bool(specs, k=k)
     dev.force_cert_fail = True
     got_fb = dev.search_bool(specs, k=k)
+    assert dev.stats["bool_host"] == 0, "a failed certificate went to host"
     cold, _ = _engine(fp, n_docs, live=live, cold_df=1 << 30)
     got_cold = cold.search_bool(specs, k=k)
     assert dev.stats["bool_device"] > 0, "device route never engaged"
-    assert cold.stats["bool_host"] > 0, "host route never engaged"
+    assert cold.stats["bool_cold_lead"] > 0, "cold lead never on the device"
+    assert cold.stats["bool_host"] == 0, "a cold clause went to the host"
+    got_host = dev.search_bool_host(specs, k=k)
+    assert dev.stats["bool_host"] > 0, "host route never engaged"
+    _assert_identical(got_dev, got_host, "device vs host route")
     return got_dev, got_fb, got_cold, dev, cold
 
 

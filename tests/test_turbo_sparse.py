@@ -887,8 +887,10 @@ def test_finish_bulk_pct_is_declared_for_the_bm25_cells_and_reads_a_share():
     name = "finish_bulk_pct.search"
     for cell in m.cell_names():
         declared = name in {x["name"] for x in m.declared(cell, 1)}
-        assert declared == m.cell(cell).config["device_counter"].startswith(
-            "tpu_turbo."), cell
+        c = m.cell(cell)          # (the `match` route's cells: the bool
+        #                           cell's finish is `_finish_bool_chunk`)
+        assert declared == (c.config["device_counter"].startswith(
+            "tpu_turbo.") and c.traffic["request"]["kind"] == "match"), cell
         assert name not in {x["name"] for x in m.declared(cell, 0)}
     spec = m.metric_spec(name)
     read = load_kind(m.dir, "reader", spec["kind"]).read
@@ -923,8 +925,10 @@ def test_gather_queries_per_launch_is_declared_for_the_bm25_cells_and_reads_a_ra
     name = "gather_queries_per_launch.search"
     for cell in m.cell_names():
         declared = name in {x["name"] for x in m.declared(cell, 1)}
-        assert declared == m.cell(cell).config["device_counter"].startswith(
-            "tpu_turbo."), cell
+        c = m.cell(cell)          # (the `match` route's cells: the bool
+        #                           cell's finish is `_finish_bool_chunk`)
+        assert declared == (c.config["device_counter"].startswith(
+            "tpu_turbo.") and c.traffic["request"]["kind"] == "match"), cell
         assert name not in {x["name"] for x in m.declared(cell, 0)}
     spec = m.metric_spec(name)
     assert spec["kind"] == "counter_share" and spec["scale"] == 1
