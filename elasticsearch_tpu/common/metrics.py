@@ -63,15 +63,16 @@ class Histogram:
         self.total = 0.0  # guarded by: _lock
         self.vmax = 0.0  # guarded by: _lock
 
-    def record(self, value: float) -> None:
+    def record(self, value: float, count: int = 1) -> None:
+        """`count` observations of `value`."""
         v = float(value)
         if v < 0.0:
             v = 0.0
         i = bisect_left(self.bounds, v)
         with self._lock:
-            self.counts[i] += 1
-            self.n += 1
-            self.total += v
+            self.counts[i] += count
+            self.n += count
+            self.total += v * count
             if v > self.vmax:
                 self.vmax = v
 
@@ -159,10 +160,11 @@ def _hist(name: str) -> Histogram:
         return h
 
 
-def observe(name: str, value: float) -> None:
-    """Record one observation. ``name`` must be declared (tpulint TPU005
-    checks literal call sites against the declarations above)."""
-    _hist(name).record(value)
+def observe(name: str, value: float, count: int = 1) -> None:
+    """Record one observation (or `count` of the same value). ``name``
+    must be declared (tpulint TPU005 checks literal call sites against the
+    declarations above)."""
+    _hist(name).record(value, count)
 
 
 def observe_if_declared(name: str, value: float) -> None:
@@ -517,7 +519,7 @@ declare_histogram("dispatch.prep", "ms", "engine call, host side before the laun
 declare_histogram("dispatch.launch", "ms", "engine call, the device programs' calls returning (async; holds trace + lower + compile when a program is new); BM25: the sweep, then the plan + launch of the chunk's cold-side gathers behind it")
 declare_histogram("dispatch.device_wait", "ms", "engine call, host blocked fetching the first pass's output")
 declare_histogram("dispatch.finish", "ms", "engine call, host side after the fetch: per (partition, query) collect / rescore / merge")
-declare_histogram("dispatch.slice_build", "ms", "within prep (BM25): host build of a fresh query's cold-term sparse slices and the device pool update")
+declare_histogram("dispatch.slice_build", "ms", "within prep (BM25): one pass a partition over ALL the cold terms of the dispatch chunk that have no sparse slice: runs allocated in one walk, granules packed in one set of array operations, one device pool update (under launch: the same pass over a query's terms when the pool could not hold the chunk's together); tpu_turbo.sparse_slices over sparse_slice_passes = slices a pass")
 declare_histogram("dispatch.mask", "ms", "within prep (kNN): per-partition filter masks, stacking, upload")
 declare_histogram("dispatch.sparse_gather", "ms", "within finish (BM25): collecting the cold side's sparse gather, launched behind the sweep, from its copy on the host")
 declare_histogram("dispatch.rescore", "ms", "within finish: exact rescore (BM25 _exact_scores; kNN host row gather + rescore program + fetch)")

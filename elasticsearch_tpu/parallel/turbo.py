@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import Counter
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -343,7 +344,7 @@ def node_bitset_stats() -> dict:
 # the groups from what it can see: the pool's residency and the ladder
 # below, no knob). It has three parts. PLAN (host only): slice residency,
 # then the program's flat step list, concatenated from what each slice
-# worked out when it was built (_slice_pairs: the (chunk, tile) that meet)
+# worked out when it was built (its step pairs: the (chunk, tile) that meet)
 # with the query's weight and its place in the result broadcast over them;
 # per query the posting spans and the bound's slack (_ColdGather). LAUNCH:
 # one i32 [4, steps] upload + one program, async, with the result's copy
@@ -390,12 +391,16 @@ def _sparse_widths() -> Tuple[int, ...]:
 # tpu_turbo by serving.turbo_node_stats next to the bitset block;
 # sparse_bytes is a gauge-like running total of currently resident padded
 # slice bytes (evictions subtract), the rest are cumulative.
+# sparse_slice_passes = _build_slices calls that placed a slice, so
+# sparse_slices over it = slices built a pass (hundreds a closed call's
+# chunk, 1-3 a single search; it falls when pool pressure splits a chunk's
+# build into per-query retries under launch).
 # finish_bulk_pairs = (partition, query) pairs the chunk-wide finish
 # answered; finish_pair_fallbacks = pairs that left it (a host-only
 # partition, a faulted sweep, a gather not launched or lost, a failed
 # certificate): the two sum to partitions x queries
-_NODE_SPARSE_STATS = {"sparse_slices": 0, "sparse_bytes": 0,
-                      "sparse_queries": 0,
+_NODE_SPARSE_STATS = {"sparse_slices": 0, "sparse_slice_passes": 0,
+                      "sparse_bytes": 0, "sparse_queries": 0,
                       "sparse_gather_launches": 0,
                       "sparse_gather_overlapped": 0,
                       "sparse_fallbacks": 0,
@@ -422,21 +427,6 @@ def _gather_desc(n_steps: int) -> np.ndarray:
     — rows coff, cw (f32 bits), meta, oidx: every step is of no kind,
     reads the zero granule and stays on result chunk 0."""
     return np.zeros((4, n_steps), np.int32)
-
-
-def _slice_pairs(docs: np.ndarray) -> np.ndarray:
-    """What a gather's plan needs of a slice, worked out once when the
-    slice is built because it depends on the slice alone: the (chunk,
-    tile) pairs that MEET, one a gather step, as chunk << 16 | tile in
-    posting order (`docs` i64, the term's sorted postings: they are the
-    distinct (posting // 1024, doc // 16384))."""
-    key = docs // TILE
-    if len(docs) <= SPARSE_GRAN:
-        return np.flatnonzero(np.bincount(key))
-    key += np.arange(len(docs)) // SPARSE_GRAN << 16
-    new = np.ones(len(key), bool)
-    np.not_equal(key[1:], key[:-1], out=new[1:])
-    return key[new]
 
 
 class _GatherGroup:
@@ -466,7 +456,7 @@ class _ColdGather:
     """One (partition, query)'s cold side: its `group` (None = unsliceable,
     host-scored), where its terms' chunks lie in the group's result
     (`spans`: [(first chunk, df, first posting)]), the bound's `slack`,
-    and `slices` [(first granule, dequant weight, `_slice_pairs`)] for
+    and `slices` [(first granule, dequant weight, step pairs)] for
     the group's step list."""
 
     __slots__ = ("cold_terms", "group", "slices", "spans", "slack", "host")
@@ -637,7 +627,10 @@ class TurboBM25:
         self._sp_host: Optional[np.ndarray] = None   # authoritative mirror
         self._sp_of: Dict[str, tuple] = {}
         #   term -> (granule start, n granules, padded width, quant scale,
-        #   _slice_pairs): a plain tuple of numbers and an array, which
+        #   step pairs: the (chunk, tile) that MEET, one a gather step, as
+        #   chunk << 16 | tile in posting order, i64; worked out when the
+        #   slice is built, _pack_slices, because they depend on the slice
+        #   alone): a plain tuple of numbers and an array, which
         #   the collector stops tracking (a pool holds thousands, and a
         #   closed loop builds a thousand a call)
         self._sp_lru: Dict[str, int] = {}
@@ -1128,14 +1121,15 @@ class TurboBM25:
                     o, "_sp_pool", jnp.asarray(o._sp_host)))
         self._hbm.set_region("sparse_pool", self._sp_pool.nbytes)
 
-    def _sp_evict(self, term: str) -> None:
+    def _sp_evict(self, term: str) -> int:
+        """Free `term`'s run for reuse at its own width; the bytes freed
+        (the caller books them, once a call)."""
         g0, n_g, w, _, _ = self._sp_of.pop(term)
         self._sp_lru.pop(term, None)
         self._sp_free.setdefault(n_g, []).append(g0)
         # the stale granules stay in place (nothing references them, and
         # host mirror == device still holds); reuse overwrites both sides
-        self.stats["sparse_bytes"] -= w * 4
-        _node_sparse_add("sparse_bytes", -w * 4)
+        return w * 4
 
     def _reset_sparse(self) -> None:
         """Drop every slice (fault containment / scrub repair): zero both
@@ -1151,38 +1145,59 @@ class TurboBM25:
             self._sp_host[:] = 0
             self._sp_pool = jnp.asarray(self._sp_host)
 
-    def _sp_alloc(self, n_g: int, protect: set) -> int:
-        """One granule run for an `n_g`-granule slice, or -1. Tries the
+    def _sp_alloc(self, need, protect: set) -> Tuple[List[int], int]:
+        """ALLOCATE, one walk over a call's `need` [(term, info, width)]:
+        a granule run for each slice in order, for as long as the pool can
+        place them, and the bytes evicted for them. A run comes from its
         width's free list, then the bump pointer (growing the pool toward
-        its cap), then LRU eviction. A victim's run is reusable only at
-        its own width — no coalescing; the ladder is small enough that
-        freed runs recycle quickly."""
-        free = self._sp_free.get(n_g)
-        if free:
-            return free.pop()
-        cur = 0 if self._sp_pool is None else self._sp_pool.shape[0]
-        if self._sp_next + n_g > cur and cur < self._sp_cap:
-            self._sp_grow(min(self._sp_cap,
-                              max(cur * 2, self._sp_next + n_g, 64)))
-            cur = self._sp_pool.shape[0]
-        if self._sp_next + n_g <= cur:
-            g0 = self._sp_next
-            self._sp_next += n_g
-            return g0
-        for t in sorted(self._sp_lru, key=self._sp_lru.get):
-            if t in protect or t not in self._sp_of:
-                continue
-            self._sp_evict(t)
+        its cap), then LRU eviction: victims are taken from ONE ordering
+        of `_sp_lru` a call, made at the call's first miss (what the call
+        itself places is protected, so the order cannot move under it),
+        evicted whatever their width until one frees a run of the width
+        wanted. A victim's run is reusable only at its own width: no
+        coalescing; the ladder is small enough that freed runs recycle
+        quickly. `protect` is never evicted; when nothing else is left
+        the walk stops and the slices placed so far stand."""
+        g0s: List[int] = []
+        freed = 0
+        victims = None
+        for _t, _info, w in need:
+            n_g = w // SPARSE_GRAN
             free = self._sp_free.get(n_g)
-            if free:
-                return free.pop()
-        return -1
+            if not free:
+                cur = 0 if self._sp_pool is None else self._sp_pool.shape[0]
+                if self._sp_next + n_g > cur and cur < self._sp_cap:
+                    self._sp_grow(min(self._sp_cap,
+                                      max(cur * 2, self._sp_next + n_g, 64)))
+                    cur = self._sp_pool.shape[0]
+                if self._sp_next + n_g <= cur:
+                    g0s.append(self._sp_next)
+                    self._sp_next += n_g
+                    continue
+                if victims is None:
+                    lru = self._sp_lru
+                    victims = iter(sorted(lru, key=lru.get))
+                for v in victims:
+                    if v in protect or v not in self._sp_of:
+                        continue
+                    freed += self._sp_evict(v)
+                    free = self._sp_free.get(n_g)
+                    if free:
+                        break
+                else:
+                    break
+            g0s.append(free.pop())
+        return g0s, freed
 
     def _ensure_sparse(self, pairs: Sequence[Tuple[str, _TermInfo]],
                        keep=()) -> bool:
-        """Build device slices for the given cold (term, info) pairs:
-        pack ``doc << 8 | impact`` granules on the host (the mirror is the
-        scrubber's truth), then batch-write them into the donated device
+        """Make the given cold (term, info) pairs' device slices resident:
+        the ones that are get their LRU tick, the missing ones are built
+        TOGETHER, in one pass over all of them (`_build_slices`; under
+        `dispatch.slice_build`, counted in `sparse_slice_passes`): runs
+        allocated in one walk, ``doc << 8 | impact`` granules packed on
+        the host in one set of array operations (the mirror is the
+        scrubber's truth), then batch-written into the donated device
         pool. `keep` = terms whose slices may not be recycled for them
         (an open gather group's). Returns False when any term cannot be
         sliced (df above the ladder, or pool pressure with everything
@@ -1195,17 +1210,17 @@ class TurboBM25:
         if not self._sp_ok:
             return False
         self._tick += 1
-        missing = []
+        missing: Dict[str, _TermInfo] = {}
         for t, info in pairs:
             if t in self._sp_of:
                 self._sp_lru[t] = self._tick
             else:
-                missing.append((t, info))
+                missing[t] = info
         if not missing:
             return True
         widths = _sparse_widths()
         need: List[Tuple[str, _TermInfo, int]] = []
-        for t, info in missing:
+        for t, info in missing.items():
             w = next((w for w in widths if w >= info.df), None)
             if w is None:
                 return False
@@ -1217,55 +1232,27 @@ class TurboBM25:
 
     def _build_slices(self, need, protect) -> bool:
         """The work of `_ensure_sparse` for the terms that have no slice
-        yet: the host slice build and the device pool update."""
-        fp = self.fp
-        idx_l, upd_l = [], []
-        fits = True
+        yet, all of them in one pass: ALLOCATE (`_sp_alloc`), PACK
+        (`_pack_slices`), one upload, and the books once. A call with one
+        missing term is the same pass over one term."""
         try:
-            for t, info, w in need:
-                n_g = w // SPARSE_GRAN
-                g0 = self._sp_alloc(n_g, protect)
-                if g0 < 0:
-                    # pool pressure with everything protected: the slices
-                    # built so far are in the mirror and in _sp_of, so
-                    # they still go up (a resident slice the device never
-                    # received would gather stale granules, silently)
-                    fits = False
-                    break
-                lo = int(fp.post_start[info.ord])
-                hi = int(fp.post_start[info.ord + 1])
-                docs = np.asarray(fp.post_doc[lo:hi], np.int64)
-                lanes = self._host_scores[
-                    info.row_start: info.row_start + info.n_rows
-                ].ravel()[: hi - lo].astype(np.float64)
-                sscale = max(float(info.smax), 1e-9) / SPARSE_IMP_MAX
-                q = np.clip(np.rint(lanes / sscale),
-                            1, SPARSE_IMP_MAX).astype(np.int64)
-                buf = np.zeros(w, np.int64)
-                buf[: hi - lo] = (docs << 8) | q
-                gran = buf.astype(np.int32).reshape(
-                    n_g, SPARSE_GRAN // 128, 128)
-                self._sp_host[g0: g0 + n_g] = gran
-                self._sp_of[t] = (g0, n_g, w, sscale, _slice_pairs(docs))
-                self._sp_lru[t] = self._tick
-                idx_l.append(np.arange(g0, g0 + n_g, dtype=np.int32))
-                upd_l.append(gran)
-                self.stats["sparse_slices"] += 1
-                self.stats["sparse_bytes"] += w * 4
-                _node_sparse_add("sparse_slices", 1)
-                _node_sparse_add("sparse_bytes", w * 4)
-                metrics.observe("sparse_slice_width", w)
-            if not idx_l:
+            g0s, freed = self._sp_alloc(need, protect)
+            # pool pressure with everything protected ends the walk early:
+            # the slices placed so far still go up (a resident slice the
+            # device never received would gather stale granules, silently)
+            placed = need[: len(g0s)]
+            widths = Counter(w for _t, _info, w in placed)
+            grown = 4 * sum(w * n for w, n in widths.items()) - freed
+            self.stats["sparse_bytes"] += grown
+            _node_sparse_add("sparse_bytes", grown)
+            if not placed:
                 return False
-            idx = np.concatenate(idx_l)
-            upd = np.concatenate(upd_l, axis=0)
-            nb = next((b for b in _SPARSE_UP_BUCKETS if b >= len(idx)),
-                      -(-len(idx) // _SPARSE_UP_BUCKETS[-1])
-                      * _SPARSE_UP_BUCKETS[-1])
-            pad = nb - len(idx)
-            idx = np.concatenate([idx, np.zeros(pad, np.int32)])
-            upd = np.concatenate(
-                [upd, np.zeros((pad, SPARSE_GRAN // 128, 128), np.int32)])
+            idx, upd = self._pack_slices(placed, g0s)
+            self.stats["sparse_slices"] += len(placed)
+            _node_sparse_add("sparse_slices", len(placed))
+            _node_sparse_add("sparse_slice_passes", 1)
+            for w, n in widths.items():
+                metrics.observe("sparse_slice_width", w, count=n)
             with faults.device_errors("sparse_gather", self.part_id):
                 self._sp_pool = sparse_pool_update(
                     self._sp_pool, jnp.asarray(idx), jnp.asarray(upd))
@@ -1275,7 +1262,78 @@ class TurboBM25:
             self._reset_sparse()
             raise
         self._hbm.set_region("sparse_pool", self._sp_pool.nbytes)
-        return fits
+        return len(placed) == len(need)
+
+    def _pack_slices(self, placed, g0s: List[int]):
+        """PACK: the slices of `placed` [(term, info, width)] at the runs
+        `g0s`, all at once and a block ROW (128 lanes) at a time. A term's
+        postings lie in `n_rows` consecutive rows of `fp.block_docs` /
+        `_host_scores`, and its slice is those rows, packed, followed by
+        zero rows up to its width: so every term's rows are read through
+        one concatenated row index, quantized against their term's scale
+        in one expression (the floats of a build a term at a time, so
+        the same granules), and written into the call's granules with one
+        scatter of rows; the granules go into the mirror with another and
+        come back with their pool indices as the upload (`idx` i32 [n],
+        `upd` i32 [n, 8, 128], n a rung of `_SPARSE_UP_BUCKETS`, padded
+        toward the zero granule). The (chunk, tile) step pairs of every
+        slice, (posting // 1024, doc // 16384) as chunk << 16 | tile, are
+        read off the same rows and split at the term offsets. Each term's
+        `_sp_of` entry and LRU tick are set."""
+        fp, k = self.fp, len(placed)
+        terms = [t for t, _info, _w in placed]
+        cols = np.array([(info.row_start, info.n_rows, w // SPARSE_GRAN)
+                         for _t, info, w in placed], np.int64).T
+        row0, n_rows, n_g = cols
+        sscale = np.array([max(float(info.smax), 1e-9)
+                           for _t, info, _w in placed]) / SPARSE_IMP_MAX
+        g_rows = SPARSE_GRAN // 128
+        # term -> its first row of the concatenation, and its first
+        # granule of the upload
+        off = np.zeros((2, k + 1), np.int64)
+        np.cumsum(cols[1:], axis=1, out=off[:, 1:])
+        roff, goff = off
+        n_r, n_gr = int(roff[-1]), int(goff[-1])
+        at = np.arange(n_r) - roff[:-1].repeat(n_rows)      # row in its term
+        src = at + row0.repeat(n_rows)
+        docs = fp.block_docs[src]                            # [n_r, 128] i32
+        lanes = self._host_scores[src]
+        # a lane holds a posting where its score is over 0 (a posting's
+        # tf is 1 or more; _host_block_scores gives the empty lanes, the
+        # end of a term's last row, 0.0)
+        real = lanes > 0
+        q = lanes.astype(np.float64)
+        q /= sscale.repeat(n_rows)[:, None]
+        q = np.rint(q, out=q).astype(np.int32)
+        np.minimum(np.maximum(q, 1, out=q), SPARSE_IMP_MAX, out=q)
+        tiles = (docs // TILE).reshape(-1)
+        # doc << 8 | impact, and 0 where there is no posting
+        docs <<= 8
+        docs |= q
+        docs *= real
+        nb = next((b for b in _SPARSE_UP_BUCKETS if b >= n_gr),
+                  -(-n_gr // _SPARSE_UP_BUCKETS[-1]) * _SPARSE_UP_BUCKETS[-1])
+        upd = np.zeros((nb * g_rows, 128), np.int32)
+        upd[at + (goff[:-1] * g_rows).repeat(n_rows)] = docs
+        upd = upd.reshape(nb, g_rows, 128)
+        idx = np.zeros(nb, np.int32)
+        idx[:n_gr] = np.arange(n_gr) + (np.asarray(g0s, np.int64)
+                                        - goff[:-1]).repeat(n_g)
+        self._sp_host[idx[:n_gr]] = upd[:n_gr]
+        # the step pairs: a posting begins one where the tile moves from
+        # the posting before it, or a chunk (8 rows) begins
+        new = real.reshape(-1)
+        new[1:] &= tiles[1:] != tiles[:-1]
+        new.reshape(n_r, 128)[at % g_rows == 0, 0] = True
+        hits = np.flatnonzero(new)
+        pairs = tiles[hits] | (at // g_rows << 16)[hits >> 7]
+        cuts = np.searchsorted(hits, roff * 128).tolist()
+        self._sp_of.update(zip(terms, zip(
+            g0s, n_g.tolist(), (w for _t, _info, w in placed),
+            sscale.tolist(),
+            (pairs[a:b].copy() for a, b in zip(cuts, cuts[1:])))))
+        self._sp_lru.update(dict.fromkeys(terms, self._tick))
+        return idx, upd
 
     def _sparse_on(self) -> bool:
         return self._sp_ok and bool(knob("ES_TPU_SPARSE"))

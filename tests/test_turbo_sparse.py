@@ -910,19 +910,29 @@ def test_finish_bulk_pct_is_declared_for_the_bm25_cells_and_reads_a_share():
     assert read(spec, bare) == 0.0
 
 
-def test_gather_queries_per_launch_is_declared_for_the_bm25_cells_and_reads_a_ratio():
-    """The benchmark's `gather_queries_per_launch.search`: declared in the
-    traced runs of the cells whose device counter is the fused turbo's;
-    its reader gives the rise of `sparse_queries` over the rise of
-    `sparse_gather_launches` (both counters older than the group gather:
-    a tree that launches a program a (partition, query) reads 1.0), 0
-    where no gather was launched or the stats lack the counters."""
+@pytest.mark.parametrize("name, numerator, denominator", [
+    ("gather_queries_per_launch.search", "sparse_queries",
+     "sparse_gather_launches"),
+    ("slices_per_pass.search", "sparse_slices", "sparse_slice_passes"),
+])
+def test_a_counter_ratio_is_declared_for_the_bm25_match_cells_and_reads_a_ratio(
+        name, numerator, denominator):
+    """The benchmark's two ratios of the sparse tier's own counters,
+    `gather_queries_per_launch.search` (PR 35: cold sides served over
+    gather programs launched; a tree that launches a program a (partition,
+    query) reads 1.0) and `slices_per_pass.search` (PR 39: slices built
+    over the build passes that placed one): declared in the traced runs
+    of the `match` cells whose device counter is the fused turbo's; the
+    reader gives the rise of the numerator over the rise of the
+    denominator, 0.0 where the denominator did not move or the stats
+    lack it (PR 39's parent has no `sparse_slice_passes`: its traced run
+    prints a line, not a KeyError)."""
     from types import SimpleNamespace
 
     from benchmark.manifest import ROOT, Manifest, load_kind
+    from elasticsearch_tpu.search.serving import turbo_node_stats
 
     m = Manifest(ROOT)
-    name = "gather_queries_per_launch.search"
     for cell in m.cell_names():
         declared = name in {x["name"] for x in m.declared(cell, 1)}
         c = m.cell(cell)          # (the `match` route's cells: the bool
@@ -932,14 +942,15 @@ def test_gather_queries_per_launch_is_declared_for_the_bm25_cells_and_reads_a_ra
         assert name not in {x["name"] for x in m.declared(cell, 0)}
     spec = m.metric_spec(name)
     assert spec["kind"] == "counter_share" and spec["scale"] == 1
+    assert spec["numerator"] == [f"tpu_turbo.{numerator}"]
+    assert spec["denominator"] == [f"tpu_turbo.{denominator}"]
+    assert {numerator, denominator} <= set(turbo_node_stats())
     read = load_kind(m.dir, "reader", spec["kind"]).read
 
-    def window(q0, l0, q1, l1):
+    def window(n0, d0, n1, d1):
         return SimpleNamespace(
-            stats_before={"tpu_turbo": {"sparse_queries": q0,
-                                        "sparse_gather_launches": l0}},
-            stats_after={"tpu_turbo": {"sparse_queries": q1,
-                                       "sparse_gather_launches": l1}})
+            stats_before={"tpu_turbo": {numerator: n0, denominator: d0}},
+            stats_after={"tpu_turbo": {numerator: n1, denominator: d1}})
 
     assert read(spec, window(7, 7, 7 + 768, 7 + 9)) == \
         pytest.approx(768 / 9)
@@ -948,6 +959,9 @@ def test_gather_queries_per_launch_is_declared_for_the_bm25_cells_and_reads_a_ra
     bare = SimpleNamespace(stats_before={"tpu_turbo": {}},
                            stats_after={"tpu_turbo": {}})
     assert read(spec, bare) == 0.0
+    lacks = SimpleNamespace(stats_before={"tpu_turbo": {numerator: 9000}},
+                            stats_after={"tpu_turbo": {numerator: 11100}})
+    assert read(spec, lacks) == 0.0
 
 
 def test_the_metric_reads_the_engines_own_counters(solo_engine):
@@ -981,3 +995,275 @@ def test_a_call_leaves_nothing_to_the_cycle_collector(solo_engine):
         assert gc.collect() == 0
     finally:
         gc.enable()
+
+
+# ---- PR 39: a call's missing slices are built in ONE pass ----
+#
+# `_build_slices` allocates, packs and uploads all the slices of one call
+# together. What it replaced, the build a term at a time, lives on here as
+# the oracle the pass is held to byte for byte: the same runs, the same
+# victims in the same order, the same granules on both sides of the pool,
+# the same `_sp_of` tuples (step pairs included), the same books.
+
+_SPECIAL_DFS = (1, 2, 100, 1023, 1024, 1025, 3000, 4096, 4097, 5000, 16384)
+_N_DOCS = 40_000           # three tiles of 16,384 docs: real step pairs
+_N_SMALL = 400
+
+
+@pytest.fixture(scope="module")
+def df_corpus():
+    """A partition whose terms have the document frequencies the cases
+    name: `d<df>` for each of `_SPECIAL_DFS`, `r000`..`r399` of 1..600,
+    and `zall` in every document (the one colized term)."""
+    from elasticsearch_tpu.index.segment import build_field_postings
+
+    rng = np.random.default_rng(39)
+    dfs = {f"d{df:05d}": df for df in _SPECIAL_DFS}
+    dfs.update((f"r{i:03d}", int(df)) for i, df in
+               enumerate(rng.integers(1, 601, _N_SMALL)))
+    dfs["zall"] = _N_DOCS
+    names = sorted(dfs)
+    docs_l, terms_l = [], []
+    for o, name in enumerate(names):
+        docs = np.sort(rng.choice(_N_DOCS, dfs[name], replace=False))
+        docs = np.repeat(docs, rng.integers(1, 4, len(docs)))   # tf 1..3
+        docs_l.append(docs)
+        terms_l.append(np.full(len(docs), o, np.int64))
+    tok_docs, tok_terms = np.concatenate(docs_l), np.concatenate(terms_l)
+    order = np.argsort(tok_docs, kind="stable")
+    lens = np.bincount(tok_docs, minlength=_N_DOCS).astype(np.int64)
+    return build_field_postings("body", lens, tok_docs[order],
+                                tok_terms[order], names), dfs
+
+
+def _term_at_a_time(self, need, protect):
+    """The oracle: `TurboBM25._build_slices` as it stood before the pass
+    (PR 38's tree), with its `_sp_alloc`, `_sp_evict` and `_slice_pairs`."""
+    from elasticsearch_tpu.parallel.turbo import (
+        SPARSE_IMP_MAX, TILE, _SPARSE_UP_BUCKETS, jnp, sparse_pool_update)
+
+    def evict(term):
+        g0, n_g, w, _, _ = self._sp_of.pop(term)
+        self._sp_lru.pop(term, None)
+        self._sp_free.setdefault(n_g, []).append(g0)
+        self.stats["sparse_bytes"] -= w * 4
+
+    def alloc(n_g):
+        free = self._sp_free.get(n_g)
+        if free:
+            return free.pop()
+        cur = 0 if self._sp_pool is None else self._sp_pool.shape[0]
+        if self._sp_next + n_g > cur and cur < self._sp_cap:
+            self._sp_grow(min(self._sp_cap,
+                              max(cur * 2, self._sp_next + n_g, 64)))
+            cur = self._sp_pool.shape[0]
+        if self._sp_next + n_g <= cur:
+            g0 = self._sp_next
+            self._sp_next += n_g
+            return g0
+        for t in sorted(self._sp_lru, key=self._sp_lru.get):
+            if t in protect or t not in self._sp_of:
+                continue
+            evict(t)
+            free = self._sp_free.get(n_g)
+            if free:
+                return free.pop()
+        return -1
+
+    def slice_pairs(docs):
+        key = docs // TILE
+        if len(docs) <= SPARSE_GRAN:
+            return np.flatnonzero(np.bincount(key))
+        key += np.arange(len(docs)) // SPARSE_GRAN << 16
+        new = np.ones(len(key), bool)
+        np.not_equal(key[1:], key[:-1], out=new[1:])
+        return key[new]
+
+    fp = self.fp
+    idx_l, upd_l = [], []
+    fits = True
+    try:
+        for t, info, w in need:
+            n_g = w // SPARSE_GRAN
+            g0 = alloc(n_g)
+            if g0 < 0:
+                fits = False
+                break
+            lo = int(fp.post_start[info.ord])
+            hi = int(fp.post_start[info.ord + 1])
+            docs = np.asarray(fp.post_doc[lo:hi], np.int64)
+            lanes = self._host_scores[
+                info.row_start: info.row_start + info.n_rows
+            ].ravel()[: hi - lo].astype(np.float64)
+            sscale = max(float(info.smax), 1e-9) / SPARSE_IMP_MAX
+            q = np.clip(np.rint(lanes / sscale),
+                        1, SPARSE_IMP_MAX).astype(np.int64)
+            buf = np.zeros(w, np.int64)
+            buf[: hi - lo] = (docs << 8) | q
+            gran = buf.astype(np.int32).reshape(
+                n_g, SPARSE_GRAN // 128, 128)
+            self._sp_host[g0: g0 + n_g] = gran
+            self._sp_of[t] = (g0, n_g, w, sscale, slice_pairs(docs))
+            self._sp_lru[t] = self._tick
+            idx_l.append(np.arange(g0, g0 + n_g, dtype=np.int32))
+            upd_l.append(gran)
+            self.stats["sparse_slices"] += 1
+            self.stats["sparse_bytes"] += w * 4
+        if not idx_l:
+            return False
+        idx = np.concatenate(idx_l)
+        upd = np.concatenate(upd_l, axis=0)
+        nb = next((b for b in _SPARSE_UP_BUCKETS if b >= len(idx)),
+                  -(-len(idx) // _SPARSE_UP_BUCKETS[-1])
+                  * _SPARSE_UP_BUCKETS[-1])
+        pad = nb - len(idx)
+        idx = np.concatenate([idx, np.zeros(pad, np.int32)])
+        upd = np.concatenate(
+            [upd, np.zeros((pad, SPARSE_GRAN // 128, 128), np.int32)])
+        with faults.device_errors("sparse_gather", self.part_id):
+            self._sp_pool = sparse_pool_update(
+                self._sp_pool, jnp.asarray(idx), jnp.asarray(upd))
+    except DeviceFaultError:
+        self._reset_sparse()
+        raise
+    return fits
+
+
+def _assert_same_pool(a, b, label):
+    """Engine `a` (the pass) against engine `b` (the oracle): everything
+    the sparse tier keeps, and mirror == device on both."""
+    assert (a._sp_host is None) == (b._sp_host is None), label
+    if a._sp_host is not None:
+        assert a._sp_host.dtype == b._sp_host.dtype
+        assert np.array_equal(a._sp_host, b._sp_host), f"{label}: mirror"
+        for e in (a, b):
+            assert np.array_equal(np.asarray(e._sp_pool), e._sp_host), \
+                f"{label}: mirror != device"
+    assert list(a._sp_of) == list(b._sp_of), f"{label}: resident terms"
+    for t, (g0, n_g, w, sscale, pairs) in a._sp_of.items():
+        g0_b, n_g_b, w_b, sscale_b, pairs_b = b._sp_of[t]
+        assert (g0, n_g, w, sscale) == (g0_b, n_g_b, w_b, sscale_b), (label, t)
+        assert [type(x) for x in (g0, n_g, w, sscale)] == \
+            [int, int, int, float], (label, t)
+        # (its own array: a view would keep its whole call's pairs alive
+        # for as long as this one slice stays resident)
+        assert pairs.dtype == pairs_b.dtype and pairs.base is None
+        assert np.array_equal(pairs, pairs_b), f"{label}: pairs of {t}"
+    assert list(a._sp_lru.items()) == list(b._sp_lru.items()), f"{label}: LRU"
+    assert a._sp_free == b._sp_free, f"{label}: free lists"
+    assert a._sp_next == b._sp_next, f"{label}: bump pointer"
+    for key in ("sparse_slices", "sparse_bytes"):
+        assert a.stats[key] == b.stats[key], (label, key)
+    assert a.stats["sparse_bytes"] == 4 * sum(
+        w for _g0, _n, w, _s, _p in a._sp_of.values()), label
+
+
+def _small(lo, hi):
+    return [f"r{i:03d}" for i in range(lo, hi)]
+
+
+# a case = (pool cap in granules or None, [(terms, keep, fits expected)]);
+# each step is one `_ensure_sparse` call on both engines
+_PASS_CASES = {
+    "one term": (None, [(["r007"], (), True)]),
+    "df 1": (None, [(["d00001"], (), True)]),
+    "df exactly a ladder rung": (None, [
+        (["d01024"], (), True), (["d04096", "d16384", "d01023"], (), True)]),
+    "multi-granule slices": (None, [
+        (["d01025", "d03000", "r001", "d04097", "d05000"], (), True)]),
+    "several hundred terms in one call": (None, [
+        (_small(0, _N_SMALL) + [f"d{df:05d}" for df in _SPECIAL_DFS],
+         (), True)]),
+    "a pool at its cap, victims taken": (24, [
+        (_small(0, 12) + ["d03000"], (), True),            # 12 + 4 of 23
+        (_small(4, 8), (), True),                          # ticks only
+        (_small(12, 22), (), True),      # 7 by the bump, 3 evict r000..
+        (["d01025", "r002", "d04097"], (), False),  # a 4-run: evicts across
+        #   widths down to d03000; a 16-run: every victim goes, none frees one
+        (_small(0, 4) + _small(30, 40), (), True)]),
+    "pool pressure with everything protected": (4, [
+        (_small(0, 6), (), False),
+        (["d03000"], (), False),         # no run of four can ever be had
+        (_small(6, 8), (), True)]),
+    "keep honoured": (4, [
+        (_small(0, 3), (), True),
+        (["r010"], ("r000",), True),     # r000 is the oldest and kept
+        (["r011", "r012"], ("r010", "r002"), False)]),
+}
+
+
+@pytest.mark.parametrize("case", list(_PASS_CASES))
+def test_the_one_pass_build_is_the_per_term_build_byte_for_byte(
+        df_corpus, case):
+    from types import MethodType
+
+    from elasticsearch_tpu.common import metrics
+
+    fp, dfs = df_corpus
+    cap, steps = _PASS_CASES[case]
+    a = _turbo(fp, _N_DOCS, cold_df=20_000)
+    b = _turbo(fp, _N_DOCS, cold_df=20_000)
+    b._build_slices = MethodType(_term_at_a_time, b)
+    if cap is not None:
+        a._sp_cap = b._sp_cap = cap
+    for si, (terms, keep, fits) in enumerate(steps):
+        label = f"{case}, call {si}"
+        pairs = [(t, a._term(t)) for t in terms]
+        assert all(info.df == dfs[t] for t, info in pairs)
+        node0 = turbo_mod.node_sparse_stats()
+        seen0 = metrics.summary("sparse_slice_width")
+        built0 = a.stats["sparse_slices"]
+        got = a._ensure_sparse(pairs, keep=keep)
+        node1 = turbo_mod.node_sparse_stats()
+        seen1 = metrics.summary("sparse_slice_width")
+        assert got == b._ensure_sparse(
+            [(t, b._term(t)) for t in terms], keep=keep), label
+        assert got == fits, label
+        _assert_same_pool(a, b, label)
+        # the books once a call: the node's counters and the histogram
+        # rise by what the pass placed, and one pass is counted for it
+        built = a.stats["sparse_slices"] - built0
+        assert node1["sparse_slices"] - node0["sparse_slices"] == built
+        assert (node1["sparse_slice_passes"]
+                - node0["sparse_slice_passes"]) == (1 if built else 0), label
+        assert ((seen1 or {}).get("count", 0)
+                - (seen0 or {}).get("count", 0)) == built, label
+        if fits:
+            assert all(t in a._sp_of for t in terms), label
+    assert a.stats["sparse_slices"] > 0
+
+
+@pytest.mark.faults
+def test_a_faulted_upload_of_the_one_pass_build_drops_every_slice(
+        df_corpus, monkeypatch):
+    """The pass and the oracle under the same faulted `sparse_pool_update`:
+    both raise `DeviceFaultError`, both end with an empty tier whose
+    mirror is the device's bytes, and both rebuild the same pool after."""
+    from types import MethodType
+
+    fp, _dfs = df_corpus
+    a = _turbo(fp, _N_DOCS, cold_df=20_000)
+    b = _turbo(fp, _N_DOCS, cold_df=20_000)
+    b._build_slices = MethodType(_term_at_a_time, b)
+    first = _small(0, 20) + ["d03000"]
+    for e in (a, b):
+        assert e._ensure_sparse([(t, e._term(t)) for t in first])
+    real = turbo_mod.sparse_pool_update
+
+    def lost(pool, idx, upd):
+        raise RuntimeError("INTERNAL: device lost")
+
+    second = _small(15, 40) + ["d05000"]
+    monkeypatch.setattr(turbo_mod, "sparse_pool_update", lost)
+    for e in (a, b):
+        with pytest.raises(DeviceFaultError):
+            e._ensure_sparse([(t, e._term(t)) for t in second])
+        assert not e._sp_of and not e._sp_lru and not e._sp_free
+        assert e._sp_next == 1 and e.stats["sparse_bytes"] == 0
+        assert not e._sp_host.any()
+    _assert_same_pool(a, b, "after the fault")
+    monkeypatch.setattr(turbo_mod, "sparse_pool_update", real)
+    for e in (a, b):
+        assert e._ensure_sparse([(t, e._term(t)) for t in second])
+    _assert_same_pool(a, b, "rebuilt")
+    assert set(second) == set(a._sp_of)
