@@ -276,6 +276,17 @@ declare_counter("agg_host_fallbacks",
                 "(unsupported shape, over budget, or device fault)")
 declare_counter("agg_bytes",
                 "precomputed agg-column bytes uploaded to HBM (cumulative)")
+declare_counter("agg_reductions",
+                "(segment, layout) segment-reduce programs run; over "
+                "agg_device_dispatches = reductions a dispatch (1.0 on the "
+                "per-collect route, segments x layouts a batch on the "
+                "filter + bucket route)")
+declare_counter("filter_device",
+                "(request, segment) match sets of the filter + bucket route "
+                "made ON THE DEVICE from the request's bounds")
+declare_counter("filter_host",
+                "(request, segment) match sets made on the host and "
+                "uploaded as a mask (the per-collect route)")
 
 # quantized kNN tier (PR 19), bumped by parallel/knn.py; the same counts
 # back the tpu_knn section of GET /_nodes/stats
@@ -522,7 +533,9 @@ declare_histogram("dispatch.finish", "ms", "engine call, host side after the fet
 declare_histogram("dispatch.slice_build", "ms", "within prep (BM25): one pass a partition over ALL the cold terms of the dispatch chunk that have no sparse slice: runs allocated in one walk, granules packed in one set of array operations, one device pool update (under launch: the same pass over a query's terms when the pool could not hold the chunk's together); tpu_turbo.sparse_slices over sparse_slice_passes = slices a pass")
 declare_histogram("dispatch.mask", "ms", "within prep (kNN): per-partition filter masks, stacking, upload")
 declare_histogram("dispatch.sparse_gather", "ms", "within finish (BM25): collecting the cold side's sparse gather, launched behind the sweep, from its copy on the host")
-declare_histogram("dispatch.rescore", "ms", "within finish: exact rescore (BM25 _exact_scores; kNN host row gather + rescore program + fetch)")
+declare_histogram("dispatch.rescore", "ms", "within finish: exact rescore (BM25 _exact_scores; kNN host row gather + rescore program + fetch; aggregations: the exact cross-check of the device's bucket counts against the match set's size)")
+declare_histogram("dispatch.agg_plan", "ms", "within prep (aggregation engine, filter + bucket route): layouts and filter columns looked up (built on first use), the batch grouped a (segment, layout), each request's bounds turned to rank intervals")
+declare_histogram("dispatch.agg_fold", "ms", "within finish (aggregation engine, filter + bucket route): the device's rank counts folded into each request's buckets, the segments reduced and the response's bucket list built")
 declare_histogram("dispatch.cert_fallback", "ms", "within finish (BM25): full exact merge after a failed certificate")
 declare_histogram("dispatch.bool_resolve", "ms", "within prep (BM25 bool route): resolving the batch's specs against every partition's terms")
 declare_histogram("dispatch.phrase_build", "ms", "within prep (BM25 bool route): the positions scan of a phrase named for the first time and its adjacency column's build")

@@ -171,12 +171,15 @@ class IndexService:
         return (version, search_type, body)
 
     def search(self, request: dict, search_type: str = "query_then_fetch",
-               searchers=None, task=None) -> dict:
+               searchers=None, task=None,
+               request_cache: bool = True) -> dict:
+        """`request_cache` False (the `request_cache=false` URL
+        parameter): neither read nor fill the shard request cache."""
         import copy as _copy
 
         self.check_read_allowed()
 
-        key = self._request_cache_key(request, search_type)             if searchers is None else None
+        key = self._request_cache_key(request, search_type)             if searchers is None and request_cache else None
         if key is not None:
             with self._req_cache_lock:
                 hit = self._req_cache.get(key)

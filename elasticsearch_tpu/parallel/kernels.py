@@ -975,7 +975,8 @@ def sparse_pool_update(pool, idx, upd):
 
 
 def _agg_count_kernel():
-    def kernel(ct0, ct1, sel_blk, seg_blk, acc_ref):
+    def kernel(ct0, ct1, nq, sel_blk, seg_blk, acc_ref):
+        q = pl.program_id(0)
         t = pl.program_id(1)
         c = pl.program_id(2)
 
@@ -985,8 +986,10 @@ def _agg_count_kernel():
 
         # pairs are grouped, so the host-prefetched inclusive bucket-tile
         # range [ct0, ct1] skips every tile a chunk cannot touch (padding
-        # chunks carry the empty range (1, 0) and never scatter)
-        @pl.when((t >= ct0[c]) & (t <= ct1[c]))
+        # chunks carry the empty range (1, 0) and never scatter); a
+        # padding ROW of the batch (q >= nq: the ladder's rung is wider
+        # than the batch) selects nothing and scatters nothing
+        @pl.when((t >= ct0[c]) & (t <= ct1[c]) & (q < nq[0]))
         def _scatter():
             base = t * AGG_SEG_TILE
             col = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
@@ -1010,43 +1013,57 @@ def _agg_count_kernel():
     return kernel
 
 
-def _agg_counts(mask, doc, seg, ct0, ct1, n_segments: int):
-    """One masked segment reduction: counts[q, s] = |{pairs (d, s) with
-    mask[q, d]}| — the scatter-as-outer-product trick applied to bucket
-    ids (within a 16384-bucket tile, bucket = hi*128 + lo). Pre-gathering
-    the mask at the pair docs keeps the kernel scatter-only, the same
-    split as `_segment_count_program` used before this kernel existed."""
-    Q = mask.shape[0]
-    p = doc.shape[0]
+def _agg_scatter(sel, seg, ct0, ct1, n_tiles: int, name: str, nq=None):
+    """The segment reduction proper: acc[q, s] = sum of sel[q, i] over the
+    pairs i with seg[i] == s — the scatter-as-outer-product trick applied
+    to bucket ids (within a 16384-bucket tile, bucket = hi*128 + lo).
+    `sel` [Q, p] f32 0/1 is the selection already laid out in pair order,
+    so the kernel is scatter-only. `name` names the custom call: a trace
+    reader finds the kernel by the instruction's own name. `nq` [1] i32:
+    the rows that hold a query (the rest pad the batch to its rung and
+    are skipped); None = all. Returns [Q, n_tiles * AGG_SEG_TILE] f32
+    (exact integers below 2^24)."""
+    Q, p = sel.shape
     nc = p // AGG_PAIR_GRAN
-    n_tiles = -(-n_segments // AGG_SEG_TILE)
-    sel = jnp.take(mask, doc, axis=1).astype(jnp.float32)
+    if nq is None:
+        nq = jnp.full((1,), Q, jnp.int32)
     acc = pl.pallas_call(
         _agg_count_kernel(),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(Q, n_tiles, nc),
             in_specs=[
                 pl.BlockSpec(
                     (1, 1, AGG_PAIR_GRAN // 128, 128),
-                    lambda q, t, c, ct0, ct1: (q, c, 0, 0)),
+                    lambda q, t, c, ct0, ct1, nq: (q, c, 0, 0)),
                 pl.BlockSpec(
                     (1, AGG_PAIR_GRAN // 128, 128),
-                    lambda q, t, c, ct0, ct1: (c, 0, 0)),
+                    lambda q, t, c, ct0, ct1, nq: (c, 0, 0)),
             ],
             out_specs=pl.BlockSpec(
                 (1, 1, 128, 128),
-                lambda q, t, c, ct0, ct1: (q, t, 0, 0)),
+                lambda q, t, c, ct0, ct1, nq: (q, t, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((Q, n_tiles, 128, 128),
                                        jnp.float32),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_interpret(),
-    )(ct0, ct1,
+        name=name,
+    )(ct0, ct1, nq,
       sel.reshape(Q, nc, AGG_PAIR_GRAN // 128, 128),
       seg.reshape(nc, AGG_PAIR_GRAN // 128, 128))
-    flat = acc.reshape(Q, n_tiles * AGG_SEG_TILE)
+    return acc.reshape(Q, n_tiles * AGG_SEG_TILE)
+
+
+def _agg_counts(mask, doc, seg, ct0, ct1, n_segments: int, name: str):
+    """One masked segment reduction: counts[q, s] = |{pairs (d, s) with
+    mask[q, d]}|. Pre-gathering the mask at the pair docs keeps the
+    kernel scatter-only, the same split as `_segment_count_program` used
+    before this kernel existed."""
+    n_tiles = -(-n_segments // AGG_SEG_TILE)
+    sel = jnp.take(mask, doc, axis=1).astype(jnp.float32)
+    flat = _agg_scatter(sel, seg, ct0, ct1, n_tiles, name)
     return flat[:, :n_segments].astype(jnp.int32)
 
 
@@ -1067,7 +1084,51 @@ def agg_segment_counts(mask, blob, *, p: int, n_segments: int):
     nc = p // AGG_PAIR_GRAN
     return _agg_counts(mask, blob[:p], blob[p:2 * p],
                        blob[2 * p:2 * p + nc],
-                       blob[2 * p + nc:2 * p + 2 * nc], n_segments)
+                       blob[2 * p + nc:2 * p + 2 * nc], n_segments,
+                       "agg_segment_counts")
+
+
+@functools.partial(jax.jit, static_argnames=("p", "n_out", "identity"))
+def agg_filter_counts(bounds, nq, cols, blob, *, p: int, n_out: int,
+                      identity: bool):
+    """`agg_segment_counts` for a match set that is MADE HERE, from each
+    query's bounds, and never crosses from the host: one (segment,
+    layout) reduction of the filter + bucket route (agg_device.py).
+
+    bounds [Q, F, 2] i32 — query q keeps a doc when, for every filter
+        column f, bounds[q, f, 0] <= cols[f][doc] < bounds[q, f, 1]. A
+        column holds each doc's RANK among the segment's sorted distinct
+        values of the field (-1 = no value), so the host's binary search
+        of a request's bounds makes the comparison exact to the last
+        bit of the field. (-1, INT32_MAX) leaves a column unconstrained;
+        (0, 0) keeps nothing (a padding row).
+    nq [1] i32 — the rows that hold a query; the kernel skips the rest
+    cols  — tuple of F [n_docs] i32 rank columns of the segment
+    blob  — the layout's column, as `agg_segment_counts` takes it
+    identity — the layout's pair i IS doc i (every doc holds exactly one
+        value of the bucketed field): the selection is laid out in pair
+        order by padding, with no gather
+
+    Returns ([Q, n_out] i32 counts per bucket rank, n_out >= the
+    layout's ranks; [Q] i32 size of each query's match set)."""
+    nc = p // AGG_PAIR_GRAN
+    Q = bounds.shape[0]
+    n = cols[0].shape[0]
+    keep = jnp.ones((Q, n), jnp.bool_)
+    for f, col in enumerate(cols):
+        keep = keep & (col[None, :] >= bounds[:, f, 0:1]) \
+            & (col[None, :] < bounds[:, f, 1:2])
+    totals = jnp.sum(keep, axis=1, dtype=jnp.int32)
+    if identity:
+        sel = jnp.pad(keep, ((0, 0), (0, p - n)))
+    else:
+        sel = jnp.take(keep, blob[:p], axis=1)
+    n_tiles = -(-n_out // AGG_SEG_TILE)
+    flat = _agg_scatter(sel.astype(jnp.float32), blob[p:2 * p],
+                        blob[2 * p:2 * p + nc],
+                        blob[2 * p + nc:2 * p + 2 * nc], n_tiles,
+                        "agg_filter_counts", nq)
+    return flat[:, :n_out].astype(jnp.int32), totals
 
 
 @functools.partial(jax.jit, static_argnames=("pd", "pm", "n_segments"))
@@ -1090,11 +1151,12 @@ def agg_two_level_counts(mask, blob, *, pd: int, pm: int, n_segments: int):
     o = 2 * pd + 2 * ncd
     dc = _agg_counts(mask, blob[:pd], blob[pd:2 * pd],
                      blob[2 * pd:2 * pd + ncd],
-                     blob[2 * pd + ncd:o], n_segments)
+                     blob[2 * pd + ncd:o], n_segments,
+                     "agg_two_level_counts")
     vc = _agg_counts(mask, blob[o:o + pm], blob[o + pm:o + 2 * pm],
                      blob[o + 2 * pm:o + 2 * pm + ncm],
                      blob[o + 2 * pm + ncm:o + 2 * pm + 2 * ncm],
-                     n_segments)
+                     n_segments, "agg_two_level_counts")
     return dc, vc
 
 

@@ -1119,8 +1119,12 @@ class _Handlers:
                 return self._ok_search(req, self.node.indices.scroll_start(
                     names[0], body, keep, task=task))
             if len(names) == 1:
+                # `request_cache=false` (ref: RestSearchAction): this
+                # request is answered by the engines, not by the cache
                 return self._ok_search(req, self.node.indices.get(
-                    names[0]).search(body, search_type, task=task))
+                    names[0]).search(
+                        body, search_type, task=task,
+                        request_cache=req.param_bool("request_cache", True)))
             return self._ok_search(req, self._multi_index_search(
                 names, body, search_type, task=task))
 

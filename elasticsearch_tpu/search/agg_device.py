@@ -43,11 +43,35 @@ bucketing):
     layout group, and each poisoned collect falls back to the host
     aggregator (counted in `agg_host_fallbacks`).
 
+  * **One dispatch a request (filter + bucket route).** A size-0
+    request whose query is `match_all` or a `bool` of `filter` clauses
+    (`range` / `term` on numeric fields) over ONE `date_histogram`
+    (`search/serving.py: extract_filter_agg_plan`) is one
+    `FilterAggWork`: every segment's reduction goes in the same
+    scheduler dispatch, concurrent requests merge into one padded batch
+    a (segment, layout), and the match set is MADE ON THE DEVICE. Each
+    filtered field lives beside the layouts as an i32 column of value
+    RANKS (`_FilterColumn`: one ledger + scrub region a (segment,
+    field)); the host turns a request's bounds into rank intervals by
+    binary search over the segment's sorted distinct values, so the
+    device's integer comparison is exact to the field's last bit, and
+    `hits.total` is the match set's size from the same program
+    (`kernels.agg_filter_counts`). The engine call runs the four
+    `dispatch.*` steps, `dispatch.agg_plan` (in prep) and
+    `dispatch.agg_fold` (in finish) one observation a dispatch each.
+    Counters: `filter_device` / `filter_host` ((request, segment) match
+    sets made on the device / made on the host and uploaded),
+    `agg_reductions` ((segment, layout) reductions run; over
+    `agg_device_dispatches` = reductions a dispatch).
+
 Fallback matrix (host path serves whenever any gate fails): knob off,
 leaf below AGG_DEVICE_MIN_DOCS, missing/script params, keyword-metric
 value_count, non-numeric histogram field, > 2^24 pairs, > 2^16 uniq
 bucket values, sub-aggs that are not plain metrics or span multiple
-metric fields, HBM budget exceeded, device fault.
+metric fields, HBM budget exceeded, device fault. The filter + bucket
+route adds: an envelope it does not recognise, a deleted document in the
+snapshot, a segment under the size floor or without the field, a
+multi-valued or NaN filter field; all of those are answered as before.
 """
 
 from __future__ import annotations
@@ -59,7 +83,10 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from elasticsearch_tpu.common import faults, hbm_ledger, integrity, metrics
+from elasticsearch_tpu.common import (
+    faults, hbm_ledger, integrity, metrics, tracing,
+)
+from elasticsearch_tpu.common.errors import DeviceFaultError
 from elasticsearch_tpu.common.settings import knob
 
 AGG_PAIR_GRAN = 1024      # pairs per kernel chunk (kernels.AGG_PAIR_GRAN)
@@ -68,6 +95,12 @@ MAX_PAIRS = 1 << 24       # f32 one-hot count accumulation exact below this
 MAX_UNIQ = 1 << 16        # uniq-rank bucket ceiling per layout
 _DATE_GRANS = (3_600_000, 60_000, 1000)   # hour / minute / second, ms
 _MAX_EXACT = float(1 << 53)               # f64 exact-integer ceiling
+_I32_MAX = (1 << 31) - 1
+
+# the steps of one engine call (tracing.steps): each takes one histogram
+# observation per call (0.0 when it did not run), see common/metrics.py
+DISPATCH_STEPS = metrics.DISPATCH_TOP_STEPS + (
+    "dispatch.agg_plan", "dispatch.agg_fold", "dispatch.rescore")
 
 # metric sub-agg types the two-level route serves (partials reproduced by
 # _metric_partial in exactly the host collect's shape)
@@ -82,7 +115,9 @@ DEVICE_METRICS = frozenset({
 
 _COUNTS_LOCK = threading.Lock()
 _COUNTS = {"agg_queries": 0, "agg_device_dispatches": 0,
-           "agg_host_fallbacks": 0, "agg_bytes": 0}   # guarded by: _COUNTS_LOCK
+           "agg_host_fallbacks": 0, "agg_bytes": 0,
+           "agg_reductions": 0, "filter_device": 0,
+           "filter_host": 0}   # guarded by: _COUNTS_LOCK
 
 
 def _count(key: str, n: int = 1) -> None:
@@ -164,6 +199,43 @@ class _AggLayout:
         self.dev = jnp.asarray(self.host)
 
 
+class _FilterColumn:
+    """One numeric field of one segment as the device filters on it: each
+    doc's RANK among the segment's sorted distinct values (`uniq`, kept
+    on the host), -1 where the doc has no value. A request's bounds
+    become a rank interval by binary search over `uniq`, so the device
+    compares i32 and the answer is exact to the field's last bit (a
+    date's millisecond). Ledger + scrub region like a layout's."""
+
+    kind = "filter"
+
+    def __init__(self, field: str, uniq: np.ndarray, rank: np.ndarray):
+        import jax.numpy as jnp
+
+        self.field = field
+        self.uniq = uniq
+        self.n_docs = len(rank)
+        self.serial = next(_layout_serials)
+        self.host = np.ascontiguousarray(rank, np.int32)
+        self.dev = jnp.asarray(self.host)
+        self.nbytes = int(self.host.nbytes)
+        self.region_name = "aggflt%d_%s" % (self.serial, "".join(
+            c if c.isalnum() else "_" for c in field))
+
+    def _reupload(self) -> None:
+        import jax.numpy as jnp
+
+        self.dev = jnp.asarray(self.host)
+
+    def rank_interval(self, lo: float, inc_lo: bool, hi: float,
+                      inc_hi: bool):
+        """[lo rank, hi rank) of the values inside the bounds."""
+        return (int(np.searchsorted(self.uniq, lo,
+                                    "left" if inc_lo else "right")),
+                int(np.searchsorted(self.uniq, hi,
+                                    "right" if inc_hi else "left")))
+
+
 # --------------------------------------------------------------------------
 # the engine: scheduler-facing dispatch adapter
 # --------------------------------------------------------------------------
@@ -183,6 +255,51 @@ class _AggWork:
         self.mask = mask
         self.result = None
         self.error: Optional[BaseException] = None
+
+
+class FilterAggWork:
+    """One REQUEST of the filter + bucket route: its histogram, its
+    filter clauses as (field, lo, include lo, hi, include hi) in the
+    field's own doc-value scale, and every segment of the snapshot (all
+    documents live). The engine fills `partial` (the shard's reduced
+    histogram partial, what the host's collect + reduce give), `result`
+    (the finalized `aggregations` entry, where `finalize`) and `total`
+    (the match set's size), or `error` (the caller then answers the old
+    way)."""
+
+    __slots__ = ("agg", "clauses", "segments", "finalize", "partial",
+                 "result", "total", "error", "_parts")
+
+    def __init__(self, agg, clauses, segments, finalize: bool = True):
+        self.agg = agg
+        self.clauses = list(clauses)
+        self.segments = list(segments)
+        self.finalize = finalize
+        self.partial = None
+        self.result = None
+        self.total = 0
+        self.error: Optional[BaseException] = None
+        self._parts: List[Any] = []
+
+
+class _Unservable(Exception):
+    """A request the route cannot take after all (a layout or column
+    refused): its caller answers it the old way."""
+
+
+class _Reduction:
+    """One (segment, layout) reduction of a dispatch: the works that
+    take part, the union of the fields they filter on."""
+
+    __slots__ = ("layout", "seg", "works", "fields", "cols", "out")
+
+    def __init__(self, layout, seg):
+        self.layout = layout
+        self.seg = seg
+        self.works: List[FilterAggWork] = []
+        self.fields: List[str] = []
+        self.cols: List[_FilterColumn] = []
+        self.out = None
 
 
 class AggDeviceEngine:
@@ -258,7 +375,11 @@ class AggDeviceEngine:
         out = []
         for works in batches:
             works = list(works)
-            self._run_works(works)
+            with tracing.steps(DISPATCH_STEPS):
+                self._run_filter_works(
+                    [w for w in works if isinstance(w, FilterAggWork)])
+                self._run_works(
+                    [w for w in works if not isinstance(w, FilterAggWork)])
             q = max(1, len(works))
             kk = max(1, int(k))
             out.append((np.zeros((q, kk), np.float32),
@@ -284,15 +405,15 @@ class AggDeviceEngine:
 
         layout = group[0].layout
         q = len(group)
-        qpad = next((s for s in self.qc_sizes if s >= q), None)
-        if qpad is None:
-            qpad = -(-q // self.qc_sizes[-1]) * self.qc_sizes[-1]
+        qpad = self._qpad(q)
         mask = np.zeros((qpad, layout.n_docs), bool)
         for i, w in enumerate(group):
             mask[i] = w.mask
         hbm_ledger.note_dispatch("agg_reduce", qpad)
         metrics.observe("agg_batch_size", q)
         _count("agg_device_dispatches")
+        _count("agg_reductions")
+        _count("filter_host", q)    # a host mask a work, uploaded below
         with faults.device_dispatch("agg_reduce", layout.serial):
             if layout.kind == "terms_metric":
                 dc, vc = kernels.agg_two_level_counts(
@@ -308,6 +429,148 @@ class AggDeviceEngine:
                     n_segments=layout.meta["n_segments"]))
                 for i, w in enumerate(group):
                     w.result = counts[i]
+
+
+    # ---- the filter + bucket route: one dispatch a request ----
+
+    def _qpad(self, q: int) -> int:
+        qpad = next((s for s in self.qc_sizes if s >= q), None)
+        if qpad is None:
+            qpad = -(-q // self.qc_sizes[-1]) * self.qc_sizes[-1]
+        return qpad
+
+    def _run_filter_works(self, works: List["FilterAggWork"]) -> None:
+        """Every segment's reduction of every request of the batch, in
+        ONE dispatch: plan (prep), one program a (segment, layout)
+        launched and waited for in turn, the fold (finish)."""
+        if not works:
+            return
+        with tracing.phase("dispatch.prep", queries=len(works)), \
+                tracing.phase("dispatch.agg_plan", queries=len(works)):
+            reds = self._plan(works)
+        _count("agg_device_dispatches")
+        # one reduction on the device at a time: its selection [Q, pairs]
+        # is the program's scratch (0.66 GB a segment of 10 M documents at
+        # width 16), and programs launched behind each other would hold
+        # one each: the peak would follow the batch's mix, not the index
+        for r in reds:
+            try:
+                with tracing.phase("dispatch.launch", width=len(r.works)):
+                    self._launch(r)
+                with tracing.phase("dispatch.device_wait"), \
+                        faults.device_errors("agg_reduce", r.layout.serial):
+                    r.out = tuple(np.asarray(a) for a in r.out)
+            except Exception as e:   # containment: this reduction's
+                self._fail(r, e)     # requests fall back, no others
+        with tracing.phase("dispatch.finish", queries=len(works)):
+            with tracing.phase("dispatch.agg_fold", queries=len(works)):
+                for r in reds:
+                    self._fold_reduction(r)
+                live = [w for w in works if w.error is None]
+                for w in live:
+                    try:
+                        _reduce_work(w)
+                    except Exception as e:
+                        w.error = e
+            with tracing.phase("dispatch.rescore", queries=len(live)):
+                # the exact cross-check of the device's f32-accumulated
+                # counts: where every doc holds one value of the
+                # bucketed field, a request's buckets sum to its total
+                for w in live:
+                    if w.error is None and not _counts_add_up(w):
+                        w.error = DeviceFaultError(
+                            "agg_reduce: bucket counts do not add up to "
+                            "the match set's size", site="agg_reduce")
+
+    @staticmethod
+    def _fail(r: "_Reduction", e: BaseException) -> None:
+        r.out = None
+        for w in r.works:
+            w.error = e
+
+    def _plan(self, works: List["FilterAggWork"]) -> List["_Reduction"]:
+        """Layouts and filter columns looked up (built on first use),
+        the works grouped a (segment, layout), bounds turned to rank
+        intervals."""
+        reds: Dict[int, _Reduction] = {}
+        for w in works:
+            w.error, w._parts = None, []      # a solo retry starts afresh
+            try:
+                fname = w.agg.params["field"]
+                gran = _pick_gran(w.agg)
+                mine = []
+                for seg in w.segments:
+                    col = seg.numeric.get(fname)
+                    lay = None if col is None else _uniq_layout(
+                        seg, fname, col, gran)
+                    if lay is None:
+                        raise _Unservable(f"no layout for [{fname}]")
+                    for f in {c[0] for c in w.clauses} | {fname}:
+                        if _filter_column(seg, f) is None:
+                            raise _Unservable(f"no filter column [{f}]")
+                    mine.append((lay, seg))
+            except Exception as e:
+                w.error = e
+                continue
+            for lay, seg in mine:
+                r = reds.get(lay.serial)
+                if r is None:
+                    r = reds[lay.serial] = _Reduction(lay, seg)
+                r.works.append(w)
+        out = list(reds.values())
+        for r in out:
+            fname = r.works[0].agg.params["field"]
+            r.fields = sorted({c[0] for w in r.works for c in w.clauses}
+                              or {fname})
+            r.cols = [_filter_column(r.seg, f) for f in r.fields]
+        return out
+
+    def _launch(self, r: "_Reduction") -> None:
+        import jax.numpy as jnp
+
+        from elasticsearch_tpu.parallel import kernels
+
+        q = len(r.works)
+        qpad = self._qpad(q)
+        # a padding row keeps nothing; an unconstrained column keeps all
+        bounds = np.zeros((qpad, len(r.cols), 2), np.int32)
+        bounds[:q, :, 0] = -1
+        bounds[:q, :, 1] = _I32_MAX
+        for i, w in enumerate(r.works):
+            for f, lo, inc_lo, hi, inc_hi in w.clauses:
+                j = r.fields.index(f)
+                lo_r, hi_r = r.cols[j].rank_interval(lo, inc_lo, hi, inc_hi)
+                bounds[i, j, 0] = max(bounds[i, j, 0], lo_r)
+                bounds[i, j, 1] = min(bounds[i, j, 1], hi_r)
+        lay = r.layout
+        # whole tiles out: segments whose rank counts differ share a program
+        n_out = -(-lay.meta["n_segments"] // AGG_SEG_TILE) * AGG_SEG_TILE
+        hbm_ledger.note_dispatch("agg_reduce", qpad)
+        metrics.observe("agg_batch_size", q)
+        _count("agg_reductions")
+        _count("filter_device", q)
+        with faults.device_dispatch("agg_reduce", lay.serial):
+            r.out = kernels.agg_filter_counts(
+                jnp.asarray(bounds), jnp.asarray([q], jnp.int32),
+                tuple(c.dev for c in r.cols), lay.dev,
+                p=lay.meta["p"], n_out=n_out,
+                identity=bool(lay.meta["identity"]))
+
+    @staticmethod
+    def _fold_reduction(r: "_Reduction") -> None:
+        """A reduction's rank counts folded into each request's buckets:
+        the partial the host's collect gives for this segment."""
+        if r.out is None:
+            return
+        counts, totals = r.out
+        lay = r.layout
+        n = lay.meta["n_segments"]
+        for i, w in enumerate(r.works):
+            if w.error is not None:
+                continue
+            _count("agg_queries")
+            w._parts.append((_fold_ranks(w.agg, lay, counts[i, :n]),
+                             int(totals[i]), bool(lay.meta["identity"])))
 
 
 _ENGINE: Optional[AggDeviceEngine] = None
@@ -457,9 +720,34 @@ def _uniq_layout(seg, fname: str, col, gran) -> Optional[_AggLayout]:
         uid_of_doc = np.full(seg.n_docs, -1, np.int64)
         uid_of_doc[sel_docs] = uid
         lay.meta["uid_of_doc"] = uid_of_doc
+        # pair i IS doc i (every doc holds the field): a selection made
+        # on the device is in pair order without a gather
+        lay.meta["identity"] = len(sel_docs) == seg.n_docs
         return lay
 
     return _cached_layout(seg, f"aggdev:uniq:{fname}:{gran}", build)
+
+
+def _filter_column(seg, fname: str) -> Optional[_FilterColumn]:
+    """The segment's filter column of one numeric field, built and
+    adopted (ledger, scrub registry, HBM budget) on first use. Refused,
+    and the refusal cached: a field the segment lacks, a multi-valued
+    one (a range keeps a doc when ANY value lies inside: one rank a doc
+    cannot say that), NaN values, a budget that is spent."""
+
+    def build():
+        col = seg.numeric.get(fname)
+        if col is None or len(col.all_values) != int(col.exists.sum()):
+            return None
+        vals = col.values[col.exists]
+        if np.isnan(vals).any():
+            return None
+        uniq, inv = np.unique(vals, return_inverse=True)
+        rank = np.full(seg.n_docs, -1, np.int32)
+        rank[col.exists] = inv
+        return _FilterColumn(fname, uniq, rank)
+
+    return _cached_layout(seg, f"aggdev:filter:{fname}", build)
 
 
 def _metric_pair_docs(seg, mfield: str, mcol) -> np.ndarray:
@@ -595,6 +883,62 @@ def _pick_gran(agg):
         if interval % g == 0 and offset % g == 0:
             return g
     return "raw"
+
+
+def _fold_ranks(agg, lay: _AggLayout, counts: np.ndarray) -> Dict[float,
+                                                                   dict]:
+    """Rank counts -> the histogram's collect partial: the ranks'
+    representative values go through the aggregator's own `_key_of`
+    (any interval and offset the granularity divides), exactly as the
+    host's collect keys the raw values."""
+    keyed = lay.meta.setdefault("keyed", {})
+    spec = (agg.type_name, agg._interval(),
+            float(agg.params.get("offset", 0.0)))
+    got = keyed.get(spec)
+    if got is None:
+        if len(keyed) >= 8:       # a few shapes a layout: dashboards
+            keyed.clear()         # repeat theirs
+        keys = np.round(agg._key_of(lay.meta["reps"]), 10)
+        got = keyed[spec] = np.unique(keys, return_inverse=True)
+    uk, uinv = got
+    dc = np.bincount(uinv, weights=counts, minlength=len(uk)).astype(
+        np.int64)
+    return {float(uk[i]): {"doc_count": int(dc[i]), "sub": {}}
+            for i in np.flatnonzero(dc)}
+
+
+def _reduce_work(w: FilterAggWork) -> None:
+    """A request's segments reduced (and finalized) by the aggregator's
+    own `reduce` / `finalize`, as the shard and the coordinator do."""
+    w.total = sum(t for _, t, _ in w._parts)
+    w.partial = {w.agg.name: w.agg.reduce([p for p, _, _ in w._parts])}
+    if w.finalize:
+        w.result = {w.agg.name: w.agg.finalize(w.partial[w.agg.name])}
+
+
+def _counts_add_up(w: FilterAggWork) -> bool:
+    return all(not identity
+               or sum(b["doc_count"] for b in part.values()) == total
+               for part, total, identity in w._parts)
+
+
+def dispatch_filter_agg(works: List[FilterAggWork]) -> None:
+    """The filter + bucket route's one scheduler dispatch: the requests'
+    works ride the bulk tier's lane of the aggregation engine, merged
+    with whatever else is queued there. A work that comes back with an
+    `error` is counted as a host fallback; its caller answers it the old
+    way."""
+    from elasticsearch_tpu.threadpool.scheduler import (
+        TIER_BULK,
+        serving_dispatch,
+    )
+
+    serving_dispatch(default_engine(), works, 1, tier=TIER_BULK)
+    for w in works:
+        if w.error is not None or w.partial is None:
+            if w.error is None:
+                w.error = RuntimeError("no result")
+            _count("agg_host_fallbacks")
 
 
 def collect_histogram(agg, ctx, col, mask: np.ndarray):

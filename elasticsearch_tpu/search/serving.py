@@ -261,6 +261,107 @@ def extract_plan(request: dict, mapper) -> Optional[FlatPlan]:
     return plan
 
 
+# the filter + bucket envelope (Kibana's Discover view and every dashboard
+# panel over a logs index): a size-0 request, its exact total, a filter,
+# one date_histogram. `profile` as on the other routes.
+_FILTER_AGG_KEYS = {"size", "track_total_hits", "query", "aggs",
+                    "aggregations", "profile"}
+_DATE_HISTOGRAM_KEYS = {"field", "fixed_interval", "offset",
+                        "min_doc_count"}
+
+
+@dataclass
+class FilterAggPlan:
+    """A recognised filter + bucket request: the parsed histogram and the
+    filter as (field, lo, include lo, hi, include hi) clauses in each
+    field's doc-value scale, ANDed (a `term` is lo = hi, both included)."""
+
+    agg: object
+    clauses: List[Tuple[str, float, bool, float, bool]]
+
+
+def extract_filter_agg_plan(request: dict, mapper) -> Optional[FilterAggPlan]:
+    """`{"size": 0, "track_total_hits": true, "query": Q, "aggs": {name:
+    {"date_histogram": {...}}}}` where Q is `match_all` (or absent) or a
+    `bool` of only `filter` clauses, each a `range` or a `term` on a
+    numeric-family field (numbers, dates), and the histogram has a
+    `fixed_interval`, an optional numeric `offset`, the default
+    `min_doc_count` and no sub-aggregation. Anything else: None, and the
+    request is answered as before."""
+    if any(k not in _FILTER_AGG_KEYS for k in request):
+        return None
+    spec = request.get("aggs") or request.get("aggregations")
+    if ("aggs" in request and "aggregations" in request) \
+            or not isinstance(spec, dict) or len(spec) != 1 \
+            or request.get("size") != 0 \
+            or request.get("track_total_hits") is not True:
+        return None
+    (name, body), = spec.items()
+    if not isinstance(body, dict) or set(body) != {"date_histogram"}:
+        return None
+    params = body["date_histogram"]
+    if not isinstance(params, dict) or set(params) - _DATE_HISTOGRAM_KEYS \
+            or not isinstance(params.get("fixed_interval"), str) \
+            or params.get("min_doc_count", 0) != 0 \
+            or isinstance(params.get("offset", 0), (bool, str)):
+        return None
+
+    def numeric(field):
+        ft = mapper.field_type(field) if isinstance(field, str) else None
+        return ft if ft is not None and ft.family == "numeric" else None
+
+    if numeric(params.get("field")) is None:
+        return None
+    from elasticsearch_tpu.search.aggregations import DateHistogramAgg
+
+    clauses: List[Tuple[str, float, bool, float, bool]] = []
+    try:
+        agg = DateHistogramAgg(name, params, [], [])
+        if not agg._interval() > 0:
+            return None
+        float(params.get("offset", 0.0))
+        node = parse_query(request["query"]) \
+            if request.get("query") is not None else q.MatchAllQuery()
+        if isinstance(node, q.BoolQuery):
+            if node.must or node.should or node.must_not \
+                    or node.minimum_should_match is not None \
+                    or getattr(node, "boost", 1.0) != 1.0:
+                return None
+            leaves = list(node.filter)
+        elif isinstance(node, q.MatchAllQuery):
+            leaves = []
+        else:
+            return None
+        for leaf in leaves:
+            ft = numeric(getattr(leaf, "field", None))
+            if ft is None:
+                return None
+            conv = ft.doc_value
+            if isinstance(leaf, q.TermQuery):
+                want = conv(str(leaf.value))
+                clauses.append((leaf.field, want, True, want, True))
+            elif isinstance(leaf, q.RangeQuery):
+                # the executor's own reading of the four bounds
+                lo, inc_lo, hi, inc_hi = -np.inf, True, np.inf, True
+                if leaf.gte is not None:
+                    lo, inc_lo = conv(leaf.gte), True
+                if leaf.gt is not None:
+                    lo, inc_lo = conv(leaf.gt), False
+                if leaf.lte is not None:
+                    hi, inc_hi = conv(leaf.lte), True
+                if leaf.lt is not None:
+                    hi, inc_hi = conv(leaf.lt), False
+                clauses.append((leaf.field, float(lo), inc_lo, float(hi),
+                                inc_hi))
+            else:
+                return None
+    except Exception:   # a body the host path will refuse in its own words
+        return None
+    if any(np.isnan(c[1]) or np.isnan(c[3]) for c in clauses):
+        return None
+    return FilterAggPlan(agg=agg, clauses=clauses)
+
+
 def _text_field(plan: FlatPlan, mapper, field: str) -> None:
     ft = mapper.field_type(field)
     if ft is None or ft.family != "inverted":
@@ -1323,12 +1424,30 @@ class ServingContext:
         plans = [extract_plan(r, self.svc.mapper) for r in requests]
         kplans = [extract_knn_plan(r, self.svc.mapper) if p is None else None
                   for p, r in zip(plans, requests)]
-        if not any(plans) and not any(kplans):
+        aplans = [extract_filter_agg_plan(r, self.svc.mapper)
+                  if p is None and kp is None else None
+                  for p, kp, r in zip(plans, kplans, requests)]
+        if not any(plans) and not any(kplans) and not any(aplans):
             return [None] * len(plans)
         snap = self.snapshot()
         if snap.total_docs == 0:
             return [None] * len(plans)
         out: List[Optional[dict]] = [None] * len(plans)
+
+        # filter + bucket bodies: ONE dispatch of the aggregation engine
+        # for all of them, every segment's reduction in it
+        agg_idxs = [i for i, ap in enumerate(aplans) if ap is not None]
+        if agg_idxs and self._filter_agg_servable(snap):
+            try:
+                results = self._filter_agg_batch(
+                    [aplans[i] for i in agg_idxs],
+                    [requests[i] for i in agg_idxs], snap, task=task)
+                for i, r in zip(agg_idxs, results):
+                    out[i] = r
+            except TaskCancelledError:
+                raise
+            except Exception as e:
+                _note_reject_error(e, "filter_agg_batch")
 
         # kNN-only bodies on the same vector field batch into ONE fused
         # quantized dispatch (first pass + rescore), filters included
@@ -1410,7 +1529,18 @@ class ServingContext:
             return None             # per-shard adapter always has one
         plan = extract_plan(request, self.svc.mapper)
         if plan is None:
-            return None
+            aplan = extract_filter_agg_plan(request, self.svc.mapper)
+            snap = self.snapshot() if aplan is not None else None
+            if snap is None or not self._filter_agg_servable(snap):
+                return None
+            # the shard's reduced partial; the coordinator finalizes
+            work = self._filter_agg_dispatch([aplan], snap, task,
+                                             finalize=False)[0]
+            if work.error is not None:
+                return None         # the dense executor serves this one
+            return QuerySearchResult(
+                total=work.total, relation="eq", hits=[], max_score=None,
+                aggregations=work.partial)
         snap = self.snapshot()
         if snap.total_docs == 0:
             return None
@@ -1493,6 +1623,72 @@ class ServingContext:
             timed_out=bool(deadline is not None and deadline.expired),
             profile=fastpath_profile_nodes(request, eng, dev_ms)
             if request.get("profile") else None)
+
+    # ---- filter + bucket (the aggregation engine) ----
+
+    @staticmethod
+    def _filter_agg_servable(snap) -> bool:
+        """The route takes a snapshot whose every document is live and
+        whose every segment reaches the aggregation engine's size floor
+        (under it a program a segment shape costs more than the host's
+        pass); anything else is answered as before."""
+        from elasticsearch_tpu.search import aggregations
+
+        return bool(knob("ES_TPU_AGG")) and snap.total_docs > 0 and all(
+            p.all_live
+            and p.segment.n_docs >= aggregations.AGG_DEVICE_MIN_DOCS
+            for p in snap.partitions)
+
+    @staticmethod
+    def _filter_agg_dispatch(aplans, snap, task, finalize: bool = True):
+        """One scheduler dispatch for the plans' requests, each ONE work
+        that holds every segment; returns the works."""
+        from elasticsearch_tpu.search import agg_device
+
+        if task is not None:
+            task.check()
+        segments = [p.segment for p in snap.partitions]
+        works = [agg_device.FilterAggWork(ap.agg, ap.clauses, segments,
+                                          finalize=finalize)
+                 for ap in aplans]
+        agg_device.dispatch_filter_agg(works)
+        return works
+
+    def _filter_agg_batch(self, aplans, requests, snap, task=None):
+        """Recognised filter + bucket bodies, answered by the aggregation
+        engine in one dispatch; None where a work came back with an error
+        (the request is then answered as before, and was counted)."""
+        from elasticsearch_tpu.search import agg_device
+
+        start = time.monotonic()
+        works = self._filter_agg_dispatch(aplans, snap, task)
+        dev_ms = (time.monotonic() - start) * 1e3
+        results: List[Optional[dict]] = []
+        with tracing.phase("demux", batch=len(requests)):
+            for request, work in zip(requests, works):
+                if work.error is not None:
+                    results.append(None)
+                    continue
+                with tracing.phase("fetch", hits=0):
+                    resp = {
+                        "took": int((time.monotonic() - start) * 1000),
+                        "timed_out": False,
+                        "_shards": self._shards_section(snap, None),
+                        "hits": {"total": {"value": work.total,
+                                           "relation": "eq"},
+                                 "max_score": None, "hits": []},
+                        "aggregations": work.result,
+                    }
+                if request.get("profile"):
+                    resp["profile"] = {"shards": [{
+                        "id": f"[{self.svc.name}][0]",
+                        "searches": [{
+                            "query": fastpath_profile_nodes(
+                                request, agg_device.default_engine(),
+                                dev_ms, parts=len(snap.partitions)),
+                            "rewrite_time": 0, "collector": []}]}]}
+                results.append(resp)
+        return results
 
     # ---- disjunctive (device) ----
 

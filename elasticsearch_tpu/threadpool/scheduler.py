@@ -682,6 +682,14 @@ class AdaptiveDispatchScheduler:
                 break              # overflow backlog: the next flush is
             chosen.append(w)       # immediate (they stay due)
             n += len(w.queries)
+        head = due[0] if due else lane.queue[0]
+        if not chosen and len(head.queries) > bucket:
+            # the oldest waiter is wider than the ladder's top rung (an
+            # `_msearch` of a few bodies on a ladder that autotuned down
+            # to (1,)): it fits no bucket, so it goes alone at its own
+            # width, or it would wait for ever behind narrower flushes
+            chosen.append(head)
+            n = bucket = len(head.queries)
         taken = set(id(x) for x in chosen)
         for w in lane.queue:
             if id(w) in taken:

@@ -337,3 +337,120 @@ def test_ledger_reconciles_and_knobs_declared(monkeypatch):
                 "enabled", "layouts"):
         assert key in section
     svc.close()
+
+
+# ---------------------------------------------------------------------------
+# the filter + bucket route (PR 40): one dispatch a request, the match set
+# made on the device; tests/test_filter_agg_route.py holds it to the
+# benchmark's reference on the benchmark's own corpus
+# ---------------------------------------------------------------------------
+
+_WEEK = [BASE_MS + 20 * 86_400_000 + 123, BASE_MS + 27 * 86_400_000 + 123]
+_DAY = [BASE_MS + 40 * 86_400_000 + 1, BASE_MS + 41 * 86_400_000 + 1]
+
+
+def _envelope(filters, interval):
+    query = {"bool": {"filter": filters}} if filters else {"match_all": {}}
+    return {"size": 0, "track_total_hits": True, "query": query,
+            "aggs": {"by_time": {"date_histogram": {
+                "field": "ts", "fixed_interval": interval}}}}
+
+
+def _ts_range(lo, hi):
+    return {"range": {"ts": {"gte": lo, "lt": hi}}}
+
+
+# the benchmark mix's five shapes (Rally http_logs' operations) over this
+# file's own index: `ts` for @timestamp, the `long` field `n` for status
+FILTER_AGG_SHAPES = {
+    "HourlyAgg": _envelope([], "1h"),
+    "RangeHourly": _envelope([_ts_range(*_WEEK)], "1h"),
+    "RangeTenMinute": _envelope([_ts_range(*_DAY)], "10m"),
+    "Status200sInRange": _envelope(
+        [_ts_range(*_WEEK), {"term": {"n": 200}}], "1h"),
+    "Status400sInRange": _envelope(
+        [_ts_range(*_WEEK), {"term": {"n": 404}}], "1h"),
+}
+NOT_THE_ENVELOPE = {
+    "a_sub_aggregation": lambda b: b["aggs"]["by_time"].update(
+        aggs={"p": {"sum": {"field": "price"}}}),
+    "a_terms_bucket": lambda b: b.update(
+        aggs={"tags": {"terms": {"field": "tag"}}}),
+    "size_10": lambda b: b.update(size=10),
+}
+
+
+def _logs_service():
+    meta = IndexMetadata(
+        index="agg", uuid="u", settings=Settings({}), mappings={
+            "properties": {"tag": {"type": "keyword"},
+                           "price": {"type": "float"},
+                           "n": {"type": "long"}, "ts": {"type": "long"}}})
+    svc = IndexService(meta)
+    rng = np.random.default_rng(23)
+    for seg in range(3):                       # three segments, in time order
+        for i in range(800):
+            svc.index_doc(f"{seg}-{i}", {
+                "tag": f"t{i % 9}", "price": float(i % 50),
+                "n": int(rng.choice([200, 200, 200, 304, 404])),
+                "ts": BASE_MS + seg * 30 * 86_400_000
+                + int(rng.integers(0, 30 * 86_400)) * 1000})
+        svc.refresh()
+    return svc
+
+
+@pytest.fixture(scope="module")
+def logs_service():
+    svc = _logs_service()
+    yield svc
+    svc.close()
+
+
+@pytest.mark.parametrize("shape", sorted(FILTER_AGG_SHAPES))
+def test_recognised_envelope_and_host_path_give_the_same_bytes(
+        logs_service, monkeypatch, shape):
+    import json
+
+    svc, body = logs_service, FILTER_AGG_SHAPES[shape]
+    monkeypatch.setattr(agg_mod, "AGG_DEVICE_MIN_DOCS", 1)
+    monkeypatch.setattr(agg_mod, "MAX_BUCKETS", 65536)   # the default
+    before = _counts()
+    fast = svc.serving.try_search(body, "query_then_fetch")
+    after = _counts()
+    assert fast is not None
+    assert after["filter_device"] == before["filter_device"] + 3
+    assert after["agg_device_dispatches"] == \
+        before["agg_device_dispatches"] + 1          # ONE dispatch a request
+    assert after["filter_host"] == before["filter_host"]
+    assert after["agg_host_fallbacks"] == before["agg_host_fallbacks"]
+    monkeypatch.setattr(agg_mod, "AGG_DEVICE_MIN_DOCS", 1 << 60)
+    assert svc.serving.try_search(body, "query_then_fetch") is None
+    host = svc._search_dense(body)                   # the host aggregators
+    assert _counts()["filter_device"] == after["filter_device"]
+    fast.pop("took"), host.pop("took")
+    assert json.dumps(fast) == json.dumps(host)
+    assert fast["hits"]["total"]["value"] > 0
+    assert len(fast["aggregations"]["by_time"]["buckets"]) > 100
+
+
+@pytest.mark.parametrize("case", sorted(NOT_THE_ENVELOPE))
+def test_unrecognised_envelope_takes_the_old_path(logs_service, monkeypatch,
+                                                  case):
+    import copy
+
+    svc = logs_service
+    body = copy.deepcopy(FILTER_AGG_SHAPES["Status200sInRange"])
+    NOT_THE_ENVELOPE[case](body)
+    monkeypatch.setattr(agg_mod, "AGG_DEVICE_MIN_DOCS", 1)
+    before = _counts()
+    assert svc.serving.try_search(body, "query_then_fetch") is None
+    resp = svc.search(body)
+    after = _counts()
+    assert after["filter_device"] == before["filter_device"]
+    # the old device route still serves a histogram's collect, a host
+    # mask a (segment, aggregation), one dispatch each
+    if case != "a_terms_bucket":
+        assert after["filter_host"] == before["filter_host"] + 3
+        assert after["agg_device_dispatches"] == \
+            before["agg_device_dispatches"] + 3
+    assert resp["hits"]["total"]["value"] > 0

@@ -198,6 +198,22 @@ def test_agg_two_level_counts(chip):
               pd=pd, pm=pm, n_segments=512)
 
 
+@pytest.mark.parametrize("identity", [True, False],
+                         ids=["identity", "gathered"])
+@pytest.mark.parametrize("Q,fields", [(1, 1), (16, 2)])
+def test_agg_filter_counts(chip, Q, fields, identity):
+    """The filter + bucket route's reduction: the selection made from
+    rank bounds on the device, at a lane's narrowest and widest width,
+    over one and two filter columns, a minute layout's four tiles."""
+    p = DOCS
+    nc = p // K.AGG_PAIR_GRAN
+    n = DOCS if not identity else DOCS - 5    # the pad to p is exercised
+    _compiled(K.agg_filter_counts, chip((Q, fields, 2), jnp.int32),
+              chip((1,), jnp.int32), tuple(chip((n,), jnp.int32) for _ in range(fields)),
+              chip((2 * p + 2 * nc,), jnp.int32), p=p,
+              n_out=4 * K.AGG_SEG_TILE, identity=identity)
+
+
 @pytest.mark.parametrize("similarity", ["cosine", "dot_product", "l2_norm"])
 @pytest.mark.parametrize("masked", [False, True], ids=["unmasked", "masked"])
 @pytest.mark.parametrize("QC", [8, 128])

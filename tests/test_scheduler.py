@@ -279,6 +279,25 @@ def test_build_batch_flush_rules():
     assert len(batch.queries) == 16 and len(lane.queue) == 1
 
 
+def test_a_waiter_wider_than_the_ladder_goes_alone():
+    """A ladder that autotuned down to (1,) still has to dispatch an
+    `_msearch` of four bodies: the waiter fits no bucket, so it flushes
+    alone at its own width (it used to wait for ever behind flushes of
+    no waiter at all)."""
+    sched = AdaptiveDispatchScheduler(buckets=(1,), interactive_us=1000.0,
+                                      bulk_us=8000.0)
+    lane = _Lane(object(), 10, ("e", 10), inflight=2)
+    now = time.monotonic()
+    lane.queue = [_waiter(4, TIER_BULK, 0.01, now),
+                  _waiter(1, TIER_BULK, 0.01, now)]
+    batch, depth = sched._build_batch(lane, now)
+    assert depth == 5 and batch.bucket == 4
+    assert [len(w.queries) for w in batch.waiters] == [4]
+    assert [len(w.queries) for w in lane.queue] == [1]
+    batch, _ = sched._build_batch(lane, now)
+    assert batch.bucket == 1 and not lane.queue
+
+
 # ---------------------------------------------------------------------------
 # bit-identity with solo execution (real engines, interpret mode)
 # ---------------------------------------------------------------------------
