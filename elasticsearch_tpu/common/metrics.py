@@ -277,10 +277,23 @@ declare_counter("agg_host_fallbacks",
 declare_counter("agg_bytes",
                 "precomputed agg-column bytes uploaded to HBM (cumulative)")
 declare_counter("agg_reductions",
-                "(segment, layout) segment-reduce programs run; over "
+                "(segment, layout) segment reductions answered, with a "
+                "program or (agg_reductions_pruned) without; over "
                 "agg_device_dispatches = reductions a dispatch (1.0 on the "
                 "per-collect route, segments x layouts a batch on the "
                 "filter + bucket route)")
+declare_counter("agg_chunks_total",
+                "1024-pair chunks of the layouts the filter + bucket "
+                "route's requests met: a (request, segment) x the layout's "
+                "chunks, whether a program ran or not")
+declare_counter("agg_chunks_run",
+                "of agg_chunks_total, the chunks inside the range the "
+                "request's bounds and the filter columns' zone maps left "
+                "(all of them for match_all, none for a segment the range "
+                "misses)")
+declare_counter("agg_reductions_pruned",
+                "(segment, layout) reductions answered with NO program: "
+                "no request of the batch could touch a chunk")
 declare_counter("filter_device",
                 "(request, segment) match sets of the filter + bucket route "
                 "made ON THE DEVICE from the request's bounds")

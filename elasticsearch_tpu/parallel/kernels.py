@@ -34,6 +34,10 @@ entirely as matmuls and tiled vector ops:
   `agg_two_level_counts` fuses the bucket level and the metric-values
   level of a sub-aggregation into ONE dispatch. Counts accumulate in f32
   one-hot matmuls — exact below 2^24 pairs, which agg_device.py gates.
+  `agg_filter_counts` makes the doc mask itself, from each query's rank
+  bounds, and scatters only the chunk range each row can touch: the
+  chunk axis of its grid is as long as the batch's widest range, a bound
+  read on the device, so one program serves every range.
 
 * **Eager sparse impact slices for the cold tier.** Terms too sparse to
   justify a dense column (df below the cold threshold) keep their postings
@@ -974,22 +978,32 @@ def sparse_pool_update(pool, idx, upd):
 # --------------------------------------------------------------------------
 
 
+def _agg_chunk(q, i, c0, c1):
+    """The pair chunk step `i` of row `q` works on: the `i`-th of the row's
+    range [c0[q], c1[q]). A step past the range repeats the range's last
+    chunk (chunk 0 for an empty range), so its blocks are not fetched
+    again, and the kernel's gate leaves it out."""
+    return jnp.clip(c0[q] + i, 0, jnp.maximum(c1[q], 1) - 1)
+
+
 def _agg_count_kernel():
-    def kernel(ct0, ct1, nq, sel_blk, seg_blk, acc_ref):
+    def kernel(ct0, ct1, c0, c1, sel_blk, seg_blk, acc_ref):
         q = pl.program_id(0)
         t = pl.program_id(1)
-        c = pl.program_id(2)
+        i = pl.program_id(2)
+        c = _agg_chunk(q, i, c0, c1)
 
-        @pl.when(c == 0)
+        @pl.when(i == 0)
         def _init():
             acc_ref[...] = jnp.zeros((1, 1, 128, 128), jnp.float32)
 
         # pairs are grouped, so the host-prefetched inclusive bucket-tile
         # range [ct0, ct1] skips every tile a chunk cannot touch (padding
-        # chunks carry the empty range (1, 0) and never scatter); a
-        # padding ROW of the batch (q >= nq: the ladder's rung is wider
-        # than the batch) selects nothing and scatters nothing
-        @pl.when((t >= ct0[c]) & (t <= ct1[c]) & (q < nq[0]))
+        # chunks carry the empty range (1, 0) and never scatter); a step
+        # past the row's chunk range [c0, c1) scatters nothing (the axis
+        # is as long as the batch's widest row; a padding row of the
+        # batch carries the empty range)
+        @pl.when((c0[q] + i < c1[q]) & (t >= ct0[c]) & (t <= ct1[c]))
         def _scatter():
             base = t * AGG_SEG_TILE
             col = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
@@ -1013,36 +1027,46 @@ def _agg_count_kernel():
     return kernel
 
 
-def _agg_scatter(sel, seg, ct0, ct1, n_tiles: int, name: str, nq=None):
+def _agg_scatter(sel, seg, ct0, ct1, n_tiles: int, name: str, crange=None):
     """The segment reduction proper: acc[q, s] = sum of sel[q, i] over the
     pairs i with seg[i] == s — the scatter-as-outer-product trick applied
     to bucket ids (within a 16384-bucket tile, bucket = hi*128 + lo).
     `sel` [Q, p] f32 0/1 is the selection already laid out in pair order,
     so the kernel is scatter-only. `name` names the custom call: a trace
-    reader finds the kernel by the instruction's own name. `nq` [1] i32:
-    the rows that hold a query (the rest pad the batch to its rung and
-    are skipped); None = all. Returns [Q, n_tiles * AGG_SEG_TILE] f32
-    (exact integers below 2^24)."""
+    reader finds the kernel by the instruction's own name. `crange`
+    [Q, 2] i32: row q's selection is all zero outside the pair chunks
+    [crange[q, 0], crange[q, 1]), so the grid's chunk axis is only as
+    long as the widest row's range (a grid bound read on the device: one
+    program whatever the ranges) and each row starts at its own first
+    chunk; (0, 0) = a row with nothing to count. None = every row, every
+    chunk. Returns [Q, n_tiles * AGG_SEG_TILE] f32 (exact integers below
+    2^24)."""
     Q, p = sel.shape
     nc = p // AGG_PAIR_GRAN
-    if nq is None:
-        nq = jnp.full((1,), Q, jnp.int32)
+    if crange is None:
+        c0, c1, span = jnp.zeros((Q,), jnp.int32), \
+            jnp.full((Q,), nc, jnp.int32), nc
+    else:
+        c0, c1 = crange[:, 0], crange[:, 1]
+        span = jnp.maximum(jnp.max(c1 - c0), 1)
     acc = pl.pallas_call(
         _agg_count_kernel(),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(Q, n_tiles, nc),
+            num_scalar_prefetch=4,
+            grid=(Q, n_tiles, span),
             in_specs=[
                 pl.BlockSpec(
                     (1, 1, AGG_PAIR_GRAN // 128, 128),
-                    lambda q, t, c, ct0, ct1, nq: (q, c, 0, 0)),
+                    lambda q, t, i, ct0, ct1, c0, c1: (
+                        q, _agg_chunk(q, i, c0, c1), 0, 0)),
                 pl.BlockSpec(
                     (1, AGG_PAIR_GRAN // 128, 128),
-                    lambda q, t, c, ct0, ct1, nq: (c, 0, 0)),
+                    lambda q, t, i, ct0, ct1, c0, c1: (
+                        _agg_chunk(q, i, c0, c1), 0, 0)),
             ],
             out_specs=pl.BlockSpec(
                 (1, 1, 128, 128),
-                lambda q, t, c, ct0, ct1, nq: (q, t, 0, 0)),
+                lambda q, t, i, ct0, ct1, c0, c1: (q, t, 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((Q, n_tiles, 128, 128),
                                        jnp.float32),
@@ -1050,7 +1074,7 @@ def _agg_scatter(sel, seg, ct0, ct1, n_tiles: int, name: str, nq=None):
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=_interpret(),
         name=name,
-    )(ct0, ct1, nq,
+    )(ct0, ct1, c0, c1,
       sel.reshape(Q, nc, AGG_PAIR_GRAN // 128, 128),
       seg.reshape(nc, AGG_PAIR_GRAN // 128, 128))
     return acc.reshape(Q, n_tiles * AGG_SEG_TILE)
@@ -1089,7 +1113,7 @@ def agg_segment_counts(mask, blob, *, p: int, n_segments: int):
 
 
 @functools.partial(jax.jit, static_argnames=("p", "n_out", "identity"))
-def agg_filter_counts(bounds, nq, cols, blob, *, p: int, n_out: int,
+def agg_filter_counts(bounds, crange, cols, blob, *, p: int, n_out: int,
                       identity: bool):
     """`agg_segment_counts` for a match set that is MADE HERE, from each
     query's bounds, and never crosses from the host: one (segment,
@@ -1102,7 +1126,11 @@ def agg_filter_counts(bounds, nq, cols, blob, *, p: int, n_out: int,
         of a request's bounds makes the comparison exact to the last
         bit of the field. (-1, INT32_MAX) leaves a column unconstrained;
         (0, 0) keeps nothing (a padding row).
-    nq [1] i32 — the rows that hold a query; the kernel skips the rest
+    crange [Q, 2] i32 — the layout's 1024-pair chunks [crange[q, 0],
+        crange[q, 1]) hold every pair whose doc query q keeps (the host
+        knows from its zone maps of the columns, agg_device.py); the
+        scatter runs those chunks and no others. (0, nc) = all of them;
+        (0, 0) = none (a padding row, a range that misses the segment)
     cols  — tuple of F [n_docs] i32 rank columns of the segment
     blob  — the layout's column, as `agg_segment_counts` takes it
     identity — the layout's pair i IS doc i (every doc holds exactly one
@@ -1127,7 +1155,7 @@ def agg_filter_counts(bounds, nq, cols, blob, *, p: int, n_out: int,
     flat = _agg_scatter(sel.astype(jnp.float32), blob[p:2 * p],
                         blob[2 * p:2 * p + nc],
                         blob[2 * p + nc:2 * p + 2 * nc], n_tiles,
-                        "agg_filter_counts", nq)
+                        "agg_filter_counts", crange)
     return flat[:, :n_out].astype(jnp.int32), totals
 
 

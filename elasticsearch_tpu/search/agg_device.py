@@ -61,8 +61,22 @@ bucketing):
     `dispatch.agg_fold` (in finish) one observation a dispatch each.
     Counters: `filter_device` / `filter_host` ((request, segment) match
     sets made on the device / made on the host and uploaded),
-    `agg_reductions` ((segment, layout) reductions run; over
+    `agg_reductions` ((segment, layout) reductions answered; over
     `agg_device_dispatches` = reductions a dispatch).
+
+  * **A range runs only the pair chunks it can touch.** Beside a filter
+    column the host keeps a ZONE MAP a layout: the least and the
+    greatest rank of the column over each 1024-pair chunk (`_zone_map`,
+    numpy, no device bytes). The plan turns a request's rank intervals
+    into the bounding range of the chunks that can hold a match
+    (`_chunk_ranges`), the kernel's chunk axis is as long as the
+    batch's widest range, and a reduction no request of which can touch
+    a chunk (a segment the range misses) launches no program. Nothing is
+    assumed of the document order: on a column that is not in order the
+    range is wide, up to the whole layout, and the answer the same.
+    Counters: `agg_chunks_total` / `agg_chunks_run` (chunks of the
+    layouts a dispatch's requests meet / chunks inside their ranges),
+    `agg_reductions_pruned` (reductions answered with no program).
 
 Fallback matrix (host path serves whenever any gate fails): knob off,
 leaf below AGG_DEVICE_MIN_DOCS, missing/script params, keyword-metric
@@ -117,7 +131,8 @@ _COUNTS_LOCK = threading.Lock()
 _COUNTS = {"agg_queries": 0, "agg_device_dispatches": 0,
            "agg_host_fallbacks": 0, "agg_bytes": 0,
            "agg_reductions": 0, "filter_device": 0,
-           "filter_host": 0}   # guarded by: _COUNTS_LOCK
+           "filter_host": 0, "agg_chunks_total": 0, "agg_chunks_run": 0,
+           "agg_reductions_pruned": 0}   # guarded by: _COUNTS_LOCK
 
 
 def _count(key: str, n: int = 1) -> None:
@@ -289,9 +304,10 @@ class _Unservable(Exception):
 
 class _Reduction:
     """One (segment, layout) reduction of a dispatch: the works that
-    take part, the union of the fields they filter on."""
+    take part, the union of the fields they filter on, those fields'
+    columns and their zone maps over the layout's chunks."""
 
-    __slots__ = ("layout", "seg", "works", "fields", "cols", "out")
+    __slots__ = ("layout", "seg", "works", "fields", "cols", "zones", "out")
 
     def __init__(self, layout, seg):
         self.layout = layout
@@ -299,6 +315,7 @@ class _Reduction:
         self.works: List[FilterAggWork] = []
         self.fields: List[str] = []
         self.cols: List[_FilterColumn] = []
+        self.zones: List[Any] = []
         self.out = None
 
 
@@ -441,8 +458,9 @@ class AggDeviceEngine:
 
     def _run_filter_works(self, works: List["FilterAggWork"]) -> None:
         """Every segment's reduction of every request of the batch, in
-        ONE dispatch: plan (prep), one program a (segment, layout)
-        launched and waited for in turn, the fold (finish)."""
+        ONE dispatch: plan (prep), one program a (segment, layout) that
+        a request's range can touch launched and waited for in turn, the
+        fold (finish)."""
         if not works:
             return
         with tracing.phase("dispatch.prep", queries=len(works)), \
@@ -489,9 +507,8 @@ class AggDeviceEngine:
             w.error = e
 
     def _plan(self, works: List["FilterAggWork"]) -> List["_Reduction"]:
-        """Layouts and filter columns looked up (built on first use),
-        the works grouped a (segment, layout), bounds turned to rank
-        intervals."""
+        """Layouts, filter columns and zone maps looked up (built on
+        first use), the works grouped a (segment, layout)."""
         reds: Dict[int, _Reduction] = {}
         for w in works:
             w.error, w._parts = None, []      # a solo retry starts afresh
@@ -523,6 +540,7 @@ class AggDeviceEngine:
             r.fields = sorted({c[0] for w in r.works for c in w.clauses}
                               or {fname})
             r.cols = [_filter_column(r.seg, f) for f in r.fields]
+            r.zones = [_zone_map(r.seg, fname, r.layout, c) for c in r.cols]
         return out
 
     def _launch(self, r: "_Reduction") -> None:
@@ -545,13 +563,26 @@ class AggDeviceEngine:
         lay = r.layout
         # whole tiles out: segments whose rank counts differ share a program
         n_out = -(-lay.meta["n_segments"] // AGG_SEG_TILE) * AGG_SEG_TILE
-        hbm_ledger.note_dispatch("agg_reduce", qpad)
+        # the pair chunks each row can touch; a padding row's interval
+        # is empty and touches none
+        crange = _chunk_ranges(bounds, r.zones)
+        run = int((crange[:, 1] - crange[:, 0]).sum())
         metrics.observe("agg_batch_size", q)
         _count("agg_reductions")
         _count("filter_device", q)
+        _count("agg_chunks_total", q * (lay.meta["p"] // AGG_PAIR_GRAN))
+        _count("agg_chunks_run", run)
+        if run == 0 and lay.meta["identity"]:
+            # no row can match a pair, and every doc is a pair (the
+            # segment a range misses): the answer is known, no program
+            _count("agg_reductions_pruned")
+            r.out = (np.zeros((qpad, n_out), np.int32),
+                     np.zeros(qpad, np.int32))
+            return
+        hbm_ledger.note_dispatch("agg_reduce", qpad)
         with faults.device_dispatch("agg_reduce", lay.serial):
             r.out = kernels.agg_filter_counts(
-                jnp.asarray(bounds), jnp.asarray([q], jnp.int32),
+                jnp.asarray(bounds), jnp.asarray(crange),
                 tuple(c.dev for c in r.cols), lay.dev,
                 p=lay.meta["p"], n_out=n_out,
                 identity=bool(lay.meta["identity"]))
@@ -748,6 +779,45 @@ def _filter_column(seg, fname: str) -> Optional[_FilterColumn]:
         return _FilterColumn(fname, uniq, rank)
 
     return _cached_layout(seg, f"aggdev:filter:{fname}", build)
+
+
+def _zone_map(seg, fname: str, lay: _AggLayout, col: _FilterColumn):
+    """(least, greatest) rank of filter column `col` over each 1024-pair
+    chunk of the segment's layouts of field `fname`, as the device
+    compares them: a pair doc with no value counts as rank -1, a pad pair
+    not at all (a chunk of pads alone reads (INT32_MAX, -2): no interval
+    meets it). Host arrays [chunks] i32, built on first use and kept
+    where the column is; a segment's layouts of one field share their
+    pair docs, so they share the map."""
+    key = f"aggdev:zone:{fname}:{col.field}"
+    with _BUILD_LOCK:
+        zone = seg._device.get(key)
+        if zone is None:
+            p = lay.meta["p"]
+            rank = col.host[lay.host[:p]]
+            real = lay.host[p:2 * p] >= 0
+            chunks = (p // AGG_PAIR_GRAN, AGG_PAIR_GRAN)
+            zone = seg._device[key] = (
+                np.where(real, rank, _I32_MAX).reshape(chunks).min(axis=1),
+                np.where(real, rank, -2).reshape(chunks).max(axis=1))
+    return zone
+
+
+def _chunk_ranges(bounds: np.ndarray, zones) -> np.ndarray:
+    """[q, 2] i32: the bounding range [c0, c1) of the chunks row q can
+    touch. A chunk is kept iff, for EVERY column, its zone [least,
+    greatest] meets the row's rank interval `bounds[q, f]` = [lo, hi);
+    (0, 0) where none is. Exact whatever the documents' order: a chunk
+    outside the range holds no pair the device's comparison would keep."""
+    lo, hi = bounds[:, :, 0, None], bounds[:, :, 1, None]
+    cmin = np.stack([z[0] for z in zones])
+    cmax = np.stack([z[1] for z in zones])
+    keep = ((cmax >= lo) & (cmin < hi) & (hi > lo)).all(axis=1)   # [q, nc]
+    out = np.zeros((len(bounds), 2), np.int32)
+    hit = keep.any(axis=1)
+    out[hit, 0] = keep[hit].argmax(axis=1)
+    out[hit, 1] = keep.shape[1] - keep[hit, ::-1].argmax(axis=1)
+    return out
 
 
 def _metric_pair_docs(seg, mfield: str, mcol) -> np.ndarray:

@@ -204,12 +204,16 @@ def test_agg_two_level_counts(chip):
 def test_agg_filter_counts(chip, Q, fields, identity):
     """The filter + bucket route's reduction: the selection made from
     rank bounds on the device, at a lane's narrowest and widest width,
-    over one and two filter columns, a minute layout's four tiles."""
+    over one and two filter columns, a minute layout's four tiles. The
+    chunk axis' length is read on the device from the rows' chunk ranges
+    (a grid bound that is an operand): this ONE program is every range's,
+    there is no ladder of rungs to compile."""
     p = DOCS
     nc = p // K.AGG_PAIR_GRAN
     n = DOCS if not identity else DOCS - 5    # the pad to p is exercised
     _compiled(K.agg_filter_counts, chip((Q, fields, 2), jnp.int32),
-              chip((1,), jnp.int32), tuple(chip((n,), jnp.int32) for _ in range(fields)),
+              chip((Q, 2), jnp.int32),
+              tuple(chip((n,), jnp.int32) for _ in range(fields)),
               chip((2 * p + 2 * nc,), jnp.int32), p=p,
               n_out=4 * K.AGG_SEG_TILE, identity=identity)
 

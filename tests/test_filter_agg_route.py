@@ -11,6 +11,11 @@ and the plain numpy reference of the benchmark
 (benchmark/kinds/request/filter_agg.py), which imports nothing of the
 program. The device route is steered onto tiny segments by shrinking
 AGG_DEVICE_MIN_DOCS, as tests/test_agg_device.py does.
+
+Since PR 41 a range runs only the pair chunks it can touch and a segment it
+misses launches no program (the kernel and the planner alone:
+tests/test_agg_chunk_pruning.py); the last section holds the route to that,
+on a corpus of twelve chunks a segment.
 """
 
 import json
@@ -89,6 +94,10 @@ def _moved(before):
             if v != before[k] and k != "agg_bytes"}
 
 
+PRUNING_COUNTERS = ("agg_chunks_total", "agg_chunks_run",
+                    "agg_reductions_pruned")
+
+
 def _strip(resp):
     return json.dumps({k: v for k, v in resp.items() if k != "took"})
 
@@ -152,8 +161,20 @@ def test_every_shape_three_ways(logs, shape):
         assert fast is not None
         # ONE dispatch a request, its three segments in it, every match
         # set made on the device, no host mask, no fallback
+        chunks = {k: moved.pop(k, 0) for k in PRUNING_COUNTERS}
         assert moved == {"agg_device_dispatches": 1, "agg_reductions": 3,
                          "agg_queries": 3, "filter_device": 3}, moved
+        # the chunks of all three layouts met, those in the range run: all
+        # of them without a range, and no segment answered with no program
+        total = sum(-(-p.n // agg_device.AGG_PAIR_GRAN) for p in parts)
+        assert chunks["agg_chunks_total"] == total
+        if r.lo is None:
+            assert chunks == {"agg_chunks_total": total,
+                              "agg_chunks_run": total,
+                              "agg_reductions_pruned": 0}
+        else:
+            assert 0 < chunks["agg_chunks_run"] < total
+            assert chunks["agg_reductions_pruned"] >= 1
         dense = svc._search_dense(r.body)
         assert _strip(fast) == _strip(dense)
         _agrees_with_reference(fast, ref.answer(r))
@@ -509,6 +530,169 @@ def test_spans_and_counters_are_declared_and_observed_once_a_dispatch(logs):
                              for j in range(5)], "query_then_fetch")
     assert {n: seen()[n] - before[n] for n in names} == dict.fromkeys(
         names, 1)
-    for name in ("agg_reductions", "filter_device", "filter_host"):
+    for name in ("agg_reductions", "filter_device", "filter_host",
+                 *PRUNING_COUNTERS):
         assert name in metrics.counter_values()
         assert name in agg_device.agg_stats()
+    # the pruning's counters move once a (segment, layout) reduction of the
+    # dispatch, in the engine's stats and in the metrics registry alike
+    c0, m0 = _counts(), metrics.counter_values()
+    svc.serving.try_msearch([_requests(cfg).request(j).body
+                             for j in range(5)], "query_then_fetch")
+    moved = _moved(c0)
+    assert moved["agg_device_dispatches"] == 1 and moved["agg_reductions"] == 6
+    chunks = sum(-(-p.n // agg_device.AGG_PAIR_GRAN) for p in parts)
+    # four requests on the hour layouts, one on the minute layouts
+    assert moved["agg_chunks_total"] == 5 * chunks
+    assert 0 < moved["agg_chunks_run"] < moved["agg_chunks_total"]
+    for name in PRUNING_COUNTERS:
+        assert metrics.counter_values()[name] - m0[name] == moved.get(name, 0)
+
+
+# ---------------------------------------------------------------------------
+# a range runs the chunks it can touch (PR 41): twelve chunks a segment
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def wide_logs():
+    """(config, parts, service): 36,000 log lines, three segments of
+    twelve 1024-pair chunks each (the tiny corpus has two a segment: a
+    week is one of them or both)."""
+    cfg = _config(36_000)
+    parts = KIND.make_parts(cfg, SEED)
+    svc = IndexService(IndexMetadata(
+        index="http_logs", uuid="u5", settings=Settings({}),
+        mappings=cfg["index"]["mappings"]))
+    for i, part in enumerate(parts):
+        svc.shards[0].install_segment(
+            segment_to_blob(KIND.segment(cfg, part, i)),
+            np.ones(part.n, bool))
+    svc.shards[0].fill_seqno_gaps(sum(p.n for p in parts) - 1)
+    yield cfg, parts, svc
+    svc.close()
+
+
+@pytest.fixture
+def launches(monkeypatch):
+    """The calls of `kernels.agg_filter_counts`: the programs launched."""
+    from elasticsearch_tpu.parallel import kernels
+
+    calls, real = [], kernels.agg_filter_counts
+
+    def spy(bounds, crange, *a, **kw):
+        calls.append(np.asarray(crange))
+        return real(bounds, crange, *a, **kw)
+
+    monkeypatch.setattr(kernels, "agg_filter_counts", spy)
+    return calls
+
+
+def test_a_range_that_misses_two_segments_launches_one_program(wide_logs,
+                                                               launches):
+    cfg, parts, svc = wide_logs
+    ref = REQ.reference(cfg, parts)
+    lo, hi = int(parts[1].ts[2_000]), int(parts[1].ts[9_000])
+    for interval, status in (("1h", None), ("10m", None), ("1h", 200)):
+        more = {} if status is None else {"status": status}
+        body = _body(_range(lo, hi, **more), interval)
+        del launches[:]
+        before = _counts()
+        fast = svc.serving.try_search(body, "query_then_fetch")
+        moved = _moved(before)
+        # three reductions ANSWERED, two of them with no program; the
+        # requests' match sets are still the device route's (none is a
+        # host mask), the requests' segments all answered
+        assert moved["agg_reductions"] == 3
+        assert moved["agg_reductions_pruned"] == 2
+        assert moved["agg_device_dispatches"] == 1
+        assert moved["filter_device"] == 3 and moved["agg_queries"] == 3
+        assert "filter_host" not in moved
+        assert "agg_host_fallbacks" not in moved
+        assert len(launches) == 1
+        c0, c1 = launches[0][0]
+        assert 1 <= c0 < c1 <= 10 and moved["agg_chunks_run"] == c1 - c0
+        assert moved["agg_chunks_total"] == 36
+        assert _strip(fast) == _strip(svc._search_dense(body))
+        _agrees_with_reference(fast, ref.answer(REQ.FilterAggRequest(
+            body=body, lo=lo, hi=hi, status=status,
+            interval=REQ.interval_ms(interval))))
+        assert fast["hits"]["total"]["value"] > 0
+    # a range before the data: three reductions, no program at all, the
+    # empty answer the host gives
+    t0 = KIND.start_ms(cfg["corpus"])
+    body = _body(_range(t0 - 9 * DAY, t0 - 2 * DAY))
+    del launches[:]
+    before = _counts()
+    fast = svc.serving.try_search(body, "query_then_fetch")
+    moved = _moved(before)
+    assert launches == [] and moved["agg_reductions_pruned"] == 3
+    assert moved["agg_reductions"] == 3 and "agg_chunks_run" not in moved
+    assert _strip(fast) == _strip(svc._search_dense(body))
+    assert fast["hits"]["total"]["value"] == 0
+
+
+def test_the_share_of_chunks_run_by_shape(wide_logs, launches):
+    """`agg_chunks_run` over `agg_chunks_total`: 1.0 for `HourlyAgg` (no
+    range: every chunk of every segment, three programs), under 0.3 for
+    every `RangeHourly` of the mix (7 of 88 days: at most two segments'
+    programs), less again for a day."""
+    cfg, parts, svc = wide_logs
+    reqs = _requests(cfg)
+    shares = {}
+    for j in range(32):
+        r = reqs.request(j)
+        del launches[:]
+        before = _counts()
+        fast = svc.serving.try_search(r.body, "query_then_fetch")
+        moved = _moved(before)
+        assert _strip(fast) == _strip(svc._search_dense(r.body))
+        assert moved["agg_chunks_total"] == 36
+        share = moved.get("agg_chunks_run", 0) / moved["agg_chunks_total"]
+        shares.setdefault(r.shape, []).append(share)
+        assert len(launches) == 3 - moved.get("agg_reductions_pruned", 0)
+        if r.shape == "HourlyAgg":
+            assert len(launches) == 3
+        else:
+            assert len(launches) <= 2
+    assert set(shares) == set(SHAPES)
+    assert shares["HourlyAgg"] == [1.0] * 4
+    for shape in ("RangeHourly", "Status200sInRange", "Status400sInRange"):
+        assert max(shares[shape]) < 0.3, shares
+    assert max(shares["RangeTenMinute"]) <= 2 / 36
+    # over the cycle: an eighth of the requests run everything, the rest
+    # a tenth or less
+    mean = np.mean([x for v in shares.values() for x in v])
+    assert 0.125 < mean < 0.25
+
+
+def test_after_the_warm_up_no_request_of_the_cycle_builds_a_program(
+        wide_logs):
+    """Once round the cycle one at a time and one burst at each width
+    builds every program the route has: a request's range picks no program
+    of its own (the chunk axis' length is an operand, not a shape), so the
+    cycle's later requests, other ranges every one, build none."""
+    from elasticsearch_tpu.common import hbm_ledger
+    from elasticsearch_tpu.parallel import kernels
+
+    cfg, parts, svc = wide_logs
+    hbm_ledger.install_jit_listener()
+    reqs = _requests(cfg)
+    bodies = [reqs.request(j).body for j in range(64)]
+    for b in bodies[:8]:
+        svc.serving.try_search(b, "query_then_fetch")
+    for width in (4, 16):
+        svc.serving.try_msearch(bodies[:width], "query_then_fetch")
+    # the host's answers first: its collects build programs of their own
+    want = [_strip(svc._search_dense(b)) for b in bodies[40:64]]
+    built = hbm_ledger.compile_stats()["jit_builds"]
+    programs = kernels.agg_filter_counts._cache_size()
+    assert programs >= 1
+    for b in bodies[8:40]:
+        assert svc.serving.try_search(b, "query_then_fetch") is not None
+    for width, at in ((4, 40), (16, 48)):
+        out = svc.serving.try_msearch(bodies[at:at + width],
+                                      "query_then_fetch")
+        assert [_strip(r) for r in out] == want[at - 40:at - 40 + width]
+    assert kernels.agg_filter_counts._cache_size() == programs
+    assert hbm_ledger.compile_stats()["jit_builds"] == built
