@@ -260,7 +260,6 @@ def gauge_values() -> Dict[str, float]:
 # into busy fractions and flush rates without an external scraper
 declare_gauge("sched_inflight",
               "device batches currently in flight across scheduler lanes")
-declare_gauge("sched_lanes", "live (engine, k) scheduler lanes")
 declare_counter("sched_flushes",
                 "adaptive-scheduler batch flushes (sampler-ring deltas "
                 "give the flush rate)")
@@ -503,7 +502,10 @@ declare_histogram("demux", "ms", "per-request hit extraction from a batched devi
 declare_histogram("fetch", "ms", "fetch phase (doc _source materialization)")
 declare_histogram("query", "ms", "shard query phase end-to-end (data node side)")
 declare_histogram("merge", "ms", "coordinator reduce of shard results")
-declare_histogram("rest_total", "ms", "whole _search request at the REST layer")
+declare_histogram("rest_total", "ms", "whole _search or _msearch request at the REST layer, on the pool worker: the handler from its first line to the response object (a _search's body is parsed before it opens, the response is encoded and written after it: rest.parse, rest.respond)")
+declare_histogram("rest.parse", "ms", "a search request's body from bytes to dicts: a _search's json.loads in the controller (before rest_total opens), or inside rest_total an _msearch's decode, its ndjson lines' json.loads and each header's index resolution")
+declare_histogram("rest.respond", "ms", "a _search or _msearch response's JSON encode and its write to the socket, on the HTTP thread, after rest_total (histogram and annotation only: that thread carries no trace context)")
+declare_histogram("route", "ms", "within rest_total, ONE observation a try_msearch (a _search is one of one body): the host work that decides who serves each body: plan extraction (extract_plan / extract_knn_plan / extract_filter_agg_plan), the snapshot, the servability checks, the grouping by field and route; the batches' dispatches and demux follow it")
 declare_histogram("coalesce_batch_size", "count", "queries per coalesced device batch")
 declare_histogram("coalesce_pad_ratio", "ratio", "fraction of a padded device batch that is qc-quantization waste")
 # continuous-batching scheduler (PR 10); sched_tier_wait.* names are
@@ -511,6 +513,7 @@ declare_histogram("coalesce_pad_ratio", "ratio", "fraction of a padded device ba
 # observe_if_declared(f"sched_tier_wait.{tier}"), one per SLA tier.
 declare_histogram("sched_bucket_size", "count", "bucket (padded batch shape) chosen per adaptive-scheduler flush")
 declare_histogram("sched_queue_depth", "count", "lane queue depth at each adaptive-scheduler flush")
+declare_histogram("sched_fill", "ms", "one observation a lane flush: from the enqueue of the flushed batch's OLDEST waiter to run_device's start (its tier budget waited out, a dispatch in flight ahead of it, the wait for an in-flight slot); sched_tier_wait = sched_fill + device + the waiter's wake-up")
 # device bitset intersection for bool queries (PR 16)
 declare_histogram("bitset_blocks_skipped", "count", "2048-doc chunks skipped (all-zero intersected match set) per bool query dispatch")
 declare_histogram("bitset_block_occupancy", "ratio", "fraction of 2048-doc chunks with surviving docs after clause intersection, per bool query")
@@ -518,9 +521,6 @@ declare_histogram("bitset_block_occupancy", "ratio", "fraction of 2048-doc chunk
 declare_histogram("sparse_slice_width", "count", "padded width (postings) of the ladder rung chosen per eager sparse cold-term slice build")
 # device analytics tier (PR 18)
 declare_histogram("agg_batch_size", "count", "agg collects fused into one device segment-reduce dispatch (pre-padding)")
-
-declare_histogram("knn_candidates_per_query", "count", "first-pass candidates kept per (query, partition) before the exact kNN rescore")
-declare_histogram("knn_nprobe_ratio", "ratio", "fraction of IVF centroids probed per kNN first pass (1.0 = exact/no pruning)")
 declare_histogram("sched_tier_wait.interactive", "ms", "scheduler wait, interactive tier (enqueue -> batch results ready)")
 declare_histogram("sched_tier_wait.bulk", "ms", "scheduler wait, bulk tier (enqueue -> batch results ready)")
 # cluster task plane (PR 11); task_duration.* names are composed
@@ -545,7 +545,11 @@ declare_histogram("dispatch.device_wait", "ms", "engine call, host blocked fetch
 declare_histogram("dispatch.finish", "ms", "engine call, host side after the fetch: per (partition, query) collect / rescore / merge")
 declare_histogram("dispatch.slice_build", "ms", "within prep (BM25): one pass a partition over ALL the cold terms of the dispatch chunk that have no sparse slice: runs allocated in one walk, granules packed in one set of array operations, one device pool update (under launch: the same pass over a query's terms when the pool could not hold the chunk's together); tpu_turbo.sparse_slices over sparse_slice_passes = slices a pass")
 declare_histogram("dispatch.mask", "ms", "within prep (kNN): per-partition filter masks, stacking, upload")
-declare_histogram("dispatch.sparse_gather", "ms", "within finish (BM25): collecting the cold side's sparse gather, launched behind the sweep, from its copy on the host")
+declare_histogram("dispatch.sparse_gather", "ms", "within finish (BM25): collecting the cold side's sparse gather, launched behind the sweep, from its copy on the host (_collect_gather alone; on the match route clock reads summed a call)")
+declare_histogram("dispatch.rescore_rows", "ms", "within rescore (BM25 match route): the chunk-wide exact rescore of the picked rows (_rescore_rows) and the candidate cut that follows it, a (partition, chunk)")
+declare_histogram("dispatch.rescore_survivors", "ms", "within rescore (BM25 match route): _survivor_terms + _exact_scores over the cold side's survivors, a query at a time (clock reads summed a call); rescore = rescore_rows + rescore_survivors on this route")
+declare_histogram("dispatch.survivor_bound", "ms", "within finish (BM25 match route): from the cold side's collect to its survivors: the live filter, the k-th candidate score, the bound test, u[keep] (clock reads summed a call)")
+declare_histogram("dispatch.merge_cert", "ms", "within finish (BM25 match route): the candidates' concatenation with the survivors, _top_k, the certificate's test and the write into the output (clock reads summed a call; a failed certificate's merge is cert_fallback)")
 declare_histogram("dispatch.rescore", "ms", "within finish: exact rescore (BM25 _exact_scores; kNN host row gather + rescore program + fetch; aggregations: the exact cross-check of the device's bucket counts against the match set's size)")
 declare_histogram("dispatch.agg_plan", "ms", "within prep (aggregation engine, filter + bucket route): layouts and filter columns looked up (built on first use), the batch grouped a (segment, layout), each request's bounds turned to rank intervals")
 declare_histogram("dispatch.agg_fold", "ms", "within finish (aggregation engine, filter + bucket route): the device's rank counts folded into each request's buckets, the segments reduced and the response's bucket list built")

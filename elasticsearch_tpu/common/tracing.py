@@ -352,7 +352,8 @@ class steps:
     when the step did not run). The step means are then per dispatch,
     children sum under parents, and the top-level steps sum to `device`.
     Nested (a fused engine calling a partition's engine) the outer call
-    owns the accumulator."""
+    owns the accumulator. A step timed by its caller's clock reads joins
+    through ``steps.add``."""
 
     __slots__ = ("names", "_st", "_own")
 
@@ -372,6 +373,32 @@ class steps:
             for name, ms in acc.items():
                 metrics.observe(name, ms)
         return False
+
+    @staticmethod
+    def add(name: str, ms: float, start_ns: int = 0, **meta: Any) -> None:
+        """A step its caller timed with clock reads: one that runs a QUERY
+        at a time inside an engine call (or a request at a time inside an
+        `_msearch`'s response loop), where a ``phase`` each would be
+        hundreds of annotations a call. ``ms``, the step's total over the
+        caller's loop, goes where a ``phase`` of that name would put it
+        (the call's accumulator; outside one, the histogram). With
+        ``start_ns`` and a context active ONE span is linked, laid from
+        there: the caller lays a loop's steps end to end from the loop's
+        start, so they partition their parent as phases do. Without it no
+        span: a parent step whose children carry the spans. No
+        annotation: the loop runs under one of the caller's."""
+        st = _state()
+        acc = st.steps
+        if acc is not None and name in acc:
+            acc[name] += ms
+        else:
+            metrics.observe(name, ms)
+        tc = st.trace
+        if tc is not None and start_ns:
+            span = _make_span(name, _open_span(tc), ms, meta)
+            span["start_ns"] = start_ns
+            span["end_ns"] = start_ns + int(ms * 1e6)
+            tc._link(span)
 
 
 # --- the interpreter's collector ----------------------------------------------

@@ -763,8 +763,6 @@ class KnnEngine:
         if first:
             hbm_ledger.note_compile_done("knn", QC, time.monotonic() - t0)
 
-        cand_hist = np.zeros(n, np.int64)
-        frac_hist = np.zeros(n, np.float64)
         with tracing.phase("dispatch.finish", queries=n):
             for i in range(S):
                 if check is not None:
@@ -778,9 +776,7 @@ class KnnEngine:
                     continue
                 if self.n_docs[i] == 0:
                     continue
-                cand_r, cand_ok, u_excl, frac = pass1[i]
-                cand_hist += cand_ok[:n].sum(axis=1)
-                frac_hist += frac[:n]
+                cand_r, cand_ok, u_excl, _frac = pass1[i]
                 ords = self._perm[i][cand_r]
                 ords = np.where(cand_ok, ords, 0).astype(np.int32)
                 _count("knn_rescore_docs", int(cand_ok[:n].sum()))
@@ -824,10 +820,6 @@ class KnnEngine:
                         hs, ho = self._host_chunk(i, chunk, k)
                         s_out[i][bad] = hs[bad]
                         o_out[i][bad] = ho[bad]
-        for j in range(n):
-            metrics.observe("knn_candidates_per_query", float(cand_hist[j]))
-            metrics.observe("knn_nprobe_ratio",
-                            float(frac_hist[j]) / max(1, S - len(failed)))
         return s_out, o_out
 
     # ---------------- merge ----------------

@@ -88,6 +88,8 @@ from functools import partial as _partial  # noqa: E402
 # observation per call, see common/metrics.py
 DISPATCH_STEPS = metrics.DISPATCH_TOP_STEPS + (
     "dispatch.slice_build", "dispatch.sparse_gather", "dispatch.rescore",
+    "dispatch.rescore_rows", "dispatch.rescore_survivors",
+    "dispatch.survivor_bound", "dispatch.merge_cert",
     "dispatch.cert_fallback", "dispatch.bool_resolve",
     "dispatch.phrase_build", "dispatch.bitset_pack")
 
@@ -1931,9 +1933,22 @@ class TurboBM25:
         bounds [n] — the max approximate score any of those could hold
         (device pick output); gathers — the queries' cold sides as
         `_chunk_gathers` launched them behind the sweep. The cold side,
-        the merge and the certificate run a query at a time."""
+        the merge and the certificate run a query at a time.
+
+        Every ms is a named step of the engine call (`DISPATCH_STEPS`):
+        the chunk-wide rescore is a phase (`dispatch.rescore_rows`); the
+        steps of the per-query loop are timed by clock reads, lap after
+        lap, and go into the call's accumulator once a (partition, chunk)
+        (`tracing.steps.add`: one span each, laid end to end from the
+        loop's start), the loop under ONE annotation
+        (`es.dispatch.finish_pairs`), not under two phases a query.
+        `dispatch.rescore` = rows + survivors."""
         n, R = rows_all.shape
-        with tracing.phase("dispatch.rescore", queries=n):
+        # (`es.dispatch.rescore` stays the profiler's name for the
+        # chunk-wide rescore: an annotation, the histogram is fed below)
+        with tracing.annotation("dispatch.rescore", queries=n), \
+                tracing.phase("dispatch.rescore_rows", queries=n, rows=int(
+                    np.count_nonzero(rows_all >= 0))) as rows:
             plane = self._exact_scores(plan, rows_all)
             # candidates = cells with a positive total whose doc is live
             cells = np.flatnonzero(plane > 0)
@@ -1943,90 +1958,126 @@ class TurboBM25:
             cand_s = plane[cells]
             q_off = np.searchsorted(
                 cells, np.arange(n + 1) * (R * 128)).tolist()
+            rows.meta["candidates"] = len(cells)
         left = 0
-        for qi in range(n):
-            qterms = plan.qterms[qi]
-            if not qterms:
-                continue
-            docs = cdocs[q_off[qi]: q_off[qi + 1]]
-            totals = cand_s[q_off[qi]: q_off[qi + 1]]
-            cold_terms = plan.cold[qi]
-            colized = len(cold_terms) < len(qterms)
-            host_scored = False
+        clock = time.monotonic_ns
+        # ns in each step of the loop, and what sized it
+        ns_gather = ns_bound = ns_surv = ns_merge = 0
+        n_cold = n_enum = n_surv = n_merged = 0
+        t_loop = clock()
+        with tracing.annotation("dispatch.finish_pairs", queries=n):
+            for qi in range(n):
+                qterms = plan.qterms[qi]
+                if not qterms:
+                    continue
+                docs = cdocs[q_off[qi]: q_off[qi + 1]]
+                totals = cand_s[q_off[qi]: q_off[qi + 1]]
+                cold_terms = plan.cold[qi]
+                colized = len(cold_terms) < len(qterms)
+                host_scored = False
+                # (the slices above, and below the books and a gather not
+                # hoisted, are the loop's own remainder under `finish`)
+                t = clock()
 
-            # ---- cold side, bound-pruned (the 10M-doc bottleneck was
-            # exact-scoring EVERY cold-touched doc — up to 2 x cold_df of
-            # them — with binary searches into multi-million-entry
-            # colized posting lists; a doc whose cold contribution plus
-            # the colized terms' maximum possible addend cannot reach the
-            # candidate k-th score needs no lookup at all) ----
-            if cold_terms:
-                if plan.sparse:
-                    self.stats["sparse_queries"] += 1
-                    _node_sparse_add("sparse_queries", 1)
-                    h = gathers.pop(qi, None)
-                    if h is None:     # not hoisted: back to back, here
-                        h = self._start_gathers([(qi, cold_terms)],
-                                                False)[qi]
-                    with tracing.phase("dispatch.sparse_gather",
-                                       terms=len(cold_terms)):
+                # ---- cold side, bound-pruned (the 10M-doc bottleneck was
+                # exact-scoring EVERY cold-touched doc — up to 2 x cold_df
+                # of them — with binary searches into multi-million-entry
+                # colized posting lists; a doc whose cold contribution plus
+                # the colized terms' maximum possible addend cannot reach
+                # the candidate k-th score needs no lookup at all) ----
+                if cold_terms:
+                    if plan.sparse:
+                        self.stats["sparse_queries"] += 1
+                        _node_sparse_add("sparse_queries", 1)
+                        h = gathers.pop(qi, None)
+                        if h is None:     # not hoisted: back to back, here
+                            h = self._start_gathers([(qi, cold_terms)],
+                                                    False)[qi]
+                        # `dispatch.sparse_gather` is the collect alone, as
+                        # the phase a query it replaces was
+                        t = clock()
                         u, contrib, slack, inv = self._collect_gather(h)
-                    host_scored = h.host
-                else:
-                    self.stats["cold_queries"] += 1
-                    u, contrib, inv = self._cold_contrib(cold_terms)
-                    slack = 0.0
-                keep = self._live_host[u] > 0
-                if colized:
-                    kth_0 = 0.0
-                    if len(totals) >= k:
-                        kth_0 = float(np.partition(
-                            totals, len(totals) - k)[len(totals) - k])
-                    # float64 contrib + margin keeps this a true upper
-                    # bound; slack covers the sparse tier's quantization so
-                    # the survivor set is a SUPERSET of the host path's —
-                    # extras are exact-rescored and provably below the
-                    # k-th score. (A cold-only query keeps every live doc:
-                    # the exact path IS the full merge.)
-                    keep &= (contrib + slack + plan.col_const[qi] + 1e-5
-                             >= kth_0)
-                cold_docs = u[keep]
-                if len(cold_docs):
-                    with tracing.phase("dispatch.rescore",
-                                       docs=len(cold_docs)):
+                        t1 = clock()
+                        ns_gather += t1 - t
+                        host_scored = h.host
+                    else:
+                        self.stats["cold_queries"] += 1
+                        u, contrib, inv = self._cold_contrib(cold_terms)
+                        slack = 0.0
+                        t1 = clock()
+                    keep = self._live_host[u] > 0
+                    if colized:
+                        kth_0 = 0.0
+                        if len(totals) >= k:
+                            kth_0 = float(np.partition(
+                                totals, len(totals) - k)[len(totals) - k])
+                        # float64 contrib + margin keeps this a true upper
+                        # bound; slack covers the sparse tier's
+                        # quantization so the survivor set is a SUPERSET of
+                        # the host path's — extras are exact-rescored and
+                        # provably below the k-th score. (A cold-only query
+                        # keeps every live doc: the exact path IS the full
+                        # merge.)
+                        keep &= (contrib + slack + plan.col_const[qi] + 1e-5
+                                 >= kth_0)
+                    cold_docs = u[keep]
+                    t = clock()
+                    ns_bound += t - t1
+                    n_cold += 1
+                    n_enum += len(u)
+                    if len(cold_docs):
                         cold_s = self._exact_scores(
                             self._survivor_terms(qterms, keep, inv),
                             cold_docs)
-                    pos = cold_s > 0
-                    docs = np.concatenate([docs, cold_docs[pos]])
-                    totals = np.concatenate([totals, cold_s[pos]])
+                        t1 = clock()
+                        ns_surv += t1 - t
+                        t = t1
+                        n_surv += len(cold_docs)
+                        pos = cold_s > 0
+                        docs = np.concatenate([docs, cold_docs[pos]])
+                        totals = np.concatenate([totals, cold_s[pos]])
 
-            if not len(docs):
-                continue
-            s, d = self._top_k(docs, totals, k)
+                ns_fallback = 0
+                if len(docs):
+                    n_merged += len(docs)
+                    s, d = self._top_k(docs, totals, k)
 
-            # ---- certificate ----
-            if colized:
-                # every collected doc is EXACT; a doc outside the pool sits
-                # in an uncollected row, whose approximate rowmax bound
-                # plus the quantization error bounds its true score
-                uncollected = float(bounds[qi])
-                limit = uncollected + plan.e_q[qi]
-                kth = float(s[k - 1]) if len(s) >= k else 0.0
-                short = len(s) < k and uncollected > 0
-                if short or (len(s) >= k and kth < limit
-                             and uncollected > 0):
-                    self.stats["fallbacks"] += 1
-                    host_scored = True
-                    with tracing.phase("dispatch.cert_fallback"):
-                        if self.fallback is not None:
-                            s, d = self.fallback(plan.chunk[qi], k)
-                        else:
-                            s, d = self._exact_merge(
-                                [q[:3] for q in qterms], k)
-            left += host_scored
-            out_s[qi, : len(s)] = s
-            out_d[qi, : len(d)] = d
+                    # ---- certificate ----
+                    if colized:
+                        # every collected doc is EXACT; a doc outside the
+                        # pool sits in an uncollected row, whose approximate
+                        # rowmax bound plus the quantization error bounds
+                        # its true score
+                        uncollected = float(bounds[qi])
+                        limit = uncollected + plan.e_q[qi]
+                        kth = float(s[k - 1]) if len(s) >= k else 0.0
+                        short = len(s) < k and uncollected > 0
+                        if short or (len(s) >= k and kth < limit
+                                     and uncollected > 0):
+                            self.stats["fallbacks"] += 1
+                            host_scored = True
+                            with tracing.phase("dispatch.cert_fallback") \
+                                    as fallback:
+                                if self.fallback is not None:
+                                    s, d = self.fallback(plan.chunk[qi], k)
+                                else:
+                                    s, d = self._exact_merge(
+                                        [q[:3] for q in qterms], k)
+                            ns_fallback = int(fallback.ms * 1e6)
+                    left += host_scored
+                    out_s[qi, : len(s)] = s
+                    out_d[qi, : len(d)] = d
+                ns_merge += clock() - t - ns_fallback
+        at = t_loop
+        for name, ns, meta in (
+                ("dispatch.sparse_gather", ns_gather,
+                 {"pairs": n_cold, "docs": n_enum}),
+                ("dispatch.survivor_bound", ns_bound, {"docs": n_enum}),
+                ("dispatch.rescore_survivors", ns_surv, {"docs": n_surv}),
+                ("dispatch.merge_cert", ns_merge, {"docs": n_merged})):
+            tracing.steps.add(name, ns / 1e6, at, **meta)
+            at += ns
+        tracing.steps.add("dispatch.rescore", rows.ms + ns_surv / 1e6)
         self._count_finish(n - left, left)
 
     def _count_finish(self, bulk: int, left: int) -> None:

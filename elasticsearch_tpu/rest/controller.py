@@ -14,6 +14,7 @@ import json
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from elasticsearch_tpu.common import tracing
 from elasticsearch_tpu.common.errors import ElasticsearchTpuError, JsonParseError
 
 
@@ -128,7 +129,8 @@ class RestController:
                     shed = self.admission(method.upper(), path, req_params)
                     if shed is not None:
                         return shed
-                parsed, raw, parse_error = _parse_body(body)
+                parsed, raw, parse_error = _parse_body(
+                    body, timed=parts[-1:] == ["_search"])
                 if parse_error and not _is_ndjson_endpoint(parts):
                     err = JsonParseError("request body is not valid JSON")
                     return RestResponse(status=err.status, body=_error_body(err))
@@ -159,14 +161,26 @@ def _is_ndjson_endpoint(parts: List[str]) -> bool:
     return any(p in ("_bulk", "_msearch") for p in parts)
 
 
-def _parse_body(body) -> Tuple[Any, bytes, bool]:
+def is_search_endpoint(path: str) -> bool:
+    """`_search` and `_msearch`: the requests whose REST steps have
+    names (`rest.parse`, `rest_total`, `rest.respond`)."""
+    return path.rstrip("/").rsplit("/", 1)[-1] in ("_search", "_msearch")
+
+
+def _parse_body(body, timed: bool = False) -> Tuple[Any, bytes, bool]:
+    """(parsed, raw, parse error). `timed`: the parse is a `_search`'s
+    `rest.parse` (an `_msearch`'s handler parses its ndjson line by line
+    under the same name)."""
     if body is None:
         return None, b"", False
     raw = body.encode() if isinstance(body, str) else body
     if not raw.strip():
         return None, raw, False
     try:
-        return json.loads(raw), raw, False
+        if not timed:
+            return json.loads(raw), raw, False
+        with tracing.phase("rest.parse", bytes=len(raw)):
+            return json.loads(raw), raw, False
     except json.JSONDecodeError:
         return None, raw, True
 

@@ -1030,18 +1030,32 @@ class _Handlers:
         return False
 
     def search(self, req: RestRequest) -> RestResponse:
-        """Search entry: wraps the phase runner in a per-request
-        TraceContext when the flight recorder is on (the `rest_total`
-        histogram records regardless). Traced profile responses gain a
-        `profile.tpu` section with the trace id and per-phase totals."""
+        """Search entry: the phase runner as one REST request of the
+        flight recorder (`_rest_total`), traced when `_trace_enabled`."""
+        from elasticsearch_tpu.common import tracing
+
+        body_view = req.body if isinstance(req.body, dict) else {}
+        return self._rest_total(
+            req, self._search_inner,
+            tracing.current() is None
+            and self._trace_enabled(req, body_view))
+
+    def _rest_total(self, req: RestRequest, inner,
+                    traced: bool) -> RestResponse:
+        """`inner(req)` (-> the response dict of a `_search` or an
+        `_msearch`) as ONE REST request: under the `rest_total` phase
+        (the histogram records regardless), under a per-request
+        TraceContext when `traced`, and under the request's SLA tier. A
+        traced profile response gains a `profile.tpu` section with the
+        trace id and per-phase totals. (The response's encode and write
+        are the HTTP thread's `rest.respond`, after this.)"""
         from elasticsearch_tpu.common import tracing
         from elasticsearch_tpu.threadpool import (
             activate_tier, tier_for_request,
         )
 
-        body_view = req.body if isinstance(req.body, dict) else {}
         tc = None
-        if tracing.current() is None and self._trace_enabled(req, body_view):
+        if traced:
             tc = tracing.TraceContext(
                 opaque_id=req.headers.get("x-opaque-id"),
                 node=self.node.node_name, kind="rest")
@@ -1051,27 +1065,32 @@ class _Handlers:
         with tracing.activate(tc), \
                 tracing.phase("rest_total", path=req.path), \
                 activate_tier(tier):
-            rr = self._search_inner(req)
+            rr = self._ok_search(req, inner(req))
         if tc is not None:
             tracing.record_trace(tc)
-            if isinstance(rr.body, dict) and isinstance(
-                    rr.body.get("profile"), dict):
-                from elasticsearch_tpu.common import hbm_ledger
-
-                # routing explainability (PR 12): why this index's engine
-                # selection went turbo or not, with the byte arithmetic
-                routing = hbm_ledger.last_routing()
-                tpu_profile = {
-                    "trace_id": tc.trace_id, "opaque_id": tc.opaque_id,
-                    "node": self.node.node_name,
-                    "phases": tc.phase_totals()}
-                if routing is not None:
-                    tpu_profile["routing_reason"] = routing["reason"]
-                    tpu_profile["routing"] = routing
-                rr.body["profile"].setdefault("tpu", tpu_profile)
+            self._profile_tpu(rr, tc)
         return rr
 
-    def _search_inner(self, req: RestRequest) -> RestResponse:
+    def _profile_tpu(self, rr: RestResponse, tc) -> None:
+        """A traced profile response's `profile.tpu` section."""
+        if not (isinstance(rr.body, dict)
+                and isinstance(rr.body.get("profile"), dict)):
+            return
+        from elasticsearch_tpu.common import hbm_ledger
+
+        # routing explainability (PR 12): why this index's engine
+        # selection went turbo or not, with the byte arithmetic
+        routing = hbm_ledger.last_routing()
+        tpu_profile = {
+            "trace_id": tc.trace_id, "opaque_id": tc.opaque_id,
+            "node": self.node.node_name,
+            "phases": tc.phase_totals()}
+        if routing is not None:
+            tpu_profile["routing_reason"] = routing["reason"]
+            tpu_profile["routing"] = routing
+        rr.body["profile"].setdefault("tpu", tpu_profile)
+
+    def _search_inner(self, req: RestRequest) -> dict:
         from elasticsearch_tpu.index.index_service import parse_keep_alive
 
         body = dict(req.body or {})
@@ -1099,13 +1118,13 @@ class _Handlers:
                 resp = svc.search(clean, searchers=ctx.extra["searchers"],
                                   task=task)
             resp["pit_id"] = pit["id"]
-            return self._ok_search(req, resp)
+            return resp
         # cross-cluster fan-out (PR 20): `remote:index` parts peel off into
         # one search RPC per registered remote; stays off the hot path for
         # expressions with no ':' or an empty remote registry
         index_expr = req.param("index")
         if self.node.remotes.has_remote_parts(index_expr):
-            return self._ok_search(req, self._ccs_search(index_expr, body))
+            return self._ccs_search(index_expr, body)
         names = self._resolve(index_expr, require=True)
         search_type = req.param("search_type", "query_then_fetch")
         # every search runs under a registered cancellable task
@@ -1116,17 +1135,16 @@ class _Handlers:
                 if len(names) != 1:
                     raise IllegalArgumentError("scroll requires a single index")
                 keep = parse_keep_alive(req.param("scroll"))
-                return self._ok_search(req, self.node.indices.scroll_start(
-                    names[0], body, keep, task=task))
+                return self.node.indices.scroll_start(
+                    names[0], body, keep, task=task)
             if len(names) == 1:
                 # `request_cache=false` (ref: RestSearchAction): this
                 # request is answered by the engines, not by the cache
-                return self._ok_search(req, self.node.indices.get(
-                    names[0]).search(
-                        body, search_type, task=task,
-                        request_cache=req.param_bool("request_cache", True)))
-            return self._ok_search(req, self._multi_index_search(
-                names, body, search_type, task=task))
+                return self.node.indices.get(names[0]).search(
+                    body, search_type, task=task,
+                    request_cache=req.param_bool("request_cache", True))
+            return self._multi_index_search(
+                names, body, search_type, task=task)
 
     def scroll_next(self, req: RestRequest) -> RestResponse:
         from elasticsearch_tpu.index.index_service import parse_keep_alive
@@ -1906,18 +1924,23 @@ class _Handlers:
                 body, local_parts, remote_groups, local_search)
 
     def msearch(self, req: RestRequest) -> RestResponse:
-        from elasticsearch_tpu.threadpool import (
-            activate_tier, tier_for_request,
-        )
+        """`_msearch` entry: one REST request of the flight recorder like
+        a `_search` (`_rest_total`), traced when ES_TPU_TRACE_SAMPLE
+        samples it."""
+        from elasticsearch_tpu.common import tracing
 
-        with activate_tier(tier_for_request(req.method, req.path,
-                                            req.params)):
+        def inner(req):
             with self.node.tasks.task(
                     "indices:data/read/msearch",
                     f"msearch bytes[{len(req.raw_body)}]"):
                 return self._msearch_inner(req)
 
-    def _msearch_inner(self, req: RestRequest) -> RestResponse:
+        return self._rest_total(
+            req, inner, tracing.current() is None and tracing.should_sample())
+
+    def _msearch_slots(self, req: RestRequest):
+        """The ndjson body as (slots, search_types, ccs_exprs): a slot a
+        (header, body) pair, its header's index expression resolved."""
         lines = [ln for ln in req.raw_body.decode().split("\n") if ln.strip()]
         slots = []   # (index_names | None, body, error | None)
         search_types = []   # per slot: header line's, else the URL's
@@ -1942,6 +1965,14 @@ class _Handlers:
                 slots.append((self._resolve(index, require=True), body, None))
             except ElasticsearchTpuError as e:
                 slots.append((None, body, e))
+        return slots, search_types, ccs_exprs
+
+    def _msearch_inner(self, req: RestRequest) -> dict:
+        from elasticsearch_tpu.common import tracing
+
+        with tracing.phase("rest.parse", bytes=len(req.raw_body)) as ph:
+            slots, search_types, ccs_exprs = self._msearch_slots(req)
+            ph.meta["bodies"] = len(slots)
         # single-index bodies group into per-index batches so eligible flat
         # queries share one device dispatch (ref P8 batched _msearch)
         by_index: dict = {}
@@ -1982,9 +2013,8 @@ class _Handlers:
                                       "status": 200})
                 except ElasticsearchTpuError as e:
                     responses.append({"error": e.to_dict(), "status": e.status})
-        return self._ok_search(req, {
-            "took": sum(r.get("took", 0) for r in responses),
-            "responses": responses})
+        return {"took": sum(r.get("took", 0) for r in responses),
+                "responses": responses}
 
     def count(self, req: RestRequest) -> RestResponse:
         body = dict(req.body or {})

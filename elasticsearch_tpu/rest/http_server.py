@@ -21,8 +21,10 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qsl, urlsplit
 
+from elasticsearch_tpu.common import tracing
 from elasticsearch_tpu.rest.controller import (
     RestController, RestResponse, _backoff_headers, _error_body,
+    is_search_endpoint,
 )
 
 
@@ -40,6 +42,14 @@ class HttpServer:
                 pass
 
             def _dispatch(self):
+                # what no pool worker covers of a request (the body's
+                # read, the hop to the pool) shows on the profiler's host
+                # plane under this thread's `es.rest.http`; the worker's
+                # spans lie inside it, a search's `rest.respond` at its end
+                with tracing.annotation("rest.http", path=self.path):
+                    self._serve()
+
+            def _serve(self):
                 parts = urlsplit(self.path)
                 params = dict(parse_qsl(parts.query, keep_blank_values=True))
                 length = int(self.headers.get("Content-Length") or 0)
@@ -63,6 +73,15 @@ class HttpServer:
                         resp = RestResponse(status=e.status,
                                             body=_error_body(e),
                                             headers=_backoff_headers(e))
+                if is_search_endpoint(parts.path):
+                    # a search's JSON encode and socket write, here where
+                    # they happen: after `rest_total`, on this thread
+                    with tracing.phase("rest.respond") as ph:
+                        ph.meta["bytes"] = self._respond(resp)
+                else:
+                    self._respond(resp)
+
+            def _respond(self, resp: RestResponse) -> int:
                 data = resp.encode()
                 self.send_response(resp.status)
                 self.send_header("Content-Type", resp.content_type)
@@ -73,6 +92,7 @@ class HttpServer:
                 self.end_headers()
                 if self.command != "HEAD":
                     self.wfile.write(data)
+                return len(data)
 
             do_GET = do_POST = do_PUT = do_DELETE = do_HEAD = _dispatch
 

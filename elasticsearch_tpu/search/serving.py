@@ -39,7 +39,7 @@ from __future__ import annotations
 import threading
 import time
 from dataclasses import dataclass, field as dc_field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -1419,73 +1419,42 @@ class ServingContext:
                     task=None) -> List[Optional[dict]]:
         """Serve each eligible body; None where the dense path must run.
         Disjunctive bodies on the same field batch into ONE device dispatch."""
-        if len(self.svc.shards) > 1 and search_type != "dfs_query_then_fetch":
-            return [None] * len(requests)
-        plans = [extract_plan(r, self.svc.mapper) for r in requests]
-        kplans = [extract_knn_plan(r, self.svc.mapper) if p is None else None
-                  for p, r in zip(plans, requests)]
-        aplans = [extract_filter_agg_plan(r, self.svc.mapper)
-                  if p is None and kp is None else None
-                  for p, kp, r in zip(plans, kplans, requests)]
-        if not any(plans) and not any(kplans) and not any(aplans):
-            return [None] * len(plans)
-        snap = self.snapshot()
-        if snap.total_docs == 0:
-            return [None] * len(plans)
-        out: List[Optional[dict]] = [None] * len(plans)
+        out: List[Optional[dict]] = [None] * len(requests)
+        with tracing.phase("route", bodies=len(requests)) as ph:
+            routed = self._route(requests, search_type)
+            if routed is None:
+                return out
+            snap, plans, kplans, aplans, groups = routed
+            ph.meta.update((name, sum(map(len, g.values()))
+                            if isinstance(g, dict) else len(g))
+                           for name, g in groups.items())
+
+        def serve(idxs, batch, where, *args):
+            try:
+                for i, r in zip(idxs, batch(*args, snap, task=task)):
+                    out[i] = r
+            except TaskCancelledError:
+                raise
+            except Exception as e:
+                _note_reject_error(e, where)
 
         # filter + bucket bodies: ONE dispatch of the aggregation engine
         # for all of them, every segment's reduction in it
-        agg_idxs = [i for i, ap in enumerate(aplans) if ap is not None]
-        if agg_idxs and self._filter_agg_servable(snap):
-            try:
-                results = self._filter_agg_batch(
-                    [aplans[i] for i in agg_idxs],
-                    [requests[i] for i in agg_idxs], snap, task=task)
-                for i, r in zip(agg_idxs, results):
-                    out[i] = r
-            except TaskCancelledError:
-                raise
-            except Exception as e:
-                _note_reject_error(e, "filter_agg_batch")
-
+        idxs = groups["agg"]
+        if idxs:
+            serve(idxs, self._filter_agg_batch, "filter_agg_batch",
+                  [aplans[i] for i in idxs], [requests[i] for i in idxs])
         # kNN-only bodies on the same vector field batch into ONE fused
         # quantized dispatch (first pass + rescore), filters included
-        knn_by_field: Dict[str, List[int]] = {}
-        for i, kp in enumerate(kplans):
-            if kp is not None:
-                knn_by_field.setdefault(kp.field, []).append(i)
-        for field, idxs in knn_by_field.items():
-            try:
-                results = self._knn_batch(
-                    field, [kplans[i] for i in idxs],
-                    [requests[i] for i in idxs], snap, task=task)
-                for i, r in zip(idxs, results):
-                    out[i] = r
-            except TaskCancelledError:
-                raise
-            except Exception as e:
-                _note_reject_error(e, "knn_batch")
-
-        # group disjunctive plans by field for batched device dispatch
-        by_field: Dict[str, List[int]] = {}
-        bool_by_field: Dict[str, List[int]] = {}
-        for i, plan in enumerate(plans):
-            if plan is None:
-                continue
-            start = time.monotonic()
-            if plan.is_disjunctive:
-                if self._disj_servable(plan, snap, requests[i]):
-                    by_field.setdefault(plan.field, []).append(i)
-                continue
-            if self._bool_spec(plan, snap) is not None:
-                bool_by_field.setdefault(plan.field, []).append(i)
-                continue
+        for field, idxs in groups["knn"].items():
+            serve(idxs, self._knn_batch, "knn_batch", field,
+                  [kplans[i] for i in idxs], [requests[i] for i in idxs])
+        for i in groups["host"]:
             try:
                 if task is not None:
                     task.check()
-                out[i] = self._conjunctive(plan, snap, requests[i], start,
-                                           task=task)
+                out[i] = self._conjunctive(plans[i], snap, requests[i],
+                                           time.monotonic(), task=task)
             except TaskCancelledError:
                 raise
             except SearchPhaseExecutionError as e:
@@ -1496,21 +1465,57 @@ class ServingContext:
             except Exception as e:
                 _note_reject_error(e, "conjunctive")
                 out[i] = None
-        for fields, serve, where in (
-                (bool_by_field, self._conjunctive_batch, "conjunctive"),
-                (by_field, self._disjunctive_batch, "disjunctive_batch")):
-            for field, idxs in fields.items():
-                try:
-                    results = serve(
-                        field, [plans[i] for i in idxs],
-                        [requests[i] for i in idxs], snap, task=task)
-                    for i, r in zip(idxs, results):
-                        out[i] = r
-                except TaskCancelledError:
-                    raise
-                except Exception as e:
-                    _note_reject_error(e, where)
+        # disjunctive / Turbo-served conjunctive plans, a device dispatch
+        # a field
+        for name, batch, where in (
+                ("bool", self._conjunctive_batch, "conjunctive"),
+                ("disj", self._disjunctive_batch, "disjunctive_batch")):
+            for field, idxs in groups[name].items():
+                serve(idxs, batch, where, field,
+                      [plans[i] for i in idxs], [requests[i] for i in idxs])
         return out
+
+    def _route(self, requests: Sequence[dict], search_type: str):
+        """`try_msearch`'s `route` step, the host work that decides who
+        serves each body: every body's plan (`extract_plan`, else
+        `extract_knn_plan`, else `extract_filter_agg_plan`), the snapshot,
+        the servability checks and the grouping by route and field. None
+        where no body is the fast path's; else (snap, plans, kplans,
+        aplans, groups), `groups` the bodies' indices a route: "agg" [i],
+        "knn" / "bool" / "disj" {field: [i]}, "host" [i] (a conjunctive
+        plan no Turbo engine serves: the host columnar path)."""
+        if len(self.svc.shards) > 1 and search_type != "dfs_query_then_fetch":
+            return None
+        plans = [extract_plan(r, self.svc.mapper) for r in requests]
+        kplans = [extract_knn_plan(r, self.svc.mapper) if p is None else None
+                  for p, r in zip(plans, requests)]
+        aplans = [extract_filter_agg_plan(r, self.svc.mapper)
+                  if p is None and kp is None else None
+                  for p, kp, r in zip(plans, kplans, requests)]
+        if not any(plans) and not any(kplans) and not any(aplans):
+            return None
+        snap = self.snapshot()
+        if snap.total_docs == 0:
+            return None
+        groups: Dict[str, Any] = {
+            "agg": [], "knn": {}, "host": [], "bool": {}, "disj": {}}
+        if any(aplans) and self._filter_agg_servable(snap):
+            groups["agg"] = [i for i, ap in enumerate(aplans)
+                             if ap is not None]
+        for i, kp in enumerate(kplans):
+            if kp is not None:
+                groups["knn"].setdefault(kp.field, []).append(i)
+        for i, plan in enumerate(plans):
+            if plan is None:
+                continue
+            if plan.is_disjunctive:
+                if self._disj_servable(plan, snap, requests[i]):
+                    groups["disj"].setdefault(plan.field, []).append(i)
+            elif self._bool_spec(plan, snap) is not None:
+                groups["bool"].setdefault(plan.field, []).append(i)
+            else:
+                groups["host"].append(i)
+        return snap, plans, kplans, aplans, groups
 
     def try_query_phase(self, request: dict, task=None):
         """QUERY-PHASE-ONLY fast path for the DISTRIBUTED shard executor
@@ -2097,18 +2102,22 @@ class ServingContext:
         window = hits[from_: from_ + size]
         max_score = hits[0][2] if hits else None
         out_hits = []
-        with tracing.phase("fetch", hits=len(window)):
-            for pi, ord_, score in window:
-                part = snap.partitions[pi]
-                sh = ShardHit(leaf_idx=part.leaf_idx, ord=ord_, score=score,
-                              global_ord=part.base + ord_)
-                fetched = execute_fetch_phase(
-                    snap.searchers[part.shard_id], [sh], request,
-                    self.svc.name)
-                hit = fetched[0]
-                if hit.get("_score") is None:
-                    hit["_score"] = score
-                out_hits.append(hit)
+        # `fetch`: one observation and one span a REQUEST, by clock reads
+        # (an `_msearch` runs this 256 times a call: no annotation each)
+        t0 = time.monotonic_ns()
+        for pi, ord_, score in window:
+            part = snap.partitions[pi]
+            sh = ShardHit(leaf_idx=part.leaf_idx, ord=ord_, score=score,
+                          global_ord=part.base + ord_)
+            fetched = execute_fetch_phase(
+                snap.searchers[part.shard_id], [sh], request,
+                self.svc.name)
+            hit = fetched[0]
+            if hit.get("_score") is None:
+                hit["_score"] = score
+            out_hits.append(hit)
+        tracing.steps.add("fetch", (time.monotonic_ns() - t0) / 1e6, t0,
+                          hits=len(window))
         took = int((time.monotonic() - start) * 1000)
         resp = {
             "took": took,

@@ -711,7 +711,8 @@ class AdaptiveDispatchScheduler:
         # take an in-flight slot BEFORE the device call; the last waiter
         # to consume the batch gives it back (double buffering: demux of
         # this batch overlaps the device sweep of the next one)
-        lane.slots.acquire()
+        with tracing.annotation("sched.slot_wait"):
+            lane.slots.acquire()
         n = len(batch.queries)
         with self._lock:
             self._inflight += 1
@@ -725,12 +726,18 @@ class AdaptiveDispatchScheduler:
                 self._bucket_counts.get(batch.bucket, 0) + 1
             self._lane_inflight[lane.key] = \
                 self._lane_inflight.get(lane.key, 0) + 1
-            inflight_now, lanes_now = self._inflight, len(self._lanes)
+            inflight_now = self._inflight
         metrics.observe("sched_bucket_size", batch.bucket)
         metrics.observe("sched_queue_depth", depth)
         metrics.gauge_set("sched_inflight", inflight_now)
-        metrics.gauge_set("sched_lanes", lanes_now)
         metrics.counter_add("sched_flushes")
+        # what the batch's oldest waiter waited before its dispatch began:
+        # its tier budget (`es.sched.fill`), a dispatch in flight ahead of
+        # it, the slot (a traced waiter sees it as its sched_tier_wait
+        # span's own time)
+        metrics.observe(
+            "sched_fill",
+            (time.monotonic() - min(w.enqueued for w in batch.waiters)) * 1e3)
         try:
             with tracing.activate(batch.trace):
                 batch.results = run_device(

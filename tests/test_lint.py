@@ -425,11 +425,13 @@ def record(ms, pool):
     assert lint_sources([_METRICS_TWIN, ok], select={"TPU005"}) == []
 
 
-@pytest.mark.parametrize("call", ["tracing.phase", "tracing.record"])
+@pytest.mark.parametrize("call", ["tracing.phase", "tracing.record",
+                                  "tracing.steps.add"])
 def test_tpu005_span_primitive_names_are_tied_to_the_registry(call):
-    """`tracing.phase("x")` / `tracing.record("x", ms)` observe under the
-    span's own name (PR 27): a literal name must be declared; a composed
-    one, and a `phase` / `record` of some other object, are left alone."""
+    """`tracing.phase("x")` / `tracing.record("x", ms)` /
+    `tracing.steps.add("x", ms)` observe under the span's own name (PR 27,
+    PR 42): a literal name must be declared; a composed one, and a `phase`
+    / `record` / `add` of some other object, are left alone."""
     src = (_TPU005_PATH, f'''
 from elasticsearch_tpu.common import tracing
 
@@ -441,6 +443,8 @@ def serve(ms, pool, health, hist):
     {call}(f"queue_wait.{{pool}}", ms)
     health.record("not_a_histogram")
     hist.phase("moon")
+    seen.add("a_set_member")
+    other.steps.add("not_tracing")
 ''')
     findings = lint_sources([_METRICS_TWIN, src], select={"TPU005"})
     assert rules_of(findings) == ["TPU005"]

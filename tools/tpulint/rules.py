@@ -465,8 +465,9 @@ class CounterHygieneRule:
 
     Same hygiene for the flight-recorder histograms: a literal
     ``metrics.observe("name", …)`` site — and a literal
-    ``tracing.phase("name", …)`` / ``tracing.record("name", …)`` site,
-    which observes under the span's own name — must name a histogram
+    ``tracing.phase("name", …)`` / ``tracing.record("name", …)`` /
+    ``tracing.steps.add("name", …)`` site, which observes under the span's
+    own name — must name a histogram
     declared in common/metrics.py — declared histograms all surface through
     ``search_latency_stats()``, so an undeclared name is a metric that can
     never reach `_nodes/stats` (and raises UndeclaredHistogramError the
@@ -492,14 +493,21 @@ class CounterHygieneRule:
     @staticmethod
     def _observes(func: ast.AST) -> Optional[str]:
         """`observe` for any `….observe(` call; `phase` / `record` only as
-        `tracing.phase(` / `tracing.record(` (both names are common)."""
+        `tracing.phase(` / `tracing.record(` (both names are common), and
+        `tracing.steps.add(`."""
         tail = dotted_tail(func)
         if tail == "observe":
             return tail
-        if tail in ("phase", "record") and isinstance(func, ast.Attribute) \
-                and isinstance(func.value, ast.Name) \
+        if not isinstance(func, ast.Attribute):
+            return None
+        if tail in ("phase", "record") and isinstance(func.value, ast.Name) \
                 and func.value.id == "tracing":
             return "tracing." + tail
+        if tail == "add" and isinstance(func.value, ast.Attribute) \
+                and func.value.attr == "steps" \
+                and isinstance(func.value.value, ast.Name) \
+                and func.value.value.id == "tracing":
+            return "tracing.steps.add"
         return None
 
     @staticmethod
