@@ -18,7 +18,24 @@ Per query the terms split three ways:
   computes EXACT totals for every cold-touched doc that a bound does not
   rule out — the other query terms' impacts come from their columns'
   host index (_column_impacts), no search — so any doc with a cold
-  contribution is scored exactly with no device help.
+  contribution is scored exactly with no device help. The order, a
+  (partition, query): COLLECT the cold postings raw (the terms' lists
+  laid end to end, each posting beside its doc's whole gathered cold
+  contribution; nothing made distinct), BOUND them (_cold_survivors),
+  then enumerate (np.unique), give impacts to and exact-rescore the
+  SURVIVORS only. The bound's threshold is a lower bound on the final
+  k-th score from two sides: the k-th exact total of the picked rows'
+  docs, which the column terms choose and which is far too low for a
+  query whose rare terms carry the idf, and the cold side's own: a
+  gathered contribution less its slack is a lower bound on its doc's
+  total (a disjunction's other terms add >= 0), a doc occurs at most
+  once a cold term, so the (k x cold terms)-th largest raw value is at
+  most the k-th best distinct doc's. The rank tolerates duplicates
+  because the raw postings are not distinct yet, and making them so is
+  the cost the bound is there to avoid. Both ends of the test carry the
+  gather's slack and `_f32_err`, what the exact scorer's f32 arithmetic
+  can be off by at the query's boosts: with a tight threshold that
+  margin decides who is scored, so it grows with the scores.
 * the final top-k merges both sides, once a (partition, dispatch chunk)
   (_finish_chunk): the host rescores EVERY doc in the collected rows in
   exact f32 (term-order identical to the reference scorer; a term's
@@ -178,6 +195,18 @@ def _quantize(ws: Sequence[float]):
     # f32 rounding of the in-kernel integer combine
     e_q += 3e-7 * sum(abs(w) for w in ws) * K1_PLUS1
     return steps, qs2 * COLSCALE2, float(e_q)
+
+
+def _f32_err(n_terms: int, most: float) -> float:
+    """How far `_exact_scores`' f32 total of a doc can lie from the real
+    sum of its terms: `n_terms` weights rounded to f32, as many products
+    and one add fewer, each within 2**-24 of a value no larger than
+    `most` = the sum of |idf x boost| x the term's largest impact, so
+    (n_terms + 1) x 2**-24 x `most`, taken twice over, above the 1e-5 the
+    survivor bound has always carried. A margin that did not grow with
+    the boosts would let a boosted query's bound drop a doc whose f32
+    total ties the k-th."""
+    return 1e-5 + (n_terms + 1) * 2.0 ** -23 * most
 
 
 _BUILD_BUCKETS = (256, 1024, 4096, 16384, 32768)   # last one bounded by
@@ -351,7 +380,8 @@ def node_bitset_stats() -> dict:
 # per query the posting spans and the bound's slack (_ColdGather). LAUNCH:
 # one i32 [4, steps] upload + one program, async, with the result's copy
 # to the host started at once. COLLECT: one fetch a group, and each
-# query's totals mapped back onto posting order out of it. Nothing the
+# query's totals read out of it in posting order, raw (the match finish
+# bounds them before it makes anything distinct: _cold_survivors). Nothing the
 # gather reads depends on the sweep's output, so search_many plans and
 # launches every group of a dispatch chunk right after the chunk's sweep
 # and BEFORE it waits for the sweep: the gathers run on the device behind
@@ -400,14 +430,21 @@ def _sparse_widths() -> Tuple[int, ...]:
 # finish_bulk_pairs = (partition, query) pairs the chunk-wide finish
 # answered; finish_pair_fallbacks = pairs that left it (a host-only
 # partition, a faulted sweep, a gather not launched or lost, a failed
-# certificate): the two sum to partitions x queries
+# certificate): the two sum to partitions x queries.
+# cold_enum_docs = RAW cold postings the match finish's pairs laid out
+# (`_collect_gather`: a doc in several of a query's cold lists counts once
+# a list; nothing is made distinct before the bound);
+# cold_survivor_docs = distinct docs of them the survivor bound kept
+# (`_cold_survivors`), each exact-rescored; both counted once a pair, so
+# survivors over enumerated says how often the bound engages
 _NODE_SPARSE_STATS = {"sparse_slices": 0, "sparse_slice_passes": 0,
                       "sparse_bytes": 0, "sparse_queries": 0,
                       "sparse_gather_launches": 0,
                       "sparse_gather_overlapped": 0,
                       "sparse_fallbacks": 0,
                       "finish_bulk_pairs": 0,
-                      "finish_pair_fallbacks": 0
+                      "finish_pair_fallbacks": 0,
+                      "cold_enum_docs": 0, "cold_survivor_docs": 0
                       }  # guarded by: _NODE_SPARSE_LOCK
 _NODE_SPARSE_LOCK = threading.Lock()
 
@@ -478,8 +515,11 @@ class _ChunkPlan:
     query: `qterms` (the terms this partition holds, in query order, as
     (term, boost, info, column slot or -1)), `cold` (those without a
     column NOW, as (term, boost, info): `_slot_of` does not move between
-    a dispatch's prep and its finish), `e_q` and `col_const` of the
-    colized ones.
+    a dispatch's prep and its finish), `e_q` of the colized ones and the
+    most and the least they can add to a doc (`col_const`, `col_floor`:
+    0 unless a boost is negative), and `f32_err`, how far the f32 exact
+    total of ANY doc can lie from the real sum of its terms (it grows with
+    the boosts: `_f32_err`).
     `wq` / `qscale` are the sweep's inputs. The p* arrays hold every
     (query, term) pair of the chunk, term POSITION major (`pos_end[j]` =
     pairs at positions <= j), for the row-span rescore: query, column
@@ -488,9 +528,9 @@ class _ChunkPlan:
     the last)] of the pairs without a column. `sparse` = the sparse tier
     serves this chunk's cold sides (read once)."""
 
-    __slots__ = ("chunk", "qterms", "cold", "e_q", "col_const", "wq",
-                 "qscale", "sparse", "pq", "pslot", "plo", "phb", "pw",
-                 "pos_end", "cold_pairs")
+    __slots__ = ("chunk", "qterms", "cold", "e_q", "col_const",
+                 "col_floor", "f32_err", "wq", "qscale", "sparse", "pq",
+                 "pslot", "plo", "phb", "pw", "pos_end", "cold_pairs")
 
 
 class TurboBM25:
@@ -652,7 +692,8 @@ class TurboBM25:
                       "sparse_bytes": 0, "sparse_fallbacks": 0,
                       "sparse_gather_launches": 0,
                       "sparse_gather_overlapped": 0,
-                      "finish_bulk_pairs": 0, "finish_pair_fallbacks": 0}
+                      "finish_bulk_pairs": 0, "finish_pair_fallbacks": 0,
+                      "cold_enum_docs": 0, "cold_survivor_docs": 0}
         # HBM residency ledger: regions mirror hbm_bytes() exactly so the
         # telemetry cross-check can hold ledger == engine to the byte
         self._hbm = hbm_ledger.register_engine(self, "turbo")
@@ -1092,6 +1133,13 @@ class TurboBM25:
         np.add.at(acc, inv, np.concatenate(vals))
         return u, acc, inv
 
+    def _cold_raw(self, cold_terms):
+        """`_cold_contrib` in `_collect_gather`'s shape: (docs_raw,
+        vals_raw, slack 0), the exact sums spread back over the terms'
+        postings as they lie, so the host walk takes the one finish."""
+        u, acc, inv = self._cold_contrib(cold_terms)
+        return u[inv], acc[inv], 0.0
+
     # ---------------- eager sparse impact slices ----------------
 
     def _sp_grow(self, new_g: int) -> None:
@@ -1496,13 +1544,18 @@ class TurboBM25:
             self._sp_inflight -= 1
 
     def _collect_gather(self, h: _ColdGather):
-        """COLLECT: (docs, contrib, slack, inv), mirroring _cold_contrib's
-        unique-doc enumeration; the group's block is fetched by the first
+        """COLLECT: (docs_raw, vals_raw, slack) — the pair's cold postings
+        AS THEY LIE, the terms' lists laid end to end in query order, and
+        beside each the accumulator cell it reads: the doc's WHOLE cold
+        contribution, the same value at every one of its occurrences (a
+        doc in several of the lists reads one cell several times).
+        Nothing is enumerated here, no `np.unique`: the survivor bound
+        runs on the raw arrays first (`_cold_survivors`) and only what it
+        keeps is made distinct. The group's block is fetched by the first
         of its queries to ask. A pair whose group was not launched, or
-        whose fetch faults, falls back to the exact host enumeration with
-        slack 0 (`h.host`) — downstream pruning then evaluates the
-        IDENTICAL expression the host path uses, so containment is
-        bit-identical by construction."""
+        whose fetch faults, is host-scored (`h.host`): `_cold_contrib`'s
+        exact sums spread back over the postings, slack 0, and the same
+        flow from there."""
         g, flat = h.group, None
         if g is not None:
             if g.out is not None:
@@ -1522,22 +1575,15 @@ class TurboBM25:
             h.host = True
             self.stats["sparse_fallbacks"] += 1
             _node_sparse_add("sparse_fallbacks", 1)
-            u, acc, inv = self._cold_contrib(h.cold_terms)
-            return u, acc, 0.0, inv
+            return self._cold_raw(h.cold_terms)
         fp = self.fp
         docs_l, vals_l = [], []
         for c0, df, lo in h.spans:
-            docs_l.append(np.asarray(fp.post_doc[lo: lo + df], np.int64))
+            docs_l.append(fp.post_doc[lo: lo + df])
             base = c0 * SPARSE_GRAN
             vals_l.append(flat[base: base + df])
-        docs = np.concatenate(docs_l)
-        vals = np.concatenate(vals_l).astype(np.float64)
-        # a doc shared by several dispatched slices reads the SAME
-        # accumulator cell at every occurrence — first occurrence wins,
-        # exactly _cold_contrib's unique-doc enumeration
-        u, fidx, inv = np.unique(docs, return_index=True,
-                                 return_inverse=True)
-        return u, vals[fidx], h.slack, inv
+        return (np.concatenate(docs_l),
+                np.concatenate(vals_l).astype(np.float64), h.slack)
 
     def sparse_hot_terms(self) -> List[str]:
         """Terms with a resident sparse slice — the warm-handoff payload a
@@ -1790,16 +1836,18 @@ class TurboBM25:
         plan.wq = wq = np.zeros((2, QC, self.Hp + 1), np.int8)
         plan.qscale = qscale = np.ones((QC, 1), np.float32)
         plan.qterms, plan.cold = [], []
-        plan.e_q, plan.col_const = [], []
+        plan.e_q, plan.col_const, plan.col_floor = [], [], []
+        plan.f32_err = []
         slot_of, term = self._slot_of, self._term
         by_pos: List[List[tuple]] = []
         for qi, terms in enumerate(chunk):
             qterms, cold, slots, ws = [], [], [], []
-            col_const = 0.0
+            col_const = col_floor = most = 0.0
             for t, b in terms or ():
                 info = term(t)
                 if info is None:
                     continue
+                most += abs(info.idf * b) * info.smax
                 # colized = owns a column NOW (a term past cold_df may have
                 # been left cold by capacity degradation)
                 slot = slot_of.get(t, -1)
@@ -1809,7 +1857,9 @@ class TurboBM25:
                 else:
                     slots.append(slot)
                     ws.append(w)
-                    col_const += w * info.smax
+                    # the most and the least a column term can add
+                    col_const += max(w, 0.0) * info.smax
+                    col_floor += min(w, 0.0) * info.smax
                 if len(by_pos) == len(qterms):
                     by_pos.append([])
                 by_pos[len(qterms)].append(
@@ -1823,6 +1873,8 @@ class TurboBM25:
             plan.cold.append(cold)
             plan.e_q.append(e_q)
             plan.col_const.append(col_const)
+            plan.col_floor.append(col_floor)
+            plan.f32_err.append(_f32_err(len(qterms), most))
         pairs = [p for at in by_pos for p in at]
         cols = list(zip(*pairs)) if pairs else [()] * 5
         plan.pq = np.asarray(cols[0], np.int64)
@@ -1900,24 +1952,81 @@ class TurboBM25:
             a = b
         return plane
 
-    def _survivor_terms(self, qterms, keep: np.ndarray, inv: np.ndarray):
-        """A plan's qterms for `_exact_scores` at the cold side's
-        survivors u[keep]: a cold term's postings are all in u (`inv`
-        holds where, the terms' lists laid end to end), so its impacts at
-        the survivors are written out here and nothing is searched; a
-        term that owns a column keeps its slot."""
-        at = np.cumsum(keep) - 1           # u index -> survivor index
-        n = int(at[-1]) + 1
-        out, o = [], 0
+    def _cold_survivors(self, docs_raw: np.ndarray, vals_raw: np.ndarray,
+                        slack: float, n_terms: int, col_const: float,
+                        col_floor: float, f32_err: float, kth_0: float,
+                        k: int) -> np.ndarray:
+        """THE SURVIVOR BOUND, on the raw postings of one pair's cold side
+        (`_collect_gather`): the ascending positions of those whose doc is
+        live and could reach or tie the final k-th score. Everything else
+        is never enumerated, made distinct or scored.
+
+        `vals_raw` is a doc's whole cold contribution within `slack`
+        (`_admit_gather`), the column terms add at most `col_const` and at
+        least `col_floor` (0 unless a boost is negative), and `f32_err`
+        (`_f32_err`: 1e-5 and what the query's boosts add to it) covers
+        the exact scorer's f32 weights and accumulation, so a live doc's
+        exact total lies in [vals - slack - f32_err + col_floor,
+                             vals + slack + f32_err + col_const].
+        The upper end has always been tested against `kth_0`, the k-th
+        best exact total among the docs of the picked ROWS. Those rows are
+        picked by the column terms; a query's rare terms are cold, carry
+        the idf, and their docs are mostly elsewhere, so `kth_0` is far
+        under the true k-th (0 for a cold-only query). The cold side
+        supplies the better threshold itself, from the LOWER end: a doc
+        occurs at most once a cold term, so at most `n_terms` times in
+        the raw values, and the (k x n_terms)-th largest live raw value is
+        at most the k-th best DISTINCT live doc's (the rank has to
+        tolerate duplicates because nothing is distinct yet: `_top_k`
+        makes the same argument with 2k). k live docs therefore have an
+        exact total of at least that value's lower end: it is a lower
+        bound on the final k-th score, as `kth_0` is, and a doc whose
+        upper end is under the larger of the two can neither enter the
+        top k nor tie with it. One `np.partition` a pair, no exact score
+        computed to get it. The test depends on the doc alone (every
+        occurrence holds the same value), so a surviving doc keeps ALL
+        its cold postings, which `_survivor_terms` relies on."""
+        live_host = self._live_host
+        kth, rank, n = kth_0, k * n_terms, len(vals_raw)
+        low = None          # the rank-th largest raw value of a live doc
+        if n >= rank:
+            # liveness is looked up where it can matter only: at the best
+            # `rank` raw values (all live, nearly always: they are then
+            # the live values' best too), and below at what passed
+            best = np.argpartition(vals_raw, n - rank)[n - rank:]
+            if (live_host[docs_raw[best]] > 0).all():
+                low = vals_raw[best].min()
+            else:           # a deleted doc among the best: rank the live
+                lv = vals_raw[live_host[docs_raw] > 0]
+                if len(lv) >= rank:
+                    low = np.partition(lv, len(lv) - rank)[len(lv) - rank]
+        if low is not None:
+            kth = max(kth, float(low) - slack - f32_err + col_floor)
+        sel = np.flatnonzero(vals_raw >= kth - (slack + col_const + f32_err))
+        return sel[live_host[docs_raw[sel]] > 0]
+
+    def _survivor_terms(self, qterms, sel: np.ndarray, at: np.ndarray,
+                        n: int):
+        """A plan's qterms for `_exact_scores` at the cold side's `n`
+        survivors: `sel` the surviving postings' positions in the raw
+        layout (the cold terms' lists laid end to end in query order,
+        ascending), `at` the survivor each belongs to. A surviving doc's
+        cold postings all survive, so a cold term's impacts at the
+        survivors are written out from the surviving postings' own
+        positions (one search of the cumulative df finds the terms'
+        boundaries in `sel`) and nothing df-sized is touched; a term
+        that owns a column keeps its slot."""
+        ends = np.cumsum([q[2].df for q in qterms if q[3] < 0])
+        cuts = iter(np.searchsorted(sel, ends).tolist())
+        lanes = self._host_scores.reshape(-1)
+        out, a, o = [], 0, 0         # a: into sel, o: the term's first posting
         for t, boost, info, known in qterms:
             if known < 0:
-                iv = inv[o: o + info.df]
-                o += info.df
-                kept = keep[iv]
+                b = next(cuts)
                 known = np.zeros(n, np.float32)
-                known[at[iv[kept]]] = self._host_scores[
-                    info.row_start: info.row_start + info.n_rows
-                ].reshape(-1)[: info.df][kept]
+                known[at[a:b]] = lanes[
+                    sel[a:b] + (info.row_start * 128 - o)]
+                a, o = b, o + info.df
             out.append((t, boost, info, known))
         return out
 
@@ -1933,7 +2042,18 @@ class TurboBM25:
         bounds [n] — the max approximate score any of those could hold
         (device pick output); gathers — the queries' cold sides as
         `_chunk_gathers` launched them behind the sweep. The cold side,
-        the merge and the certificate run a query at a time.
+        the merge and the certificate run a query at a time, the cold
+        side in this order: collect the pair's cold postings RAW
+        (`_collect_gather`: no `np.unique`), bound them
+        (`_cold_survivors`: liveness and the test against the larger of
+        the rows' k-th exact total and the cold side's own k-th lower
+        bound, the (k x cold terms)-th largest raw value less its slack,
+        a rank that tolerates a doc's duplicates among the raw values),
+        and only then make the SURVIVORS distinct, write their impacts
+        (`_survivor_terms`) and score them exactly. A cold-only query
+        takes the same test. What the bound drops is provably under the
+        final k-th score; every exact score still leaves through
+        `_exact_scores`, so the answers are the same bits.
 
         Every ms is a named step of the engine call (`DISPATCH_STEPS`):
         the chunk-wide rescore is a phase (`dispatch.rescore_rows`); the
@@ -1942,7 +2062,14 @@ class TurboBM25:
         (`tracing.steps.add`: one span each, laid end to end from the
         loop's start), the loop under ONE annotation
         (`es.dispatch.finish_pairs`), not under two phases a query.
-        `dispatch.rescore` = rows + survivors."""
+        `dispatch.rescore` = rows + survivors; the survivors' `np.unique`
+        is booked under `dispatch.rescore_survivors` (it is part of
+        scoring them), `dispatch.sparse_gather` stays the collect alone
+        and `dispatch.survivor_bound` the test on the raw postings. The
+        spans' `docs`: raw postings enumerated (`sparse_gather`,
+        `survivor_bound`), distinct survivors (`rescore_survivors`);
+        the same two sums feed `tpu_turbo.cold_enum_docs` /
+        `cold_survivor_docs`."""
         n, R = rows_all.shape
         # (`es.dispatch.rescore` stays the profiler's name for the
         # chunk-wide rescore: an annotation, the histogram is fed below)
@@ -1984,7 +2111,8 @@ class TurboBM25:
                 # of them — with binary searches into multi-million-entry
                 # colized posting lists; a doc whose cold contribution plus
                 # the colized terms' maximum possible addend cannot reach
-                # the candidate k-th score needs no lookup at all) ----
+                # a lower bound on the k-th score needs no lookup at all,
+                # and is not even enumerated) ----
                 if cold_terms:
                     if plan.sparse:
                         self.stats["sparse_queries"] += 1
@@ -1996,38 +2124,38 @@ class TurboBM25:
                         # `dispatch.sparse_gather` is the collect alone, as
                         # the phase a query it replaces was
                         t = clock()
-                        u, contrib, slack, inv = self._collect_gather(h)
+                        docs_r, vals_r, slack = self._collect_gather(h)
                         t1 = clock()
                         ns_gather += t1 - t
                         host_scored = h.host
                     else:
                         self.stats["cold_queries"] += 1
-                        u, contrib, inv = self._cold_contrib(cold_terms)
-                        slack = 0.0
+                        docs_r, vals_r, slack = self._cold_raw(cold_terms)
                         t1 = clock()
-                    keep = self._live_host[u] > 0
-                    if colized:
-                        kth_0 = 0.0
-                        if len(totals) >= k:
-                            kth_0 = float(np.partition(
-                                totals, len(totals) - k)[len(totals) - k])
-                        # float64 contrib + margin keeps this a true upper
-                        # bound; slack covers the sparse tier's
-                        # quantization so the survivor set is a SUPERSET of
-                        # the host path's — extras are exact-rescored and
-                        # provably below the k-th score. (A cold-only query
-                        # keeps every live doc: the exact path IS the full
-                        # merge.)
-                        keep &= (contrib + slack + plan.col_const[qi] + 1e-5
-                                 >= kth_0)
-                    cold_docs = u[keep]
+                    # the bound BEFORE the enumeration (`_cold_survivors`:
+                    # the k-th of the picked rows' exact totals, raised by
+                    # the cold side's own k-th lower bound); a cold-only
+                    # query has no row candidates and takes the same test
+                    kth_0 = 0.0
+                    if len(totals) >= k:
+                        kth_0 = float(np.partition(
+                            totals, len(totals) - k)[len(totals) - k])
+                    sel = self._cold_survivors(
+                        docs_r, vals_r, slack, len(cold_terms),
+                        plan.col_const[qi], plan.col_floor[qi],
+                        plan.f32_err[qi], kth_0, k)
                     t = clock()
                     ns_bound += t - t1
                     n_cold += 1
-                    n_enum += len(u)
-                    if len(cold_docs):
+                    n_enum += len(docs_r)
+                    if len(sel):
+                        # the survivors made distinct: booked, with their
+                        # impacts and exact scores, under rescore_survivors
+                        cold_docs, into = np.unique(docs_r[sel],
+                                                    return_inverse=True)
                         cold_s = self._exact_scores(
-                            self._survivor_terms(qterms, keep, inv),
+                            self._survivor_terms(qterms, sel, into,
+                                                 len(cold_docs)),
                             cold_docs)
                         t1 = clock()
                         ns_surv += t1 - t
@@ -2078,13 +2206,16 @@ class TurboBM25:
             tracing.steps.add(name, ns / 1e6, at, **meta)
             at += ns
         tracing.steps.add("dispatch.rescore", rows.ms + ns_surv / 1e6)
-        self._count_finish(n - left, left)
+        self._count_finish(n - left, left, n_enum, n_surv)
 
-    def _count_finish(self, bulk: int, left: int) -> None:
-        self.stats["finish_bulk_pairs"] += bulk
-        self.stats["finish_pair_fallbacks"] += left
-        _node_sparse_add("finish_bulk_pairs", bulk)
-        _node_sparse_add("finish_pair_fallbacks", left)
+    def _count_finish(self, bulk: int, left: int, enum: int = 0,
+                      surv: int = 0) -> None:
+        for key, v in (("finish_bulk_pairs", bulk),
+                       ("finish_pair_fallbacks", left),
+                       ("cold_enum_docs", enum),
+                       ("cold_survivor_docs", surv)):
+            self.stats[key] += v
+            _node_sparse_add(key, v)
 
     @staticmethod
     def _top_k(docs: np.ndarray, totals: np.ndarray, k: int):
@@ -2772,9 +2903,18 @@ class TurboBM25:
                 _node_sparse_add("sparse_queries", 1)
                 # the same plan / launch / collect, back to back
                 h = self._start_gathers([(0, cold_should)], False)[0]
+                # (read through the old shape, distinct docs, and today's
+                # bound: a match must ALSO hold the required clauses, so a
+                # cold contribution is no lower bound on a hit's score
+                # here and `_cold_survivors`' threshold does not apply)
                 with tracing.phase("dispatch.sparse_gather",
                                    terms=len(cold_should)):
-                    docs_c, contrib, slack, _ = self._collect_gather(h)
+                    docs_r, vals_r, slack = self._collect_gather(h)
+                    # every occurrence of a doc holds the same value: the
+                    # first one stands for it, `_cold_contrib`'s shape
+                    docs_c, first = np.unique(docs_r.astype(np.int64),
+                                              return_index=True)
+                    contrib = vals_r[first]
             else:
                 self.stats["cold_queries"] += 1
                 docs_c, contrib, _ = self._cold_contrib(cold_should)

@@ -229,6 +229,39 @@ def test_the_finish_steps_are_one_span_a_chunk_laid_end_to_end(
     assert spans["dispatch.merge_cert"][0]["meta"]["docs"] > 0
 
 
+def test_the_spans_docs_are_what_the_cold_counters_count(env, monkeypatch):
+    """PR 43: the bound runs before the enumeration. `sparse_gather` and
+    `survivor_bound` carry the RAW cold postings the pairs laid out,
+    `rescore_survivors` the distinct docs the bound kept (their
+    `np.unique` is booked there, with their impacts and exact scores);
+    `tpu_turbo.cold_enum_docs` / `cold_survivor_docs` of
+    `GET /_nodes/stats` rise by the same two sums."""
+    node, call = env
+
+    def cold():
+        _, stats = call("GET", "/_nodes/stats")
+        turbo = stats["nodes"][node.node_id]["tpu_turbo"]
+        return turbo["cold_enum_docs"], turbo["cold_survivor_docs"]
+
+    monkeypatch.setenv("ES_TPU_TRACE_SAMPLE", "1")
+    enum0, surv0 = cold()
+    st, _ = call("POST", "/_msearch", raw=msearch_raw(8))
+    assert st == 200
+    spans = spans_by_name(next(
+        t for t in reversed(tracing.recent_traces())
+        if any(sp["name"] == "dispatch.survivor_bound"
+               for sp in t["spans"])))
+    enum1, surv1 = cold()
+    (bound,) = spans["dispatch.survivor_bound"]
+    (surv,) = spans["dispatch.rescore_survivors"]
+    assert bound["meta"]["docs"] == enum1 - enum0 \
+        == spans["dispatch.sparse_gather"][0]["meta"]["docs"]
+    assert surv["meta"]["docs"] == surv1 - surv0
+    # 8 pairs of one cold term each (df 7-8 of 96): all enumerated
+    assert 8 * 7 <= enum1 - enum0 <= 8 * 8
+    assert 0 < surv1 - surv0 <= enum1 - enum0
+
+
 @pytest.mark.parametrize("path", ["_search", "_msearch", "_doc"])
 def test_every_search_call_observes_each_rest_step_once(over_http, path):
     """(An `_msearch`'s ndjson is parsed by its handler alone: the

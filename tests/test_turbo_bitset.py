@@ -33,13 +33,15 @@ class _Seg:
         self.vectors = {}
 
 
-def _pcorpus(n_docs, vocab, seed):
+def _pcorpus(n_docs, vocab, seed, reps=1):
+    """`reps` copies of `n_docs` random docs, one after the other."""
     rng = np.random.default_rng(seed)
     probs = 1.0 / np.arange(1, vocab + 1) ** 1.1
     probs /= probs.sum()
     lens = rng.integers(4, 24, size=n_docs).astype(np.int64)
     tokens = rng.choice(vocab, size=int(lens.sum()), p=probs).astype(np.int64)
-    tok_docs = np.repeat(np.arange(n_docs, dtype=np.int64), lens)
+    lens, tokens = np.tile(lens, reps), np.tile(tokens, reps)
+    tok_docs = np.repeat(np.arange(n_docs * reps, dtype=np.int64), lens)
     bounds = np.concatenate([[0], np.cumsum(lens)])
     tok_pos = (np.arange(len(tokens), dtype=np.int64)
                - np.repeat(bounds[:-1], lens))
