@@ -103,6 +103,54 @@ def tiny_manifest(tmp: str) -> Manifest:
     return Manifest(tiny_root(tmp))
 
 
+# What a PR that changes the program may do to the manifest: APPEND a cell
+# to `workloads` and an entry to `per_layer`, edit nothing. The tests that
+# hold an accepted cell's place take the manifest through conftest.py's
+# `grown` fixture, as committed and with these two appended, and pass in
+# both: a cell 7 meets no test that counts cells or holds a last place.
+APPENDED = "appended.search-open"
+APPENDED_MIX = "appended-search-open"
+APPENDED_METRIC = "batch_queries.appended"
+
+
+def _linked(src: str, dst: str) -> None:
+    """`dst`, a directory of links to `src`'s entries: one can be added."""
+    os.makedirs(dst)
+    for name in os.listdir(src):
+        os.symlink(os.path.join(src, name), os.path.join(dst, name))
+
+
+def appended_root(tmp: str) -> str:
+    """The committed data root (full size: links, nothing copied but the
+    manifest) with one more cell appended to `workloads`, an accepted
+    configuration under an accepted mix (its file under a name of the
+    cell's own, so the pair meets no cell that lands later), and one more
+    `per_layer` entry that lists only it, with its metric file."""
+    src, bdir = os.path.join(ROOT, "benchmark"), os.path.join(tmp, "benchmark")
+    os.makedirs(os.path.join(tmp, "tests"))
+    os.symlink(os.path.join(ROOT, "tests", "bench_harness"),
+               os.path.join(tmp, "tests", "bench_harness"))
+    for d in ("metrics", "traffic"):
+        _linked(os.path.join(src, d), os.path.join(bdir, d))
+    for d in ("configs", "kinds", "testdata"):
+        os.symlink(os.path.join(src, d), os.path.join(bdir, d))
+    shutil.copy(os.path.join(src, "traffic", "search-open.json"),
+                os.path.join(bdir, "traffic", APPENDED_MIX + ".json"))
+    shutil.copy(os.path.join(src, "metrics", "batch_queries.bool.json"),
+                os.path.join(bdir, "metrics", APPENDED_METRIC + ".json"))
+    doc = _read(ROOT, "BENCHMARK.json")
+    doc["workloads"].append({
+        "name": APPENDED, "config": "msmarco-passage-bm25",
+        "traffic": APPENDED_MIX, "chips": 1,
+        "why": "a later PR's cell: the north-star request once more"})
+    doc["per_layer"].append({
+        "name": APPENDED_METRIC, "unit": "queries", "better": "higher",
+        "source": "program_counter", "layer": "dispatch scheduler",
+        "moves": "search_p50_ms", "workloads": [APPENDED]})
+    _write(doc, tmp, "BENCHMARK.json")
+    return tmp
+
+
 # ---- what a cell is, read from its data ----------------------------------
 
 def loop_of(cell: Cell) -> str:
@@ -124,9 +172,10 @@ def data_of(cell: str) -> Cell:
     return REAL.cell(cell)
 
 
-def cells_where(pred) -> tuple:
-    """The cells (CELLS order) of whose data (`data_of`) `pred` holds."""
-    return tuple(c for c in CELLS if pred(data_of(c)))
+def cells_where(pred, manifest: Manifest = REAL) -> tuple:
+    """The cells (the manifest's order) of whose data `pred` holds: the
+    manifest as committed, or the one a test was handed."""
+    return tuple(c for c in manifest.cell_names() if pred(manifest.cell(c)))
 
 
 def steer_engines(mp, cache_dir: str) -> None:

@@ -18,3 +18,19 @@ def _aggregations_on_tiny_segments():
     mp.setattr(aggregations, "MAX_BUCKETS", 65536)
     yield
     mp.undo()
+
+
+@pytest.fixture(scope="session", params=["as_committed", "one_more_appended"])
+def grown(request, tmp_path_factory):
+    """The manifest a test that holds an accepted cell's place is handed:
+    as committed, and with one more cell and one more `per_layer` entry
+    appended (`bench_tiny.appended_root`). A test that passes in the
+    first case alone counts cells or holds a last place, and the next
+    cell's PR, which may not edit it, would fail it."""
+    import bench_tiny
+    from benchmark.manifest import Manifest
+
+    if request.param == "as_committed":
+        return bench_tiny.REAL
+    return Manifest(bench_tiny.appended_root(
+        str(tmp_path_factory.mktemp("appended"))))

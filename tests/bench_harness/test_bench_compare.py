@@ -26,9 +26,11 @@ for _c in bench_tiny.cells_where(
         lambda c: c.request_kind.numbers is compare.hit_list_numbers):
     HIT_LIST.setdefault(bench_tiny.data_of(_c).config_name, _c)
 ONE_CHIP = bench_tiny.data_of(bench_tiny.TWIN).config_name
-# the configurations that name what must and may not have moved
+# the configurations of more than one shard (a configuration may name
+# what must and may not have moved for another reason than a merge)
 SHARDED = sorted(c for c, cell in HIT_LIST.items()
-                 if "must_stay" in bench_tiny.data_of(cell).config)
+                 if bench_tiny.data_of(cell).config["index"].get(
+                     "shards", 1) > 1)
 
 
 @pytest.fixture(scope="module")

@@ -1,38 +1,39 @@
 """PR 42's per-layer metrics: ten files under benchmark/metrics/, each a
-`span_mean` over a histogram the program holds since this PR, and the ten
+`span_mean` over a histogram the program holds since that PR, and the ten
 `per_layer` entries that read them, rehearsed on the CPU at tiny size.
 
-The entries are NOT in BENCHMARK.json: they wait in
-`pending_per_layer.json` beside this file, as a `benchmark` PR will append
-them. A PR that changes the program may only append to `per_layer` (the
-driver refused them placed before `gen_late_ms.agg`, where ISSUE 42 put
-them), and `test_bench_filter_agg.py` holds the LAST entries of
-`per_layer` to be the logs cell's nine, so an appended entry fails that
-test until a `benchmark` PR rewrites its last assert. Here they are laid
-at the END of a tiny root's copy of the manifest, where the driver's rule
-puts them, and read through the harness as committed.
+The entries are IN BENCHMARK.json since PR 44, appended behind what PR 40
+left, in the table's order (they waited two PRs in a file beside this
+one: a PR that changes the program may only append to `per_layer`, and a
+test of the logs cell held the LAST entries to be that cell's nine). The
+four REST / route steps carry no `workloads` list: every cell passes the
+handler, the route step and the response, and a cell that comes later
+reads them with no entry of its own. The tests that hold the entries'
+place take the manifest through conftest.py's `grown`, as committed and
+with one more cell appended.
 """
 
 import json
 
 import pytest
 
-import os
-
 from benchmark import readers, run, trace, validate
-from benchmark.manifest import Manifest, name_faults
+from benchmark.manifest import name_faults
 
 import bench_tiny
 
 SEED = 2147483647
 CHIP = {"platform": "tpu", "kind": "TPU v5 lite"}
-ALL = bench_tiny.CELLS
-OPEN = bench_tiny.cells_where(lambda c: bench_tiny.loop_of(c) == "open")
-MATCH = bench_tiny.cells_where(
-    lambda c: bench_tiny.is_bm25(c)
-    and c.traffic["request"]["kind"] == "match")
+# the cells PR 42 wrote the entries for: their lists, which no later PR
+# may edit (a later bm25 or open cell reads these steps through entries
+# of its own)
+MATCH = ("msmarco-bm25.search-open", "msmarco-bm25.msearch-closed",
+         "msmarco-bm25-4shard.msearch-closed")
+OPEN = ("msmarco-bm25.search-open", "msmarco-knn.search-open",
+        "msmarco-bm25.bool-open", "http-logs.filter-agg-open")
+EVERY = None        # no `workloads` list: every cell's, a later cell's too
 
-# metric -> (histogram, layer, the cells its reader finds something in)
+# metric -> (histogram, layer, the cells its entry lists)
 TABLE = {
     "rescore_rows_ms.search": ("dispatch.rescore_rows", "host finish", MATCH),
     "rescore_survivors_ms.search": (
@@ -42,22 +43,29 @@ TABLE = {
     "merge_cert_ms.search": ("dispatch.merge_cert", "host finish", MATCH),
     "cert_fallback_ms.search": (
         "dispatch.cert_fallback", "host finish", MATCH),
-    "rest_total_ms.search": ("rest_total", "HTTP + thread pools", ALL),
-    "rest_parse_ms.search": ("rest.parse", "HTTP + thread pools", ALL),
-    "route_ms.search": ("route", "routing", ALL),
-    "rest_respond_ms.search": ("rest.respond", "HTTP + thread pools", ALL),
+    "rest_total_ms.search": ("rest_total", "HTTP + thread pools", EVERY),
+    "rest_parse_ms.search": ("rest.parse", "HTTP + thread pools", EVERY),
+    "route_ms.search": ("route", "routing", EVERY),
+    "rest_respond_ms.search": ("rest.respond", "HTTP + thread pools", EVERY),
     "sched_fill_ms.search": ("sched_fill", "dispatch scheduler", OPEN),
 }
-REAL = bench_tiny.REAL.doc["per_layer"]
-with open(os.path.join(os.path.dirname(__file__),
-                       "pending_per_layer.json")) as _f:
-    ENTRIES = json.load(_f)
+PR40_LAST = "agg_reduce_roofline_pct.agg"   # the entry the ten stand behind
 
 
-def test_the_table_is_the_issues():
-    assert MATCH == ("msmarco-bm25.search-open", "msmarco-bm25.msearch-closed",
-                     "msmarco-bm25-4shard.msearch-closed")
-    assert len(OPEN) == 4 and len(ALL) == 6 and len(ENTRIES) == 10
+def table_names(cell: str) -> list:
+    """The table's metrics a run of `cell` prints, in the table's order."""
+    return [n for n, (_h, _l, cells) in TABLE.items()
+            if cells is EVERY or cell in cells]
+
+
+def test_the_table_is_the_issues(grown):
+    # at least these, however many cells follow
+    assert set(MATCH) <= set(bench_tiny.cells_where(
+        lambda c: bench_tiny.is_bm25(c)
+        and c.traffic["request"]["kind"] == "match", grown))
+    assert set(OPEN) <= set(bench_tiny.cells_where(
+        lambda c: bench_tiny.loop_of(c) == "open", grown))
+    assert set(bench_tiny.CELLS) <= set(grown.cell_names())
     assert bench_tiny.TWIN in MATCH and bench_tiny.TWIN not in OPEN
 
 
@@ -79,51 +87,46 @@ def test_a_metric_file_names_span_mean_and_a_histogram_the_program_declares(
 
 @pytest.fixture(scope="module")
 def manifest(tmp_path_factory):
-    root = bench_tiny.tiny_root(str(tmp_path_factory.mktemp("tiny")))
-    path = os.path.join(root, "BENCHMARK.json")
-    with open(path) as f:
-        doc = json.load(f)
-    doc["per_layer"] += ENTRIES
-    with open(path, "w") as f:
-        json.dump(doc, f)
-    return Manifest(root)
+    return bench_tiny.tiny_manifest(str(tmp_path_factory.mktemp("tiny")))
 
 
-def test_the_ten_wait_beside_the_manifest_and_are_laid_at_its_end(manifest):
-    assert [m["name"] for m in ENTRIES] == list(TABLE)
-    # the manifest as committed holds none of them ...
-    assert not {m["name"] for m in REAL} & set(TABLE)
-    # ... and laid over, nothing that was there moved
-    laid = manifest.doc["per_layer"]
-    assert laid[:len(REAL)] == REAL and laid[len(REAL):] == ENTRIES
+def test_the_ten_are_in_behind_what_pr_40_left_in_the_tables_order(grown):
+    names = [m["name"] for m in grown.doc["per_layer"]]
+    at = names.index(PR40_LAST) + 1
+    assert names[at:at + len(TABLE)] == list(TABLE)
 
 
-def test_the_entries_fit_the_manifest_and_list_the_tables_cells(manifest):
-    doc = manifest.doc
+def test_the_entries_fit_the_manifest_and_list_the_tables_cells(grown):
+    doc = grown.doc
     assert name_faults(doc) == []
     assert len(json.dumps(doc)) < 64 * 1024
-    layers = {m["layer"] for m in REAL}
     by_name = {m["name"]: m for m in doc["per_layer"]}
     assert len(by_name) == len(doc["per_layer"])
-    for m in ENTRIES:
-        assert by_name[m["name"]] == m
-        assert m == {"name": m["name"], "unit": "ms", "better": "lower",
-                     "source": "program_span", "moves": "search_p50_ms",
-                     "layer": TABLE[m["name"]][1],
-                     "workloads": m["workloads"]}
-        assert tuple(m["workloads"]) == TABLE[m["name"]][2]
+    layers = {m["layer"] for m in doc["per_layer"] if m["name"] not in TABLE}
+    for name, (_hist, layer, cells) in TABLE.items():
+        m = by_name[name]
+        want = {"name": name, "unit": "ms", "better": "lower",
+                "source": "program_span", "moves": "search_p50_ms",
+                "layer": layer}
+        if cells is not EVERY:
+            want["workloads"] = list(cells)
+            # every listed cell reports what the metric moves; none lists
+            # one cell alone
+            for cell in cells:
+                assert m["moves"] in {x["name"]
+                                      for x in grown.end_to_end(cell)}
+            assert len(cells) >= 3
+        assert m == want
         # a layer the manifest already names, letter for letter
-        assert m["layer"] in layers or m["layer"] == "routing"
-        # every listed cell reports what the metric moves; none lists the
-        # logs cell alone (test_bench_filter_agg holds that set to nine)
-        for cell in m["workloads"]:
-            assert m["moves"] in {x["name"]
-                                  for x in manifest.end_to_end(cell)}
-        assert len(m["workloads"]) >= 3
-    for cell in ALL:
-        want = [n for n, (_h, _l, cells) in TABLE.items() if cell in cells]
-        got = [x["name"] for x in manifest.declared(cell, 1)]
-        assert [n for n in got if n in TABLE] == want
+        assert layer in layers or layer == "routing"
+    # what a cell prints follows: a cell appended later reads the REST
+    # path and the route with no entry of its own
+    for cell in grown.cell_names():
+        got = [x["name"] for x in grown.declared(cell, 1)]
+        assert [n for n in got if n in TABLE] == table_names(cell)
+        assert set(table_names(cell)) >= {
+            "rest_total_ms.search", "rest_parse_ms.search",
+            "route_ms.search", "rest_respond_ms.search"}
 
 
 @pytest.mark.parametrize("name", list(TABLE))
@@ -180,7 +183,7 @@ def test_a_traced_closed_window_prints_the_finish_by_its_steps(
         capsys.readouterr().err[-2000:]
     assert line["correct"] is True
     m = {k: v["value"] for k, v in line["metrics"].items()}
-    want = [n for n, (_h, _l, cells) in TABLE.items() if cell in cells]
+    want = table_names(cell)
     assert [n for n in m if n in TABLE] == want and len(want) == 9
     assert "sched_fill_ms.search" not in m     # its batches skip the lane
     rows, surv = m["rescore_rows_ms.search"], m["rescore_survivors_ms.search"]

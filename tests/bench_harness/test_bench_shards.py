@@ -83,14 +83,15 @@ def test_a_seeded_tiny_corpus_is_what_it_was_before_the_kinds_moved(config):
     assert (len(parts), h.hexdigest()) == GOLDEN_CORPUS[config]
 
 
-def test_the_committed_four_shard_file_is_the_recipe_plus_its_size_and_cuts():
+def test_the_committed_four_shard_file_is_the_recipe_plus_its_size_and_cuts(
+        grown):
     """benchmark/configs/<FOUR_CONFIG>.json = `four_shard_config()` (the
     accepted bm25 file, four shards of one segment, the path parameter,
     what must and must not move) and exactly what landing the cell added:
     the host's half of the collection, every cut named, the words about
     both. The recipe and the file cannot drift, and neither can the
     one-chip file it starts from."""
-    cell = bench_tiny.REAL.cell(FOUR)
+    cell = grown.cell(FOUR)
     assert (cell.chips, cell.config_name, cell.traffic_name) == (
         4, bench_tiny.FOUR_CONFIG, "msearch-closed-4shard")
     got, want = cell.config, bench_tiny.four_shard_config()
@@ -108,22 +109,21 @@ def test_the_committed_four_shard_file_is_the_recipe_plus_its_size_and_cuts():
         assert word in got["deployment"]
     # the traffic is the twin's but for the warm-up calls (and why): the
     # two cells send the same calls in their windows
-    twin = bench_tiny.REAL.cell(bench_tiny.TWIN).traffic
+    twin = grown.cell(bench_tiny.TWIN).traffic
     own = {k: v for k, v in cell.traffic.items() if k != "why"}
     assert own == dict(twin, warmup={"calls": 8}) and cell.traffic["why"]
 
 
-def test_hbm_peak_skew_reads_the_spread_of_the_chips_peaks():
+def test_hbm_peak_skew_reads_the_spread_of_the_chips_peaks(grown):
     """The fullest chip over the mean of the chips, from what a result
     line prints as `memory_peak_bytes_per_device`: even = 1.0, one chip of
     four holding everything = 4.0; where no device reports a peak (the
     CPU backend) there is nothing to read, never a 0."""
-    m = Manifest(ROOT)
-    entry = next(x for x in m.doc["per_layer"]
+    entry = next(x for x in grown.doc["per_layer"]
                  if x["name"] == "hbm_peak_skew.search")
     assert entry["workloads"] == [FOUR] and entry["better"] == "lower"
     assert (entry["moves"], entry["layer"]) == ("hbm_peak_gb", "device")
-    spec = m.metric_spec("hbm_peak_skew.search")
+    spec = grown.metric_spec("hbm_peak_skew.search")
     w = _roofline_window([], 4, {})
     for peaks, want in (([2 << 30] * 4, 1.0), ([8 << 30, 0, 0, 0], 4.0),
                         (bench_tiny.RECORDED_PEAKS, 2.3565),
