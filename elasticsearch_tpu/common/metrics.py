@@ -505,7 +505,7 @@ declare_histogram("merge", "ms", "coordinator reduce of shard results")
 declare_histogram("rest_total", "ms", "whole _search or _msearch request at the REST layer, on the pool worker: the handler from its first line to the response object (a _search's body is parsed before it opens, the response is encoded and written after it: rest.parse, rest.respond)")
 declare_histogram("rest.parse", "ms", "a search request's body from bytes to dicts: a _search's json.loads in the controller (before rest_total opens), or inside rest_total an _msearch's decode, its ndjson lines' json.loads and each header's index resolution")
 declare_histogram("rest.respond", "ms", "a _search or _msearch response's JSON encode and its write to the socket, on the HTTP thread, after rest_total (histogram and annotation only: that thread carries no trace context)")
-declare_histogram("route", "ms", "within rest_total, ONE observation a try_msearch (a _search is one of one body): the host work that decides who serves each body: plan extraction (extract_plan / extract_knn_plan / extract_filter_agg_plan), the snapshot, the servability checks, the grouping by field and route; the batches' dispatches and demux follow it")
+declare_histogram("route", "ms", "within rest_total, ONE observation a try_msearch (a _search is one of one body): the host work that decides who serves each body: plan extraction (extract_plan / extract_knn_plan / extract_hybrid_plan / extract_filter_agg_plan), the snapshot, the servability checks, the grouping by field and route; the batches' dispatches and demux follow it")
 declare_histogram("coalesce_batch_size", "count", "queries per coalesced device batch")
 declare_histogram("coalesce_pad_ratio", "ratio", "fraction of a padded device batch that is qc-quantization waste")
 # continuous-batching scheduler (PR 10); sched_tier_wait.* names are
@@ -558,6 +558,9 @@ declare_histogram("dispatch.bool_resolve", "ms", "within prep (BM25 bool route):
 declare_histogram("dispatch.phrase_build", "ms", "within prep (BM25 bool route): the positions scan of a phrase named for the first time and its adjacency column's build")
 declare_histogram("dispatch.bitset_pack", "ms", "within prep (BM25 bool route): packing the match-set bitsets from the column cache after it moved, and packing + writing the cold clauses' rows")
 declare_histogram("dispatch.dense_rerun", "ms", "within finish (kNN): dense route re-run of the uncertified queries")
+declare_histogram("dispatch.hybrid_bm25", "ms", "hybrid route (a body with query AND knn): the batch's BM25 side, around its serving_dispatch on the thread that runs it (the caller's); the engine's device span and its steps lie inside")
+declare_histogram("dispatch.hybrid_knn", "ms", "hybrid route: the batch's kNN side, around its serving_dispatch on the thread that runs it (one of its own beside the BM25 side's)")
+declare_histogram("dispatch.hybrid_join", "ms", "hybrid route, within demux (it IS this route's demux), after both sides, a batch: the exact BM25 point scores of the nearest documents the sweep did not return, the union ordered and cut (hybrid_join), hits.total with the documents only the knn section matches")
 # engine build steps (PR 27): they run in set-up or under the first
 # request of a field, so no benchmark reader (window deltas) sees them;
 # GET /_nodes/stats after start-up does

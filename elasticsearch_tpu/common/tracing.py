@@ -264,6 +264,22 @@ def _annotation_cls():
     return _ANNOTATION
 
 
+def name_thread(name: str) -> None:
+    """Give the CALLING thread its OS name (15 bytes at most): the
+    profiler's host plane files a thread's annotations under a line of
+    that name, taken when the thread's first one is recorded, and every
+    Python thread is otherwise a line called after the process. For
+    threads that carry spans side by side with others (a scheduler lane,
+    a hybrid batch's second side): call it first in the thread. Linux
+    only; elsewhere, and on any error, nothing happens."""
+    try:
+        import ctypes
+
+        ctypes.CDLL(None).prctl(15, name.encode()[:15], 0, 0, 0)
+    except Exception:   # noqa: BLE001 — a name is never worth a failure
+        pass
+
+
 def annotation(name: str, **stats: Any):
     """A bare profiler annotation ``es.<name>`` (context manager): live
     exactly while a profiler session is, no histogram and no span. For

@@ -199,6 +199,12 @@ class IndexService:
             # the error, not a dense retry, is the answer
             raise resp
         if resp is None:
+            from elasticsearch_tpu.search import serving
+
+            if serving.is_hybrid(request):
+                # a body with `query` AND `knn` that the device route
+                # declined (or failed): the host answers, and it shows
+                serving.count_hybrid(hybrid_host=1)
             resp = self._search_dense(request, search_type,
                                       searchers=searchers, task=task)
         if key is not None and not resp.get("timed_out"):

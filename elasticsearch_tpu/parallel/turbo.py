@@ -1719,6 +1719,25 @@ class TurboBM25:
             total = total + w * impacts
         return total
 
+    def point_scores(self, terms, docs: np.ndarray) -> np.ndarray:
+        """Exact f32 BM25 of one query at GIVEN docs of this partition
+        (0 where no term occurs, or the doc is not live): a host point
+        read of `_exact_scores`, at most len(docs) x the query's terms, no
+        sweep. `terms`: a term / (term, boost) list as `search_many` takes
+        it; duplicate terms sum and the terms this partition lacks fall
+        out as there, so a doc the sweep would have returned reads the
+        same bits here. For callers that hold candidates the sweep did not
+        propose (the hybrid route: the `k` nearest vectors)."""
+        docs = np.asarray(docs, np.int32)
+        (flat,), _ = _flatten_queries([[terms]])
+        qterms = [(t, b, info) for t, b in flat
+                  for info in (self._term(t),) if info is not None]
+        if not qterms or not len(docs):
+            return np.zeros(len(docs), np.float32)
+        out = self._exact_scores(qterms, docs)
+        out[self._live_host[docs] <= 0] = 0.0
+        return out
+
     # ---------------- search ----------------
 
     def search_many(self, batches: Sequence[List], k: int = 10, check=None):

@@ -2239,6 +2239,7 @@ class _Handlers:
             "tpu_hbm": _tpu_hbm_stats(),
             "tpu_agg": _tpu_agg_stats(),
             "tpu_knn": _tpu_knn_stats(),
+            "tpu_hybrid": _tpu_hybrid_stats(),
             "tpu_compile": _tpu_compile_stats(),
             "tpu_tasks": self.node.tasks.stats(),
             "tpu_overload": self.node.overload.stats(),
@@ -2747,6 +2748,15 @@ def _tpu_knn_stats() -> dict:
     from elasticsearch_tpu.parallel import knn
 
     return knn.knn_node_stats()
+
+
+def _tpu_hybrid_stats() -> dict:
+    """Hybrid route section (PR 45): bodies with `query` AND `knn` by who
+    answered them (both engines + the exact join, or the dense executor),
+    the join's point reads, and each side's time beside the batch's."""
+    from elasticsearch_tpu.search import serving
+
+    return serving.hybrid_node_stats()
 
 
 def _gc_stats() -> dict:

@@ -70,8 +70,9 @@ _ALLOWED_KEYS = {"query", "size", "from", "_source", "stored_fields",
                  "timeout", "allow_partial_search_results", "profile"}
 _MAX_K = 1000
 # kNN-only bodies: the same envelope plus the top-level `knn` section and
-# minus `query` (a body with BOTH stays on the dense executor, which owns
-# the combined bm25+vector scoring semantics)
+# minus `query`. A body with BOTH is a hybrid body (`extract_hybrid_plan`):
+# each section is held to its own route's envelope, and the two engines'
+# answers are joined (`hybrid_join`)
 _KNN_ALLOWED_KEYS = (_ALLOWED_KEYS | {"knn"}) - {"query"}
 
 # serving-path fault/containment counters (GET /_nodes/stats tpu_health)
@@ -90,6 +91,39 @@ def serving_fault_stats() -> dict:
 def _count_serving(key: str, n: int = 1) -> None:
     with _SERVING_LOCK:
         _SERVING_STATS[key] += n
+
+
+# the hybrid route's node counters (GET /_nodes/stats tpu_hybrid): bodies
+# with `query` AND `knn` by who answered them, the join's point reads, and
+# each side's wall time beside the batch's (their sum over the wall: 2.0 =
+# the two threads' sides overlap whole, 1.0 = not at all)
+_HYBRID_STATS = {"hybrid_device": 0, "hybrid_host": 0,
+                 "point_scored_docs": 0, "knn_only_hits": 0,
+                 "bm25_us": 0, "knn_us": 0,
+                 "wall_us": 0}  # guarded by: _SERVING_LOCK
+
+
+def is_hybrid(request: dict) -> bool:
+    """A body with a `query` AND a top-level `knn` section."""
+    return request.get("knn") is not None \
+        and request.get("query") is not None
+
+
+def count_hybrid(**rises: int) -> None:
+    with _SERVING_LOCK:
+        for key, n in rises.items():
+            _HYBRID_STATS[key] += int(n)
+
+
+def hybrid_node_stats() -> dict:
+    """The `tpu_hybrid` section of GET /_nodes/stats. `hybrid_device`:
+    bodies `_hybrid_batch` answered (both engines + the exact join);
+    `hybrid_host`: bodies the dense executor answered (the route declined
+    them, or a dispatch of theirs failed); `hybrid_queries`: both."""
+    with _SERVING_LOCK:
+        out = dict(_HYBRID_STATS)
+    return dict(out, hybrid_queries=out["hybrid_device"]
+                + out["hybrid_host"])
 
 
 def _note_reject_error(e: BaseException, where: str) -> None:
@@ -234,6 +268,111 @@ def _knn_filter_mask(fplan: FlatPlan, part) -> np.ndarray:
         for t in terms:
             mask[_post_docs(fpf, t)] = False
     return mask
+
+
+@dataclass
+class HybridPlan:
+    """A body with `query` AND `knn`: its two sections, each as its own
+    route's plan."""
+
+    query: FlatPlan
+    knn: KnnPlan
+
+
+def extract_hybrid_plan(request: dict, mapper) -> Optional[HybridPlan]:
+    """Elasticsearch's hybrid body (a top-level `knn` section BESIDE a
+    `query`: the hits are the query's matches united with the `k` nearest
+    vectors, a document's score the SUM of the two where it is in both)
+    as the plans of the two routes it joins, or None for the dense
+    executor. `query` must flatten to a disjunction on one text field
+    (what `_disjunctive_batch` serves), `knn` pass `extract_knn_plan`'s
+    checks (one section, boost 1.0, a vector field, an optional postings
+    filter); the envelope is each route's own."""
+    if not is_hybrid(request):
+        return None
+    plan = extract_plan({k: v for k, v in request.items() if k != "knn"},
+                        mapper)
+    if plan is None or not plan.is_disjunctive:
+        return None
+    kplan = extract_knn_plan(
+        {k: v for k, v in request.items() if k != "query"}, mapper)
+    if kplan is None:
+        return None
+    return HybridPlan(query=plan, knn=kplan)
+
+
+def hybrid_join(bm_hits, nn_hits, point_scores, k: int):
+    """One hybrid query's answer from its two sides, exact.
+
+    `bm_hits`: the BM25 side's top `k` [(partition, ord, score)];
+    `nn_hits`: the `knn.k` nearest [(partition, ord, vector score)];
+    `point_scores(partition, ords)`: the exact f32 BM25 of nearest
+    documents the sweep did not return (0 where none of the query's terms
+    occurs). Score = f32 BM25 + f32 vector score where the document is
+    among the nearest; order (score desc, partition asc, ord asc), the
+    convention of both engines' merges; cut to `k`. Returns (hits,
+    documents point-read, nearest documents that match no term).
+
+    Why the union is enough: the vector score of this engine is positive
+    ((1 + cos) / 2; `KnnEngine` refuses negative similarities), so a
+    document OUTSIDE the nearest scores its BM25 alone, and one outside
+    BM25's top `k` as well has at least `k` documents above it on BM25
+    alone, each of which scores at least its BM25 in the sum: it cannot
+    enter the top `k`. Every document that can is in one of the two
+    lists, and every one of those carries its exact sum."""
+    sums = {(p, o): np.float32(s) for p, o, s in bm_hits}
+    need: Dict[int, List[int]] = {}
+    for p, o, _ in nn_hits:
+        if (p, o) not in sums:
+            need.setdefault(p, []).append(o)
+    n_point = n_knn_only = 0
+    for p, ords in need.items():
+        for o, s in zip(ords, point_scores(p, np.asarray(ords, np.int32))):
+            sums[(p, o)] = np.float32(s)
+            n_knn_only += int(s <= 0)
+        n_point += len(ords)
+    for p, o, v in nn_hits:
+        sums[(p, o)] = sums[(p, o)] + np.float32(v)
+    best = sorted(sums.items(), key=lambda kv: (-kv[1], kv[0]))[:k]
+    return ([(p, o, float(s)) for (p, o), s in best], n_point, n_knn_only)
+
+
+def _both(first, second, **meta):
+    """Two (phase name, call) sides of one batch, each under its phase:
+    `second` on a thread of its own beside `first` on the caller's, with
+    the caller's trace context and SLA tier. Returns ((result, ms),
+    (result, ms)); an error of `first`, else of `second`, is raised once
+    both have ended."""
+    from elasticsearch_tpu.threadpool.scheduler import (
+        activate_tier, current_tier,
+    )
+
+    tc, parent, tier = tracing.current(), tracing.current_span(), \
+        current_tier()
+
+    def side(name, call, box):
+        ph = tracing.phase(name, **meta)
+        try:
+            with tracing.activate(tc, parent), activate_tier(tier), ph:
+                box["out"] = call()
+        except BaseException as e:   # noqa: BLE001 — re-raised by the caller
+            box["err"] = e
+        box["ms"] = ph.ms
+
+    def beside():
+        tracing.name_thread("es-hybrid-side")   # its own profile line
+        side(*second, b)
+
+    a: dict = {}
+    b: dict = {}
+    t = threading.Thread(target=beside, name="es-hybrid-side")
+    t.start()
+    side(*first, a)
+    t.join()
+    for box in (a, b):
+        if "err" in box:
+            raise box["err"]
+    return (a["out"], a["ms"]), (b["out"], b["ms"])
 
 
 def extract_plan(request: dict, mapper) -> Optional[FlatPlan]:
@@ -955,6 +1094,12 @@ class TurboEngine:
         self._health_account(log, n0)
         return out
 
+    def point_scores(self, terms, part: int, docs) -> np.ndarray:
+        """Exact f32 BM25 of one disjunction at given docs of partition
+        `part` (`TurboBM25.point_scores`: a host point read, no sweep):
+        what a caller needs of documents the sweep did not propose."""
+        return self.turbos[part].point_scores(terms, docs)
+
     def search_phrase(self, phrases: Sequence[List[str]], k: int = 10,
                       slop: int = 0, check=None, fault_log=None):
         """Batched match_phrase top-k; slop-0 rides the adjacency
@@ -1424,7 +1569,7 @@ class ServingContext:
             routed = self._route(requests, search_type)
             if routed is None:
                 return out
-            snap, plans, kplans, aplans, groups = routed
+            snap, plans, kplans, hplans, aplans, groups = routed
             ph.meta.update((name, sum(map(len, g.values()))
                             if isinstance(g, dict) else len(g))
                            for name, g in groups.items())
@@ -1449,6 +1594,11 @@ class ServingContext:
         for field, idxs in groups["knn"].items():
             serve(idxs, self._knn_batch, "knn_batch", field,
                   [kplans[i] for i in idxs], [requests[i] for i in idxs])
+        # hybrid bodies (`query` AND `knn`) on the same pair of fields:
+        # both engines of the snapshot for one batch, joined exactly
+        for fields, idxs in groups["hybrid"].items():
+            serve(idxs, self._hybrid_batch, "hybrid_batch", fields,
+                  [hplans[i] for i in idxs], [requests[i] for i in idxs])
         for i in groups["host"]:
             try:
                 if task is not None:
@@ -1478,33 +1628,44 @@ class ServingContext:
     def _route(self, requests: Sequence[dict], search_type: str):
         """`try_msearch`'s `route` step, the host work that decides who
         serves each body: every body's plan (`extract_plan`, else
-        `extract_knn_plan`, else `extract_filter_agg_plan`), the snapshot,
-        the servability checks and the grouping by route and field. None
-        where no body is the fast path's; else (snap, plans, kplans,
-        aplans, groups), `groups` the bodies' indices a route: "agg" [i],
-        "knn" / "bool" / "disj" {field: [i]}, "host" [i] (a conjunctive
-        plan no Turbo engine serves: the host columnar path)."""
+        `extract_knn_plan`, else `extract_hybrid_plan`, else
+        `extract_filter_agg_plan`), the snapshot, the servability checks
+        and the grouping by route and field. None where no body is the
+        fast path's; else (snap, plans, kplans, hplans, aplans, groups),
+        `groups` the bodies' indices a route: "agg" [i], "knn" / "bool" /
+        "disj" {field: [i]}, "hybrid" {(text field, vector field): [i]},
+        "host" [i] (a conjunctive plan no Turbo engine serves: the host
+        columnar path)."""
         if len(self.svc.shards) > 1 and search_type != "dfs_query_then_fetch":
             return None
-        plans = [extract_plan(r, self.svc.mapper) for r in requests]
-        kplans = [extract_knn_plan(r, self.svc.mapper) if p is None else None
+        mapper = self.svc.mapper
+        plans = [extract_plan(r, mapper) for r in requests]
+        kplans = [extract_knn_plan(r, mapper) if p is None else None
                   for p, r in zip(plans, requests)]
-        aplans = [extract_filter_agg_plan(r, self.svc.mapper)
-                  if p is None and kp is None else None
-                  for p, kp, r in zip(plans, kplans, requests)]
-        if not any(plans) and not any(kplans) and not any(aplans):
+        # (a body with `query` and `knn` is neither of the two above)
+        hplans = [extract_hybrid_plan(r, mapper) for r in requests]
+        aplans = [extract_filter_agg_plan(r, mapper)
+                  if p is None and kp is None and hp is None else None
+                  for p, kp, hp, r in zip(plans, kplans, hplans, requests)]
+        if not any(plans) and not any(kplans) and not any(hplans) \
+                and not any(aplans):
             return None
         snap = self.snapshot()
         if snap.total_docs == 0:
             return None
-        groups: Dict[str, Any] = {
-            "agg": [], "knn": {}, "host": [], "bool": {}, "disj": {}}
+        groups: Dict[str, Any] = {"agg": [], "knn": {}, "hybrid": {},
+                                  "host": [], "bool": {}, "disj": {}}
         if any(aplans) and self._filter_agg_servable(snap):
             groups["agg"] = [i for i, ap in enumerate(aplans)
                              if ap is not None]
         for i, kp in enumerate(kplans):
             if kp is not None:
                 groups["knn"].setdefault(kp.field, []).append(i)
+        for i, hp in enumerate(hplans):
+            if hp is not None and self._hybrid_servable(hp, snap,
+                                                        requests[i]):
+                groups["hybrid"].setdefault(
+                    (hp.query.field, hp.knn.field), []).append(i)
         for i, plan in enumerate(plans):
             if plan is None:
                 continue
@@ -1515,7 +1676,7 @@ class ServingContext:
                 groups["bool"].setdefault(plan.field, []).append(i)
             else:
                 groups["host"].append(i)
-        return snap, plans, kplans, aplans, groups
+        return snap, plans, kplans, hplans, aplans, groups
 
     def try_query_phase(self, request: dict, task=None):
         """QUERY-PHASE-ONLY fast path for the DISTRIBUTED shard executor
@@ -1730,30 +1891,23 @@ class ServingContext:
                     raise DispatchDeadlineError()
         return check
 
-    def _disjunctive_batch(self, field: str, plans, requests, snap, task=None):
-        start = time.monotonic()
-        bm = snap.engine(field)
-        k = max(int(r.get("from", 0)) + int(r.get("size", 10))
-                for r in requests)
-        queries = [p.disj for p in plans]
+    def _dispatched(self, requests, snap, task, start, eng, run, extract,
+                    on_fault=None):
+        """What the device routes share around their engine call: the
+        requests' deadlines and the cooperative check, `run(check, flog)`
+        (the dispatch; its answer is `extract`'s) with an expired deadline
+        answered as timed-out partials and a `DeviceFaultError` that left
+        the engine as None for every body (the dense executor's;
+        `on_fault(e)` feeds the circuit, None = the error is the
+        caller's), `extract(answer)` -> every body's (hits, total,
+        relation) under `demux`, and the `_respond` loop. `eng` names the
+        tier in a profiled body's DeviceDispatch node."""
         deadlines = [self._deadline_for(r) for r in requests]
         check = self._combined_check(task, deadlines)
-        # TurboEngine degrades itself (internal circuit + host tier);
-        # engines that can't (BlockMax) get the circuit enforced here,
-        # with the dense executor as their fallback tier
-        health = (getattr(bm, "health", None)
-                  if getattr(bm, "kind", "") != "turbo" else None)
-        if health is not None and not health.allow_device():
-            health.record_fallback(len(queries))
-            return [None] * len(requests)
         flog: List[FaultRecord] = []
-        # small batches continuous-batch with concurrent dispatches on the
-        # same engine (threadpool/scheduler); large msearch batches go
-        # direct
         try:
             t_dev = time.monotonic()
-            scores, parts, ords = serving_dispatch(
-                bm, queries, k, check=check, fault_log=flog)
+            answer = run(check, flog)
             dev_ms = (time.monotonic() - t_dev) * 1e3
         except DispatchDeadlineError:
             _count_serving("fastpath_timed_out")
@@ -1763,97 +1917,18 @@ class ServingContext:
                     if d is not None and d.timed_out else None
                     for r, d in zip(requests, deadlines)]
         except DeviceFaultError as e:
-            if health is not None:
-                health.record_fault(e)
-            _count_serving("fastpath_device_fault")
-            return [None] * len(requests)
-        if health is not None:
-            health.record_success()
-        if flog:
-            _count_serving("shard_fault_recoveries", len(flog))
-        extracted = []
-        with tracing.phase("demux", batch=len(requests)):
-            for qi, (plan, request) in enumerate(zip(plans, requests)):
-                hits = []
-                for j in range(k):
-                    if scores[qi, j] <= 0 or not np.isfinite(scores[qi, j]):
-                        break
-                    hits.append((int(parts[qi, j]), int(ords[qi, j]),
-                                 float(scores[qi, j])))
-                total, relation = self._disj_total(plan, snap, request,
-                                                   len(hits))
-                extracted.append((hits, total, relation))
-        results = []
-        for qi, request in enumerate(requests):
-            hits, total, relation = extracted[qi]
-            d = deadlines[qi]
-            try:
-                results.append(self._respond(
-                    request, snap, hits, total, relation, start,
-                    timed_out=bool(d is not None and d.expired),
-                    faults=flog,
-                    profile_nodes=fastpath_profile_nodes(request, bm, dev_ms)
-                    if request.get("profile") else None))
-            except SearchPhaseExecutionError as e:
-                results.append(e)
-        return results
-
-    def _knn_batch(self, field: str, kplans, requests, snap, task=None):
-        """kNN-only bodies on one vector field: resolve each filter to
-        per-partition candidate masks (postings unions — the BM25 sweep's
-        candidate set) and serve filter + kNN in ONE quantized dispatch
-        per chunk. None per body where the dense executor must run."""
-        from elasticsearch_tpu.parallel.knn import KnnWork
-
-        start = time.monotonic()
-        eng = snap.knn_engine(field)
-        if eng is None:
-            return [None] * len(requests)
-        k = max(kp.k for kp in kplans)
-        works = []
-        for kp in kplans:
-            filters = None
-            if kp.filter_plan is not None:
-                filters = [_knn_filter_mask(kp.filter_plan, p)
-                           for p in snap.partitions]
-            works.append(KnnWork(np.asarray(kp.vector, np.float32),
-                                 filters=filters))
-        deadlines = [self._deadline_for(r) for r in requests]
-        check = self._combined_check(task, deadlines)
-        flog: List[FaultRecord] = []
-        # KnnEngine degrades itself (internal circuit + host-exact tier),
-        # so unlike BlockMax no external circuit enforcement is needed
-        try:
-            t_dev = time.monotonic()
-            scores, parts, ords = serving_dispatch(
-                eng, works, k, check=check, fault_log=flog)
-            dev_ms = (time.monotonic() - t_dev) * 1e3
-        except DispatchDeadlineError:
-            _count_serving("fastpath_timed_out")
-            return [self._timed_out_response(r, snap, start)
-                    if d is not None and d.timed_out else None
-                    for r, d in zip(requests, deadlines)]
-        except DeviceFaultError as e:
-            eng.health.record_fault(e)
+            if on_fault is None:
+                raise
+            on_fault(e)
             _count_serving("fastpath_device_fault")
             return [None] * len(requests)
         if flog:
             _count_serving("shard_fault_recoveries", len(flog))
-        extracted = []
         with tracing.phase("demux", batch=len(requests)):
-            for qi, kp in enumerate(kplans):
-                hits = []
-                for j in range(min(k, kp.k)):
-                    if scores[qi, j] <= 0 or not np.isfinite(scores[qi, j]):
-                        break
-                    hits.append((int(parts[qi, j]), int(ords[qi, j]),
-                                 float(scores[qi, j])))
-                # kNN totals are the k nearest by definition, always exact
-                extracted.append((hits, len(hits), "eq"))
+            extracted = extract(answer)
         results = []
-        for qi, request in enumerate(requests):
-            hits, total, relation = extracted[qi]
-            d = deadlines[qi]
+        for request, d, (hits, total, relation) in zip(requests, deadlines,
+                                                       extracted):
             try:
                 results.append(self._respond(
                     request, snap, hits, total, relation, start,
@@ -1865,7 +1940,176 @@ class ServingContext:
                 results.append(e)
         return results
 
-    def _disj_total(self, plan, snap, request, n_found) -> Tuple[int, str]:
+    @staticmethod
+    def _hit_rows(answer, qi: int, k: int) -> List[Tuple[int, int, float]]:
+        """Row `qi` of an engine's (scores, parts, ords) as (partition,
+        ord, score) hits: its first `k` slots, up to the first empty."""
+        scores, parts, ords = answer
+        hits = []
+        for j in range(k):
+            s = float(scores[qi, j])
+            if s <= 0 or not np.isfinite(s):
+                break
+            hits.append((int(parts[qi, j]), int(ords[qi, j]), s))
+        return hits
+
+    def _disjunctive_batch(self, field: str, plans, requests, snap, task=None):
+        start = time.monotonic()
+        bm = snap.engine(field)
+        k = max(int(r.get("from", 0)) + int(r.get("size", 10))
+                for r in requests)
+        queries = [p.disj for p in plans]
+        # TurboEngine degrades itself (internal circuit + host tier);
+        # engines that can't (BlockMax) get the circuit enforced here,
+        # with the dense executor as their fallback tier
+        health = (getattr(bm, "health", None)
+                  if getattr(bm, "kind", "") != "turbo" else None)
+        if health is not None and not health.allow_device():
+            health.record_fallback(len(queries))
+            return [None] * len(requests)
+
+        def run(check, flog):
+            # small batches continuous-batch with concurrent dispatches on
+            # the same engine (threadpool/scheduler); large msearch
+            # batches go direct
+            answer = serving_dispatch(bm, queries, k, check=check,
+                                      fault_log=flog)
+            if health is not None:
+                health.record_success()
+            return answer
+
+        def extract(answer):
+            rows = [self._hit_rows(answer, qi, k)
+                    for qi in range(len(requests))]
+            return [(hits,) + self._disj_total(plan, snap, request, len(hits))
+                    for hits, plan, request in zip(rows, plans, requests)]
+
+        def on_fault(e):
+            if health is not None:
+                health.record_fault(e)
+
+        return self._dispatched(requests, snap, task, start, bm, run,
+                                extract, on_fault=on_fault)
+
+    def _knn_batch(self, field: str, kplans, requests, snap, task=None):
+        """kNN-only bodies on one vector field: resolve each filter to
+        per-partition candidate masks (postings unions — the BM25 sweep's
+        candidate set) and serve filter + kNN in ONE quantized dispatch
+        per chunk. None per body where the dense executor must run."""
+        start = time.monotonic()
+        eng = snap.knn_engine(field)
+        if eng is None:
+            return [None] * len(requests)
+        k = max(kp.k for kp in kplans)
+        works = self._knn_works(kplans, snap)
+
+        def extract(answer):
+            rows = [self._hit_rows(answer, qi, min(k, kp.k))
+                    for qi, kp in enumerate(kplans)]
+            # kNN totals are the k nearest by definition, always exact
+            return [(hits, len(hits), "eq") for hits in rows]
+
+        # KnnEngine degrades itself (internal circuit + host-exact tier),
+        # so unlike BlockMax no external circuit enforcement is needed
+        return self._dispatched(
+            requests, snap, task, start, eng,
+            lambda check, flog: serving_dispatch(
+                eng, works, k, check=check, fault_log=flog),
+            extract, on_fault=eng.health.record_fault)
+
+    @staticmethod
+    def _knn_works(kplans, snap) -> list:
+        """The plans as the kNN engine's works, each filter resolved to
+        its per-partition candidate masks."""
+        from elasticsearch_tpu.parallel.knn import KnnWork
+
+        works = []
+        for kp in kplans:
+            filters = None
+            if kp.filter_plan is not None:
+                filters = [_knn_filter_mask(kp.filter_plan, p)
+                           for p in snap.partitions]
+            works.append(KnnWork(np.asarray(kp.vector, np.float32),
+                                 filters=filters))
+        return works
+
+    # ---- hybrid: both engines of one snapshot, joined exactly ----
+
+    def _hybrid_servable(self, hp: HybridPlan, snap, request) -> bool:
+        """The route is taken where the index is one shard, the text
+        field's engine serves the disjunction AND offers the exact point
+        score of a given document (`point_scores`: Turbo does, BlockMax
+        does not), and the vector field has its engine. Anything else is
+        the dense executor's, and counted (`tpu_hybrid.hybrid_host`)."""
+        return (len(self.svc.shards) == 1
+                and self._disj_servable(hp.query, snap, request)
+                and hasattr(snap.engine(hp.query.field), "point_scores")
+                and snap.knn_engine(hp.knn.field) is not None)
+
+    def _hybrid_batch(self, fields, hplans, requests, snap, task=None):
+        """Hybrid bodies on one (text field, vector field): the batch's
+        disjunctions to the BM25 engine and its vectors to the kNN engine
+        (the calls `_disjunctive_batch` and `_knn_batch` make, side by
+        side: they share no state), then `hybrid_join` a query: the exact
+        top from + size of (BM25 + vector score of the `k` nearest), no
+        candidate cut on either side. `hits.total` = the disjunction's
+        count plus the nearest documents no term matches."""
+        start = time.monotonic()
+        field, vfield = fields
+        bm, eng = snap.engine(field), snap.knn_engine(vfield)
+        n = len(requests)
+        ks = [int(r.get("from", 0)) + int(r.get("size", 10))
+              for r in requests]
+        queries = [hp.query.disj for hp in hplans]
+        works = self._knn_works([hp.knn for hp in hplans], snap)
+        nn_k = max(hp.knn.k for hp in hplans)
+
+        def run(check, flog):
+            # a log of its own: Turbo reads what ITS call appended to the
+            # one it is handed as its own faults
+            knn_flog: List[FaultRecord] = []
+            t0 = time.monotonic_ns()
+            (bm_out, bm_ms), (nn_out, nn_ms) = _both(
+                ("dispatch.hybrid_bm25", lambda: serving_dispatch(
+                    bm, queries, max(ks), check=check, fault_log=flog)),
+                ("dispatch.hybrid_knn", lambda: serving_dispatch(
+                    eng, works, nn_k, check=check, fault_log=knn_flog)),
+                queries=n)
+            flog.extend(knn_flog)
+            count_hybrid(bm25_us=bm_ms * 1e3, knn_us=nn_ms * 1e3,
+                         wall_us=(time.monotonic_ns() - t0) // 1000)
+            return bm_out, nn_out
+
+        def extract(answer):
+            # the hybrid's demux IS the join: `demux` holds it whole
+            bm_out, nn_out = answer
+            joined, n_point, n_only = [], 0, 0
+            with tracing.phase("dispatch.hybrid_join", queries=n):
+                for qi, (hp, request) in enumerate(zip(hplans, requests)):
+                    hits, pts, only = hybrid_join(
+                        self._hit_rows(bm_out, qi, ks[qi]),
+                        self._hit_rows(nn_out, qi, hp.knn.k),
+                        lambda p, ords, q=queries[qi]:
+                            bm.point_scores(q, p, ords),
+                        ks[qi])
+                    joined.append((hits,) + self._disj_total(
+                        hp.query, snap, request, len(hits), extra=only))
+                    n_point += pts
+                    n_only += only
+            count_hybrid(hybrid_device=n, point_scored_docs=n_point,
+                         knn_only_hits=n_only)
+            return joined
+
+        # Turbo contains its device faults itself (circuit + host tier):
+        # one that leaves a side is the kNN engine's, as in `_knn_batch`
+        return self._dispatched(requests, snap, task, start, bm, run,
+                                extract, on_fault=eng.health.record_fault)
+
+    def _disj_total(self, plan, snap, request, n_found,
+                    extra: int = 0) -> Tuple[int, str]:
+        """The disjunction's hit count under the track_total_hits cap;
+        `extra`: documents that match by another section of the body (a
+        hybrid body's nearest documents that hold none of the terms)."""
         track = request.get("track_total_hits", 10000)
         if track is False:
             return n_found, "gte"
@@ -1893,6 +2137,7 @@ class ServingContext:
                 continue
             u = arrs[0] if len(arrs) == 1 else np.unique(np.concatenate(arrs))
             count += int(p.live[u].sum()) if not p.all_live else len(u)
+        count += extra
         if count > track_n:
             return track_n, "gte"
         return count, "eq"
@@ -1943,49 +2188,19 @@ class ServingContext:
         eng = snap.engine(field)
         k = max(int(r.get("from", 0)) + int(r.get("size", 10))
                 for r in requests)
-        deadlines = [self._deadline_for(r) for r in requests]
-        check = self._combined_check(task, deadlines)
-        flog: List[FaultRecord] = []
         counted = np.zeros(len(plans), np.int64)
-        try:
-            t_dev = time.monotonic()
-            scores, parts, ords = serving_dispatch(
+
+        def extract(answer):
+            return [(self._hit_rows(answer, qi, k),) + self._conj_total(
+                plan, snap, request, int(counted[qi]))
+                for qi, (plan, request) in enumerate(zip(plans, requests))]
+
+        return self._dispatched(
+            requests, snap, task, start, eng,
+            lambda check, flog: serving_dispatch(
                 eng, [_turbo_bool_spec(p) for p in plans], k, check=check,
-                fault_log=flog, totals=counted)
-            dev_ms = (time.monotonic() - t_dev) * 1e3
-        except DispatchDeadlineError:
-            _count_serving("fastpath_timed_out")
-            return [self._timed_out_response(r, snap, start)
-                    if d is not None and d.timed_out else None
-                    for r, d in zip(requests, deadlines)]
-        if flog:
-            _count_serving("shard_fault_recoveries", len(flog))
-        extracted = []
-        with tracing.phase("demux", batch=len(requests)):
-            for qi, (plan, request) in enumerate(zip(plans, requests)):
-                hits = []
-                for j in range(k):
-                    s = float(scores[qi, j])
-                    if s <= 0 or not np.isfinite(s):
-                        break
-                    hits.append((int(parts[qi, j]), int(ords[qi, j]), s))
-                extracted.append((hits,) + self._conj_total(
-                    plan, snap, request, int(counted[qi])))
-        results = []
-        for qi, request in enumerate(requests):
-            hits, total, relation = extracted[qi]
-            d = deadlines[qi]
-            try:
-                results.append(self._respond(
-                    request, snap, hits, total, relation, start,
-                    timed_out=bool(d is not None and d.expired),
-                    faults=flog,
-                    profile_nodes=fastpath_profile_nodes(
-                        request, eng, dev_ms)
-                    if request.get("profile") else None))
-            except SearchPhaseExecutionError as e:
-                results.append(e)
-        return results
+                fault_log=flog, totals=counted),
+            extract)
 
     def _conjunctive(self, plan, snap, request, start, task=None):
         """The host columnar path: a conjunctive plan no Turbo engine

@@ -606,6 +606,9 @@ class AdaptiveDispatchScheduler:
     # ---- the per-lane dispatch thread ----
 
     def _lane_loop(self, lane: _Lane) -> None:
+        # a line of its own in a profile: two engines' lanes may be parked
+        # and dispatching side by side (a hybrid body waits on both)
+        tracing.name_thread(f"es-lane-{lane.key[0]}/{lane.key[1]}")
         try:
             while True:
                 with lane.lock:
