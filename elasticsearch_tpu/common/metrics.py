@@ -316,6 +316,15 @@ declare_counter("knn_bytes",
 declare_counter("knn_uncertified",
                 "queries whose int8 superset certificate failed and were "
                 "re-served through the exact f32 first pass")
+declare_counter("knn_dense_mask_free",
+                "dense-route (partition, chunk) programs that sent no mask: "
+                "no query filtered the partition, the resident ok row is all")
+declare_counter("knn_dense_masked",
+                "dense-route (partition, chunk) programs that uploaded "
+                "their queries' filters, as bits")
+declare_counter("knn_dense_mask_bytes",
+                "bytes of filter bits uploaded to the dense route "
+                "(cumulative)")
 
 # cross-cluster plane (PR 20): CCS counters bumped by cluster/remote.py
 # (the `tpu_ccs` section of GET /_nodes/stats), CCR counters by

@@ -104,7 +104,9 @@ _MERGE_ORD_MAX = 1 << 24   # device merge packs ordinals into 24 bits
 _COUNTS_LOCK = threading.Lock()
 _COUNTS = {"knn_queries": 0, "knn_int8_dispatches": 0,
            "knn_rescore_docs": 0, "knn_host_fallbacks": 0,
-           "knn_bytes": 0, "knn_uncertified": 0}   # guarded by: _COUNTS_LOCK
+           "knn_bytes": 0, "knn_uncertified": 0,
+           "knn_dense_mask_free": 0, "knn_dense_masked": 0,
+           "knn_dense_mask_bytes": 0}              # guarded by: _COUNTS_LOCK
 
 _ENGINES: "weakref.WeakSet[KnnEngine]" = weakref.WeakSet()
 
@@ -295,14 +297,41 @@ def _rescore_program(qf, rows, nrmg, okg, ordg, u_excl, *,
             jnp.where(valid, top_o, 0), certified)
 
 
+def _filter_bits_width(n: int) -> int:
+    """Bytes a query of `_pack_filter`'s rows: n >= 1 bits, rounded up to
+    whole 128-byte lanes so the eight bit planes unpack lane-aligned."""
+    return -(-n // 1024) * 128
+
+
+_PLANE_SHIFTS = np.arange(8, dtype=np.uint8)[:, None]
+
+
+def _pack_filter(flt: np.ndarray, nb: int) -> np.ndarray:
+    """One query's [n] bool doc filter as [nb] uint8, bit-PLANE order: doc
+    d is bit d // nb of byte d % nb, so the device unpacks with eight
+    shifts and a concatenation along the doc axis (no [.., 8] minor
+    dimension to re-tile). Bits past n are 0."""
+    planes = np.zeros(8 * nb, np.uint8)
+    planes[:len(flt)] = flt
+    # eight contiguous shifts + ors: np.packbits down axis 0 reads the
+    # planes a strided byte at a time and takes eight times as long
+    return np.bitwise_or.reduce(planes.reshape(8, nb) << _PLANE_SHIFTS,
+                                axis=0)
+
+
 @functools.partial(jax.jit, static_argnames=("similarity", "k"))
-def _dense_topk(qf, vectors, norms, exists, qmask, *,
+def _dense_topk(qf, vectors, norms, ok, fbits=None, *,
                 similarity: str, k: int):
     """The f32 brute-force reference route (ES_TPU_KNN_INT8=0 A/B and
-    uncertified re-runs): ops.knn.knn_scores + per-query mask + top_k —
-    bit-identical to knn_top_k for any broadcast mask."""
-    sc = knn_scores(qf, vectors, norms, exists, similarity=similarity)
-    sc = jnp.where(qmask, sc, -jnp.inf)
+    uncertified re-runs): ops.knn.knn_scores over the resident `ok` =
+    exists & live row + (only where a query of the chunk carries one) the
+    per-query filters, `_pack_filter` rows [QC, nb] u8 unpacked here +
+    top_k — bit-identical to knn_top_k under the mask ok & filter."""
+    sc = knn_scores(qf, vectors, norms, ok, similarity=similarity)
+    if fbits is not None:
+        keep = jnp.concatenate(
+            [(fbits >> b) & 1 for b in range(8)], axis=1)[:, :sc.shape[1]]
+        sc = jnp.where(keep != 0, sc, -jnp.inf)
     ts, to = jax.lax.top_k(sc, k)
     valid = ts > -jnp.inf
     return jnp.where(valid, ts, 0.0), jnp.where(valid, to, 0)
@@ -526,21 +555,24 @@ class KnnEngine:
         """Lazily upload partition i's bf16 mirror for the dense f32
         brute-force route (the INT8=0 A/B path and uncertified re-runs).
         device cast of the SAME host f32 rows the reference uploads —
-        bitwise-equal bf16 values."""
+        bitwise-equal bf16 values. The third array is the `ok` = exists &
+        live row: the route's whole mask unless a query carries a filter,
+        kept current by `set_live`."""
         if self._dense[i] is not None:
             return
         with tracing.phase("engine_build.dense_mirror", partition=i), \
                 faults.device_errors("column_upload"):
             trip = (jnp.asarray(self._vecs[i]).astype(jnp.bfloat16),
                     jnp.asarray(self._norms[i]),
-                    jnp.asarray(self._exists[i]))
+                    jnp.asarray(self._ok[i]))
         self._dense[i] = trip
         _count("knn_bytes", sum(a.nbytes for a in trip))
         self._register_hbm_regions()
 
     def set_live(self, i: int, live: np.ndarray) -> None:
         """Refresh one partition's live mask (deletes): host meta update
-        + one device re-upload of the okf row, under the column_upload
+        + one device re-upload of the okf row (and of the dense mirror's
+        `ok` row where the mirror exists), under the column_upload
         containment site like every other engine refresh."""
         n = self.n_docs[i]
         ok = self._exists[i] & np.asarray(live, bool)
@@ -555,6 +587,8 @@ class KnnEngine:
             if self._fused:
                 upd = jax.device_put(upd, self._sharding)
             self.d_meta = upd
+            if self._dense[i] is not None:
+                self._dense[i] = self._dense[i][:2] + (jnp.asarray(ok),)
 
     # ---------------- scheduler hooks ----------------
 
@@ -636,22 +670,39 @@ class KnnEngine:
                 self.nw, KNN_W)
         return fm
 
-    def _dense_chunk(self, i: int, qf: np.ndarray, chunk, QC: int, k: int):
-        """The f32 brute-force route for one partition (solo dispatch)."""
-        self._ensure_dense(i)
+    def _dense_chunk(self, i: int, qfd, chunk, k: int):
+        """The f32 brute-force route for one partition (solo dispatch):
+        (scores, ords), each [len(chunk), k]. `qfd` is the
+        padded [QC, dims] query matrix, on the device already. The mask
+        stays there too: liveness is the mirror's resident `ok` row, and
+        only a chunk in which some query filters this partition uploads
+        anything — the filters, as bits, [QC, nb] u8 whatever the mix."""
         n = self.n_docs[i]
-        qmask = np.zeros((QC, max(n, 1)), bool)
-        for j, wk in enumerate(chunk):
-            m = self._ok[i]
-            if wk.filters is not None and wk.filters[i] is not None:
-                m = m & np.asarray(wk.filters[i], bool)
-            qmask[j, :n] = m
-        v, nrm, ex = self._dense[i]
+        if n == 0:
+            return (np.zeros((len(chunk), k), np.float32),
+                    np.zeros((len(chunk), k), np.int32))
+        self._ensure_dense(i)
+        fbits = None
+        flts = [wk.filters[i] if wk.filters is not None else None
+                for wk in chunk]
+        if any(f is not None for f in flts):
+            nb = _filter_bits_width(n)
+            fbits = np.full((qfd.shape[0], nb), 0xFF, np.uint8)
+            for j, f in enumerate(flts):
+                if f is not None:
+                    fbits[j] = _pack_filter(np.asarray(f, bool), nb)
+            _count("knn_dense_masked")
+            _count("knn_dense_mask_bytes", fbits.nbytes)
+        else:
+            _count("knn_dense_mask_free")
+        v, nrm, ok = self._dense[i]
         with faults.device_dispatch("knn_score", part=i):
-            ts, to = _dense_topk(jnp.asarray(qf), v, nrm, ex,
-                                 jnp.asarray(qmask),
-                                 similarity=self.similarity, k=k)
-            return np.asarray(ts), np.asarray(to)
+            ts, to = _dense_topk(
+                qfd, v, nrm, ok,
+                None if fbits is None else jnp.asarray(fbits),
+                similarity=self.similarity, k=k)
+            return (np.asarray(ts)[:len(chunk)],
+                    np.asarray(to)[:len(chunk)])
 
     def _run_chunk(self, chunk, QC: int, k: int, local_faults: List,
                    check=None):
@@ -671,7 +722,7 @@ class KnnEngine:
                 qf[j, :len(wk.vector)] = wk.vector
             if use_int8 and k <= C:
                 qi8, qmeta = self._quantize_queries(qf)
-                qfd = jnp.asarray(qf)
+            qfd = jnp.asarray(qf)
 
         if not use_int8 or k > C:
             # the f32 brute-force A/B path, verbatim per partition: one
@@ -681,8 +732,8 @@ class KnnEngine:
             with tracing.phase("dispatch.launch", qc=QC, route="dense"):
                 for i in range(S):
                     try:
-                        ds, do = self._dense_chunk(i, qf, chunk, QC, k)
-                        s_out[i], o_out[i] = ds[:n], do[:n]
+                        s_out[i], o_out[i] = self._dense_chunk(
+                            i, qfd, chunk, k)
                     except DeviceFaultError as e:
                         local_faults.append(
                             FaultRecord.from_error(e, partition=i))
@@ -809,7 +860,7 @@ class KnnEngine:
                     try:
                         with tracing.phase("dispatch.dense_rerun", partition=i,
                                            queries=len(bad)):
-                            ds, do = self._dense_chunk(i, qf, chunk, QC, k)
+                            ds, do = self._dense_chunk(i, qfd, chunk, k)
                         s_out[i][bad] = ds[bad]
                         o_out[i][bad] = do[bad]
                     except DeviceFaultError as e:

@@ -291,6 +291,186 @@ def test_ledger_matches_engine_bytes():
     assert node["hbm_bytes"] >= eng.hbm_bytes()
 
 
+# --------------------------------------------------------------------------
+# the dense route's mask stays on the device (PR 46)
+# --------------------------------------------------------------------------
+
+def _parent_dense(cols, lives, i, chunk, QC, k):
+    """The dense route as the parent built it: `knn_scores` + a HOST-built
+    [QC, n] mask (exists & live & the query's filter; pad rows all False)
+    + `top_k`, invalid slots zeroed. From the columns, not the engine's
+    device state. Returns the chunk's rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from elasticsearch_tpu.ops.knn import knn_scores
+
+    col = cols[i]
+    n = len(col.vectors)
+    if n == 0:
+        return (np.zeros((len(chunk), k), np.float32),
+                np.zeros((len(chunk), k), np.int32))
+    v = col.vectors.astype(np.float32)
+    if col.similarity == "cosine":
+        v = v / np.maximum(col.norms, 1e-20)[:, None]
+    ok = np.asarray(col.exists, bool)
+    if lives is not None and lives[i] is not None:
+        ok = ok & lives[i]
+    qf = np.zeros((QC, v.shape[1]), np.float32)
+    qmask = np.zeros((QC, n), bool)
+    for j, wk in enumerate(chunk):
+        qf[j] = wk.vector
+        m = ok
+        if wk.filters is not None and wk.filters[i] is not None:
+            m = m & np.asarray(wk.filters[i], bool)
+        qmask[j] = m
+    sc = knn_scores(jnp.asarray(qf), jnp.asarray(v).astype(jnp.bfloat16),
+                    jnp.asarray(col.norms), jnp.asarray(col.exists),
+                    similarity=col.similarity)
+    sc = jnp.where(jnp.asarray(qmask), sc, -jnp.inf)
+    ts, to = jax.lax.top_k(sc, k)
+    valid = np.asarray(ts) > -np.inf
+    return (np.where(valid, np.asarray(ts), 0.0)[:len(chunk)],
+            np.where(valid, np.asarray(to), 0)[:len(chunk)])
+
+
+def _never_certified(monkeypatch):
+    """Every (query, partition) pair of the int8 pass reads uncertified,
+    so the dense re-run decides every score `search_many` returns."""
+    real = knn_mod._rescore_program
+
+    def program(*a, **kw):
+        ts, to, cert = real(*a, **kw)
+        return ts, to, np.zeros(np.asarray(cert).shape, bool)
+
+    monkeypatch.setattr(knn_mod, "_rescore_program", program)
+
+
+# case -> (partition sizes, queries, which queries filter, deletes)
+_DENSE_CASES = {
+    "unfiltered": ([640, 520], 8, "none", None),
+    "all_filtered": ([640, 520], 8, "all", None),
+    "mixed_filters": ([640, 520], 8, "odd", None),
+    "short_chunk_pad_rows": ([640, 520], 3, "odd", None),
+    "empty_partition": ([0, 600], 8, "odd", None),
+    "n_not_multiple_of_8": ([613, 1027], 8, "odd", None),
+    "set_live_mirror_first": ([640, 520], 8, "odd", "after_mirror"),
+    "set_live_before_mirror": ([640, 520], 8, "none", "before_mirror"),
+}
+
+
+@pytest.mark.parametrize("similarity", ["cosine", "dot_product", "l2_norm"])
+@pytest.mark.parametrize("case", sorted(_DENSE_CASES))
+def test_dense_route_matches_host_mask_construction(case, similarity,
+                                                    monkeypatch):
+    """`_dense_chunk`, the uncertified re-run through `search_many` and
+    the ES_TPU_KNN_INT8=0 A/B route answer bit for bit what the parent's
+    construction answers: the resident `ok` row and the bit-packed filters
+    are the same mask as the host-built [QC, n] bool array."""
+    import jax.numpy as jnp
+
+    sizes, nq, who, deletes = _DENSE_CASES[case]
+    unit = similarity == "dot_product"
+    cols = _cols(sizes, similarity=similarity, seed=71, unit=unit)
+    qs = _queries(nq, seed=31, unit=unit)
+    rng = np.random.default_rng(83)
+    works = []
+    for j, q in enumerate(qs):
+        filtered = who == "all" or (who == "odd" and j % 2 == 1)
+        # a query may filter one partition and leave the other alone
+        flt = [rng.random(n) > 0.5 if (j + i) % 3 else None
+               for i, n in enumerate(sizes)] if filtered else None
+        works.append(KnnWork(q, filters=flt))
+    eng = KnnEngine(cols, qc_sizes=(8,))
+    QC = 8
+    qfd = np.zeros((QC, DIMS), np.float32)
+    qfd[:nq] = qs
+    qfd = jnp.asarray(qfd)
+    lives = None
+    if deletes:
+        if deletes == "after_mirror":
+            for i in range(len(sizes)):
+                eng._ensure_dense(i)
+        # delete what the live corpus ranks first, so the answer must move
+        lives = []
+        for i, n in enumerate(sizes):
+            _, top = _parent_dense(cols, None, i, works, QC, K)
+            live = np.ones(n, bool)
+            live[np.unique(top[:, :3])] = False
+            lives.append(live)
+            eng.set_live(i, live)
+    want = [_parent_dense(cols, lives, i, works, QC, K)
+            for i in range(len(sizes))]
+    for i in range(len(sizes)):
+        gs, go = eng._dense_chunk(i, qfd, works, K)
+        assert np.array_equal(gs, want[i][0]), f"{case}: scores, part {i}"
+        assert np.array_equal(go, want[i][1]), f"{case}: ords, part {i}"
+    if deletes:
+        assert all((np.take_along_axis(~lives[i][None, :], want[i][1], 1)
+                    & (want[i][0] > 0)).sum() == 0 for i in range(len(sizes)))
+    merged = eng._merge(np.stack([w[0] for w in want]),
+                        np.stack([w[1] for w in want]), K)
+    with monkeypatch.context() as mp:
+        _never_certified(mp)
+        knn_mod.reset_for_tests()
+        got = eng.search_many([works], k=K)[0]
+        live_parts = sum(1 for n in sizes if n)
+        assert knn_mod.knn_node_stats()["knn_uncertified"] == nq * live_parts
+    for g, w in zip(got, merged):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), \
+            f"{case}: re-run through search_many"
+    monkeypatch.setenv("ES_TPU_KNN_INT8", "0")
+    got = eng.search_many([works], k=K)[0]
+    for g, w in zip(got, merged):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), \
+            f"{case}: ES_TPU_KNN_INT8=0 route"
+
+
+def test_dense_route_uploads_no_mask_or_bits_only(monkeypatch):
+    """The mechanism: an unfiltered re-run hands the device nothing of
+    size QC x n (no mask operand at all); a filtered one hands it the
+    filters as bits, QC x `_filter_bits_width(n)` bytes whatever the mix.
+    The resident `ok` row is in the HBM ledger's count."""
+    n, QC = 1500, 8
+    cols = _cols([n], seed=89)
+    eng = KnnEngine(cols, qc_sizes=(QC,))
+    seen = []
+    real = knn_mod._dense_topk
+
+    def spy(*a, **kw):
+        seen.append([(getattr(x, "shape", None), getattr(x, "dtype", None))
+                     for x in a])
+        return real(*a, **kw)
+
+    monkeypatch.setattr(knn_mod, "_dense_topk", spy)
+    _never_certified(monkeypatch)
+    qs = _queries(5, seed=37)
+
+    knn_mod.reset_for_tests()
+    eng.search_many([[KnnWork(q) for q in qs]], k=K)
+    st = knn_mod.knn_node_stats()
+    assert st["knn_dense_mask_free"] == 1 and st["knn_dense_masked"] == 0
+    assert st["knn_dense_mask_bytes"] == 0
+    assert len(seen) == 1 and seen[0][4][0] is None, "a mask operand went up"
+    assert all(shape != (QC, n) for shape, _ in seen[0])
+    assert seen[0][3] == ((n,), np.dtype(bool)), "ok row not the resident one"
+
+    seen.clear()
+    flt = np.random.default_rng(97).random(n) > 0.5
+    works = [KnnWork(q, filters=[flt] if j == 2 else None)
+             for j, q in enumerate(qs)]
+    eng.search_many([works], k=K)
+    st = knn_mod.knn_node_stats()
+    nb = knn_mod._filter_bits_width(n)
+    assert nb == 256 and nb * 8 >= n
+    assert st["knn_dense_mask_free"] == 1 and st["knn_dense_masked"] == 1
+    assert st["knn_dense_mask_bytes"] == QC * nb
+    assert seen[0][4] == ((QC, nb), np.dtype(np.uint8))
+    assert all(shape != (QC, n) for shape, _ in seen[0])
+    assert eng._hbm.total_bytes() == eng.hbm_bytes()
+    assert eng._mirror_bytes() == n * (DIMS * 2 + 4 + 1)
+
+
 class TestServingFastPath:
     """REST-level knn bodies through IndexService: the quantized fast
     path (forced eligible via ES_TPU_FORCE_KNN) must match _search_dense
